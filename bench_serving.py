@@ -15,7 +15,13 @@ Since ISSUE 6 the harness also measures the restart story: a
 manifest — the first cold (empty cache), the second warm (pre-
 populated, manifest-replayed) — and records ``warmup_cold_s`` /
 ``warmup_warm_s`` as first-class fields (acceptance: warm <= 0.5x
-cold on the 5-bucket ladder).
+cold on the 5-bucket ladder).  A chip belongs to one process at a
+time, so this leg runs FIRST, before the parent touches jax: each
+probe child has the device to itself and has exited before the next
+process needs it.  Their shared cache is one fixed-name directory
+under the cache root (``JAX_COMPILATION_CACHE_DIR`` if set, else
+``<checkout>/.jax_cache``), emptied at the start of the cold leg — the
+path is part of jax's cache key, so a temp name could never hit.
 
 Since ISSUE 15 there is also a **multi-tenant leg** (``--multitenant``
 runs it standalone and merges into BENCH_SERVING.json): two tenant
@@ -35,9 +41,11 @@ within 3% req/s (the disarmed path is one boolean check per seam).
 Methodology mirrors bench.py: warmup excluded from measurement (every
 bucket compiled by ``warmup()`` before the clock starts), ONE JSON
 line on stdout win or lose, details written incrementally to
-BENCH_SERVING.json.  Runs on whatever platform jax selects — the
-relative claim (batched vs sequential on the SAME device) is
-platform-independent.  Small hosts are noisy (the capture box has 2
+BENCH_SERVING.json.  The stdout line and the JSON file carry the
+device jax reported (platform, device_kind, count): a number taken on
+``cpu`` is a host number, never a device metric, and only the relative
+claim (batched vs sequential on the SAME device) carries across
+platforms.  Small hosts are noisy (the capture box has 2
 cores shared by 64 client threads), so like bench.py's
 discard-first/median-of-readings rule each number is a multi-pass
 reading: the sequential baseline is the median of 3 passes, each
@@ -79,6 +87,14 @@ def _fail(reason, code):
     }))
     sys.stdout.flush()
     raise SystemExit(code)
+
+
+def _device():
+    """The device every number of this process was taken on."""
+    import jax
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
 
 
 def _build_model():
@@ -206,6 +222,7 @@ def _warmup_probe():
     wall = time.perf_counter() - t0
     print(json.dumps({
         "warmup_s": round(wall, 3),
+        "device": _device(),
         "warmed": len(warmed),
         "source": source,
         "compile_cache": compile_cache.stats(),
@@ -215,10 +232,15 @@ def _warmup_probe():
 
 def _measure_warm_restart():
     """Parent side of the warm-restart leg: two fresh subprocesses
-    sharing one compile cache dir + manifest."""
-    tmp = tempfile.mkdtemp(prefix="mxnet-bench-compile-cache-")
+    sharing one compile cache dir + manifest.  Must run before this
+    process touches jax (module docstring)."""
+    root = os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+        or os.path.join(HERE, ".jax_cache")
+    tmp = os.path.join(root, "bench_serving_restart")
+    shutil.rmtree(tmp, ignore_errors=True)      # the cold leg starts empty
     env = dict(os.environ)
-    env["MXNET_COMPILE_CACHE_DIR"] = os.path.join(tmp, "cache")
+    # placed from outside, as far as the children can tell
+    env["JAX_COMPILATION_CACHE_DIR"] = tmp
     env["MXNET_COMPILE_CACHE_MANIFEST"] = os.path.join(tmp, "warmup.json")
     legs = {}
     try:
@@ -261,6 +283,7 @@ def _multitenant_only():
         json.dump(result, f, indent=1)
     print(json.dumps({
         "metric": "serving_multitenant_rollback_s",
+        "device": _device(),
         "value": leg["canary"]["rollback_wall_s"],
         "unit": "s",
         "victim_req_per_sec": leg["per_tenant"]["tenantA"]["req_per_sec"],
@@ -376,6 +399,7 @@ def _tracing_only():
         json.dump(result, f, indent=1)
     print(json.dumps({
         "metric": "serving_tracing_overhead_pct",
+        "device": _device(),
         "value": leg["overhead_pct"],
         "unit": "%",
         "off_req_per_sec": leg["off"]["req_per_sec"],
@@ -518,6 +542,7 @@ def _generative_only():
         json.dump(result, f, indent=1)
     print(json.dumps({
         "metric": "serving_generative_decode_tokens_per_sec",
+        "device": _device(),
         "value": leg["decode_tokens_per_sec"],
         "unit": "tokens/s",
         "ttft_solo_p99_ms": leg["ttft_ms"]["solo_p99"],
@@ -538,9 +563,24 @@ def main():
         with open(OUT_PATH, "w") as f:
             json.dump(result, f, indent=1)
 
+    # warm-restart leg FIRST, while this process has not touched jax:
+    # the ISSUE-6 headline — a restarted replica's warmup with a
+    # pre-populated persistent compile cache vs cold
+    try:
+        legs = _measure_warm_restart()
+        result["warm_restart"] = legs
+        result["warmup_cold_s"] = legs["cold"]["warmup_s"]
+        result["warmup_warm_s"] = legs["warm"]["warmup_s"]
+        result["warmup_warm_ratio"] = round(
+            legs["warm"]["warmup_s"] / legs["cold"]["warmup_s"], 3)
+        checkpoint()
+    except Exception as exc:   # noqa: BLE001
+        _fail("warm-restart leg failed: %r" % (exc,), 6)
+
     try:
         from mxnet_tpu.serving import ModelServer
         symb, arg_params, aux_params = _build_model()
+        result["device"] = _device()
     except Exception as exc:   # noqa: BLE001
         _fail("model build failed: %r" % (exc,), 3)
 
@@ -581,19 +621,6 @@ def main():
     finally:
         srv.stop(drain=False)
 
-    # warm-restart leg: the ISSUE-6 headline — a restarted replica's
-    # warmup with a pre-populated persistent compile cache vs cold
-    try:
-        legs = _measure_warm_restart()
-        result["warm_restart"] = legs
-        result["warmup_cold_s"] = legs["cold"]["warmup_s"]
-        result["warmup_warm_s"] = legs["warm"]["warmup_s"]
-        result["warmup_warm_ratio"] = round(
-            legs["warm"]["warmup_s"] / legs["cold"]["warmup_s"], 3)
-        checkpoint()
-    except Exception as exc:   # noqa: BLE001
-        _fail("warm-restart leg failed: %r" % (exc,), 6)
-
     # multi-tenant leg: the ISSUE-15 drill evidence — quotas, a
     # poisoned canary's auto-rollback latency, per-tenant isolation
     try:
@@ -624,6 +651,7 @@ def main():
         "metric": "serving_resnet_req_per_sec_c64",
         "value": value,
         "unit": "req/s",
+        "device": result["device"],
         "p99_ms": c64[0]["p99_ms"],
         "vs_sequential": result["vs_sequential_c64"],
         "warmup_cold_s": result["warmup_cold_s"],

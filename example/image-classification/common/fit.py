@@ -110,6 +110,13 @@ def fit(args, network, data_loader, **kwargs):
     head = "%(asctime)-15s Node[" + str(kv.rank) + "] %(message)s"
     logging.basicConfig(level=logging.INFO, format=head)
     logging.info("start with arguments %s", args)
+    # what jax gave this process: bench.py reads this line and refuses
+    # a number from anything but a TPU; mx.tpu() below names a host
+    # device when there is no accelerator (how the CPU tests run)
+    import jax
+    devs = jax.devices()
+    logging.info("device platform=%s kind=%s count=%d", devs[0].platform,
+                 devs[0].device_kind, len(devs))
 
     (train, val) = data_loader(args, kv)
     if args.test_io:

@@ -24,7 +24,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 from common import data, fit  # noqa: E402
 
 
-def main():
+def main(argv=None, **fit_kwargs):
+    """Parse ``argv`` (default: the command line) and train; returns
+    the fitted Module.  ``fit_kwargs`` reach ``Module.fit`` (e.g. a
+    ``batch_end_callback``) — how ``chip_smoke.py`` drives this exact
+    path in-process."""
     parser = argparse.ArgumentParser(
         description="train imagenet-1k",
         formatter_class=argparse.ArgumentDefaultsHelpFormatter)
@@ -42,14 +46,14 @@ def main():
         num_epochs=1,
         batch_size=128,
     )
-    args = parser.parse_args()
+    args = parser.parse_args(argv)
 
     net_module = importlib.import_module("symbols." + args.network)
     sym = net_module.get_symbol(num_classes=args.num_classes,
                                 num_layers=args.num_layers,
                                 image_shape=args.image_shape,
                                 dtype=args.dtype)
-    fit.fit(args, sym, data.get_rec_iter)
+    return fit.fit(args, sym, data.get_rec_iter, **fit_kwargs)
 
 
 if __name__ == "__main__":

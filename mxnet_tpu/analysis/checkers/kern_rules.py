@@ -168,7 +168,7 @@ def shard_safety(report):
     equals the operand's block count along the axis.  Splitting the
     buffers 1/mesh then splits exactly that grid dimension — each
     shard's kernel reads and writes only its own blocks, so the wrap
-    (which must pass ``check_rep=False``: pallas_call has no
+    (which must pass ``check_vma=False``: pallas_call has no
     replication rule) cannot change any result.
 
     Returns ``{"candidate", "safe", "grid_dim", "reasons"}``.

@@ -20,7 +20,7 @@ Fact channels:
   attaches inside the backward stream is found where it actually
   lives.  A refactor that drops the constraint drops the eqn, and
   ``ir-collective-schedule`` fires.
-- **dtype drift** — tracing runs under ``jax.experimental.enable_x64``
+- **dtype drift** — tracing runs under ``jax.enable_x64``
   so an injected f64 is representable instead of silently truncated;
   forward bf16→f32 converts are promotions unless scoped deliberate
   (``DELIBERATE_CAST_SCOPES`` — the codec decode, the amp fp32-master
@@ -30,7 +30,7 @@ Fact channels:
   computed-but-unused work (a dropped residual/output) is visible as
   an eqn whose results reach no output; only flop-bearing eqns are
   reported (dead converts/broadcasts are trace lint, not lost work).
-- **pallas** — ``pallas_call`` kernel names (``name_and_src_info``),
+- **pallas** — ``pallas_call`` kernel names (the call's ``name``),
   found through wrapper sub-jaxprs too (``shard_map``/``pjit`` descend
   explicitly in ``_subjaxprs`` — the multi-chip fused sweep's kernels
   live inside a ``shard_map`` body).
@@ -70,7 +70,7 @@ _COLLECTIVE_PRIMS = {
 def _subjaxprs(eqn):
     """``(jaxpr, scale, estimated)`` children of one eqn.  scan bodies
     multiply by trip count; while/cond bodies count once (estimate)."""
-    import jax
+    import jax.extend
     name = eqn.primitive.name
     if name == "pallas_call":
         # the kernel body runs once per grid step; charging it flat
@@ -93,23 +93,22 @@ def _subjaxprs(eqn):
         # explicit, not left to the generic fallback: the per-shard /
         # inner program is where shard_map-wrapped Pallas kernels live
         # (the fused optimizer sweep on a multi-chip mesh), and
-        # ir-pallas-presence must see through the wrapper whatever
-        # param type this jax version uses (Jaxpr vs ClosedJaxpr)
+        # ir-pallas-presence must see through the wrapper (shard_map
+        # carries a Jaxpr, pjit a ClosedJaxpr)
         body = eqn.params.get("jaxpr")
         if body is not None:
             out.append((body, 1, False))
             return out
     for v in eqn.params.values():
-        if isinstance(v, jax.core.ClosedJaxpr):
-            out.append((v, 1, False))
-        elif isinstance(v, jax.core.Jaxpr):
+        if isinstance(v, (jax.extend.core.ClosedJaxpr,
+                          jax.extend.core.Jaxpr)):
             out.append((v, 1, False))
     return out
 
 
 def _inner(jaxpr):
-    import jax
-    return jaxpr.jaxpr if isinstance(jaxpr, jax.core.ClosedJaxpr) \
+    import jax.extend
+    return jaxpr.jaxpr if isinstance(jaxpr, jax.extend.core.ClosedJaxpr) \
         else jaxpr
 
 
@@ -227,10 +226,8 @@ def collect_facts(closed_jaxpr, f64_allow=(), deliberate=None):
                     (name, flops, eqn_bytes(eqn), scale, estimated))
 
             if name == "pallas_call":
-                info = str(eqn.params.get(
-                    "name_and_src_info",
-                    eqn.params.get("name", "pallas")))
-                kernel = info.split(" at ")[0].strip()
+                # every in-tree pallas_call passes its kernel's name
+                kernel = str(eqn.params["name"])
                 if kernel not in seen_pallas:
                     seen_pallas.add(kernel)
                     facts["pallas"].append(kernel)
@@ -400,13 +397,12 @@ def trace_program(jit_fn, args, name, kind="program", origin="",
     import contextlib
 
     import jax
-    from jax.experimental import enable_x64
 
     if f64_allow is None:
         from ... import config as _config
         raw = _config.get("MXNET_IR_F64_ALLOWLIST") or ""
         f64_allow = tuple(s.strip() for s in raw.split(",") if s.strip())
-    ctx = enable_x64() if x64 else contextlib.nullcontext()
+    ctx = jax.enable_x64(True) if x64 else contextlib.nullcontext()
     with ctx:
         traced = jit_fn.trace(*args, **(kwargs or {}))
         facts = collect_facts(traced.jaxpr, f64_allow=f64_allow)

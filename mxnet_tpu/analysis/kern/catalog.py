@@ -199,21 +199,28 @@ def flash_reports(bh=8, tq=512, tk=512, d=64, bq=128, bk=128):
 
 # -- inference BatchNorm+ReLU epilogue -------------------------------------
 
-def scale_bias_relu_reports(n=4096, c=64, block=1024):
+def scale_bias_relu_reports(n=16 * 7 * 7, c=2048, block=1024):
+    """The epilogue at ResNet-50's widest eval shape (last stage,
+    serving bucket 16): a (784, 2048) fp32 array, where a block choice
+    blind to the width would not fit VMEM."""
+    import numpy as np
+
     from mxnet_tpu.ops import pallas_kernels as pk
-    bn = pk._pick_block(n, block)
-    elems = n * c
+    bn = pk._row_block(n, c, np.float32, block)
+    npad = n + (-n) % bn
     return [_report(
         "_scale_bias_relu_kernel", "MXNET_PALLAS_BN_RELU",
-        pk.scale_bias_relu_plan(n, c, bn),
+        pk.scale_bias_relu_plan(npad, c, bn),
         ("x", "scale", "bias"), ("y",),
         python_constants=[
             {"name": "relu", "detail": "structural branch: the "
                                        "epilogue with/without "
                                        "activation"}],
-        tail={"logical_elems": elems, "padded_elems": elems,
+        tail={"logical_elems": n * c, "padded_elems": npad * c,
               "masked": True,
-              "how": "no padding: _pick_block divides N exactly"})]
+              "how": "_row_block prefers a divisor of N; otherwise "
+                     "zero pad rows (_pad_rows) — a row-wise "
+                     "elementwise pass, pad sliced away on return"})]
 
 
 # -- fused layernorm -------------------------------------------------------

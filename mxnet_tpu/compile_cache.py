@@ -14,7 +14,23 @@ This module wires jax's persistent compilation cache
 ``MXNET_COMPILE_CACHE_*`` knobs, initialized once from the executor's
 bind path so EVERY jit in the stack — executor fwd/train/fused-step,
 kvstore reduce, serving binds — reads and writes one shared on-disk
-cache.  On top of the raw wiring it adds what jax leaves out:
+cache.
+
+Placement (:func:`placement`), first match wins:
+
+1. ``JAX_COMPILATION_CACHE_DIR`` set — the cache was placed from
+   outside (a machine that keeps it between runs).  jax reads that
+   variable itself; this module sets NO directory of its own, it only
+   attaches thresholds, hygiene, counters and ``stats()["dir"]`` to it.
+2. ``MXNET_COMPILE_CACHE_DIR`` set — that directory; set to the empty
+   string it turns the cache off (what tier-1's conftest does, so the
+   CPU test suite does not fill the checkout).
+3. neither — ``<checkout>/.jax_cache``: one fixed, git-ignored path.
+   The directory is part of jax's cache key, so a temp name, pid or
+   timestamp would never hit; its size cap is clamped to
+   ``_DEFAULT_DIR_MAX_BYTES`` so the checkout stays copyable.
+
+On top of the raw wiring it adds what jax leaves out:
 
 - **hygiene** — a size cap (``MXNET_COMPILE_CACHE_MAX_BYTES``) with
   LRU eviction by recency (jax touches a ``-atime`` sibling per read;
@@ -46,8 +62,17 @@ import logging
 import os
 import threading
 
-__all__ = ["ensure_initialized", "configure", "enabled", "cache_dir",
-           "stats", "sweep", "reset"]
+__all__ = ["ensure_initialized", "configure", "placement", "enabled",
+           "cache_dir", "stats", "sweep", "reset", "DEFAULT_DIR"]
+
+_EXTERNAL_ENV = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+# the in-checkout default is copied along with the checkout by
+# whatever ships it to a machine; hold it well under such a copy's
+# budget whatever MXNET_COMPILE_CACHE_MAX_BYTES says
+_DEFAULT_DIR_MAX_BYTES = 128 * 1024 * 1024
 
 _LOCK = threading.Lock()
 _INIT_LOCK = threading.Lock()   # serializes first-time configuration so
@@ -133,11 +158,8 @@ def _install_listener():
         if _STATE["listener"]:
             return
         _STATE["listener"] = True
-    try:
-        from jax._src import monitoring
-        monitoring.register_event_listener(_on_jax_event)
-    except (ImportError, AttributeError):   # jax drift: counts stay 0
-        pass
+    from jax._src import monitoring
+    monitoring.register_event_listener(_on_jax_event)
 
 
 def _install_error_hooks():
@@ -147,15 +169,12 @@ def _install_error_hooks():
     ``raise_persistent_cache_errors`` is off) but exposes no counter;
     wrapping the two entry points gives exact error accounting without
     changing behavior — exceptions are re-raised for jax's own
-    handling.  Degrades to no accounting if jax's internals drift."""
+    handling."""
     with _LOCK:
         if _STATE["hooks"]:
             return
         _STATE["hooks"] = True
-    try:
-        from jax._src import compilation_cache as _cc
-    except ImportError:
-        return
+    from jax._src import compilation_cache as _cc
 
     def _wrap(orig):
         def wrapper(*args, **kwargs):
@@ -168,56 +187,80 @@ def _install_error_hooks():
         return wrapper
 
     for name in ("get_executable_and_time", "put_executable_and_time"):
-        orig = getattr(_cc, name, None)
-        if orig is not None and not getattr(
-                orig, "_mxnet_compile_cache_hook", False):
+        orig = getattr(_cc, name)
+        if not getattr(orig, "_mxnet_compile_cache_hook", False):
             setattr(_cc, name, _wrap(orig))
 
 
 def _reset_jax_cache():
     """Drop jax's in-memory handle on the cache dir so a config change
     takes effect (jax latches the directory on first use)."""
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except (ImportError, AttributeError):   # version drift; next init latches
-        pass
+    from jax._src import compilation_cache as _cc
+    _cc.reset_cache()
+
+
+def placement():
+    """``(directory, source)`` of the cache as the environment places
+    it right now (module docstring): source ``"external"``
+    (``JAX_COMPILATION_CACHE_DIR`` — the directory is read back from
+    jax, which owns it), ``"knob"`` (``MXNET_COMPILE_CACHE_DIR``; the
+    empty string gives directory None, cache off) or ``"default"``
+    (:data:`DEFAULT_DIR`)."""
+    if os.environ.get(_EXTERNAL_ENV):
+        import jax
+        return jax.config.jax_compilation_cache_dir, "external"
+    from . import config as _config
+    knob = _config.get("MXNET_COMPILE_CACHE_DIR")
+    if knob is None:
+        return DEFAULT_DIR, "default"
+    return (os.path.abspath(knob) if knob else None), "knob"
 
 
 def ensure_initialized():
-    """Read the ``MXNET_COMPILE_CACHE_*`` knobs and wire jax's
-    persistent cache, once per process — called from the executor's
-    bind path, so the first bind of anything (trainer, server, kvstore)
-    turns the cache on for every jit after it.  Returns whether the
-    cache is enabled.  After the first call this is one dict read;
-    concurrent first binds WAIT on the init lock instead of racing
-    ahead and compiling cold before the cache config lands."""
+    """Place the cache (:func:`placement`) and wire thresholds,
+    hygiene and counters to it, once per process — called from the
+    executor's bind path, so the first bind of anything (trainer,
+    server, kvstore) turns the cache on for every jit after it.
+    Returns whether the cache is enabled.  After the first call this is
+    one dict read; concurrent first binds WAIT on the init lock instead
+    of racing ahead and compiling cold before the cache config lands."""
     if _STATE["checked"]:
         return _STATE["enabled"]
     with _INIT_LOCK:
         if _STATE["checked"]:
             return _STATE["enabled"]
-        from . import config as _config
-        return configure(_config.get("MXNET_COMPILE_CACHE_DIR"))
+        return configure(placement()[0])
+
+
+def _commit_disabled(external):
+    import jax
+    if not external:
+        jax.config.update("jax_compilation_cache_dir", None)
+        _reset_jax_cache()
+    with _LOCK:        # checked last: it is the commit marker the
+        _STATE["enabled"] = False      # lock-free fast path trusts
+        _STATE["dir"] = None
+        _STATE["checked"] = True
+    return False
 
 
 def configure(directory, min_compile_secs=None, min_entry_bytes=None,
               max_bytes=None):
     """Point jax's persistent compile cache at ``directory`` (None/empty
-    disables).  Unset thresholds come from the ``MXNET_COMPILE_CACHE_*``
-    knobs.  A directory that cannot be created or written disables the
-    cache with a warning — a bad cache mount must degrade a replica to
-    cold compiles, never crash it.  Returns whether the cache is on."""
+    disables).  Where ``JAX_COMPILATION_CACHE_DIR`` is set the cache was
+    placed from outside: ``directory`` is ignored, jax's own setting is
+    left untouched and everything below attaches to that directory.
+    Unset thresholds come from the ``MXNET_COMPILE_CACHE_*`` knobs.  A
+    directory that cannot be created or written disables the cache with
+    a warning — a bad cache mount must degrade a replica to cold
+    compiles, never crash it.  Returns whether the cache is on."""
     import jax
     from . import config as _config
+    external = bool(os.environ.get(_EXTERNAL_ENV))
+    if external:
+        directory = jax.config.jax_compilation_cache_dir
     if not directory:
-        jax.config.update("jax_compilation_cache_dir", None)
-        _reset_jax_cache()
-        with _LOCK:        # checked last: it is the commit marker the
-            _STATE["enabled"] = False      # lock-free fast path trusts
-            _STATE["dir"] = None
-            _STATE["checked"] = True
-        return False
+        return _commit_disabled(external)
     directory = os.path.abspath(directory)
     if min_compile_secs is None:
         min_compile_secs = _config.get("MXNET_COMPILE_CACHE_MIN_COMPILE_SECS")
@@ -225,6 +268,9 @@ def configure(directory, min_compile_secs=None, min_entry_bytes=None,
         min_entry_bytes = _config.get("MXNET_COMPILE_CACHE_MIN_ENTRY_BYTES")
     if max_bytes is None:
         max_bytes = _config.get("MXNET_COMPILE_CACHE_MAX_BYTES")
+    if directory == DEFAULT_DIR:
+        max_bytes = min(int(max_bytes), _DEFAULT_DIR_MAX_BYTES) \
+            if int(max_bytes) > 0 else _DEFAULT_DIR_MAX_BYTES
     try:
         os.makedirs(directory, exist_ok=True)
         probe = os.path.join(directory, ".mxnet-cache-probe-%d" % os.getpid())
@@ -236,22 +282,18 @@ def configure(directory, min_compile_secs=None, min_entry_bytes=None,
         logging.warning(
             "compile cache disabled: %r is not a writable directory (%s); "
             "every process will pay cold compiles", directory, exc)
-        jax.config.update("jax_compilation_cache_dir", None)
-        _reset_jax_cache()
-        with _LOCK:
-            _STATE["enabled"] = False
-            _STATE["dir"] = None
-            _STATE["checked"] = True
-        return False
-    jax.config.update("jax_enable_compilation_cache", True)
-    jax.config.update("jax_compilation_cache_dir", directory)
+        return _commit_disabled(external)
+    if not external:
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_compilation_cache_dir", directory)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       float(min_compile_secs))
     jax.config.update("jax_persistent_cache_min_entry_size_bytes",
                       int(min_entry_bytes))
     # corruption/IO errors must degrade to a cold compile, not raise
     jax.config.update("jax_raise_persistent_cache_errors", False)
-    _reset_jax_cache()
+    if not external:
+        _reset_jax_cache()
     _declare_counters()
     _install_listener()
     _install_error_hooks()
@@ -367,10 +409,13 @@ def stats(refresh=True):
 
 def reset():
     """Test hook: disable the cache and zero the counters so the next
-    :func:`ensure_initialized` re-reads the environment."""
-    import jax
-    jax.config.update("jax_compilation_cache_dir", None)
-    _reset_jax_cache()
+    :func:`ensure_initialized` re-reads the environment.  An externally
+    placed cache (``JAX_COMPILATION_CACHE_DIR``) is jax's to keep: only
+    this module's view of it is forgotten."""
+    if not os.environ.get(_EXTERNAL_ENV):
+        import jax
+        jax.config.update("jax_compilation_cache_dir", None)
+        _reset_jax_cache()
     with _LOCK:
         _STATE["checked"] = False
         _STATE["enabled"] = False

@@ -238,9 +238,11 @@ register_env("MXNET_CKPT_WATCH_INTERVAL_S", float, 10.0,
              "poll period of serving ModelRegistry.watch_checkpoints "
              "for newly committed checkpoint versions")
 register_env("MXNET_COMPILE_CACHE_DIR", str, None,
-             "directory for the persistent XLA compile cache; when set, "
-             "compiled executables are cached on disk and a restarted "
-             "process warm-starts instead of recompiling "
+             "directory for the persistent XLA compile cache: compiled "
+             "executables are cached on disk and a restarted process "
+             "warm-starts instead of recompiling.  jax's own "
+             "JAX_COMPILATION_CACHE_DIR wins over it; unset = "
+             "<checkout>/.jax_cache; empty = cache off "
              "(docs/faq/compile_cache.md)")
 register_env("MXNET_COMPILE_CACHE_MIN_COMPILE_SECS", float, 0.0,
              "only compiles at least this slow are persisted (0 caches "

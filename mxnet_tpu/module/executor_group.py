@@ -231,8 +231,15 @@ class DataParallelExecutorGroup:
 
     def get_params(self, arg_params, aux_params):
         """Average params back from devices (reference: :453)."""
+        owned = {}
+        if len(self.execs) == 1 and self.execs[0].donates_weights:
+            # the fused step deletes these buffers on its next
+            # dispatch; what leaves the executor must be a copy
+            from ..ndarray.ndarray import _copy_buffers, _wrap
+            owned = dict(zip(self.param_names, map(_wrap, _copy_buffers(
+                tuple(block[0]._data for block in self.param_arrays)))))
         for name, block in zip(self.param_names, self.param_arrays):
-            weight = block[0]
+            weight = owned.get(name, block[0])
             if len(block) > 1:
                 weight = block[0].copy()
                 for w in block[1:]:
@@ -241,7 +248,9 @@ class DataParallelExecutorGroup:
             arg_params[name] = weight.astype(arg_params[name].dtype) \
                 if name in arg_params else weight
         for name, block in zip(self.aux_names, self.aux_arrays):
-            weight = block[0]
+            # a snapshot, not the executor's own NDArray: that one is
+            # rebound to the new moving stats by every training step
+            weight = block[0].detach()
             if len(block) > 1:
                 weight = block[0].copy()
                 for w in block[1:]:

@@ -47,6 +47,7 @@ kernel uses.
 from __future__ import annotations
 
 import functools
+import logging
 import math
 
 import jax
@@ -114,6 +115,12 @@ def _sweep_shard_verdict():
             from ..analysis.kern import sweep_shard_verdict
             _SWEEP_SHARD_VERDICT = bool(sweep_shard_verdict()["safe"])
         except Exception:
+            # an analysis crash is reported, not swallowed: the price
+            # of the fallback is every multi-chip bucket updating
+            # through tree_map
+            logging.getLogger(__name__).exception(
+                "graftkern sweep-shard analysis failed; multi-chip "
+                "fused sweep disabled (tree_map fallback)")
             _SWEEP_SHARD_VERDICT = False
     return _SWEEP_SHARD_VERDICT
 
@@ -134,10 +141,7 @@ def mesh_sweep_safe(mesh_size):
 
 
 def _on_tpu():
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _interpret():
@@ -280,6 +284,44 @@ def _pick_block(t, pref):
     return max(b, 1)
 
 
+def _sublane(dtype):
+    """Rows of one native VMEM tile: 8 for 4-byte elements, 16 for
+    bf16/f16, 32 for 1-byte types (narrow types pack along sublanes).
+    A row-block must be a multiple of it or span the whole array."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def flash_seq_ok(t, dtype, pref=128):
+    """Whether :func:`flash_attention` can tile a length-``t`` sequence
+    of ``dtype`` natively: the halving block choice must land on whole
+    sublane tiles (or on the whole sequence)."""
+    b = _pick_block(t, pref)
+    return b == t or b % _sublane(dtype) == 0
+
+
+# one operand block, counted at the f32 width the kernels compute in:
+# in + out blocks double-buffered stay a small fraction of the 16 MiB
+# of scoped VMEM whatever the channel count
+_BLOCK_BYTES = 512 * 1024
+
+
+def _row_block(n, c, dtype, pref):
+    """Rows per grid step of a row-blocked pass over an (n, c) array:
+    at most ``pref`` and at most ``_BLOCK_BYTES`` worth of ``c``-wide
+    rows, in whole sublane tiles of ``dtype``.  An array that fits one
+    block is taken whole (always a legal block shape); otherwise the
+    largest tile-multiple divisor of ``n`` within 8x of the cap (no
+    padding), else the cap itself and the caller pads the rows."""
+    sub = _sublane(dtype)
+    cap = max(sub, min(int(pref), _BLOCK_BYTES // (4 * c)) // sub * sub)
+    if n <= cap:
+        return n
+    for b in range(cap, max(cap // 8, sub) - 1, -sub):
+        if n % b == 0:
+            return b
+    return cap
+
+
 def _qspec(bq, d):
     return pl.BlockSpec((None, bq, d), lambda b, i, j: (b, i, 0))
 
@@ -379,6 +421,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
         ],
         scratch_shapes=[pltpu.VMEM(s, jnp.float32)
                         for s in plan["scratch"]],
+        name="_flash_fwd_kernel",
         interpret=_interpret(),
     )(q, k, v)
     return o, (q, k, v, o, lse)
@@ -410,6 +453,7 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, res, do):
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM(sh, jnp.float32)
                         for sh in dq_plan["scratch"]],
+        name="_flash_bwd_dq_kernel",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
     dkv_plan = flash_bwd_dkv_plan(bh, tq, tk, d, bq, bk)
@@ -425,6 +469,7 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, res, do):
         ],
         scratch_shapes=[pltpu.VMEM(sh, jnp.float32)
                         for sh in dkv_plan["scratch"]],
+        name="_flash_bwd_dkv_kernel",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
@@ -467,17 +512,20 @@ def fused_scale_bias_relu(x, scale, bias, relu=True, block=1024):
     """
     _count("fused_scale_bias_relu")
     n, c = x.shape
-    bn = _pick_block(n, block)
+    bn = _row_block(n, c, x.dtype, block)
+    xp = _pad_rows(x, bn)
     kernel = functools.partial(_scale_bias_relu_kernel, relu=relu)
-    plan = scale_bias_relu_plan(n, c, bn)
-    return pl.pallas_call(
+    plan = scale_bias_relu_plan(xp.shape[0], c, bn)
+    out = pl.pallas_call(
         kernel,
         grid=plan["grid"],
         in_specs=plan["in_specs"],
         out_specs=plan["out_specs"][0],
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        out_shape=jax.ShapeDtypeStruct(xp.shape, x.dtype),
+        name="_scale_bias_relu_kernel",
         interpret=_interpret(),
-    )(x, scale.reshape(1, c), bias.reshape(1, c))
+    )(xp, scale.reshape(1, c), bias.reshape(1, c))
+    return out[:n]
 
 
 # ---------------------------------------------------------------------------
@@ -598,6 +646,7 @@ def _sweep_call_single(kernel, hyper, *flats, n_outs, block_elems):
             in_specs=plan["in_specs"], out_specs=plan["out_specs"]),
         out_shape=[jax.ShapeDtypeStruct((padded_rows, LANES),
                                         jnp.float32)] * n_outs,
+        name=kernel.func.__name__,
         interpret=_interpret(),
     )(hyper, *[_to_rows(f, padded_rows) for f in flats])
     return tuple(o.reshape(-1)[:n] for o in outs)
@@ -609,7 +658,7 @@ def _sweep_call(kernel, hyper, flats, n_outs, block_elems, mesh=None):
     With a multi-device ``mesh`` the sweep runs under ``shard_map``:
     every chip sweeps its contiguous 1/mesh shard of each buffer with
     the same kernel (hyperparameters replicated), the exact ZeRO
-    layout the trainer's bucket plan hands in.  ``check_rep=False`` is
+    layout the trainer's bucket plan hands in.  ``check_vma=False`` is
     mandatory — pallas_call has no replication rule — which is
     precisely the unproven-safety gap graftkern closes: the
     ``kern-shard-safety`` verdict (block-local index maps along the
@@ -624,17 +673,16 @@ def _sweep_call(kernel, hyper, flats, n_outs, block_elems, mesh=None):
                 "bucket length (%d) padded to a mesh multiple — the "
                 "bucket plan's pad_multiple contract"
                 % (mesh.size, n))
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec
         axes = PartitionSpec(tuple(mesh.axis_names))
         local = functools.partial(_sweep_call_single, kernel,
                                   n_outs=n_outs,
                                   block_elems=block_elems)
-        outs = shard_map(
+        outs = jax.shard_map(
             local, mesh=mesh,
             in_specs=(PartitionSpec(),) + (axes,) * len(flats),
             out_specs=(axes,) * n_outs,
-            check_rep=False)(hyper, *flats)
+            check_vma=False)(hyper, *flats)
         return list(outs)
     return list(_sweep_call_single(kernel, hyper, *flats, n_outs=n_outs,
                                    block_elems=block_elems))
@@ -716,13 +764,15 @@ def _pad_rows(x2, br):
     return x2
 
 
-def _norm_block_rows(r, c, knob, value=None):
+def _norm_block_rows(r, c, knob, value=None, dtype=jnp.float32):
     # `value` lets grafttune price a CANDIDATE block size through the
     # exact production clamp without touching the process env
+    sub = _sublane(dtype)
     br = _knob(knob) if value is None else value
     if not br or br <= 0:
-        br = max(8, min(256, (512 * 1024 // max(4 * c, 1)) // 8 * 8))
-    return max(8, min(int(br), -(-r // 8) * 8))
+        br = min(256, _BLOCK_BYTES // max(4 * c, 1))
+    br = max(sub, int(br) // sub * sub)
+    return min(br, -(-r // sub) * sub)
 
 
 def _norm_specs(br, c):
@@ -797,7 +847,8 @@ def _layernorm_fwd(x, gamma, beta, eps):
     c = x.shape[-1]
     x2 = x.reshape(-1, c)
     r = x2.shape[0]
-    br = _norm_block_rows(r, c, "MXNET_PALLAS_NORM_BLOCK_ROWS")
+    br = _norm_block_rows(r, c, "MXNET_PALLAS_NORM_BLOCK_ROWS",
+                          dtype=x.dtype)
     x2p = _pad_rows(x2, br)
     rp = x2p.shape[0]
     plan = layernorm_fwd_plan(rp, c, br)
@@ -811,6 +862,7 @@ def _layernorm_fwd(x, gamma, beta, eps):
             jax.ShapeDtypeStruct((rp, LANES), jnp.float32),
             jax.ShapeDtypeStruct((rp, LANES), jnp.float32),
         ],
+        name="_layernorm_fwd_kernel",
         interpret=_interpret(),
     )(x2p, gamma.reshape(1, c), beta.reshape(1, c))
     return out[:r].reshape(x.shape), (x, gamma, mu[:r], rstd[:r])
@@ -837,7 +889,8 @@ def _fused_layernorm_bwd_rule(eps, res, do):
     x2 = x.reshape(-1, c)
     do2 = do.reshape(-1, c)
     r = x2.shape[0]
-    br = _norm_block_rows(r, c, "MXNET_PALLAS_NORM_BLOCK_ROWS")
+    br = _norm_block_rows(r, c, "MXNET_PALLAS_NORM_BLOCK_ROWS",
+                          dtype=x.dtype)
     x2p = _pad_rows(x2, br)
     do2p = _pad_rows(do2, br)
     mup = _pad_rows(mu, br)
@@ -850,6 +903,7 @@ def _fused_layernorm_bwd_rule(eps, res, do):
         in_specs=plan["in_specs"],
         out_specs=plan["out_specs"][0],
         out_shape=jax.ShapeDtypeStruct((rp, c), x.dtype),
+        name="_layernorm_bwd_kernel",
         interpret=_interpret(),
     )(x2p, do2p, gamma.reshape(1, c), mup, rsp)
     xhat = (x2.astype(jnp.float32) - mu[:, :1]) * rstd[:, :1]
@@ -928,7 +982,8 @@ def _softmax_call(kernel3, ops, col_fill, bias=None):
                 [bias, jnp.zeros((bias.shape[0], cpad), bias.dtype)],
                 axis=1)
     c = c0 + cpad
-    br = _norm_block_rows(r, c, "MXNET_PALLAS_SOFTMAX_BLOCK_ROWS")
+    br = _norm_block_rows(r, c, "MXNET_PALLAS_SOFTMAX_BLOCK_ROWS",
+                          dtype=ops[0].dtype)
     rpad = (-r) % br
     if rpad:
         ops = [jnp.concatenate([a, jnp.zeros((b, rpad, c), a.dtype)],
@@ -947,6 +1002,7 @@ def _softmax_call(kernel3, ops, col_fill, bias=None):
         in_specs=plan["in_specs"],
         out_specs=plan["out_specs"][0],
         out_shape=jax.ShapeDtypeStruct((b, rp, c), ops[0].dtype),
+        name=kernel3.__name__,
         interpret=_interpret(),
     )(*args)
     return out[:, :r, :c0]

@@ -27,25 +27,21 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .mesh import shard_map as _shard_map_compat
 
 __all__ = ["ring_attention", "ulysses_attention", "local_attention"]
 
 
 def _flash_eligible(q, k, causal, q_offset, kv_offset):
     """Flash path: TPU backend, aligned offsets (the kernel's causal mask
-    assumes a shared origin), block-divisible sequence lengths."""
-    try:
-        import jax as _jax
-        if _jax.default_backend() != "tpu":
-            return False
-    except Exception:  # pragma: no cover
+    assumes a shared origin), sequence lengths the kernel's halving
+    block choice tiles in whole sublane tiles of the operand dtype."""
+    from ..ops.pallas_kernels import flash_seq_ok
+    if jax.default_backend() != "tpu":
         return False
     if causal and (q_offset != 0 or kv_offset != 0):
         return False
-    # kernel picks halving block sizes; power-of-two-divisible lengths
-    # keep the grid exact
-    return q.shape[1] % 8 == 0 and k.shape[1] % 8 == 0
+    return flash_seq_ok(q.shape[1], q.dtype) \
+        and flash_seq_ok(k.shape[1], k.dtype)
 
 
 def local_attention(q, k, v, causal=False, q_offset=0, kv_offset=0,
@@ -256,7 +252,7 @@ def ring_attention(q, k, v, mesh=None, axis_name="sp", causal=False,
     # single device (jit outputs are), which shard_map rejects
     sharding = NamedSharding(mesh, spec)
     q, k, v = (jax.device_put(a, sharding) for a in (q, k, v))
-    fn = _shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(_ring_attention_local, axis_name=axis_name,
                           causal=causal, scale=scale, kv_len=kv_len),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
@@ -300,7 +296,7 @@ def ulysses_attention(q, k, v, mesh=None, axis_name="sp", causal=False,
     spec = P(None, axis_name, None, None)
     sharding = NamedSharding(mesh, spec)
     q, k, v = (jax.device_put(a, sharding) for a in (q, k, v))
-    fn = _shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(_ulysses_local, axis_name=axis_name, causal=causal,
                           scale=scale, kv_len=kv_len),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

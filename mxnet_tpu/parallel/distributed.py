@@ -40,12 +40,9 @@ def init_distributed(coordinator_address=None, num_processes=None,
         # single-process: nothing to do, collectives stay intra-process
         _STATE["initialized"] = True
         return
-    try:
-        # CPU backend needs an explicit cross-process collective transport
-        # (gloo); harmless on TPU where ICI/DCN collectives are native
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # pragma: no cover - older/newer jax w/o the flag
-        pass
+    # CPU backend needs an explicit cross-process collective transport
+    # (gloo); harmless on TPU where ICI/DCN collectives are native
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id,

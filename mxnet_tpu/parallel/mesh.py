@@ -19,43 +19,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..base import MXNetError
 
-__all__ = ["shard_map",
-           "make_mesh", "current_mesh", "mesh_scope", "replicated",
+__all__ = ["make_mesh", "current_mesh", "mesh_scope", "replicated",
            "batch_sharded", "P", "NamedSharding", "Mesh"]
 
 AXES = ("dp", "fsdp", "tp", "pp", "sp", "ep")
 
 _CURRENT = []
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma=None):
-    """Version-compat shard_map: newer jax exposes ``jax.shard_map``
-    (replication check flag ``check_vma``), older jax only
-    ``jax.experimental.shard_map.shard_map`` (same flag named
-    ``check_rep``).  Every shard_map in this tree goes through here so
-    the parallel layers run on both."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-
-
-def pcast_varying(x, axis):
-    """Mark ``x`` device-varying over ``axis`` inside a shard_map body.
-    Newer jax requires the explicit ``lax.pcast(..., to="varying")``
-    type ascription (e.g. for a scan carry that differs per stage);
-    older jax has no varying-type system — the value already behaves
-    that way, so this is the identity there."""
-    import jax.lax as lax
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axis, to="varying")
-    if hasattr(lax, "pvary"):          # brief intermediate spelling
-        return lax.pvary(x, axis)
-    return x
 
 
 def make_mesh(dp=None, tp=1, pp=1, sp=1, ep=1, fsdp=1, devices=None):

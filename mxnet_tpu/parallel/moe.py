@@ -26,7 +26,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..base import MXNetError
-from .mesh import shard_map as _shard_map_compat
 from .pipeline import stack_stages as stack_experts  # same stacking helper
 
 __all__ = ["switch_moe", "stack_experts"]
@@ -79,7 +78,7 @@ def switch_moe(x, gate_w, expert_params, expert_fn, mesh,
         return out
 
     spec_params = jax.tree.map(lambda _: P(axis), expert_params)
-    fn = _shard_map_compat(per_device, mesh=mesh,
+    fn = jax.shard_map(per_device, mesh=mesh,
                        in_specs=(P(axis), P(), spec_params),
                        out_specs=P(axis))
     return fn(x, gate_w, expert_params)

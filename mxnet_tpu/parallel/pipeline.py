@@ -24,8 +24,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..base import MXNetError
-from .mesh import pcast_varying as _pcast_varying
-from .mesh import shard_map as _shard_map_compat
 
 __all__ = ["pipeline_apply", "stack_stages"]
 
@@ -81,15 +79,15 @@ def pipeline_apply(stage_fn, stacked_params, x, mesh, num_microbatches=None,
 
         # the carry is device-varying under shard_map (each stage holds
         # different activations), so the init must be typed as such
-        init = (_pcast_varying(jnp.zeros(mb_shape, x.dtype), axis),
-                _pcast_varying(jnp.zeros(mbs.shape, x.dtype), axis))
+        init = (lax.pcast(jnp.zeros(mb_shape, x.dtype), axis, to="varying"),
+                lax.pcast(jnp.zeros(mbs.shape, x.dtype), axis, to="varying"))
         (_, outs), _ = lax.scan(body, init, jnp.arange(M + S - 1))
         # result lives on the last stage only; psum replicates it (and
         # transposes to an identity-on-last-stage in backward)
         return lax.psum(jnp.where(idx == S - 1, outs, 0), axis)
 
     spec_params = jax.tree.map(lambda _: P(axis), stacked_params)
-    fn = _shard_map_compat(per_stage, mesh=mesh,
+    fn = jax.shard_map(per_stage, mesh=mesh,
                        in_specs=(spec_params, P()), out_specs=P())
     out = fn(stacked_params, mbs)
     return out.reshape((B,) + out.shape[2:])

@@ -199,11 +199,20 @@ def spawn_replica(root, rid, world, seed=0, env=None, fault_plan=None):
     --replica`` builds the standard linear test model (deterministic in
     ``seed``, so every replica computes the same function and routing
     is invisible to clients).  ``fault_plan`` ships a seeded plan into
-    the child via ``MXNET_FAULT_PLAN``."""
+    the child via ``MXNET_FAULT_PLAN``.
+
+    A CPU harness (the fleet drills and tests): a chip belongs to one
+    process at a time and nothing here pins a replica to its own chip,
+    so the children run on the CPU whatever platform the parent holds.
+    Only a caller-built ``env`` that names ``JAX_PLATFORMS`` itself
+    decides otherwise."""
     import subprocess
     import sys
-    child = dict(os.environ if env is None else env)
-    child.setdefault("JAX_PLATFORMS", "cpu")
+    if env is None:
+        child = dict(os.environ, JAX_PLATFORMS="cpu")
+    else:
+        child = dict(env)
+        child.setdefault("JAX_PLATFORMS", "cpu")
     # replicas load the fleet-shared tuning DB at spawn: a custom env
     # inherits the parent's MXNET_TUNE switch and DB location unless
     # the caller pinned them, so one committed winner reaches every

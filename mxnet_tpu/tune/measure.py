@@ -15,6 +15,12 @@ timed, and two guards run alongside the clock:
   retraces per call would win the single-step clock and lose the
   training run.
 
+The clock is the clock of whatever the child ran on — by default the
+CPU with the kernels under the Pallas INTERPRETER (the hermetic
+setting below) — so every result names it (``platform``,
+``interpret``): ``us_per_step`` is a device metric only where that says
+``tpu`` and ``False``.
+
 The subprocess is bounded by a wall timeout and always leaves exactly
 one JSON line on stdout; any other exit (crash, hang, parity miss,
 retrace) degrades to ``{"ok": False, "error": ...}`` — the driver
@@ -85,14 +91,16 @@ us = (time.perf_counter() - t0) / max(steps, 1) * 1e6
 for _ in range(3):
     jax.block_until_ready(jstep(w, g, m, v))
 print(json.dumps({"us_per_step": us, "parity": bool(parity),
-                  "recompiles": traces[0]}))
+                  "recompiles": traces[0],
+                  "platform": jax.devices()[0].platform,
+                  "interpret": bool(pk._interpret())}))
 """
 
 
 def measure_candidate(candidate, space=None, n=65536, steps=10,
                       warmup=2, timeout=240.0, extra_env=None):
     """Measure one candidate; returns ``{"ok", "us_per_step",
-    "parity", "recompiles", "error"}``.
+    "parity", "recompiles", "platform", "interpret", "error"}``.
 
     ``space`` (a :class:`~.space.TunableSpace`) maps the candidate's
     knob names onto config env vars for the subprocess; without it the
@@ -147,4 +155,6 @@ def measure_candidate(candidate, space=None, n=65536, steps=10,
             % out.get("recompiles")
     return {"ok": ok, "us_per_step": out.get("us_per_step"),
             "parity": out.get("parity"),
-            "recompiles": out.get("recompiles"), "error": err}
+            "recompiles": out.get("recompiles"),
+            "platform": out.get("platform"),
+            "interpret": out.get("interpret"), "error": err}

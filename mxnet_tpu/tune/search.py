@@ -157,7 +157,9 @@ def _apply(state, rec):
         if best is None or us < best["us_per_step"]:
             state["best_measured"] = {"candidate": dict(cand),
                                       "us_per_step": us,
-                                      "k": int(rec["k"])}
+                                      "k": int(rec["k"]),
+                                      "platform": rec.get("platform"),
+                                      "interpret": rec.get("interpret")}
 
 
 def _mutation_base(state):
@@ -234,7 +236,8 @@ def run_sweep(space, context, budget=None, seed=None, prune_only=None,
                 if m.get("ok"):
                     rec["outcome"] = "measured"
                     rec["us_per_step"] = float(m["us_per_step"])
-                    for extra in ("parity", "recompiles"):
+                    for extra in ("parity", "recompiles", "platform",
+                                  "interpret"):
                         if extra in m:
                             rec[extra] = m[extra]
                     _count_candidate("measured")

@@ -393,6 +393,90 @@ def test_two_processes_share_one_cache_dir(tmp_path):
         "a fresh process must warm-start from what the racers wrote"
 
 
+# -- placement: outside > knob > fixed in-checkout default --------------------
+_BIND_CHILD = textwrap.dedent("""
+    import json, os
+    import numpy as np
+    import jax
+    import mxnet_tpu as mx
+    from mxnet_tpu import compile_cache, nd, sym
+    before = jax.config.jax_compilation_cache_dir
+    data = sym.Variable("data")
+    out = sym.softmax(sym.FullyConnected(data, num_hidden=4, name="fc"))
+    args = {"fc_weight": nd.array(np.ones((4, 6), np.float32)),
+            "fc_bias": nd.array(np.zeros((4,), np.float32))}
+    pred = mx.Predictor.from_parts(out, args, {}, {"data": (1, 6)})
+    pred.forward(data=np.zeros((1, 6), np.float32))
+    pred.get_output(0).asnumpy()
+    print(json.dumps({"before": before,
+                      "after": jax.config.jax_compilation_cache_dir,
+                      "placement": list(compile_cache.placement()),
+                      "stats": compile_cache.stats()}))
+""")
+
+
+def _child_env(**extra):
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR",
+                        "MXNET_COMPILE_CACHE_DIR")}
+    env.update(JAX_PLATFORMS="cpu", **extra)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
+    return env
+
+
+def test_externally_placed_cache_survives_the_first_bind(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: after the first bind jax still
+    points at it, an entry landed there, stats() names it — and the
+    MXNET knob, set to somewhere else, was ignored."""
+    ext, other = str(tmp_path / "outside"), str(tmp_path / "knob")
+    proc = subprocess.run(
+        [sys.executable, "-c", _BIND_CHILD], capture_output=True,
+        text=True, timeout=300,
+        env=_child_env(JAX_COMPILATION_CACHE_DIR=ext,
+                       MXNET_COMPILE_CACHE_DIR=other))
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    doc = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert doc["before"] == ext and doc["after"] == ext
+    assert doc["placement"] == [ext, "external"]
+    assert doc["stats"]["enabled"] and doc["stats"]["dir"] == ext
+    assert doc["stats"]["entries"] >= 1
+    assert [f for f in os.listdir(ext) if f.endswith("-cache")]
+    assert not os.path.exists(other)
+
+
+def test_default_placement_is_one_fixed_path_in_the_checkout(tmp_path):
+    """Neither variable set: two processes started in different
+    directories agree on <checkout>/.jax_cache — no temp name, pid or
+    timestamp in it.  (placement() only: the suite must not fill the
+    checkout.)"""
+    src = ("from mxnet_tpu import compile_cache as c; "
+           "print(c.placement()[0]); print(c.placement()[1])")
+    procs = [subprocess.Popen([sys.executable, "-c", src], cwd=cwd,
+                              stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+                              text=True, env=_child_env())
+             for cwd in (str(tmp_path), os.getcwd())]
+    seen = []
+    for proc in procs:
+        out, err = proc.communicate(timeout=120)
+        assert proc.returncode == 0, err[-2000:]
+        seen.append(out.split())
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert seen[0] == seen[1] == [os.path.join(root, ".jax_cache"),
+                                  "default"]
+    assert compile_cache.DEFAULT_DIR == seen[0][0]
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_empty_knob_turns_the_cache_off(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", "")
+    assert compile_cache.placement() == (None, "knob")
+    assert compile_cache.ensure_initialized() is False
+    assert compile_cache.stats()["dir"] is None
+
+
 # -- bench plumbing ----------------------------------------------------------
 @pytest.mark.slow
 def test_bench_warmup_probe_emits_parseable_json(tmp_path):

@@ -376,3 +376,53 @@ def test_sweep_demotes_on_runtime_mult_change(monkeypatch):
     w_array, _ = train("0")
     for k in w_sweep:
         np.testing.assert_array_equal(w_sweep[k], w_array[k], err_msg=k)
+
+
+def test_exported_params_survive_further_fused_steps():
+    """The fused step DONATES (deletes) the weight buffers it is handed
+    and rebinds the aux NDArrays every step.  What get_params /
+    export_serving hand out must be snapshots: training that goes on
+    after an export may neither free the exported weights ("Array has
+    been deleted") nor move the exported BatchNorm statistics."""
+    from mxnet_tpu import serving
+
+    rng = np.random.RandomState(0)
+    x = rng.rand(32, 8).astype(np.float32)
+    y = rng.randint(0, 4, 32).astype(np.float32)
+    net = mx.sym.FullyConnected(mx.sym.Variable("data"), num_hidden=16,
+                                name="fc1")
+    net = mx.sym.BatchNorm(net, fix_gamma=False, name="bn")
+    net = mx.sym.Activation(net, act_type="relu")
+    net = mx.sym.FullyConnected(net, num_hidden=4, name="fc2")
+    net = mx.sym.SoftmaxOutput(net, name="softmax")
+    it = mx.io.NDArrayIter(x, y, batch_size=8)
+    mod = mx.mod.Module(net, context=mx.cpu())
+    mod.fit(it, num_epoch=2, kvstore="tpu", optimizer="sgd",
+            optimizer_params={"learning_rate": 0.1, "momentum": 0.9})
+    assert mod._fused_exec_update is True
+
+    arg, aux = mod.get_params()
+    kept = {k: v.asnumpy() for k, v in list(arg.items()) + list(aux.items())}
+    it.reset()
+    want = mod.predict(it).asnumpy()
+    srv = serving.ModelServer(max_batch=4)
+    mod.export_serving("m", srv)
+
+    it.reset()
+    for batch in it:                 # training continues after the export
+        mod.forward_backward(batch)
+        mod.update()
+
+    for k, v in list(arg.items()) + list(aux.items()):
+        assert not v._data.is_deleted(), k
+        np.testing.assert_array_equal(v.asnumpy(), kept[k], err_msg=k)
+    srv.start()
+    try:
+        got = np.concatenate([srv.infer("m", {"data": x[i:i + 1]})[0]
+                              for i in range(8)])
+    finally:
+        srv.stop(drain=False)
+    np.testing.assert_allclose(got, want[:8], rtol=1e-5, atol=1e-6)
+    # and the module itself moved on
+    it.reset()
+    assert np.abs(mod.predict(it).asnumpy() - want).max() > 1e-4

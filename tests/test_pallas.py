@@ -439,3 +439,49 @@ def test_local_attention_empty_causal_rows_keep_loud_path(monkeypatch):
         outs[knob] = np.asarray(att.local_attention(
             q, k, v, causal=True, q_offset=4, kv_offset=0, impl="einsum"))
     assert np.allclose(outs["1"], outs["0"], atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Block choice: what native Mosaic needs that interpret mode never checks
+# ---------------------------------------------------------------------------
+def test_row_block_is_width_and_dtype_aware():
+    """ResNet-50's last stage at serving bucket 16 is a (784, 2048)
+    array: one 784-row fp32 block is 6.4 MB, and in + out
+    double-buffered is 25 MiB against 16 MiB of scoped VMEM.  The
+    block must shrink with the width, stay a whole number of sublane
+    tiles of the dtype, and prefer a divisor (no padding)."""
+    for dtype, sub in ((np.float32, 8), (jnp.bfloat16, 16), (np.int8, 32)):
+        assert pk._sublane(dtype) == sub
+        bn = pk._row_block(784, 2048, dtype, 1024)
+        assert bn * 2048 * 4 <= pk._BLOCK_BYTES and bn % sub == 0
+        assert 784 % bn == 0 or dtype is np.int8
+    assert pk._row_block(784, 2048, np.float32, 1024) == 56
+    # narrow and long: the preferred block, which divides
+    assert pk._row_block(256 * 56 * 56, 64, np.float32, 1024) == 1024
+    # fits one block: taken whole, whatever its row count
+    assert pk._row_block(49, 2048, jnp.bfloat16, 1024) == 49
+    # no usable divisor (2 x prime): the cap, and the caller pads
+    x = jnp.asarray(np.random.RandomState(0).randn(2 * 1009, 512)
+                    .astype(np.float32))
+    assert pk._row_block(x.shape[0], 512, x.dtype, 1024) == 256
+    y = pk.fused_scale_bias_relu(x, jnp.ones(512), jnp.zeros(512))
+    assert y.shape == x.shape
+    np.testing.assert_array_equal(np.asarray(y),
+                                  np.maximum(np.asarray(x), 0))
+
+
+def test_norm_block_rows_follow_the_dtype_sublane():
+    knob = "MXNET_PALLAS_SOFTMAX_BLOCK_ROWS"
+    assert pk._norm_block_rows(1, 1024, knob) == 8
+    assert pk._norm_block_rows(1, 1024, knob, dtype=jnp.bfloat16) == 16
+    assert pk._norm_block_rows(256, 1024, knob, dtype=jnp.bfloat16) == 128
+    # an explicit value is clamped to whole tiles too
+    assert pk._norm_block_rows(256, 1024, knob, value=8,
+                               dtype=jnp.bfloat16) == 16
+
+
+def test_flash_eligibility_knows_the_sublane_tile():
+    assert pk.flash_seq_ok(2048, jnp.bfloat16)
+    assert pk.flash_seq_ok(8, jnp.bfloat16)          # whole sequence
+    assert pk.flash_seq_ok(136, jnp.float32)         # 8-row blocks, f32
+    assert not pk.flash_seq_ok(136, jnp.bfloat16)    # 8 rows < bf16 tile

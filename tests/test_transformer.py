@@ -88,6 +88,7 @@ def test_transformer_lm_trains_and_hybridizes():
     assert not np.allclose(ref[:, -1], out2[:, -1], atol=1e-4)
 
 
+@pytest.mark.slow      # 85 s: ring attention on the 8-device CPU mesh
 def test_transformer_sp_mesh_transparent():
     """Entering an sp mesh scope reroutes attention through ring
     attention with identical results — the long-context path."""

@@ -18,9 +18,13 @@ training scripts and our ``mxnet_tpu.parallel.init_distributed`` both
 understand them).
 
 Launchers:
-  local : spawn all N workers on this host (multi-process CPU/TPU-pod
-          simulation; the pattern the reference used for nightly
-          dist tests)
+  local : spawn all N workers on this host — a CPU simulation of a
+          multi-process job (the pattern the reference used for
+          nightly dist tests).  A chip belongs to one process at a
+          time and the N workers get identical environments, none
+          pinned to a chip of its own, so local workers run with
+          JAX_PLATFORMS=cpu unless --env/--env-worker names
+          JAX_PLATFORMS explicitly
   ssh   : one worker per host from --hostfile
   mpi   : delegate process placement to mpirun
 """
@@ -54,10 +58,14 @@ def worker_env(args, worker_id):
 def submit_local(args):
     import time
     procs = []
+    pinned = any(pair.split(":", 1)[0] == "JAX_PLATFORMS"
+                 for pair in args.env_worker + args.env)
     for wid in range(args.num_workers):
         logging.info("starting local worker %d", wid)
-        procs.append(subprocess.Popen(args.command,
-                                      env=worker_env(args, wid)))
+        env = worker_env(args, wid)
+        if not pinned:
+            env["JAX_PLATFORMS"] = "cpu"    # module docstring: local
+        procs.append(subprocess.Popen(args.command, env=env))
     # poll rather than wait sequentially: when any worker fails, kill the
     # survivors (they may be blocked in coordinator init waiting for it)
     rc = 0
