@@ -33,6 +33,8 @@ from .context import Context, current_context
 from .ndarray.ndarray import (NDArray, zeros as nd_zeros, _wrap,
                               _copy_buffers)
 from .symbol.symbol import build_graph_fn, _infer_graph
+from . import telemetry as _telemetry
+from .telemetry import phases as _phases, tracing as _trace
 
 __all__ = ["Executor"]
 
@@ -85,7 +87,6 @@ class Executor:
         # compile-time histograms come from the bind/dispatch path itself
         # (the serving cache's miss==recompile insight, generalized)
         self._compile_seen = set()
-        from . import telemetry as _telemetry
         if _telemetry.enabled():
             _telemetry.counter(
                 "mxnet_executor_binds_total",
@@ -141,7 +142,8 @@ class Executor:
                 full = list(args)
                 for j, i in enumerate(diff_idx):
                     full[i] = diff_args[j]
-                outs, new_aux = fn_train(_cast(full), aux, key)
+                with jax.named_scope(_phases.FWD_SCOPE):
+                    outs, new_aux = fn_train(_cast(full), aux, key)
                 return tuple(outs), new_aux
 
             outs, vjp_fn, new_aux = jax.vjp(f, diff, has_aux=True)
@@ -161,6 +163,7 @@ class Executor:
         self._fused_codec = None    # shared gradient-compression codec
         self._fused_resids = None   # error-feedback residuals (codec on)
         self._jit_fbu = None
+        self._registered_fbu = None     # the jit telemetry last saw
         self._updates_applied = False
 
     # -- fused optimizer step ------------------------------------------------
@@ -306,20 +309,21 @@ class Executor:
         for bi, (b, idxs) in enumerate(sw["plan"]):
             wf = flatten_bucket([diff[j] for j in idxs], b)
             gf = flatten_bucket([grads[j] for j in idxs], b)
-            if sw["kind"] == "sgd":
-                # tuple arity is static at trace time (len, not value)
-                mom = states[bi][0] if len(states[bi]) else None
-                nw, nm = pk.fused_sgd_momentum(
-                    wf, gf, mom, lr=lrs[bi], momentum=sw["momentum"],
-                    wd=wds[bi], rescale=sw["rescale"], clip=sw["clip"])
-                new_states.append((nm,) if nm is not None else ())
-            else:
-                nw, nm, nv = pk.fused_adam(
-                    wf, gf, states[bi][0], states[bi][1], lr_eff=lrs[bi],
-                    beta1=sw["beta1"], beta2=sw["beta2"],
-                    epsilon=sw["epsilon"], wd=wds[bi],
-                    rescale=sw["rescale"], clip=sw["clip"])
-                new_states.append((nm, nv))
+            with jax.named_scope(_phases.SWEEP_SCOPE):
+                if sw["kind"] == "sgd":
+                    # tuple arity is static at trace time (len, not value)
+                    mom = states[bi][0] if len(states[bi]) else None
+                    nw, nm = pk.fused_sgd_momentum(
+                        wf, gf, mom, lr=lrs[bi], momentum=sw["momentum"],
+                        wd=wds[bi], rescale=sw["rescale"], clip=sw["clip"])
+                    new_states.append((nm,) if nm is not None else ())
+                else:
+                    nw, nm, nv = pk.fused_adam(
+                        wf, gf, states[bi][0], states[bi][1],
+                        lr_eff=lrs[bi], beta1=sw["beta1"],
+                        beta2=sw["beta2"], epsilon=sw["epsilon"],
+                        wd=wds[bi], rescale=sw["rescale"], clip=sw["clip"])
+                    new_states.append((nm, nv))
             views = unflatten_bucket(nw, b)
             for j, name in zip(idxs, b.names):
                 new_diff[j] = views[name].astype(diff[j].dtype)
@@ -372,7 +376,11 @@ class Executor:
                 full = list(rest)
                 for j, i in enumerate(diff_idx):
                     full[i] = diff_args[j]
-                outs, new_aux = fn_train(_cast(full), aux, key)
+                # the phase scopes (telemetry/phases.py) are metadata
+                # only: forward instructions come out of the compiler as
+                # jvp(mx_fwd), backward ones as transpose(jvp(mx_fwd))
+                with _jax.named_scope(_phases.FWD_SCOPE):
+                    outs, new_aux = fn_train(_cast(full), aux, key)
                 return tuple(outs), new_aux
 
             outs, vjp_fn, new_aux = _jax.vjp(f, list(diff), has_aux=True)
@@ -385,23 +393,26 @@ class Executor:
             new_resids = resids
             if codec is not None:
                 decoded, new_resids = [], []
-                for g, r in zip(grads, resids):
-                    d, nr = codec.roundtrip(g.astype(jnp.float32), r)
-                    decoded.append(d.astype(g.dtype))
-                    new_resids.append(nr)
+                with _jax.named_scope(_phases.CODEC_SCOPE):
+                    for g, r in zip(grads, resids):
+                        d, nr = codec.roundtrip(g.astype(jnp.float32), r)
+                        decoded.append(d.astype(g.dtype))
+                        new_resids.append(nr)
                 grads = decoded
             # lrs/wds are ONE packed array each (per weight on the
             # per-array path, per BUCKET on the sweep) — one host
             # transfer when the schedule moves, not one per scalar
-            if sweep is not None:
-                new_diff, new_states = self._sweep_update(
-                    diff, grads, states, lrs, wds)
-            else:
-                new_diff, new_states = [], []
-                for j, (w, g, st) in enumerate(zip(diff, grads, states)):
-                    nw, nst = one(w, g, st, lrs[j], wds[j])
-                    new_diff.append(nw)
-                    new_states.append(nst)
+            with _jax.named_scope(_phases.UPDATE_SCOPE):
+                if sweep is not None:
+                    new_diff, new_states = self._sweep_update(
+                        diff, grads, states, lrs, wds)
+                else:
+                    new_diff, new_states = [], []
+                    for j, (w, g, st) in enumerate(zip(diff, grads,
+                                                       states)):
+                        nw, nst = one(w, g, st, lrs[j], wds[j])
+                        new_diff.append(nw)
+                        new_states.append(nst)
             # grads are consumed in-program (XLA frees them); they are not
             # outputs — saves an HBM round-trip per step.  backward() is a
             # no-op in fused mode (grad_dict intentionally not populated).
@@ -418,27 +429,12 @@ class Executor:
         # outputs right after the call)
         return _jax.jit(fbu, donate_argnums=(0, 5, 6))
 
-    def _forward_fused(self, args, aux, key):
+    def _fused_lr_wd(self, optimizer):
+        """This step's (lrs, wds) as device arrays — per weight on the
+        per-array path, per BUCKET on the sweep — advancing the
+        optimizer's schedule bookkeeping for every weight."""
         from . import optimizer as opt_mod
-
-        optimizer = self._fused_update[0]
-        init_state = self._fused_update[1]
-        diff_set = set(self._diff_idx)
-        diff = [args[i] for i in self._diff_idx]
-        # None placeholders where diff args go (overwritten inside the
-        # program) — the donated weight buffers must not appear twice
-        rest = [None if i in diff_set else a for i, a in enumerate(args)]
         sweep = getattr(self, "_sweep", None)
-        if self._fused_state is None:
-            self._fused_state = (self._sweep_init_state()
-                                 if sweep is not None
-                                 else [init_state(d) for d in diff])
-        if self._fused_resids is None:
-            # error-feedback residuals, one per weight when a codec is
-            # installed (empty pytree otherwise: ONE program shape)
-            self._fused_resids = [
-                jnp.zeros(d.shape, jnp.float32) for d in diff] \
-                if getattr(self, "_fused_codec", None) is not None else []
         lrs, wds = [], []
         for i in self._diff_idx:
             lr, wd = opt_mod.fused_lr_wd(optimizer, self.arg_names[i])
@@ -472,7 +468,29 @@ class Executor:
         if cached is None or not (np.array_equal(cached[0], lrs)
                                   and np.array_equal(cached[1], wds)):
             self._lr_wd_cache = (lrs, wds, jnp.asarray(lrs), jnp.asarray(wds))
-        lrs_dev, wds_dev = self._lr_wd_cache[2], self._lr_wd_cache[3]
+        return self._lr_wd_cache[2], self._lr_wd_cache[3]
+
+    def _forward_fused(self, args, aux, key):
+        optimizer = self._fused_update[0]
+        init_state = self._fused_update[1]
+        diff_set = set(self._diff_idx)
+        diff = [args[i] for i in self._diff_idx]
+        # None placeholders where diff args go (overwritten inside the
+        # program) — the donated weight buffers must not appear twice
+        rest = [None if i in diff_set else a for i, a in enumerate(args)]
+        sweep = getattr(self, "_sweep", None)
+        if self._fused_state is None:
+            self._fused_state = (self._sweep_init_state()
+                                 if sweep is not None
+                                 else [init_state(d) for d in diff])
+        if self._fused_resids is None:
+            # error-feedback residuals, one per weight when a codec is
+            # installed (empty pytree otherwise: ONE program shape)
+            self._fused_resids = [
+                jnp.zeros(d.shape, jnp.float32) for d in diff] \
+                if getattr(self, "_fused_codec", None) is not None else []
+        with _trace.span("executor.hyper"):
+            lrs_dev, wds_dev = self._fused_lr_wd(optimizer)
         # key chain: consume the device key-DATA the previous step
         # emitted; first call seeds from the host counter chain
         key_dev = getattr(self, "_fused_key", None)
@@ -492,18 +510,25 @@ class Executor:
             donated = (list(diff)
                        + _tree.tree_leaves(self._fused_state)
                        + _tree.tree_leaves(self._fused_resids))
-        outs, new_diff, new_states, new_resids, new_aux, new_key = \
-            self._dispatch_compiled(
-                "fbu", self._jit_fbu, diff, diff, rest, aux, key_dev,
-                seeds, self._fused_state, self._fused_resids,
-                lrs_dev, wds_dev)
-        self._fused_key = new_key
-        self._fused_state = new_states
-        self._fused_resids = new_resids
-        for j, i in enumerate(self._diff_idx):
-            self.arg_dict[self.arg_names[i]]._data = new_diff[j]
-        self._cached_grads = None
-        self._updates_applied = True
+        call = (diff, rest, aux, key_dev, seeds, self._fused_state,
+                self._fused_resids, lrs_dev, wds_dev)
+        if _telemetry.enabled() and \
+                self._registered_fbu is not self._jit_fbu:
+            # so that telemetry.program_hlo("fbu") can name the phase of
+            # every instruction after the fact; nothing compiles here
+            _telemetry.register_program("fbu", self._jit_fbu, call)
+            self._registered_fbu = self._jit_fbu
+        with _trace.span("executor.dispatch"):
+            outs, new_diff, new_states, new_resids, new_aux, new_key = \
+                self._dispatch_compiled("fbu", self._jit_fbu, diff, *call)
+        with _trace.span("executor.rebind"):
+            self._fused_key = new_key
+            self._fused_state = new_states
+            self._fused_resids = new_resids
+            for j, i in enumerate(self._diff_idx):
+                self.arg_dict[self.arg_names[i]]._data = new_diff[j]
+            self._cached_grads = None
+            self._updates_applied = True
         if donated is not None:
             # after the rebinds: any executor slot (or later NDArray
             # read) still referencing a donated buffer is a defect

@@ -21,6 +21,7 @@ from ..model import BatchEndParam
 from ..initializer import Uniform
 from ..io import DataDesc
 from ..base import MXNetError
+from ..telemetry import tracing as _tracing
 
 __all__ = ["BaseModule"]
 
@@ -69,8 +70,9 @@ class BaseModule:
     # -- high level ----------------------------------------------------------
     def forward_backward(self, data_batch):
         """Reference: base_module.py:191."""
-        self.forward(data_batch, is_train=True)
-        self.backward()
+        with _tracing.span("module.forward_backward"):
+            self.forward(data_batch, is_train=True)
+            self.backward()
 
     def score(self, eval_data, eval_metric, num_batch=None,
               batch_end_callback=None, score_end_callback=None, reset=True,
@@ -322,7 +324,6 @@ class BaseModule:
                           ckpt_mgr=None, progress=None, sigterm=None):
         from ..analysis.sanitizers import hooks as _san_hooks
         from ..fault import hooks as _fault
-        from ..telemetry import tracing as _tracing
         # graftfault step address: a monotone batch counter across
         # epochs, so plans can say "SIGTERM at global batch 7" and the
         # kill-and-resume drill is exact (published only while armed)
@@ -345,6 +346,7 @@ class BaseModule:
             # handle lives on self so fit()'s finally also closes it
             # when an exception aborts the loop mid-epoch)
             while data_batch is not None:
+                # fit.step is the parent of the three module.* spans
                 with _tracing.span("fit.step", epoch=epoch,
                                    batch=global_batch):
                     if _fault.ACTIVE[0]:
@@ -355,15 +357,17 @@ class BaseModule:
                         monitor.tic()
                     self.forward_backward(data_batch)
                     self.update()
-                if getattr(self, "_san_fit_region", None) is None and \
-                        _san_hooks.region_sanitizers_active():
-                    from ..analysis import sanitizers as _sanitizers
-                    self._san_fit_region = _sanitizers.steady_state("fit")
-                labels = ([db.label for db in data_batch]
-                          if isinstance(data_batch, list) else
-                          data_batch.label)
-                self.update_metric(eval_metric, labels,
-                                   pre_sliced=isinstance(data_batch, list))
+                    if getattr(self, "_san_fit_region", None) is None and \
+                            _san_hooks.region_sanitizers_active():
+                        from ..analysis import sanitizers as _sanitizers
+                        self._san_fit_region = \
+                            _sanitizers.steady_state("fit")
+                    labels = ([db.label for db in data_batch]
+                              if isinstance(data_batch, list) else
+                              data_batch.label)
+                    self.update_metric(
+                        eval_metric, labels,
+                        pre_sliced=isinstance(data_batch, list))
                 if progress is not None:
                     # batch (epoch, nbatch) is fully applied and the
                     # iterator has advanced past exactly nbatch+1 batches
@@ -383,13 +387,15 @@ class BaseModule:
                     ckpt_mgr.save_module(self, epoch=epoch,
                                          nbatch=nbatch + 1,
                                          train_data=train_data)
-                upcoming = next(batches, None)
-                if upcoming is not None:
-                    self.prepare(upcoming, sparse_row_id_fn=sparse_row_id_fn)
-                    if progress is not None:
-                        # fetched but untrained: the SIGTERM save must
-                        # rewind the cursor over this batch
-                        progress["pending"] = True
+                with _tracing.span("fit.data_wait"):
+                    upcoming = next(batches, None)
+                    if upcoming is not None:
+                        self.prepare(upcoming,
+                                     sparse_row_id_fn=sparse_row_id_fn)
+                if upcoming is not None and progress is not None:
+                    # fetched but untrained: the SIGTERM save must
+                    # rewind the cursor over this batch
+                    progress["pending"] = True
                 if monitor is not None:
                     monitor.toc_print()
                 if upcoming is None:

@@ -19,6 +19,7 @@ from ..model import (_create_kvstore, _initialize_kvstore, _update_params,
                      _update_params_on_kvstore, load_checkpoint,
                      save_checkpoint)
 from ..ndarray import zeros
+from ..telemetry import tracing as _tracing
 from .base_module import BaseModule, _check_input_names
 from .executor_group import DataParallelExecutorGroup
 
@@ -433,18 +434,19 @@ class Module(BaseModule):
                 self._exec_group.execs[0].updates_applied:
             # weights already advanced inside the compiled train step
             return
-        if self._update_on_kvstore:
-            _update_params_on_kvstore(self._exec_group.param_arrays,
-                                      self._exec_group.grad_arrays,
-                                      self._kvstore,
-                                      self._exec_group.param_names)
-        else:
-            _update_params(self._exec_group.param_arrays,
-                           self._exec_group.grad_arrays,
-                           updater=self._updater,
-                           num_device=len(self._context),
-                           kvstore=self._kvstore,
-                           param_names=self._exec_group.param_names)
+        with _tracing.span("module.update"):
+            if self._update_on_kvstore:
+                _update_params_on_kvstore(self._exec_group.param_arrays,
+                                          self._exec_group.grad_arrays,
+                                          self._kvstore,
+                                          self._exec_group.param_names)
+            else:
+                _update_params(self._exec_group.param_arrays,
+                               self._exec_group.grad_arrays,
+                               updater=self._updater,
+                               num_device=len(self._context),
+                               kvstore=self._kvstore,
+                               param_names=self._exec_group.param_names)
 
     def get_outputs(self, merge_multi_context=True):
         assert self.binded and self.params_initialized
@@ -458,7 +460,8 @@ class Module(BaseModule):
             merge_multi_context=merge_multi_context)
 
     def update_metric(self, eval_metric, labels, pre_sliced=False):
-        self._exec_group.update_metric(eval_metric, labels, pre_sliced)
+        with _tracing.span("module.update_metric"):
+            self._exec_group.update_metric(eval_metric, labels, pre_sliced)
 
     def _sync_params_from_devices(self):
         """Reference: module.py _sync_params_from_devices."""

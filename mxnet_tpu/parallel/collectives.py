@@ -22,7 +22,10 @@ that are pure planning — no jax tracing:
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
+
+from ..telemetry import phases as _phases
 
 __all__ = ["Bucket", "build_bucket_plan", "flatten_bucket",
            "unflatten_bucket", "comm_stats", "ring_all_reduce_bytes",
@@ -104,23 +107,26 @@ def flatten_bucket(values, bucket):
     # `values` is a Python LIST of arrays — its truthiness is its
     # length, static at trace time (an empty bucket never reads an
     # array's value)
-    if values:  # graftlint: disable=recompile-hazard
-        flat = jnp.concatenate([v.reshape(-1).astype(jnp.float32)
-                                for v in values])
-    else:
-        flat = jnp.zeros((0,), jnp.float32)
-    if bucket.padded_n != bucket.n:
-        flat = jnp.concatenate(
-            [flat, jnp.zeros((bucket.padded_n - bucket.n,), jnp.float32)])
+    with jax.named_scope(_phases.FLATTEN_SCOPE):
+        if values:  # graftlint: disable=recompile-hazard
+            flat = jnp.concatenate([v.reshape(-1).astype(jnp.float32)
+                                    for v in values])
+        else:
+            flat = jnp.zeros((0,), jnp.float32)
+        if bucket.padded_n != bucket.n:
+            flat = jnp.concatenate(
+                [flat, jnp.zeros((bucket.padded_n - bucket.n,),
+                                 jnp.float32)])
     return flat
 
 
 def unflatten_bucket(flat, bucket):
     """Split a fused buffer back into ``{name: array}`` views."""
     out = {}
-    for name, shape, off, sz in zip(bucket.names, bucket.shapes,
-                                    bucket.offsets, bucket.sizes):
-        out[name] = flat[off:off + sz].reshape(shape)
+    with jax.named_scope(_phases.UNFLATTEN_SCOPE):
+        for name, shape, off, sz in zip(bucket.names, bucket.shapes,
+                                        bucket.offsets, bucket.sizes):
+            out[name] = flat[off:off + sz].reshape(shape)
     return out
 
 

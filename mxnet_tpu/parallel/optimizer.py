@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from ..base import MXNetError
+from ..telemetry import phases as _phases
 
 __all__ = ["PureSGD", "PureAdam", "make_optimizer", "sharded_zeros_like"]
 
@@ -108,15 +109,16 @@ class PureSGD:
             # in one kernel
             from ..ops import pallas_kernels as pk
             new_params, new_mom = {}, {}
-            for k in params:
-                nw, nm = pk.fused_sgd_momentum(
-                    params[k], grads[k],
-                    None if self.momentum == 0.0 else state["mom"][k],
-                    lr=lr, momentum=self.momentum, wd=self.wd,
-                    rescale=self.rescale_grad, clip=clip, mesh=mesh)
-                new_params[k] = nw
-                if nm is not None:
-                    new_mom[k] = nm
+            with jax.named_scope(_phases.SWEEP_SCOPE):
+                for k in params:
+                    nw, nm = pk.fused_sgd_momentum(
+                        params[k], grads[k],
+                        None if self.momentum == 0.0 else state["mom"][k],
+                        lr=lr, momentum=self.momentum, wd=self.wd,
+                        rescale=self.rescale_grad, clip=clip, mesh=mesh)
+                    new_params[k] = nw
+                    if nm is not None:
+                        new_mom[k] = nm
             if self.momentum == 0.0:
                 return new_params, state
             return new_params, {"mom": new_mom}
@@ -186,15 +188,16 @@ class PureAdam:
             # sweep is bit-identical; t bookkeeping stays out here
             lr_eff = lr * coef
             new_params, new_mean, new_var = {}, {}, {}
-            for k in params:
-                nw, nm, nv = pk.fused_adam(
-                    params[k], grads[k], state["mean"][k],
-                    state["var"][k], lr_eff=lr_eff, beta1=b1, beta2=b2,
-                    epsilon=self.epsilon, wd=self.wd,
-                    rescale=self.rescale_grad, clip=clip, mesh=mesh)
-                new_params[k] = nw
-                new_mean[k] = nm
-                new_var[k] = nv
+            with jax.named_scope(_phases.SWEEP_SCOPE):
+                for k in params:
+                    nw, nm, nv = pk.fused_adam(
+                        params[k], grads[k], state["mean"][k],
+                        state["var"][k], lr_eff=lr_eff, beta1=b1,
+                        beta2=b2, epsilon=self.epsilon, wd=self.wd,
+                        rescale=self.rescale_grad, clip=clip, mesh=mesh)
+                    new_params[k] = nw
+                    new_mean[k] = nm
+                    new_var[k] = nv
             return new_params, {"mean": new_mean, "var": new_var, "t": t}
 
         def prep(g, w):

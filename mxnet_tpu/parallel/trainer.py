@@ -58,9 +58,11 @@ from .. import autograd
 from .. import config as _config
 from .. import ndarray as ndmod
 from .. import random as _mxrandom
+from .. import telemetry
 from ..base import MXNetError
 from ..gradient_compression import make_codec
 from ..ndarray import NDArray
+from ..telemetry import phases as _phases, tracing as _trace
 from .collectives import (build_bucket_plan, comm_stats, flatten_bucket,
                           unflatten_bucket)
 from .mesh import make_mesh, mesh_scope
@@ -297,6 +299,7 @@ class ParallelTrainer:
         self._resids = self._init_residuals()
         self._comm = self._comm_model()
         self._jit_step = None
+        self._registered_step = None    # the jit telemetry last saw
         self._jit_eval = None
         self._export_state_gauges()
 
@@ -433,7 +436,6 @@ class ParallelTrainer:
         return {"total": int(total), "per_device": int(per_device)}
 
     def _export_state_gauges(self):
-        from .. import telemetry
         sb = self.optimizer_state_bytes()
         g = telemetry.gauge(
             "mxnet_parallel_optimizer_state_bytes",
@@ -470,6 +472,13 @@ class ParallelTrainer:
         amp = self._amp_dtype
 
         def loss_of(params, key, x, y):
+            # phase scopes (telemetry/phases.py), metadata only: under
+            # value_and_grad these instructions come out as jvp(mx_fwd)
+            # and their backward as transpose(jvp(mx_fwd))
+            with jax.named_scope(_phases.FWD_SCOPE):
+                return _loss_of(params, key, x, y)
+
+        def _loss_of(params, key, x, y):
             if amp is not None:
                 params = {k: v.astype(amp) if v.dtype == jnp.float32 else v
                           for k, v in params.items()}
@@ -486,9 +495,10 @@ class ParallelTrainer:
             out = jax.lax.with_sharding_constraint(
                 out, NamedSharding(mesh, P(*([("dp", "fsdp")]
                                              + [None] * (out.ndim - 1)))))
-            with autograd.pause(train_mode=True):
+            with autograd.pause(train_mode=True), \
+                    jax.named_scope(_phases.LOSS_SCOPE):
                 l = loss_blk(NDArray(out), NDArray(y))
-            return jnp.mean(l._data)
+                return jnp.mean(l._data)
 
         if self._zero == 0:
             step = self._make_step_replicated(loss_of, opt, trainable)
@@ -546,13 +556,16 @@ class ParallelTrainer:
             if codec is not None and plan:
                 grads = dict(grads)
                 out_res = []
-                for b, res in zip(plan, resids):
-                    gf = flatten_bucket([grads[n] for n in b.names], b)
-                    decoded, nres = codec.roundtrip(gf, res)
-                    out_res.append(nres)
-                    grads.update(unflatten_bucket(decoded, b))
+                with jax.named_scope(_phases.CODEC_SCOPE):
+                    for b, res in zip(plan, resids):
+                        gf = flatten_bucket([grads[n] for n in b.names], b)
+                        decoded, nres = codec.roundtrip(gf, res)
+                        out_res.append(nres)
+                        grads.update(unflatten_bucket(decoded, b))
                 new_resids = tuple(out_res)
-            new_train, new_state = opt.apply(train_params, grads, opt_state)
+            with jax.named_scope(_phases.UPDATE_SCOPE):
+                new_train, new_state = opt.apply(train_params, grads,
+                                                 opt_state)
             new_params = {**frozen, **new_train}
             return new_params, new_state, new_resids, loss
 
@@ -585,7 +598,8 @@ class ParallelTrainer:
             with ``_coll_scope`` (zero-2 no-codec buckets are tagged
             at their tap instead)."""
             if codec is not None:
-                payload, decoded, new_res = codec.encode(gf, res)
+                with jax.named_scope(_phases.CODEC_SCOPE):
+                    payload, decoded, new_res = codec.encode(gf, res)
                 if payload.dtype != jnp.uint32:
                     # cast codec: the collective itself rides the wire
                     # dtype — constrain the payload, decode shard-side
@@ -617,20 +631,32 @@ class ParallelTrainer:
             frozen = {k: v for k, v in params.items()
                       if not trainable[k] and k not in fused_set}
             pp = {n: params[n] for n in perparam_names}
-            flats = [flatten_bucket([params[n] for n in b.names], b)
-                     for b in plan]
+            # mx_update holds everything the flat-bucket optimizer costs:
+            # this flatten, the views f() cuts back out of the buckets
+            # (and their backward, the gradients' flatten), the sweep
+            # and the unflatten after it
+            with jax.named_scope(_phases.UPDATE_SCOPE):
+                flats = [flatten_bucket([params[n] for n in b.names], b)
+                         for b in plan]
 
             def f(flats_, pp_):
                 flats_ = [t(fl) if t is not None else fl
                           for t, fl in zip(taps, flats_)]
                 recon = {}
-                for b, fl in zip(plan, flats_):
-                    recon.update(unflatten_bucket(fl, b))
+                with jax.named_scope(_phases.UPDATE_SCOPE):
+                    for b, fl in zip(plan, flats_):
+                        recon.update(unflatten_bucket(fl, b))
                 return loss_of({**recon, **pp_, **frozen}, key, x, y)
 
             loss, (gflats, gpp) = jax.value_and_grad(
                 f, argnums=(0, 1))(flats, pp)
 
+            with jax.named_scope(_phases.UPDATE_SCOPE):
+                new_params, new_state, new_resids = _update(
+                    frozen, pp, flats, gflats, gpp, opt_state, resids)
+            return new_params, new_state, new_resids, loss
+
+        def _update(frozen, pp, flats, gflats, gpp, opt_state, resids):
             p_shards, g_shards, new_resids = {}, {}, []
             for b, fl, gf in zip(plan, flats, gflats):
                 res = resids[b.index] if codec is not None else None
@@ -671,7 +697,7 @@ class ParallelTrainer:
             new_params = {**frozen, **new_fused, **new_pp}
             new_state = {"fused": new_fused_state,
                          "perparam": new_pp_state}
-            return new_params, new_state, tuple(new_resids), loss
+            return new_params, new_state, tuple(new_resids)
 
         return step
 
@@ -716,12 +742,26 @@ class ParallelTrainer:
     # -- driving -------------------------------------------------------------
     def step(self, data, label):
         """One fused train step; returns the scalar loss NDArray."""
+        with _trace.span("trainer.step"):
+            return self._step(data, label)
+
+    def _step(self, data, label):
         x = data._data if isinstance(data, NDArray) else jnp.asarray(data)
         y = label._data if isinstance(label, NDArray) else jnp.asarray(label)
         if self._jit_step is None:
             self._build(1)
         key = _mxrandom.next_key()
-        with mesh_scope(self._mesh):
+        if telemetry.enabled() and \
+                self._registered_step is not self._jit_step:
+            # so that telemetry.program_hlo("step") can name the phase
+            # of every instruction after the fact; nothing compiles here
+            mesh = self._mesh
+            telemetry.register_program(
+                "step", self._jit_step,
+                (self._params, self._opt_state, self._resids, x, y, key),
+                scope=lambda: mesh_scope(mesh))
+            self._registered_step = self._jit_step
+        with mesh_scope(self._mesh), _trace.span("trainer.dispatch"):
             self._params, self._opt_state, self._resids, loss = \
                 self._jit_step(self._params, self._opt_state, self._resids,
                                x, y, key)
@@ -729,7 +769,6 @@ class ParallelTrainer:
         return NDArray(loss)
 
     def _record_comm(self):
-        from .. import telemetry
         if not telemetry.enabled():
             return
         ops = telemetry.counter(

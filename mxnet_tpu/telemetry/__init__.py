@@ -33,7 +33,8 @@ from .registry import (Counter, Gauge, Histogram, MetricFamily,
                        MetricsRegistry, exponential_buckets,
                        validate_exposition)
 from .step_logger import StepLogger
-from . import tracing, flight
+from . import tracing, flight, phases
+from .phases import program_hlo, register_program
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricFamily",
            "MetricsRegistry", "StepLogger", "counter", "gauge",
@@ -41,7 +42,8 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricFamily",
            "prometheus_text", "write_prometheus", "validate_exposition",
            "exponential_buckets", "enabled", "enable", "disable",
            "reset", "scalar_totals", "publish_to_profiler",
-           "chrome_counter_events", "tracing", "flight"]
+           "chrome_counter_events", "tracing", "flight", "phases",
+           "register_program", "program_hlo"]
 
 _REGISTRY = MetricsRegistry()
 _ENABLED = [False]
@@ -91,7 +93,15 @@ def enabled():
 
 
 def enable(on=True):
+    """Hot-path instrumentation on (or off): the counters, and the
+    program's spans (``tracing.arm_ring`` — ring and profiler
+    annotation, no exporter).  Off takes back only what on armed: a
+    process tracing under ``MXNET_TRACE`` keeps tracing."""
     _ENABLED[0] = bool(on)
+    if on:
+        tracing.arm_ring()
+    else:
+        tracing.disarm_ring()
 
 
 def disable():

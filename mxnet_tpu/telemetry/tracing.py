@@ -40,6 +40,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 import zlib
@@ -48,7 +49,7 @@ from collections import OrderedDict, deque
 __all__ = ["ACTIVE", "TraceContext", "Span", "enable", "disable",
            "enabled", "mint", "current", "use", "span", "start_span",
            "add_span", "mark", "complete", "inject", "extract", "keep",
-           "flush",
+           "flush", "arm_ring", "disarm_ring",
            "export_jsonl", "chrome_events", "snapshot", "anomalous",
            "retained_traces", "reset", "shard_path"]
 
@@ -78,6 +79,9 @@ _ANOMALOUS = OrderedDict()        # guarded-by: _lock — trace_id -> reason
 _ROOTS_DONE = OrderedDict()       # guarded-by: _lock — trace_id -> True
 _P99 = {}     # guarded-by: _lock — name -> [deque(durs), threshold, n]
 _ATEXIT = [False]
+# True while ACTIVE was set by telemetry.enable() (arm_ring) and not by
+# MXNET_TRACE / enable(): telemetry.disable() takes back only that
+_BORROWED = [False]
 
 # id source: a C-level counter, not the module lock — ids are minted
 # several times per request on the serving hot path, and next() on a
@@ -206,7 +210,8 @@ class Span:
     try/finally and exempts ownership transfers."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "baggage",
-                 "tags", "status", "_ts", "_t0", "_done", "_pushed")
+                 "tags", "status", "_ts", "_t0", "_done", "_pushed",
+                 "_annotation")
 
     def __init__(self, name, parent_ctx, tags):
         if parent_ctx is None:
@@ -222,6 +227,7 @@ class Span:
         self._t0 = time.perf_counter()
         self._done = False
         self._pushed = False
+        self._annotation = None
 
     @property
     def ctx(self):
@@ -234,9 +240,18 @@ class Span:
     def __enter__(self):
         _stack().append(self.ctx)
         self._pushed = True
+        # a lexical span is also an event of the jax profiler's host
+        # plane — the device trace's clock — while a trace records
+        annotation = _profiler_annotation()
+        if annotation is not None:
+            self._annotation = annotation(self.name)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         if self._pushed:
             st = _stack()
             if st:
@@ -260,7 +275,8 @@ class Span:
         dur_ms = (time.perf_counter() - self._t0) * 1000.0
         rec = {"trace": self.trace_id, "span": self.span_id,
                "parent": self.parent_id, "name": self.name,
-               "ts": self._ts, "dur_ms": round(dur_ms, 4),
+               "ts": self._ts, "t0_ns": int(self._t0 * 1e9),
+               "dur_ms": round(dur_ms, 4),
                "status": self.status, "pid": os.getpid()}
         if self.baggage:
             rec["baggage"] = dict(self.baggage)
@@ -293,6 +309,14 @@ class Span:
             ent[1] = p99 * _STATE["p99_factor"]
 
 
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` once jax is imported, else None
+    — this module imports no jax itself (a front door without it stays
+    without it)."""
+    jax = sys.modules.get("jax")
+    return getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+
+
 def _jsonable(v):
     if isinstance(v, (str, int, float, bool)) or v is None:
         return v
@@ -323,10 +347,12 @@ def add_span(name, ctx, ts, dur_ms, status="ok", **tags):
     per queued entry)."""
     if not ACTIVE[0] or ctx is None:
         return
+    # the caller's wall-clock start, carried onto the spans' clock
+    t0_ns = time.perf_counter_ns() - int((time.time() - float(ts)) * 1e9)
     rec = {"trace": ctx.trace_id, "span": _new_id(),
            "parent": ctx.span_id, "name": str(name), "ts": float(ts),
-           "dur_ms": round(float(dur_ms), 4), "status": str(status),
-           "pid": os.getpid()}
+           "t0_ns": t0_ns, "dur_ms": round(float(dur_ms), 4),
+           "status": str(status), "pid": os.getpid()}
     if ctx.baggage:
         rec["baggage"] = dict(ctx.baggage)
     if tags:
@@ -500,8 +526,11 @@ def chrome_events():
         args = {"trace": rec["trace"], "span": rec["span"],
                 "parent": rec["parent"], "status": rec["status"]}
         args.update(rec.get("tags") or {})
+        # perf_counter: the clock of profiler.py's own events and of
+        # telemetry.chrome_counter_events
         evs.append({"name": rec["name"], "cat": "trace", "ph": "X",
-                    "ts": rec["ts"] * 1e6, "dur": rec["dur_ms"] * 1000.0,
+                    "ts": rec["t0_ns"] / 1e3,
+                    "dur": rec["dur_ms"] * 1000.0,
                     "pid": rec["pid"],
                     "tid": zlib.crc32(rec["trace"].encode()) % 100000,
                     "args": args})
@@ -570,10 +599,26 @@ def enable(sample=None, seed=None, ring=None, trace_dir=None,
         atexit.register(_atexit_flush)
         _ATEXIT[0] = True
     ACTIVE[0] = True
+    _BORROWED[0] = False
 
 
 def disable():
     ACTIVE[0] = False
+    _BORROWED[0] = False
+
+
+def arm_ring():
+    """What ``telemetry.enable()`` throws: spans land in the ring and in
+    a running profiler trace; no exporter, no knob is read.  A process
+    armed by ``MXNET_TRACE`` / :func:`enable` is left as it is."""
+    if not ACTIVE[0]:
+        ACTIVE[0] = _BORROWED[0] = True
+
+
+def disarm_ring():
+    """Undo :func:`arm_ring`, and only that."""
+    if _BORROWED[0]:
+        ACTIVE[0] = _BORROWED[0] = False
 
 
 def _atexit_flush():

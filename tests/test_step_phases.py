@@ -1,0 +1,344 @@
+"""The step measured from inside (PR 25): the phase scopes in the two
+step programs, ``telemetry.register_program`` / ``program_hlo``, and the
+program's own step spans armed by ``telemetry.enable()``.
+
+- ``phases.phase_of`` / ``instruction_phases`` on hand-written HLO,
+  a metadata-less ``copy`` that inherits included;
+- a tiny ``Module`` fused step and a tiny ``ParallelTrainer`` step carry
+  ``jvp(mx_fwd)`` / ``transpose(jvp(mx_fwd))`` / ``mx_update/flatten`` /
+  ``mx_update/unflatten`` through the compiler, and ``program_hlo``
+  returns the text of the jit's own lowering;
+- the scopes change no computation: with ``jax.named_scope`` patched to
+  a null context the StableHLO text is the same;
+- ``telemetry.enable()`` arms the spans, ``telemetry.disable()`` takes
+  back only that.
+"""
+import contextlib
+import re
+
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import gluon, parallel, telemetry
+from mxnet_tpu.telemetry import phases, tracing
+
+
+@pytest.fixture(autouse=True)
+def _clean():
+    yield
+    telemetry.disable()
+    tracing.disable()
+    tracing.reset()
+    phases._PROGRAMS.clear()
+
+
+# ---------------------------------------------------------------------------
+# the two pure functions
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("op_name, phase", [
+    ("jit(fbu)/jvp(mx_fwd)/conv0/conv_general_dilated", "fwd"),
+    ("jit(step)/jvp(mx_fwd)/mx_loss/reduce_sum", "fwd"),
+    ("jit(fbu)/transpose(jvp(mx_fwd))/conv0/conv_general_dilated", "bwd"),
+    ("jit(step)/transpose(jvp(mx_fwd))/mx_loss/mul;"
+     "jit(step)/jvp(mx_fwd)/mx_loss/exp", "bwd"),
+    ("jit(fbu)/mx_update/flatten/reshape", "update"),
+    ("jit(step)/transpose(jvp(mx_update))/unflatten/pad", "update"),
+    ("jit(fbu)/mx_codec/convert_element_type", "update"),
+    ("jit(step)/mx_update/mx_coll:all_gather:b0/sharding_constraint",
+     "collective"),
+    ("jit(step)/transpose(jvp(mx_fwd))/mx_coll:reduce_scatter:b1/x",
+     "collective"),
+    ("jit(fbu)/jit(_threefry_fold_in)/xor", "other"),
+    ("w", "other"),
+    ("", "other"),
+    (None, "other"),
+])
+def test_phase_of(op_name, phase):
+    assert phases.phase_of(op_name) == phase
+
+
+HLO = """HloModule jit_fbu, is_scheduled=true
+
+%fused_computation (param_0: f32[4]) -> f32[4] {
+  %param_0 = f32[4]{0} parameter(0)
+  ROOT %neg.1 = f32[4]{0} negate(%param_0), metadata={op_name="jit(fbu)/transpose(jvp(mx_fwd))/neg" stack_frame_id=3}
+}
+
+ENTRY %main.9 (w.1: f32[4], x.1: f32[4]) -> (f32[4], f32[]) {
+  %w.1 = f32[4]{0} parameter(0), metadata={op_name="w"}
+  %x.1 = f32[4]{0} parameter(1), metadata={op_name="x"}
+  %copy-start.1 = (f32[4]{0:S(1)}, f32[4]{0}, u32[]) copy-start(%w.1)
+  %copy-done.1 = f32[4]{0:S(1)} copy-done(%copy-start.1)
+  %copy.0 = f32[4]{0} copy(%x.1), metadata={op_name="x"}
+  %fusion.1 = f32[4]{0} fusion(%copy-done.1, %copy.0), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(fbu)/jvp(mx_fwd)/mul" stack_frame_id=1}, backend_config={"x":{"y":"%not_an_operand"}}
+  %fusion.2 = f32[4]{0} fusion(%fusion.1), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(fbu)/transpose(jvp(mx_fwd))/neg" stack_frame_id=3}
+  %copy.3 = f32[4]{0} copy(%fusion.2)
+  %reshape.4 = f32[4]{0} reshape(%copy.3), metadata={op_name="jit(fbu)/mx_update/flatten/reshape"}
+  %_sgd_mom_kernel.5 = f32[4]{0} custom-call(%reshape.4), custom_call_target="tpu_custom_call", metadata={op_name="jit(fbu)/mx_update/sweep/pallas_call"}
+  %copy.6 = f32[4]{0} copy(%_sgd_mom_kernel.5)
+  %xor.7 = u32[] constant(1), metadata={op_name="jit(fbu)/xor"}
+  %bitcast.8 = u32[] bitcast(%xor.7)
+  ROOT %tuple.9 = (f32[4]{0}, u32[]) tuple(%copy.6, %bitcast.8)
+}
+"""
+
+
+def test_instruction_phases_on_a_handwritten_module():
+    got = phases.instruction_phases(HLO)
+    assert got["fusion.1"] == "fwd" and got["fusion.2"] == "bwd"
+    assert got["reshape.4"] == "update"
+    assert got["_sgd_mom_kernel.5"] == "update"
+    # a parameter's op_name names no scope and hands nothing on ...
+    assert got["w.1"] == "other" and got["xor.7"] == "other"
+    # ... so the prefetch of the weights takes the phase of what it feeds
+    assert got["copy-start.1"] == "fwd" and got["copy-done.1"] == "fwd"
+    # as does a layout change that carries its argument's name
+    assert got["x.1"] == "other" and got["copy.0"] == "fwd"
+    # a metadata-less copy takes the phase of what it reads
+    assert got["copy.3"] == "bwd" and got["copy.6"] == "update"
+    # nothing with a phase on either side: unattributed
+    assert got["bitcast.8"] == "other"
+    assert got["neg.1"] == "bwd"            # fused computations too
+    assert "not_an_operand" not in got and "fused_computation" not in got
+
+
+# ---------------------------------------------------------------------------
+# the two step programs
+# ---------------------------------------------------------------------------
+def _toy_module(monkeypatch):
+    monkeypatch.setenv("MXNET_PALLAS_FUSED_OPT", "1")   # the sweep's path
+    data = mx.sym.var("data")
+    net = mx.sym.Convolution(data, num_filter=8, kernel=(3, 3), pad=(1, 1),
+                             name="c1")
+    net = mx.sym.BatchNorm(net, name="bn1")
+    net = mx.sym.Activation(net, act_type="relu")
+    net = mx.sym.Flatten(mx.sym.Pooling(net, global_pool=True,
+                                        pool_type="avg", kernel=(1, 1)))
+    net = mx.sym.FullyConnected(net, num_hidden=10, name="fc")
+    sym = mx.sym.SoftmaxOutput(net, name="softmax")
+    x = np.random.rand(16, 1, 8, 8).astype(np.float32)
+    y = np.random.randint(0, 10, (16,)).astype(np.float32)
+    it = mx.io.NDArrayIter(x, y, batch_size=16, label_name="softmax_label")
+    mod = mx.mod.Module(sym, context=mx.cpu())
+    mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
+    mod.init_params(mx.init.Xavier())
+    mod.init_optimizer(kvstore="tpu", optimizer="sgd",
+                       optimizer_params={"learning_rate": 0.1,
+                                         "momentum": 0.9})
+    assert mod._exec_group.execs[0]._sweep is not None
+    return mod, next(iter(it))
+
+
+def _toy_trainer(monkeypatch):
+    import jax
+    monkeypatch.setenv("MXNET_PALLAS_FUSED_OPT", "1")
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(16, in_units=8, activation="relu"),
+            gluon.nn.Dense(4, in_units=16))
+    net.initialize(mx.init.Xavier())
+    mesh = parallel.make_mesh(dp=2, devices=jax.devices()[:2])
+    return parallel.ParallelTrainer(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+        {"learning_rate": 0.1, "momentum": 0.9}, mesh=mesh, zero=2,
+        bucket_bytes=4096)
+
+
+def _module_step(monkeypatch):
+    mod, _batch = _toy_module(monkeypatch)
+    return mod._exec_group.execs[0].step_callable("fused"), None
+
+
+def _trainer_step(monkeypatch):
+    tr = _toy_trainer(monkeypatch)
+    return tr.step_callable((8, 8)), tr
+
+
+STEPS = pytest.mark.parametrize("build", [_module_step, _trainer_step],
+                                ids=["module_fused", "parallel_trainer"])
+
+
+@STEPS
+def test_step_program_carries_the_phase_scopes(build, monkeypatch):
+    (jit_fn, args), tr = build(monkeypatch)
+    with parallel.mesh.mesh_scope(tr.mesh) if tr is not None \
+            else contextlib.nullcontext():
+        lowered = jit_fn.lower(*args)
+        text = lowered.compile().as_text()
+    # what the program hands the compiler ...
+    handed = lowered.as_text(debug_info=True)
+    for scope in ("jvp(mx_fwd)", "transpose(jvp(mx_fwd))",
+                  "mx_update/flatten", "mx_update/unflatten",
+                  "mx_update/sweep"):
+        assert scope in handed, scope
+    if tr is not None:
+        assert "mx_fwd)/mx_loss" in handed
+    # ... and what comes out of it (the CPU compiler folds this toy's
+    # flatten into bitcasts; the TPU's keeps it: perfbench/testdata)
+    for scope in ("jvp(mx_fwd)", "transpose(jvp(mx_fwd))", "mx_update/"):
+        assert scope in text, scope
+    found = set(phases.instruction_phases(text).values())
+    assert {"fwd", "bwd", "update"} <= found
+
+
+@STEPS
+def test_scopes_change_no_computation(build, monkeypatch):
+    """Metadata only: the StableHLO without debug info is the same text
+    with ``jax.named_scope`` patched to a null context."""
+    import jax
+
+    def stablehlo():
+        (jit_fn, args), tr = build(monkeypatch)
+        with parallel.mesh.mesh_scope(tr.mesh) if tr is not None \
+                else contextlib.nullcontext():
+            text = jit_fn.lower(*args).as_text()
+        # gluon numbers its blocks process-wide: dense2_weight, dense4_...
+        return re.sub(r"dense\d+_", "dense_", text)
+
+    scoped = stablehlo()
+    with monkeypatch.context() as m:
+        m.setattr(jax, "named_scope",
+                  lambda name: contextlib.nullcontext())
+        bare = stablehlo()
+    assert "mx_fwd" not in scoped       # debug info is not in this text
+    assert scoped == bare
+
+
+def _instruction_lines(text):
+    return [ln.split(", metadata=")[0] for ln in text.splitlines()
+            if " = " in ln]
+
+
+def test_program_hlo_is_the_fused_steps_own_lowering(monkeypatch):
+    mod, batch = _toy_module(monkeypatch)
+    mod.forward_backward(batch)         # telemetry off: nothing registered
+    assert telemetry.program_hlo("fbu") is None
+    telemetry.enable()
+    for _ in range(2):
+        mod.forward_backward(batch)
+        mod.update()
+    exe = mod._exec_group.execs[0]
+    ent = phases._PROGRAMS["fbu"]
+    assert ent[0] is exe._jit_fbu and ent[3] is None    # nothing compiled
+    import jax
+    assert not any(isinstance(leaf, jax.Array) and not
+                   isinstance(leaf, jax.core.Tracer) and leaf.size > 8
+                   for leaf in jax.tree_util.tree_leaves(ent[1]))
+    text = telemetry.program_hlo("fbu")
+    assert telemetry.program_hlo("fbu") is text         # memoised
+    assert ent[0] is None       # and the program is let go of
+    jit_fn, args = exe.step_callable("fused")
+    own = jit_fn.lower(*args).compile().as_text()
+    assert _instruction_lines(text) == _instruction_lines(own)
+    assert text.startswith("HloModule jit_fbu")
+
+
+def test_program_hlo_is_the_trainers_own_lowering(monkeypatch):
+    tr = _toy_trainer(monkeypatch)
+    telemetry.enable()
+    x = mx.nd.array(np.random.rand(8, 8).astype(np.float32))
+    y = mx.nd.array(np.random.randint(0, 4, (8,)).astype(np.float32))
+    for _ in range(2):
+        tr.step(x, y)
+    assert phases._PROGRAMS["step"][3] is None
+    text = telemetry.program_hlo("step")
+    jit_fn, args = tr.step_callable((8, 8))
+    with parallel.mesh.mesh_scope(tr.mesh):
+        own = jit_fn.lower(*args).compile().as_text()
+    assert _instruction_lines(text) == _instruction_lines(own)
+    assert text.startswith("HloModule jit_step")
+    # the newest registration of a name replaces the last
+    tr2 = _toy_trainer(monkeypatch)
+    tr2.step(x, y)
+    assert phases._PROGRAMS["step"][0] is tr2._jit_step
+    assert phases.program_names() == ["step"]
+
+
+# ---------------------------------------------------------------------------
+# the program's own step spans
+# ---------------------------------------------------------------------------
+def _nested(child, parent):
+    return parent["t0_ns"] <= child["t0_ns"] and \
+        child["t0_ns"] + child["dur_ms"] * 1e6 \
+        <= parent["t0_ns"] + parent["dur_ms"] * 1e6 + 1e3
+
+
+def test_enable_arms_the_module_spans_and_disable_takes_them_back(
+        monkeypatch):
+    mod, batch = _toy_module(monkeypatch)
+    metric = mx.metric.create("acc")
+    assert tracing.span("off") is tracing._NOOP
+    telemetry.enable()
+    assert tracing.ACTIVE[0]
+    for _ in range(3):
+        mod.forward_backward(batch)
+        mod.update()
+        mod.update_metric(metric, batch.label)
+    telemetry.disable()
+    assert tracing.span("off") is tracing._NOOP
+    mod.forward_backward(batch)         # records nothing
+    recs = tracing.snapshot()
+    by_name = {}
+    for r in recs:
+        by_name.setdefault(r["name"], []).append(r)
+    assert len(by_name["module.forward_backward"]) == 3
+    assert len(by_name["module.update_metric"]) == 3
+    assert "module.update" not in by_name   # the fused step already did it
+    t0s = [r["t0_ns"] for r in by_name["module.forward_backward"]]
+    assert t0s == sorted(t0s) and len(set(t0s)) == 3
+    for name in ("executor.hyper", "executor.dispatch"):
+        for child, parent in zip(by_name[name],
+                                 by_name["module.forward_backward"]):
+            assert child["parent"] == parent["span"]
+            assert _nested(child, parent)
+    # one clock for the chrome dump: perf_counter microseconds
+    ev = tracing.chrome_events()[0]
+    assert ev["ts"] == recs[0]["t0_ns"] / 1e3
+
+
+def test_enable_arms_the_trainer_spans(monkeypatch):
+    tr = _toy_trainer(monkeypatch)
+    x = mx.nd.array(np.random.rand(8, 8).astype(np.float32))
+    y = mx.nd.array(np.random.randint(0, 4, (8,)).astype(np.float32))
+    telemetry.enable()
+    tr.step(x, y)
+    tr.step(x, y)
+    telemetry.disable()
+    recs = tracing.snapshot()
+    steps = [r for r in recs if r["name"] == "trainer.step"]
+    inner = [r for r in recs if r["name"] == "trainer.dispatch"]
+    assert len(steps) == len(inner) == 2
+    assert steps[0]["t0_ns"] < steps[1]["t0_ns"]
+    for child, parent in zip(inner, steps):
+        assert child["parent"] == parent["span"] and _nested(child, parent)
+
+
+def test_fit_step_is_the_parent_of_the_module_spans(monkeypatch):
+    mod, _batch = _toy_module(monkeypatch)
+    x = np.random.rand(32, 1, 8, 8).astype(np.float32)
+    y = np.random.randint(0, 10, (32,)).astype(np.float32)
+    it = mx.io.NDArrayIter(x, y, batch_size=16, label_name="softmax_label")
+    telemetry.enable()
+    mod.fit(it, num_epoch=1, kvstore="tpu", optimizer="sgd",
+            optimizer_params={"learning_rate": 0.1, "momentum": 0.9})
+    telemetry.disable()
+    recs = tracing.snapshot()
+    fit = [r for r in recs if r["name"] == "fit.step"]
+    assert len(fit) == 2
+    assert len([r for r in recs if r["name"] == "fit.data_wait"]) == 2
+    for name in ("module.forward_backward", "module.update_metric"):
+        kids = [r for r in recs if r["name"] == name]
+        assert [k["parent"] for k in kids] == [f["span"] for f in fit]
+
+
+def test_an_explicitly_traced_process_stays_armed():
+    tracing.enable(sample=1.0, trace_dir=None)
+    telemetry.enable()
+    telemetry.disable()
+    assert tracing.ACTIVE[0] and tracing.span("x") is not tracing._NOOP
+    tracing.disable()
+    # and the other order: enable() after telemetry armed owns the switch
+    telemetry.enable()
+    tracing.enable(sample=1.0, trace_dir=None)
+    telemetry.disable()
+    assert tracing.ACTIVE[0]
