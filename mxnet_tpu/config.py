@@ -355,8 +355,11 @@ register_env("MXNET_KERN_VMEM_BYTES", int, 16 * 1024 * 1024,
              "exceeds it fails tools/lint.py --kern; default 16 MiB "
              "(v5e-class core)")
 register_env("MXNET_PALLAS_FUSED_OPT", str, "auto",
-             "one-sweep Pallas optimizer (ParallelTrainer ZeRO sweep, "
-             "executor fused step; fused_sgd_momentum/fused_adam): "
+             "one-sweep Pallas optimizer (ParallelTrainer ZeRO sweep; "
+             "executor fused step, where only the 1-D leaves — biases, "
+             "norm gammas/betas — ride the flat buckets and every N-D "
+             "weight is updated per array in its own layout; "
+             "fused_sgd_momentum/fused_adam): "
              "auto = on where the kernels compile natively (TPU), 1 = "
              "force on anywhere (interpret mode — how CPU tier-1 "
              "exercises the kernels), 0 = off; the per-array tree_map "
@@ -389,8 +392,10 @@ register_env("MXNET_PALLAS_SOFTMAX_BLOCK_ROWS", int, 0,
              tunable=True)
 register_env("MXNET_PALLAS_OPT_BUCKET_BYTES", int, 0,
              "bucket size cap for the executor fused step's optimizer "
-             "sweep (params flattened into contiguous fp32 buckets); "
-             "<= 0 sweeps everything as one monolithic bucket",
+             "sweep (the 1-D leaves of each (lr_mult, wd_mult) group "
+             "concatenated into contiguous fp32 buckets; N-D weights "
+             "are never bucketed); <= 0 sweeps each group as one "
+             "monolithic bucket",
              tunable=True)
 register_env("MXNET_FAULT_PLAN", str, None,
              "deterministic fault-injection schedule (graftfault): "

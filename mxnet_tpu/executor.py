@@ -209,12 +209,14 @@ class Executor:
         self._fused_resids = None
         self._jit_fbu = None
         self._updates_applied = False
-        # one-sweep Pallas path (MXNET_PALLAS_FUSED_OPT): flatten the
-        # weights into contiguous fp32 buckets and update each bucket in
-        # ONE kernel instead of a per-array kernel stream — the
-        # mega-kernel tail cut (ROADMAP item 3).  None falls back to the
-        # per-array path, which stays the bit-parity oracle.
+        # one-sweep Pallas path (MXNET_PALLAS_FUSED_OPT): the 1-D
+        # leaves (biases, norm scales/shifts) are concatenated into
+        # contiguous fp32 buckets and each bucket updates in ONE kernel
+        # instead of a tail of tiny per-array kernels (ROADMAP item 3);
+        # every N-D weight is updated in its own layout.  None is the
+        # all-per-array program, which stays the bit-parity oracle.
         self._sweep = self._plan_sweep(optimizer)
+        self._export_update_gauges()
         return True
 
     @property
@@ -237,14 +239,26 @@ class Executor:
     def _plan_sweep(self, optimizer):
         """Bucket plan for the one-sweep fused optimizer, or None.
 
-        Weights are grouped by their static (lr_mult, wd_mult) pair —
-        each group's members share one effective (lr, wd) at every
-        step, so each bucket's hyperparameters stay two scalars riding
-        the kernel's scalar-prefetch operand (per-element lr/wd vectors
-        would double the sweep's HBM traffic).  The reference
-        convention of wd_mult=0 on biases/norms makes two groups the
-        common case.  Eligibility: SGD/Adam (the kernels we have) over
-        all-fp32 weights."""
+        A leaf rides a flat bucket only if flattening it IS a
+        concatenation: ``ndim <= 1`` (biases, BatchNorm/LayerNorm
+        gammas and betas).  A row-major 1-D view of an N-D weight is not
+        free on the TPU — the compiler keeps e.g. a ``[O, I, 3, 3]``
+        convolution weight tiled with the 3x3 dims major, so
+        ``reshape(-1)`` is an element-wise re-layout, paid for weights
+        in, gradients in and weights out on every step (ResNet-50: 46 ms
+        around a 0.6 ms kernel; PERF.md, PR 26).  Those leaves
+        (``info["rest"]``) are updated per array in their own layout
+        inside the same program.
+
+        The bucketed leaves are grouped by their static (lr_mult,
+        wd_mult) pair — each group's members share one effective
+        (lr, wd) at every step, so each bucket's hyperparameters stay
+        two scalars riding the kernel's scalar-prefetch operand
+        (per-element lr/wd vectors would double the sweep's HBM
+        traffic).  The reference convention of wd_mult=0 on
+        biases/betas makes two groups the common case.  Eligibility:
+        SGD/Adam (the kernels we have) over all-fp32 weights, and at
+        least one 1-D leaf."""
         from . import config as _config
         from .ops.pallas_kernels import family_enabled
         if not family_enabled("MXNET_PALLAS_FUSED_OPT"):
@@ -256,13 +270,18 @@ class Executor:
         if any(self.arg_dict[n].dtype != np.float32 for n in names):
             return None
         from .parallel.collectives import build_bucket_plan
-        groups = {}
-        for j, (i, n) in enumerate(zip(self._diff_idx, names)):
+        groups, rest = {}, []
+        for j, n in enumerate(names):
+            if len(self.arg_dict[n].shape) > 1:
+                rest.append(j)
+                continue
             key = (float(optimizer._param_mult(n, optimizer.lr_mult,
                                                "lr_mult")),
                    float(optimizer._param_mult(n, optimizer.wd_mult,
                                                "wd_mult")))
             groups.setdefault(key, []).append(j)
+        if not groups:
+            return None
         cap = _config.tuned("MXNET_PALLAS_OPT_BUCKET_BYTES",
                             program="executor-fused-step")
         plan = []
@@ -281,7 +300,7 @@ class Executor:
         clip = optimizer.clip_gradient
         if clip is not None and clip < 0:
             clip = None
-        info = {"kind": kind.lower(), "plan": plan,
+        info = {"kind": kind.lower(), "plan": plan, "rest": rest,
                 "rescale": float(optimizer.rescale_grad), "clip": clip}
         if kind == "SGD":
             info["momentum"] = float(optimizer.momentum)
@@ -291,19 +310,48 @@ class Executor:
                         epsilon=float(optimizer.epsilon))
         return info
 
+    def _export_update_gauges(self):
+        """How the installed update splits the leaves, set when the plan
+        is made or dropped (never per step)."""
+        if not _telemetry.enabled():
+            return
+        swept = {j for _b, idxs in self._sweep["plan"] for j in idxs} \
+            if self._sweep is not None else set()
+        split = {"sweep": [], "per_array": []}
+        for j, i in enumerate(self._diff_idx):
+            split["sweep" if j in swept else "per_array"].append(
+                self.arg_dict[self.arg_names[i]])
+        g_leaves = _telemetry.gauge(
+            "mxnet_fused_update_leaves",
+            "leaves of the newest fused executor step by update path "
+            "(sweep = 1-D leaves in flat Pallas buckets, per_array = "
+            "updated in their own layout)")
+        g_bytes = _telemetry.gauge(
+            "mxnet_fused_update_bytes",
+            "weight bytes of the newest fused executor step by update "
+            "path (sweep / per_array)")
+        for path, arrs in split.items():
+            g_leaves.labels(path=path).set(len(arrs))
+            g_bytes.labels(path=path).set(sum(
+                int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
+                for a in arrs))
+
     @property
     def updates_applied(self):
         return self._updates_applied
 
     def _sweep_update(self, diff, grads, states, lrs, wds):
-        """One-sweep fused optimizer: flatten each bucket's weights and
-        gradients into contiguous fp32 buffers and run ONE Pallas kernel
-        per bucket (ops/pallas_kernels.py) — slots live bucket-major in
-        the fused state.  lrs/wds are per-BUCKET packed scalars.
-        Returns (new_diff, new_states)."""
+        """The mixed update of a sweep plan.  Each bucket's 1-D weights
+        and gradients are concatenated into contiguous fp32 buffers and
+        updated by ONE Pallas kernel (ops/pallas_kernels.py); every
+        remaining (N-D) leaf is updated in its own layout by the
+        per-array kernel, so no weight is ever re-laid.  states / lrs /
+        wds are packed per bucket, then per remaining leaf
+        (:meth:`_state_like`).  Returns (new_diff, new_states)."""
         from .ops import pallas_kernels as pk
         from .parallel.collectives import flatten_bucket, unflatten_bucket
         sw = self._sweep
+        one = self._fused_update[2]
         new_diff = list(diff)
         new_states = []
         for bi, (b, idxs) in enumerate(sw["plan"]):
@@ -327,36 +375,45 @@ class Executor:
             views = unflatten_bucket(nw, b)
             for j, name in zip(idxs, b.names):
                 new_diff[j] = views[name].astype(diff[j].dtype)
+        for k, j in enumerate(sw["rest"], len(sw["plan"])):
+            new_diff[j], nst = one(diff[j], grads[j], states[k],
+                                   lrs[k], wds[k])
+            new_states.append(nst)
         return new_diff, new_states
 
-    def _sweep_init_state(self):
-        """Bucket-major slots for the sweep (host-built zeros: no XLA
-        broadcast compile per bucket, same rationale as the per-array
-        init's _host_zeros_like)."""
+    def _state_like(self, diff):
+        """One shape/dtype carrier per entry of the fused state (and of
+        the packed lrs / wds), in order: per weight on the per-array
+        path; on the sweep per BUCKET (its flat fp32 buffer), then per
+        remaining leaf."""
         sw = self._sweep
-        n_slots = (1 if sw["momentum"] != 0.0 else 0) \
-            if sw["kind"] == "sgd" else 2
-        return [tuple(jnp.asarray(np.zeros((b.n,), np.float32))
-                      for _ in range(n_slots))
-                for b, _idxs in sw["plan"]]
+        if sw is None:
+            return list(diff)
+        return [jax.ShapeDtypeStruct((b.n,), jnp.float32)
+                for b, _idxs in sw["plan"]] + [diff[j] for j in sw["rest"]]
 
     def _demote_sweep(self):
         """Permanently fall back from the sweep to the per-array path
         (a runtime multiplier change invalidated the bucket grouping):
-        bucket-major slots are sliced back into per-weight arrays —
-        values bit-identical, only the layout changes — and the fused
-        program rebuilds on the next dispatch."""
+        bucket-major slots are sliced back into per-weight arrays, the
+        remaining leaves' slots move to their weight's index — values
+        bit-identical — and the fused program rebuilds on the next
+        dispatch."""
         from .parallel.collectives import unflatten_bucket
+        sw = self._sweep
         if self._fused_state is not None:
             per = [()] * len(self._diff_idx)
-            for bi, (b, idxs) in enumerate(self._sweep["plan"]):
+            for bi, (b, idxs) in enumerate(sw["plan"]):
                 views = [unflatten_bucket(s, b)
                          for s in self._fused_state[bi]]
                 for j, name in zip(idxs, b.names):
                     per[j] = tuple(v[name] for v in views)
+            for k, j in enumerate(sw["rest"], len(sw["plan"])):
+                per[j] = self._fused_state[k]
             self._fused_state = per
         self._sweep = None
         self._jit_fbu = None
+        self._export_update_gauges()
 
     def _build_fbu(self):
         import jax as _jax
@@ -400,8 +457,9 @@ class Executor:
                         new_resids.append(nr)
                 grads = decoded
             # lrs/wds are ONE packed array each (per weight on the
-            # per-array path, per BUCKET on the sweep) — one host
-            # transfer when the schedule moves, not one per scalar
+            # per-array path; on the sweep per BUCKET, then per
+            # remaining leaf) — one host transfer when the schedule
+            # moves, not one per scalar
             with _jax.named_scope(_phases.UPDATE_SCOPE):
                 if sweep is not None:
                     new_diff, new_states = self._sweep_update(
@@ -430,9 +488,9 @@ class Executor:
         return _jax.jit(fbu, donate_argnums=(0, 5, 6))
 
     def _fused_lr_wd(self, optimizer):
-        """This step's (lrs, wds) as device arrays — per weight on the
-        per-array path, per BUCKET on the sweep — advancing the
-        optimizer's schedule bookkeeping for every weight."""
+        """This step's (lrs, wds) as device arrays, packed in
+        :meth:`_state_like`'s order, advancing the optimizer's schedule
+        bookkeeping for every weight."""
         from . import optimizer as opt_mod
         sweep = getattr(self, "_sweep", None)
         lrs, wds = [], []
@@ -451,17 +509,16 @@ class Executor:
             self._demote_sweep()
             sweep = None
         if sweep is not None:
-            # per-BUCKET scalars: every member of a bucket shares its
-            # static (lr_mult, wd_mult), so the first member's effective
-            # values are the bucket's (the per-index loop above still
-            # ran — num_update bookkeeping advances for every weight)
-            lrs = np.asarray([lrs[idxs[0]] for _b, idxs in sweep["plan"]],
-                             np.float32)
-            wds = np.asarray([wds[idxs[0]] for _b, idxs in sweep["plan"]],
-                             np.float32)
-        else:
-            lrs = np.asarray(lrs, np.float32)
-            wds = np.asarray(wds, np.float32)
+            # per-BUCKET scalars, then the remaining leaves' own: every
+            # member of a bucket shares its static (lr_mult, wd_mult),
+            # so the first member's effective values are the bucket's
+            # (the per-index loop above still ran — num_update
+            # bookkeeping advances for every weight)
+            order = [idxs[0] for _b, idxs in sweep["plan"]] + sweep["rest"]
+            lrs = [lrs[j] for j in order]
+            wds = [wds[j] for j in order]
+        lrs = np.asarray(lrs, np.float32)
+        wds = np.asarray(wds, np.float32)
         # device-resident lr/wd cache, refreshed only when the schedule
         # moves — no host transfer on a steady-state step
         cached = getattr(self, "_lr_wd_cache", None)
@@ -478,11 +535,11 @@ class Executor:
         # None placeholders where diff args go (overwritten inside the
         # program) — the donated weight buffers must not appear twice
         rest = [None if i in diff_set else a for i, a in enumerate(args)]
-        sweep = getattr(self, "_sweep", None)
         if self._fused_state is None:
-            self._fused_state = (self._sweep_init_state()
-                                 if sweep is not None
-                                 else [init_state(d) for d in diff])
+            # host-built zeros (init_state's _host_zeros_like): no XLA
+            # broadcast compile per bucket or weight shape
+            self._fused_state = [init_state(d)
+                                 for d in self._state_like(diff)]
         if self._fused_resids is None:
             # error-feedback residuals, one per weight when a codec is
             # installed (empty pytree otherwise: ONE program shape)
@@ -986,36 +1043,27 @@ class Executor:
         if self._fused_update is None:
             raise MXNetError("step_callable('fused') requires "
                              "install_fused_update() first")
-        sweep = self._sweep
         diff_set = set(self._diff_idx)
         diff = [args[i] for i in self._diff_idx]
         rest = [None if i in diff_set else a for i, a in enumerate(args)]
         init_state = self._fused_update[1]
+        like = self._state_like(diff)
         if self._fused_state is not None:
             states = _jax.tree_util.tree_map(_sds, self._fused_state)
-        elif sweep is not None:
-            # abstract mirror of _sweep_init_state's bucket-major slot
-            # layout — no buffers materialize for a trace
-            n_slots = (1 if sweep["momentum"] != 0.0 else 0) \
-                if sweep["kind"] == "sgd" else 2
-            states = [tuple(_jax.ShapeDtypeStruct((b.n,), jnp.float32)
-                            for _ in range(n_slots))
-                      for b, _idxs in sweep["plan"]]
         else:
-            # slots are zeros_like(weight) (fused_update_kernel's
+            # slots are zeros_like(carrier) (fused_update_kernel's
             # init_state contract) — build ONE prototype to learn the
-            # slot count/dtypes, then mirror abstractly per weight
+            # slot count/dtypes, then mirror abstractly per entry
             # instead of allocating the full state
-            proto = init_state(diff[0]) if diff else ()
+            proto = init_state(like[0]) if like else ()
             states = [tuple(_jax.ShapeDtypeStruct(d.shape, s.dtype)
-                            for s in proto) for d in diff]
+                            for s in proto) for d in like]
         resids = ([_jax.ShapeDtypeStruct(d.shape, jnp.float32)
                    for d in diff]
                   if getattr(self, "_fused_codec", None) is not None
                   else [])
-        n_hyper = len(sweep["plan"]) if sweep is not None else len(diff)
-        lrs = _jax.ShapeDtypeStruct((n_hyper,), jnp.float32)
-        wds = _jax.ShapeDtypeStruct((n_hyper,), jnp.float32)
+        lrs = _jax.ShapeDtypeStruct((len(like),), jnp.float32)
+        wds = _jax.ShapeDtypeStruct((len(like),), jnp.float32)
         outs = _jax.eval_shape(self._jit_fwd_train, args, aux, key)[0]
         seeds = [_jax.ShapeDtypeStruct(o.shape, o.dtype) for o in outs]
         if self._jit_fbu is None:

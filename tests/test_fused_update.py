@@ -242,16 +242,22 @@ def test_accuracy_device_accumulation():
     assert abs(m2.get()[1] - 0.5) < 1e-6
 
 
-def test_module_pallas_sweep_matches_per_array(monkeypatch):
-    """The executor's one-sweep Pallas update (MXNET_PALLAS_FUSED_OPT,
-    default on) must train to EXACTLY the per-array kernel stream's
-    weights — same expressions, same grouping, flatten/slice is
-    value-preserving.  Weights group by static (lr_mult, wd_mult):
-    biases/norms ride a wd=0 bucket (reference wd_mult convention)."""
+@pytest.mark.parametrize("opt, params", [
+    ("sgd", {"learning_rate": 0.1, "momentum": 0.9, "wd": 0.01}),
+    ("adam", {"learning_rate": 0.01, "wd": 0.001}),
+])
+def test_module_pallas_sweep_matches_per_array(monkeypatch, opt, params):
+    """The executor's mixed update (MXNET_PALLAS_FUSED_OPT, default on:
+    1-D leaves in flat Pallas buckets, every N-D weight per array in its
+    own layout) must train to EXACTLY the all-per-array kernel stream's
+    weights — same expressions, same grouping, concatenate/slice is
+    value-preserving.  Bucketed leaves group by static (lr_mult,
+    wd_mult): biases/betas ride a wd=0 bucket (reference wd_mult
+    convention), the BatchNorm gamma one with weight decay."""
     sym = _toy_symbol()
     x, y = _toy_data()
 
-    def train(knob, opt, opt_params):
+    def train(knob):
         monkeypatch.setenv("MXNET_PALLAS_FUSED_OPT", knob)
         mx.random.seed(7)
         np.random.seed(7)
@@ -259,7 +265,7 @@ def test_module_pallas_sweep_matches_per_array(monkeypatch):
                                label_name="softmax_label")
         mod = mx.mod.Module(sym, context=mx.cpu())
         mod.fit(it, num_epoch=2, kvstore="tpu", optimizer=opt,
-                optimizer_params=opt_params,
+                optimizer_params=params,
                 initializer=mx.init.Xavier(), force_init=True,
                 force_rebind=True)
         exe = mod._exec_group.execs[0]
@@ -267,17 +273,89 @@ def test_module_pallas_sweep_matches_per_array(monkeypatch):
         return ({k: v.asnumpy() for k, v in args.items()},
                 getattr(exe, "_sweep", None))
 
-    for opt, params in (("sgd", {"learning_rate": 0.1, "momentum": 0.9,
-                                 "wd": 0.01}),
-                        ("adam", {"learning_rate": 0.01, "wd": 0.001})):
-        w_sweep, sweep = train("1", opt, params)
-        w_array, off = train("0", opt, params)
-        assert sweep is not None, "sweep did not engage"
-        assert off is None, "knob=0 must fall back to the per-array path"
-        assert len(sweep["plan"]) >= 2   # wd_mult split biases out
-        for k in w_sweep:
-            np.testing.assert_array_equal(w_sweep[k], w_array[k],
-                                          err_msg="%s/%s" % (opt, k))
+    w_sweep, sweep = train("1")
+    w_array, off = train("0")
+    assert sweep is not None, "sweep did not engage"
+    assert off is None, "knob=0 must fall back to the per-array path"
+    assert len(sweep["plan"]) >= 2   # wd_mult split the gamma out
+    # selection is by rank alone: no bucket holds an N-D leaf, and every
+    # N-D leaf is on the per-array list
+    for b, idxs in sweep["plan"]:
+        assert all(len(shape) <= 1 for shape in b.shapes), b
+        assert len(idxs) == len(b.names)
+    bucketed = sorted(n for b, _ in sweep["plan"] for n in b.names)
+    assert bucketed == sorted(k for k, v in w_sweep.items() if v.ndim <= 1)
+    assert len(sweep["rest"]) == sum(v.ndim > 1 for v in w_sweep.values())
+    for k in w_sweep:
+        np.testing.assert_array_equal(w_sweep[k], w_array[k],
+                                      err_msg="%s/%s" % (opt, k))
+
+
+def test_sweep_is_none_without_a_1d_leaf(monkeypatch):
+    """A module whose every leaf is N-D has nothing to bucket: the plan
+    is None, i.e. the all-per-array program."""
+    monkeypatch.setenv("MXNET_PALLAS_FUSED_OPT", "1")
+    net = mx.sym.FullyConnected(mx.sym.var("data"), num_hidden=16,
+                                no_bias=True, name="fc1")
+    net = mx.sym.Activation(net, act_type="relu")
+    net = mx.sym.FullyConnected(net, num_hidden=4, no_bias=True, name="fc2")
+    net = mx.sym.SoftmaxOutput(net, name="softmax")
+    rng = np.random.RandomState(0)
+    it = mx.io.NDArrayIter(rng.rand(32, 8).astype(np.float32),
+                           rng.randint(0, 4, 32).astype(np.float32),
+                           batch_size=8, label_name="softmax_label")
+    mod = mx.mod.Module(net, context=mx.cpu())
+    mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
+    mod.init_params(mx.init.Xavier())
+    mod.init_optimizer(kvstore="tpu", optimizer="sgd",
+                       optimizer_params={"learning_rate": 0.1,
+                                         "momentum": 0.9})
+    exe = mod._exec_group.execs[0]
+    assert mod._fused_exec_update is True
+    assert exe._sweep is None
+    before = {k: v.asnumpy() for k, v in mod.get_params()[0].items()}
+    batch = next(iter(it))
+    mod.forward(batch, is_train=True)
+    mod.backward()
+    mod.update()
+    assert len(exe._fused_state) == 2           # one slot tuple a weight
+    after = mod.get_params()[0]
+    assert all((after[k].asnumpy() != before[k]).any() for k in before)
+
+
+def test_fused_update_gauges_read_the_leaf_split(monkeypatch):
+    """mxnet_fused_update_{leaves,bytes}{path=sweep|per_array} are set
+    when the plan is made: the toy module has four 1-D leaves (c1_bias,
+    bn1_gamma, bn1_beta, fc_bias: 8 + 8 + 8 + 10 floats) and two N-D
+    (c1_weight 8x1x3x3, fc_weight 10x8)."""
+    from mxnet_tpu import telemetry
+    sym = _toy_symbol()
+    x, y = _toy_data(32)
+
+    def install(knob):
+        monkeypatch.setenv("MXNET_PALLAS_FUSED_OPT", knob)
+        it = mx.io.NDArrayIter(x, y, batch_size=16, shuffle=False,
+                               label_name="softmax_label")
+        mod = mx.mod.Module(sym, context=mx.cpu())
+        mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
+        mod.init_params(mx.init.Xavier())
+        mod.init_optimizer(kvstore="tpu", optimizer="sgd",
+                           optimizer_params={"learning_rate": 0.1,
+                                             "momentum": 0.9})
+        leaves = telemetry.gauge("mxnet_fused_update_leaves")
+        nbytes = telemetry.gauge("mxnet_fused_update_bytes")
+        return {path: (leaves.labels(path=path).value,
+                       nbytes.labels(path=path).value)
+                for path in ("sweep", "per_array")}
+
+    telemetry.enable()
+    try:
+        assert install("1") == {"sweep": (4, 4 * 34),
+                                "per_array": (2, 4 * (72 + 80))}
+        assert install("0") == {"sweep": (0, 0),
+                                "per_array": (6, 4 * (34 + 72 + 80))}
+    finally:
+        telemetry.disable()
 
 
 def test_fused_sweep_lr_schedule_no_recompile(monkeypatch):
@@ -338,11 +416,15 @@ def test_sweep_negative_clip_sentinel_means_disabled(monkeypatch):
     assert exe._sweep["clip"] is None
 
 
-def test_sweep_demotes_on_runtime_mult_change(monkeypatch):
-    """set_lr_mult AFTER install breaks the uniform-bucket contract:
-    the executor must demote to the per-array path (slot values carried
-    over) instead of stepping with a stale group lr — final weights
-    must match a run that was per-array throughout."""
+@pytest.mark.parametrize("leaf, demotes", [("fc_bias", True),
+                                           ("fc_weight", False)])
+def test_sweep_demotes_on_runtime_mult_change(monkeypatch, leaf, demotes):
+    """set_lr_mult on a BUCKETED leaf AFTER install breaks the
+    uniform-bucket contract: the executor must demote to the per-array
+    path (both kinds of slot carried over) instead of stepping with a
+    stale group lr.  On an N-D weight it breaks nothing — that leaf
+    carries its own lr — and the plan stays.  Either way the final
+    weights must match a run that was per-array throughout."""
     sym = _toy_symbol()
     x, y = _toy_data(32)
 
@@ -363,7 +445,7 @@ def test_sweep_demotes_on_runtime_mult_change(monkeypatch):
         batch = next(iter(it))
         for step in range(4):
             if step == 2:
-                mod._optimizer.set_lr_mult({"fc_weight": 0.1})
+                mod._optimizer.set_lr_mult({leaf: 0.1})
             mod.forward(batch, is_train=True)
             mod.backward()
             mod.update()
@@ -372,7 +454,14 @@ def test_sweep_demotes_on_runtime_mult_change(monkeypatch):
         return {k: v.asnumpy() for k, v in args.items()}, exe
 
     w_sweep, exe = train("1")
-    assert exe._sweep is None, "mult change must demote the sweep"
+    if demotes:
+        assert exe._sweep is None, "mult change must demote the sweep"
+        # per-weight slots again, in the weights' order and shapes
+        shapes = [exe.arg_dict[exe.arg_names[i]].shape
+                  for i in exe._diff_idx]
+        assert [st[0].shape for st in exe._fused_state] == shapes
+    else:
+        assert exe._sweep is not None, "an N-D leaf's mult is its own"
     w_array, _ = train("0")
     for k in w_sweep:
         np.testing.assert_array_equal(w_sweep[k], w_array[k], err_msg=k)

@@ -10,6 +10,9 @@ program's own step spans armed by ``telemetry.enable()``.
   returns the text of the jit's own lowering;
 - the scopes change no computation: with ``jax.named_scope`` patched to
   a null context the StableHLO text is the same;
+- the fused step never re-lays a weight (PR 26): only rank-1 operands
+  enter ``mx_update/flatten``, and an N-D weight reaches its output
+  through elementwise ops alone;
 - ``telemetry.enable()`` arms the spans, ``telemetry.disable()`` takes
   back only that.
 """
@@ -106,7 +109,7 @@ def test_instruction_phases_on_a_handwritten_module():
 # ---------------------------------------------------------------------------
 # the two step programs
 # ---------------------------------------------------------------------------
-def _toy_module(monkeypatch):
+def _toy_module(monkeypatch, optimizer="sgd", optimizer_params=None):
     monkeypatch.setenv("MXNET_PALLAS_FUSED_OPT", "1")   # the sweep's path
     data = mx.sym.var("data")
     net = mx.sym.Convolution(data, num_filter=8, kernel=(3, 3), pad=(1, 1),
@@ -123,9 +126,9 @@ def _toy_module(monkeypatch):
     mod = mx.mod.Module(sym, context=mx.cpu())
     mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
     mod.init_params(mx.init.Xavier())
-    mod.init_optimizer(kvstore="tpu", optimizer="sgd",
-                       optimizer_params={"learning_rate": 0.1,
-                                         "momentum": 0.9})
+    mod.init_optimizer(kvstore="tpu", optimizer=optimizer,
+                       optimizer_params=optimizer_params or
+                       {"learning_rate": 0.1, "momentum": 0.9})
     assert mod._exec_group.execs[0]._sweep is not None
     return mod, next(iter(it))
 
@@ -202,6 +205,70 @@ def test_scopes_change_no_computation(build, monkeypatch):
         bare = stablehlo()
     assert "mx_fwd" not in scoped       # debug info is not in this text
     assert scoped == bare
+
+
+_OP = re.compile(r"^\s*(%\w+)(?::\d+)? = \"?([\w.]+)\"?(.*) loc\((#loc\d+)\)$")
+_LAYOUT_OPS = {"reshape", "concatenate", "slice", "dynamic_slice", "pad",
+               "transpose", "gather", "dynamic_update_slice"}
+
+
+def _main_ops(handed):
+    """``{ssa: (op, operands, operand ranks, scope)}`` of @main in a
+    StableHLO text with debug info, and the values it returns."""
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', handed, re.M))
+    body = handed[handed.index("func.func public @main"):]
+    ops, returned = {}, None
+    for ln in body.splitlines():
+        if ln.startswith("    return "):
+            returned = re.findall(r"%\w+", ln.split(" : ")[0])
+            break
+        m = _OP.match(ln)
+        if m is None:
+            continue
+        ssa, op, rest, loc = m.groups()
+        sig = rest.rsplit(" : ", 1)
+        types = sig[1].split(" -> ")[0] if len(sig) == 2 else ""
+        ranks = [t.count("x") for t in re.findall(r"tensor<([^>]*)>", types)]
+        ops[ssa] = (op.split(".")[-1], re.findall(r"%\w+", sig[0]), ranks,
+                    names.get(loc, ""))
+    return ops, returned
+
+
+@pytest.mark.parametrize("optimizer, optimizer_params", [
+    ("sgd", {"learning_rate": 0.1, "momentum": 0.9, "wd": 1e-4}),
+    ("adam", {"learning_rate": 0.01, "wd": 1e-4}),
+])
+def test_fused_step_never_relays_a_weight(monkeypatch, optimizer,
+                                          optimizer_params):
+    """What the chip gain of PR 26 rests on, read off the program handed
+    to the compiler: flattening is a concatenation of rank-1 leaves, and
+    the conv and dense weights go from argument to output through
+    elementwise ops in their own shape."""
+    mod, _batch = _toy_module(monkeypatch, optimizer, optimizer_params)
+    exe = mod._exec_group.execs[0]
+    jit_fn, args = exe.step_callable("fused")
+    ops, returned = _main_ops(jit_fn.lower(*args).as_text(debug_info=True))
+    flat = [v for v in ops.values() if "/mx_update/flatten/" in v[3]]
+    assert len(flat) >= 2   # weights, gradients (a one-leaf bucket has none)
+    for op, _operands, ranks, _scope in flat:
+        assert op == "concatenate" and set(ranks) == {1}, (op, ranks)
+    shapes = [exe.arg_dict[exe.arg_names[i]].shape for i in exe._diff_idx]
+    nd = [j for j, shape in enumerate(shapes) if len(shape) > 1]
+    assert nd == exe._sweep["rest"] and len(nd) == 2
+    for j in nd:
+        # outputs are (outs, new_diff, ...): the toy has one output
+        seen, todo, reached = set(), [returned[1 + j]], False
+        while todo:
+            v = todo.pop()
+            if v == "%%arg%d" % j:
+                reached = True
+            if v in seen or v not in ops or "/mx_update/" not in ops[v][3]:
+                continue
+            seen.add(v)
+            assert ops[v][0] not in _LAYOUT_OPS or max(ops[v][2]) <= 1, \
+                (exe.arg_names[exe._diff_idx[j]], ops[v])
+            todo.extend(ops[v][1])
+        assert reached and len(seen) >= 3, (j, seen)
 
 
 def _instruction_lines(text):
