@@ -31,6 +31,7 @@ from ..nn import Dense, Dropout, Embedding, HybridSequential, LayerNorm
 from ..parameter import DeferredInitializationError
 
 __all__ = ["MultiHeadAttention", "TransformerEncoderCell", "TransformerLM",
+           "LoopedLM", "looped_lm_forward",
            "causal_attention", "cached_attention_step"]
 
 
@@ -246,3 +247,163 @@ class TransformerLM(HybridBlock):
             "max_len": self._max_len,
         }
         return {"config": config, "params": params}
+
+
+# one looped layer's leaves, in construction order
+_LOOPED_LAYER_LEAVES = (
+    "norm1_gamma", "q_weight", "k_weight", "v_weight", "out_weight",
+    "norm2_gamma", "norm3_gamma", "gate_weight", "up_weight", "down_weight",
+    "norm4_gamma")
+
+
+def looped_lm_forward(params, tokens, *, num_layers, num_heads, num_passes,
+                      eps=1e-6, rope_base=1e6, remat=True):
+    """The looped LM's forward as ONE pure function of ``(params,
+    tokens)`` (ROADMAP D1's shape: :class:`LoopedLM` wraps it; a serving
+    path would call the same function with a cache).
+
+    ``params`` maps :class:`LoopedLM`'s short parameter names to jax
+    arrays; ``tokens`` is ``(B, T)`` int.  One stack of ``num_layers``
+    sandwich-norm layers — ``x + RMS(Attn(RMS(x)))``, ``x +
+    RMS(SwiGLU(RMS(x)))``, causal attention with rotary q and k — is
+    applied ``num_passes`` times WITH THE SAME WEIGHTS inside one
+    ``lax.scan``; after every pass the state is normed (the normed state
+    enters the next pass), and read by a one-output exit gate.  With
+    ``remat`` each pass of the stack is a ``jax.checkpoint``: the
+    backward pass keeps the state that enters a pass and runs the pass
+    again, so activations cost one pass, not ``num_passes``.
+
+    Returns ``(logits, states, gates)``, batch-major: the LAST exit's
+    logits ``(B, T, V)``, every exit's normed state ``(B, P, T, U)`` and
+    gate logit ``(B, P, T)``.  The other exits' logits are the head over
+    their state; a loss fuses that product with its cross-entropy
+    (``gluon.loss.ExitWeightedCELoss``)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ...ops.contrib import (_flash_attention_op, _gated_ffn,
+                                _rotary_embedding)
+    from ...ops.nn import _rms_norm
+    from ...telemetry import phases
+
+    layers = [[params["l%d_%s" % (i, n)] for n in _LOOPED_LAYER_LEAVES]
+              for i in range(num_layers)]
+
+    def layer(x, p):
+        n1, wq, wk, wv, wo, n2, n3, wg, wu, wd, n4 = p
+        b, t, u = x.shape
+        h = _rms_norm(x, n1, eps=eps)
+        heads = lambda w: jnp.einsum("btu,ou->bto", h, w).reshape(
+            b, t, num_heads, u // num_heads)
+        q = _rotary_embedding(heads(wq), base=rope_base)
+        k = _rotary_embedding(heads(wk), base=rope_base)
+        o = _flash_attention_op(q, k, heads(wv), causal=True)
+        a = jnp.einsum("btu,ou->bto", o.reshape(b, t, u), wo)
+        x = x + _rms_norm(a, n2, eps=eps)
+        m = _gated_ffn(_rms_norm(x, n3, eps=eps), wg, wu, wd)
+        return x + _rms_norm(m, n4, eps=eps)
+
+    def stack(x, layers_):
+        for p in layers_:
+            x = layer(x, p)
+        return x
+
+    if remat:
+        stack = jax.checkpoint(stack)
+
+    def one_pass(h, _):
+        with jax.named_scope(phases.LOOP_SCOPE):
+            x = stack(h, layers)
+        with jax.named_scope(phases.EXIT_SCOPE):
+            h = _rms_norm(x, params["norm_gamma"], eps=eps)
+            g = jnp.einsum("btu,ou->bto", h, params["exit_weight"])[..., 0] \
+                + params["exit_bias"][0]
+        return h, (h, g)
+
+    h0 = params["embed_weight"][tokens.astype(jnp.int32)]
+    h, (states, gates) = lax.scan(one_pass, h0, None, length=num_passes)
+    with jax.named_scope(phases.EXIT_SCOPE):
+        logits = jnp.einsum("btu,vu->btv", h, params["head_weight"])
+    return logits, jnp.moveaxis(states, 0, 1), jnp.moveaxis(gates, 0, 1)
+
+
+class LoopedLM(HybridBlock):
+    """Looped decoder-only LM: ONE stack of layers applied
+    ``num_passes`` times with shared weights, an exit — final norm, LM
+    head, one-output gate — after every pass (Ouro, "Scaling Latent
+    Reasoning via Looped Language Models", arXiv:2510.25741).
+
+    Sandwich-norm layers (four RMSNorm gains a layer), causal attention
+    with rotary positions (half-split pairing), SwiGLU, no biases but
+    the gate's, untied head.  Input ``(B, T)`` token ids; outputs
+    ``(logits, states, gates)`` as :func:`looped_lm_forward` gives them,
+    which this block only wraps: the last exit's logits first, so
+    ``ParallelTrainer.forward`` and plain inference read the deepest
+    exit, then what ``gluon.loss.ExitWeightedCELoss`` (``exit_loss()``)
+    needs of every exit.
+
+    Each pass is rematerialised in the backward pass (the block's own
+    property, no option): a weight's gradient is the sum of
+    ``num_passes`` contributions, and activations are kept for one pass
+    at a time."""
+
+    def __init__(self, vocab_size, units=128, hidden_size=512, num_layers=2,
+                 num_heads=4, num_passes=4, epsilon=1e-6, rope_base=1e6,
+                 **kwargs):
+        super().__init__(**kwargs)
+        if units % num_heads or (units // num_heads) % 2:
+            raise ValueError("units (%d) must divide into num_heads (%d) "
+                             "heads of even size" % (units, num_heads))
+        self._config = dict(num_layers=num_layers, num_heads=num_heads,
+                            num_passes=num_passes, eps=epsilon,
+                            rope_base=rope_base)
+        shapes = [("embed_weight", (vocab_size, units))]
+        wide = {"gate_weight": (hidden_size, units),
+                "up_weight": (hidden_size, units),
+                "down_weight": (units, hidden_size)}
+        for i in range(num_layers):
+            shapes += [("l%d_%s" % (i, n),
+                        (units,) if n.endswith("_gamma")
+                        else wide.get(n, (units, units)))
+                       for n in _LOOPED_LAYER_LEAVES]
+        shapes += [("norm_gamma", (units,)), ("head_weight",
+                                              (vocab_size, units)),
+                   ("exit_weight", (1, units)), ("exit_bias", (1,))]
+        with self.name_scope():
+            # the initializer reads the suffix: gains 1, the bias 0
+            for name, shape in shapes:
+                setattr(self, name, self.params.get(name, shape=shape))
+        self._export_gauges()
+
+    def _export_gauges(self):
+        from ... import telemetry
+        c = self._config
+        for name, value, what in (
+                ("mxnet_loop_passes", c["num_passes"],
+                 "times the newest LoopedLM applies its stack"),
+                ("mxnet_loop_layers", c["num_layers"],
+                 "layers in the newest LoopedLM's shared stack"),
+                ("mxnet_loop_layer_applications",
+                 c["num_passes"] * c["num_layers"],
+                 "layer applications a forward of the newest LoopedLM "
+                 "runs (passes x layers)")):
+            telemetry.gauge(name, what).set(value)
+
+    def hybrid_forward(self, F, tokens, **params):
+        from ...imperative import invoke_fn
+        names = list(params)
+        config = self._config
+
+        def forward(tokens_, *leaves):
+            return looped_lm_forward(dict(zip(names, leaves)), tokens_,
+                                     **config)
+
+        return tuple(invoke_fn(forward, [tokens] + [params[n]
+                                                    for n in names]))
+
+    def exit_loss(self, beta=0.05, **kwargs):
+        """The training objective over this block's outputs, sharing its
+        head: ``loss(*net(tokens), labels)``."""
+        from ..loss import ExitWeightedCELoss
+        return ExitWeightedCELoss(beta=beta, params=self.params, **kwargs)

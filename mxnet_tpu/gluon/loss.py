@@ -15,7 +15,8 @@ from .block import HybridBlock
 __all__ = ["Loss", "L2Loss", "L1Loss", "SigmoidBinaryCrossEntropyLoss",
            "SigmoidBCELoss", "SoftmaxCrossEntropyLoss", "SoftmaxCELoss",
            "KLDivLoss", "CTCLoss", "HuberLoss", "HingeLoss",
-           "SquaredHingeLoss", "LogisticLoss", "TripletLoss"]
+           "SquaredHingeLoss", "LogisticLoss", "TripletLoss",
+           "ExitWeightedCELoss"]
 
 
 def _apply_weighting(F, loss, weight=None, sample_weight=None):
@@ -262,3 +263,40 @@ class TripletLoss(Loss):
         loss = F.relu(loss)
         loss = _apply_weighting(F, loss, self._weight, sample_weight)
         return loss
+
+
+class ExitWeightedCELoss(Loss):
+    """Expected next-token cross-entropy of a model with several exits
+    under its learned exit distribution, less ``beta`` times that
+    distribution's entropy (no reference analogue; the first-stage
+    objective of looped LMs, arXiv:2510.25741):
+    ``mean_tokens(sum_t p_t * CE_t - beta * H(p))``.
+
+    Called as ``loss(logits, states, gates, label)`` on the outputs of
+    ``gluon.contrib.transformer.LoopedLM``: ``states`` ``(B, P, T, U)``
+    are the exits' normed states, ``gates`` ``(B, P, T)`` their gate
+    logits; ``logits`` (the last exit's, for inference) is not read.
+    Every exit's logits are the SHARED head over its state — construct
+    the loss with ``params=net.params`` (``net.exit_loss()``) — and the
+    product is fused with its cross-entropy
+    (``F.contrib.linear_cross_entropy``), so one exit's float32 logits
+    live at a time.  Returns ``(B,)``."""
+
+    def __init__(self, beta=0.05, weight=None, batch_axis=0, **kwargs):
+        super().__init__(weight, batch_axis, **kwargs)
+        self._beta = beta
+        self.head_weight = self.params.get("head_weight")
+
+    def hybrid_forward(self, F, logits, states, gates, label, head_weight,
+                       sample_weight=None):
+        import jax
+        from ..telemetry import phases
+        ce = []
+        for t in range(states.shape[1]):
+            with jax.named_scope(phases.EXIT_SCOPE):
+                ce.append(F.contrib.linear_cross_entropy(
+                    states[:, t], head_weight, label))
+        loss = F.contrib.exit_weighted_loss(F.stack(*ce, axis=1), gates,
+                                            beta=self._beta)
+        loss = _apply_weighting(F, loss, self._weight, sample_weight)
+        return _mean_all_but_batch(loss, self._batch_axis)

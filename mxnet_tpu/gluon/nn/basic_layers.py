@@ -15,8 +15,8 @@ from ..parameter import DeferredInitializationError
 from .activations import Activation
 
 __all__ = ["Sequential", "HybridSequential", "Dense", "Dropout", "BatchNorm",
-           "Embedding", "Flatten", "InstanceNorm", "LayerNorm", "Lambda",
-           "HybridLambda"]
+           "Embedding", "Flatten", "InstanceNorm", "LayerNorm", "RMSNorm",
+           "Lambda", "HybridLambda"]
 
 
 class Sequential(Block):
@@ -340,6 +340,32 @@ class LayerNorm(HybridBlock):
             name=self.__class__.__name__, in_channels=in_channels,
             content=", ".join(["=".join([k, v.__repr__()])
                                for k, v in self._kwargs.items()]))
+
+
+class RMSNorm(HybridBlock):
+    """Root-mean-square normalisation over ``axis`` with a learned gain
+    (arXiv:1910.07467; no reference analogue — MXNet 1.2 predates it)."""
+
+    def __init__(self, axis=-1, epsilon=1e-6, gamma_initializer="ones",
+                 in_channels=0, prefix=None, params=None):
+        super().__init__(prefix=prefix, params=params)
+        self._axis = axis
+        self._epsilon = epsilon
+        with self.name_scope():
+            self.gamma = self.params.get(
+                "gamma", shape=(in_channels,),
+                init=_init(gamma_initializer), allow_deferred_init=True)
+
+    def _shape_hook(self, inputs):
+        self.gamma.shape = (inputs[0].shape[self._axis],)
+
+    def hybrid_forward(self, F, data, gamma):
+        return F.RMSNorm(data, gamma=gamma, axis=self._axis,
+                         eps=self._epsilon)
+
+    def __repr__(self):
+        return "RMSNorm(eps=%r, axis=%r, in_channels=%d)" % (
+            self._epsilon, self._axis, self.gamma.shape[0])
 
 
 class Lambda(Block):

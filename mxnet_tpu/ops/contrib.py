@@ -845,3 +845,78 @@ def _flash_attention_op(q, k, v, causal=False, scale=None, **attrs):
         return ring_attention(q, k, v, mesh=mesh,
                               causal=_boolattr(causal), scale=scale)
     return local_attention(q, k, v, causal=_boolattr(causal), scale=scale)
+
+
+# ---------------------------------------------------------------------------
+# Today's dense block: rotary positions, gated feed-forward, and the
+# projection fused with its cross-entropy (no reference analogue)
+# ---------------------------------------------------------------------------
+@register("_contrib_rotary_embedding")
+def _rotary_embedding(data, base=10000.0, **attrs):
+    """Rotary position embedding (Su et al., arXiv:2104.09864) over
+    ``(B, T, H, D)`` with HALF-SPLIT pairing: element ``i < D/2`` turns
+    with element ``i + D/2`` by ``t * base**(-2i/D)``.  The
+    angles, sines and the rotation run in float32; the result is cast
+    back to ``data``'s dtype."""
+    t, d = data.shape[1], data.shape[-1]
+    half = d // 2
+    inv_freq = float(base) ** (-jnp.arange(half, dtype=jnp.float32)
+                               * (2.0 / d))
+    pos = jnp.arange(t, dtype=jnp.float32)
+    ang = pos[:, None] * inv_freq[None, :]
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.sin(ang)[None, :, None, :]
+    xf = data.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return out.astype(data.dtype)
+
+
+@register("_contrib_gated_ffn")
+def _gated_ffn(data, gate_weight, up_weight, down_weight, **attrs):
+    """SwiGLU feed-forward (Shazeer, arXiv:2002.05202):
+    ``down(silu(gate(x)) * up(x))`` over the last axis, weights stored
+    ``(out, in)`` like FullyConnected's, no biases."""
+    g = jnp.einsum("...u,fu->...f", data, gate_weight)
+    u = jnp.einsum("...u,fu->...f", data, up_weight)
+    return jnp.einsum("...f,uf->...u", jax.nn.silu(g) * u, down_weight)
+
+
+@register("_contrib_linear_cross_entropy")
+def _linear_cross_entropy(data, weight, label, **attrs):
+    """Projection fused with its softmax cross-entropy: per position
+    ``-log softmax(data @ weight.T)[label]`` in float32, WITHOUT keeping
+    the ``(..., V)`` logits for the backward pass — they are recomputed
+    there (``jax.checkpoint``), so only one projection's float32 logits
+    live at a time however many heads a loss reads.  The product runs in
+    ``weight``'s dtype with float32 accumulation."""
+    @jax.checkpoint
+    def ce(x, w, y):
+        logits = jnp.einsum("...u,vu->...v", x.astype(w.dtype), w,
+                            preferred_element_type=jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, y[..., None], axis=-1)[..., 0]
+        return lse - picked
+
+    return ce(data, weight, label.astype(jnp.int32))
+
+
+@register("_contrib_exit_weighted_loss")
+def _exit_weighted_loss(loss, gate, beta=0.0, **attrs):
+    """Expected loss of a model with ``P`` exits under its learned exit
+    distribution, less ``beta`` times that distribution's entropy (the
+    first-stage objective of looped LMs, arXiv:2510.25741).  ``loss``
+    and ``gate`` are ``(B, P, ...)``: per-exit losses and gate logits.
+    ``lam = sigmoid(gate)``; exit ``t < P`` is taken with probability
+    ``lam_t * prod_{j<t}(1 - lam_j)``, the last with what is left (its own
+    gate is not read), so the distribution sums to 1.  Returns
+    ``(B, ...)``.  Computed through log-probabilities in float32."""
+    lf, gf = loss.astype(jnp.float32), gate.astype(jnp.float32)
+    stay = jax.nn.log_sigmoid(-gf[:, :-1])        # log(1 - lam_j), j < P
+    before = jnp.concatenate(
+        [jnp.zeros_like(gf[:, :1]), jnp.cumsum(stay, axis=1)], axis=1)
+    logp = before + jnp.concatenate(
+        [jax.nn.log_sigmoid(gf[:, :-1]), jnp.zeros_like(gf[:, :1])], axis=1)
+    p = jnp.exp(logp)
+    entropy = -jnp.sum(p * logp, axis=1)
+    return jnp.sum(p * lf, axis=1) - float(beta) * entropy

@@ -504,6 +504,22 @@ def _layer_norm(data, gamma, beta, axis=-1, eps=1e-5, output_mean_var=False, **a
     return out * gamma.reshape(shape) + beta.reshape(shape)
 
 
+@register("RMSNorm", params=[
+    P("axis", int, default=-1),
+    P("eps", float, default=1e-6, low=0.0)])
+def _rms_norm(data, gamma, axis=-1, eps=1e-6, **attrs):
+    """Root-mean-square normalisation (Zhang & Sennrich,
+    arXiv:1910.07467): ``data * rsqrt(mean(data**2, axis) + eps) *
+    gamma`` — no mean subtracted, no beta.  The statistic and the scaling
+    run in float32 whatever ``data``'s dtype; the result is cast back."""
+    xf = data.astype(jnp.float32)
+    inv = lax.rsqrt(jnp.mean(jnp.square(xf), axis=axis, keepdims=True) + eps)
+    shape = [1] * data.ndim
+    shape[axis % data.ndim] = data.shape[axis % data.ndim]
+    out = xf * inv * gamma.astype(jnp.float32).reshape(shape)
+    return out.astype(data.dtype)
+
+
 @register("InstanceNorm", params=[
     P("eps", float, default=1e-3, low=0.0)])
 def _instance_norm(data, gamma, beta, eps=1e-3, **attrs):

@@ -79,8 +79,7 @@ def pure_block_apply(block, param_names, is_train):
     traces the whole block into the surrounding jit."""
 
     def apply_fn(params, key, *inputs):
-        nds = {name.split(":", 1)[1] if ":" in name else name: NDArray(a)
-               for name, a in params.items()}
+        nds = _by_param_name(params)
         ins = [NDArray(x) for x in inputs]
         with autograd.pause(train_mode=is_train), \
                 _mxrandom.trace_key_scope(key):
@@ -92,14 +91,19 @@ def pure_block_apply(block, param_names, is_train):
     return apply_fn
 
 
+def _by_param_name(params):
+    """{gluon parameter name: NDArray} of a step's parameter arrays."""
+    return {name.split(":", 1)[1] if ":" in name else name: NDArray(a)
+            for name, a in params.items()}
+
+
 def _apply_with_params(block, params, *inputs):
     """Temporarily install param values into the block tree and run it."""
     saved = []
     try:
         for name, p in block.collect_params().items():
-            if name in params:
-                saved.append((p, p._data))
-                p._data = params[name]
+            saved.append((p, p._data))
+            p._data = params.get(name, p._data)
         return block(*inputs)
     finally:
         for p, old in saved:
@@ -483,21 +487,30 @@ class ParallelTrainer:
                 params = {k: v.astype(amp) if v.dtype == jnp.float32 else v
                           for k, v in params.items()}
                 x = x.astype(amp) if x.dtype == jnp.float32 else x
-            out = apply_train(params, key, x)
-            if isinstance(out, tuple):
-                out = out[0]
+            outs = apply_train(params, key, x)
+            # a block with several outputs hands every one of them to the
+            # loss, in order and batch-major (a model with several exits);
+            # forward() / evaluate still answer with the first
+            outs = outs if isinstance(outs, tuple) else (outs,)
             with jax.named_scope("mx_master_fp32"):
-                out = out.astype(jnp.float32)  # loss always in fp32
+                # loss always in fp32
+                outs = [o.astype(jnp.float32) for o in outs]
             # pin logits to the batch layout: gives GSPMD a fixed
             # resharding boundary between model body and loss (see
             # _param_pspec docstring for the CPU-backend miscompile this
             # also guards against)
-            out = jax.lax.with_sharding_constraint(
-                out, NamedSharding(mesh, P(*([("dp", "fsdp")]
-                                             + [None] * (out.ndim - 1)))))
+            outs = [jax.lax.with_sharding_constraint(
+                o, NamedSharding(mesh, P(*([("dp", "fsdp")]
+                                           + [None] * (o.ndim - 1)))))
+                for o in outs]
             with autograd.pause(train_mode=True), \
                     jax.named_scope(_phases.LOSS_SCOPE):
-                l = loss_blk(NDArray(out), NDArray(y))
+                # a loss that shares parameters with the block (gluon's
+                # ``params=``: a projection fused with its cross-entropy)
+                # computes with this step's values of them
+                l = _apply_with_params(loss_blk, _by_param_name(params),
+                                       *[NDArray(o) for o in outs],
+                                       NDArray(y))
                 return jnp.mean(l._data)
 
         if self._zero == 0:

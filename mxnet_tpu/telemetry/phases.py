@@ -22,17 +22,27 @@ import re
 
 __all__ = ["FWD_SCOPE", "LOSS_SCOPE", "UPDATE_SCOPE", "CODEC_SCOPE",
            "FLATTEN_SCOPE", "UNFLATTEN_SCOPE", "SWEEP_SCOPE",
-           "COLLECTIVE_PREFIX", "FWD", "BWD", "UPDATE", "COLLECTIVE",
-           "OTHER", "phase_of", "instruction_phases", "register_program",
-           "program_hlo", "program_names"]
+           "COLLECTIVE_PREFIX", "LOOP_SCOPE", "EXIT_SCOPE", "FWD", "BWD",
+           "UPDATE", "COLLECTIVE", "CONTROL", "OTHER", "LOOP", "EXIT",
+           "phase_of", "instruction_phases", "loop_part_of",
+           "instruction_loop_parts", "register_program", "program_hlo",
+           "program_names"]
 
 FWD_SCOPE, LOSS_SCOPE = "mx_fwd", "mx_loss"
 UPDATE_SCOPE, CODEC_SCOPE = "mx_update", "mx_codec"
 # inside mx_update: the flat buckets' layout changes and the one kernel
 FLATTEN_SCOPE, UNFLATTEN_SCOPE, SWEEP_SCOPE = "flatten", "unflatten", "sweep"
 COLLECTIVE_PREFIX = "mx_coll:"
-FWD, BWD, UPDATE, COLLECTIVE, OTHER = \
-    "fwd", "bwd", "update", "collective", "other"
+# inside mx_fwd, in a block that applies one stack of layers several
+# times (gluon.contrib.transformer.LoopedLM): one pass of the stack, and
+# one exit's norm -> gate and projection -> cross-entropy
+LOOP_SCOPE, EXIT_SCOPE = "mx_loop", "mx_exit"
+# what jax writes into the name stack of a forward that is run again in
+# the backward pass (jax.checkpoint)
+REMAT_MARK = "rematted_computation"
+FWD, BWD, UPDATE, COLLECTIVE, CONTROL, OTHER = \
+    "fwd", "bwd", "update", "collective", "control", "other"
+LOOP, EXIT = "loop", "exit"
 
 
 def phase_of(op_name):
@@ -49,12 +59,100 @@ def phase_of(op_name):
     return OTHER
 
 
+def loop_part_of(op_name):
+    """``(part, recomputed)`` of an HLO ``op_name``: ``part`` is
+    :data:`EXIT`, :data:`LOOP` or None (the inner scope wins: an exit's
+    norm and gate sit inside the loop's body), ``recomputed`` whether the
+    instruction is a forward run again for the backward pass."""
+    if not op_name:
+        return None, False
+    part = EXIT if EXIT_SCOPE in op_name else \
+        LOOP if LOOP_SCOPE in op_name else None
+    return part, REMAT_MARK in op_name
+
+
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%([\w.\-]+) = (.*)$")
 _OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
 _REF = re.compile(r"%([\w.\-]+)")
 _TUPLE = re.compile(r"[\])}] tuple\(")
 _PARAMETER = re.compile(r"[\])}] parameter\(\d+\)")
 _NAME_STACK = "jit("        # what every op_name traced in a program holds
+
+
+_CONTROL = re.compile(r"[\])}] (?:while|conditional|call)\(")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(")
+# the computations a control instruction runs (a while's condition is a
+# compare of the counter, not its body)
+_BODIES = re.compile(r"(?:body|to_apply|true_computation|false_computation)"
+                     r"=%([\w.\-]+)|branch_computations=\{([^}]*)\}")
+
+
+def _classify(hlo_text, of, nothing, control):
+    """``{instruction name: of(op_name)}`` over an optimized module's
+    text, with the inheritance :func:`instruction_phases` describes;
+    ``nothing`` is the class that hands nothing on.  A ``while`` /
+    ``conditional`` / ``call`` whose body's instructions are in the text
+    (so in this map, to be found as events of their own) is ``control``
+    and lends the class of its own name only to an instruction no other
+    neighbour names; one whose body is not there keeps the class of its
+    own name, like any instruction."""
+    found, lent, waiting, users = {}, {}, [], {}
+    controls, bodies, computation = set(), set(), None
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            head = _COMPUTATION.match(line)
+            computation = head.group(1) if head else computation
+            continue
+        name, rest = m.groups()
+        named = _OP_NAME.search(rest)
+        own = of(named.group(1)) \
+            if named and _NAME_STACK in named.group(1) else None
+        refs = _REF.findall(rest.split(", metadata=", 1)[0])
+        for r in refs:
+            users.setdefault(r, []).append(name)
+        if _PARAMETER.search(rest) or _TUPLE.search(rest):
+            found[name] = nothing
+            continue
+        bodies.add(computation)     # it holds what an event can name
+        if _CONTROL.search(rest):
+            ran = [c for one, many in _BODIES.findall(rest)
+                   for c in [one] + _REF.findall(many) if c]
+            # callees are printed before their callers
+            if ran and bodies.issuperset(ran):
+                found[name] = control
+                if own is not None:
+                    # what is unpacked from a loop's result and nothing
+                    # else names (a weight carried through one loop and
+                    # prefetched for the next) belongs to the loop's class
+                    lent[name] = own
+                controls.add(name)
+                continue
+        if own is not None:
+            found[name] = own
+        else:
+            waiting.append((name, refs))
+
+    def known(n):
+        return n not in controls and found.get(n, nothing) != nothing
+
+    def lends(n):
+        return known(n) or lent.get(n, nothing) != nothing
+
+    # program order resolves chains of reads, the reverse chains of feeds;
+    # a loop's own class is tried only for what is still unnamed after both
+    for has in (known, lends):
+        for order, side in ((waiting, None), (reversed(waiting), users)):
+            for name, refs in order:
+                if known(name):
+                    continue
+                near = refs if side is None else side.get(name, ())
+                src = next((r for r in near if has(r)), None)
+                if src is not None:
+                    found[name] = found[src] if known(src) else lent[src]
+    for name, _refs in waiting:
+        found.setdefault(name, nothing)
+    return found
 
 
 def instruction_phases(hlo_text):
@@ -66,39 +164,23 @@ def instruction_phases(hlo_text):
     else of the one it feeds.  A parameter, a name stack outside every
     scope (``jit(fbu)/jit(_threefry_fold_in)/xor``) and a ``tuple``,
     which gathers results of every phase, are ``other`` and hand
-    nothing on."""
-    phases, waiting, users = {}, [], {}
-    for line in hlo_text.splitlines():
-        m = _INSTRUCTION.match(line)
-        if m is None:
-            continue
-        name, rest = m.groups()
-        named = _OP_NAME.search(rest)
-        refs = _REF.findall(rest.split(", metadata=", 1)[0])
-        for r in refs:
-            users.setdefault(r, []).append(name)
-        if _PARAMETER.search(rest) or _TUPLE.search(rest):
-            phases[name] = OTHER
-        elif named and _NAME_STACK in named.group(1):
-            phases[name] = phase_of(named.group(1))
-        else:
-            waiting.append((name, refs))
+    nothing on.  A ``while`` (a ``lax.scan``), ``conditional`` or
+    ``call`` whose body's instructions are in the text is ``control``:
+    where the profiler gives it an event of its own, that event spans its
+    body's instructions, which are events too and carry their own phases
+    (on the v5e a ``while``'s event is covered to 99.99 % by them,
+    PERF.md).  One whose body is not in the text keeps the phase of its
+    own name, so its time counts once, for a phase or for ``other``."""
+    return _classify(hlo_text, phase_of, OTHER, CONTROL)
 
-    def known(n):
-        return phases.get(n, OTHER) != OTHER
 
-    # program order resolves chains of reads, the reverse chains of feeds
-    for order, side in ((waiting, None), (reversed(waiting), users)):
-        for name, refs in order:
-            if known(name):
-                continue
-            near = refs if side is None else side.get(name, ())
-            src = next((r for r in near if known(r)), None)
-            if src is not None:
-                phases[name] = phases[src]
-    for name, _refs in waiting:
-        phases.setdefault(name, OTHER)
-    return phases
+def instruction_loop_parts(hlo_text):
+    """``{instruction name: (part, recomputed)}`` beside
+    :func:`instruction_phases`, for a program that applies one stack of
+    layers several times: ``part`` is ``loop``, ``exit`` or None,
+    ``recomputed`` whether the instruction is a forward run again in the
+    backward pass (:func:`loop_part_of`); the same inheritance."""
+    return _classify(hlo_text, loop_part_of, (None, False), (None, False))
 
 
 # name -> [jitted fn, abstract args, context factory or None, HLO text]

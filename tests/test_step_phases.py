@@ -106,6 +106,62 @@ def test_instruction_phases_on_a_handwritten_module():
     assert "not_an_operand" not in got and "fused_computation" not in got
 
 
+LOOP_HLO = """HloModule jit_step, is_scheduled=true
+
+%body.1 (arg.1: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %arg.1 = (s32[], f32[4]{0}) parameter(0)
+  %gte.1 = f32[4]{0} get-tuple-element(%arg.1), index=1
+  %mul.1 = f32[4]{0} multiply(%gte.1, %gte.1), metadata={op_name="jit(step)/jvp(mx_fwd)/while/body/mx_loop/mul"}
+  %exp.1 = f32[4]{0} exponential(%mul.1), metadata={op_name="jit(step)/jvp(mx_fwd)/while/body/mx_exit/exp"}
+  ROOT %tuple.1 = (s32[], f32[4]{0}) tuple(%gte.1, %exp.1)
+}
+
+%cond.1 (arg.2: (s32[], f32[4])) -> pred[] {
+  %arg.2 = (s32[], f32[4]{0}) parameter(0)
+  ROOT %lt.1 = pred[] constant(true)
+}
+
+ENTRY %main.2 (w.1: f32[4]) -> f32[4] {
+  %w.1 = f32[4]{0} parameter(0), metadata={op_name="w"}
+  %copy-start.1 = (f32[4]{0:S(1)}, f32[4]{0}, u32[]) copy-start(%w.1)
+  %copy-done.1 = f32[4]{0:S(1)} copy-done(%copy-start.1)
+  %tuple.2 = (s32[], f32[4]{0:S(1)}) tuple(%copy-done.1)
+  %while.1 = (s32[], f32[4]{0}) while(%tuple.2), condition=%cond.1, body=%body.1, metadata={op_name="jit(step)/jvp(mx_fwd)/mx_loop/while"}
+  %gte.2 = f32[4]{0} get-tuple-element(%while.1), index=1
+  %gte.3 = s32[] get-tuple-element(%while.1), index=0
+  %call.1 = f32[4]{0} call(%gte.2), to_apply=%elsewhere.1, metadata={op_name="jit(step)/transpose(jvp(mx_fwd))/mx_loop/checkpoint/rematted_computation/call"}
+  %call.2 = f32[4]{0} call(%call.1), to_apply=%elsewhere.2
+  ROOT %neg.2 = f32[4]{0} negate(%call.2), metadata={op_name="jit(step)/mx_update/neg"}
+}
+"""
+
+
+def test_a_control_instruction_is_control_only_over_a_body_in_the_text():
+    got = phases.instruction_phases(LOOP_HLO)
+    # the loop's event spans its body's, which carry their own phases
+    assert got["while.1"] == "control"
+    assert got["mul.1"] == "fwd" and got["exp.1"] == "fwd"
+    # what only the loop names takes the class of the loop's own name;
+    # another neighbour's goes first
+    assert got["gte.3"] == "fwd" and got["gte.2"] == "bwd"
+    # no body in the text, so no events under it: its time counts once,
+    # for the phase of its own name ...
+    assert got["call.1"] == "bwd"
+    # ... and unnamed it inherits like a copy: from what it reads, else
+    # from what it feeds, else it is unattributed, where the guard sees it
+    assert got["call.2"] == "bwd"
+    bare = LOOP_HLO.replace("(%call.1), to_apply", "(%w.1), to_apply")
+    assert phases.instruction_phases(bare)["call.2"] == "update"
+    bare = bare.replace("negate(%call.2)", "negate(%w.1)")
+    assert phases.instruction_phases(bare)["call.2"] == "other"
+    parts = phases.instruction_loop_parts(LOOP_HLO)
+    assert parts["while.1"] == (None, False)        # its body's events count
+    assert parts["mul.1"] == ("loop", False)
+    assert parts["exp.1"] == ("exit", False)
+    assert parts["call.1"] == ("loop", True)
+    assert parts["neg.2"] == (None, False)
+
+
 # ---------------------------------------------------------------------------
 # the two step programs
 # ---------------------------------------------------------------------------
