@@ -38,10 +38,15 @@ from __future__ import annotations
 import itertools
 
 __all__ = ["kernel_reports", "sweep_reports", "flash_reports",
-           "scale_bias_relu_reports", "layernorm_reports",
+           "flash_cell_reports", "scale_bias_relu_reports", "layernorm_reports",
            "softmax_reports", "ORIGIN"]
 
 ORIGIN = "mxnet_tpu/ops/pallas_kernels.py"
+
+
+def _dtype_name(dtype):
+    import numpy as np
+    return np.dtype(dtype).name
 
 
 def _eval_index(spec, grid, n_prefetch):
@@ -78,19 +83,30 @@ def _report(name, family, plan, in_names, out_names, *, hyper=None,
             "dtype": "float32", "block": None,
             "shape": [len((hyper or {}).get("names") or ())],
             "index": None})
+    # a plan that names its operands' dtypes (the flash plans) is
+    # counted at them; every other kernel's operands are float32
+    dtypes = map(_dtype_name,
+                 plan.get("dtypes") or itertools.repeat("float32"))
     for nm, spec, shape in zip(in_names, plan["in_specs"],
                                plan["in_shapes"]):
-        operands.append(_operand(nm, "in", spec, shape, grid, npf))
+        operands.append(_operand(nm, "in", spec, shape, grid, npf,
+                                 next(dtypes)))
     for nm, spec, shape in zip(out_names, plan["out_specs"],
                                plan["out_shapes"]):
-        operands.append(_operand(nm, "out", spec, shape, grid, npf))
+        operands.append(_operand(nm, "out", spec, shape, grid, npf,
+                                 next(dtypes)))
     report = {
         "name": name, "family": family, "origin": ORIGIN,
         "grid": grid,
         "operands": operands,
+        # a flash plan's score tiles live in VMEM beside the declared
+        # scratch: the budget counts both
         "scratch": [{"shape": [int(s) for s in sh],
-                     "dtype": "float32"}
-                    for sh in plan.get("scratch", ())],
+                     "dtype": _dtype_name(dt)}
+                    for sh, dt in (
+                        *((sh, "float32")
+                          for sh in plan.get("scratch", ())),
+                        *plan.get("tiles", ()))],
         "hyper": hyper or {"transport": None, "names": []},
         "python_constants": list(python_constants),
         "tail": tail,
@@ -157,44 +173,58 @@ def sweep_reports(n=None):
 
 # -- flash attention -------------------------------------------------------
 
-def flash_reports(bh=8, tq=512, tk=512, d=64, bq=128, bk=128):
+def flash_reports(bh=8, tq=512, tk=512, d=64, bq=128, bk=128,
+                  causal=False, dtype="float32"):
+    """The three flash kernels at one shape.  ``bq`` / ``bk`` None:
+    the blocks ``_flash_blocks`` picks for each kernel from the shape,
+    as a call without explicit blocks runs them."""
     from mxnet_tpu.ops import pallas_kernels as pk
     structural = [
         {"name": "scale", "detail": "architecture constant (1/sqrt(d) "
                                     "unless overridden)"},
         {"name": "causal", "detail": "structural branch: masking "
-                                     "changes the kernel body"},
+                                     "changes the kernel body and "
+                                     "clamps the index maps"},
         {"name": "bq", "detail": "block size"},
         {"name": "bk", "detail": "block size"},
     ]
     elems = bh * tq * d
     tail = {"logical_elems": elems, "padded_elems": elems,
             "masked": True,
-            "how": "no padding: _pick_block divides T exactly"}
+            "how": "no padding: the picked (or halved explicit) "
+                   "blocks divide T exactly"}
     # flash has no MXNET_PALLAS_* family knob: parallel/attention.py
     # selects it per call via impl="auto"/"flash" — label the family
     # by that entry point, not a fabricated knob name
     family = "flash_attention(impl=...)"
-    return [
-        _report("_flash_fwd_kernel", family,
-                pk.flash_fwd_plan(bh, tq, tk, d, bq, bk),
-                ("q", "k", "v"), ("o", "lse"),
-                python_constants=structural + [
-                    {"name": "nk", "detail": "grid extent"}],
-                tail=tail),
-        _report("_flash_bwd_dq_kernel", family,
-                pk.flash_bwd_dq_plan(bh, tq, tk, d, bq, bk),
-                ("q", "k", "v", "do", "lse", "delta"), ("dq",),
-                python_constants=structural + [
-                    {"name": "nk", "detail": "grid extent"}],
-                tail=tail),
-        _report("_flash_bwd_dkv_kernel", family,
-                pk.flash_bwd_dkv_plan(bh, tq, tk, d, bq, bk),
-                ("q", "k", "v", "do", "lse", "delta"), ("dk", "dv"),
-                python_constants=structural + [
-                    {"name": "nq", "detail": "grid extent"}],
-                tail=tail),
-    ]
+    reports = []
+    for name, kernel, ins, outs, extent in (
+            ("_flash_fwd_kernel", "fwd", ("q", "k", "v"), ("o", "lse"),
+             "nk"),
+            ("_flash_bwd_dq_kernel", "dq",
+             ("q", "k", "v", "do", "lse", "delta"), ("dq",), "nk"),
+            ("_flash_bwd_dkv_kernel", "dkv",
+             ("q", "k", "v", "do", "lse", "delta"), ("dk", "dv"), "nq")):
+        pq, pk_ = pk._flash_blocks(tq, tk, d, dtype, kernel)
+        reports.append(_report(
+            name, family,
+            pk._FLASH_PLANS[kernel](bh, tq, tk, d, bq or pq, bk or pk_,
+                                    causal, dtype),
+            ins, outs,
+            python_constants=structural + [
+                {"name": extent, "detail": "grid extent"}],
+            tail=tail))
+    return reports
+
+
+def flash_cell_reports():
+    """The flash kernels as the benchmark's two LM cells run them:
+    causal bf16 at T 2048 with the blocks picked from the shape —
+    OPT-1.3B's 2 x 32 heads of 64, Ouro-2.6B's 1 x 16 heads of 128."""
+    return (flash_reports(64, 2048, 2048, 64, None, None, True,
+                          "bfloat16")
+            + flash_reports(16, 2048, 2048, 128, None, None, True,
+                            "bfloat16"))
 
 
 # -- inference BatchNorm+ReLU epilogue -------------------------------------
@@ -277,6 +307,6 @@ def softmax_reports(b=8, r=128, c0=1000):
 def kernel_reports():
     """Every in-tree kernel family's reports — the catalog
     ``tools/lint.py --kern`` / ``--all`` judge."""
-    return (sweep_reports() + flash_reports()
+    return (sweep_reports() + flash_reports() + flash_cell_reports()
             + scale_bias_relu_reports() + layernorm_reports()
             + softmax_reports())

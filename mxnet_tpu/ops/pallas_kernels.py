@@ -7,8 +7,14 @@ when-does-it-fuse table and the ``MXNET_PALLAS_*`` knobs):
 
 - ``flash_attention`` — blockwise online-softmax attention (forward and
   backward), the kernel behind long-context attention: O(T) memory
-  instead of XLA's materialized (T, T) logits.  This is the per-device
-  block kernel of ring/Ulysses sequence parallelism
+  instead of XLA's materialized (T, T) logits.  The score blocks are
+  chosen per kernel from the shape (``_flash_blocks``: the largest
+  whole-tile divisors of T, up to 1024 rows a side, that fit the
+  scoped-VMEM budget); the causal schedule fetches and runs nothing
+  for a block above the diagonal, masks only the blocks the diagonal
+  crosses, and in the backward works a square diagonal block in slabs,
+  so its upper half costs no product and no exponential.  This is the
+  per-device block kernel of ring/Ulysses sequence parallelism
   (parallel/attention.py); reference long-sequence analogue: the fused
   cuDNN RNN workspace kernels (src/operator/cudnn_rnn-inl.h).
 - ``fused_scale_bias_relu`` — the inference BatchNorm + ReLU epilogue as
@@ -42,7 +48,9 @@ Layout note: per-row softmax stats (m, l, lse, delta) are stored with a
 trailing 128-lane dim, every lane holding the same value — the Mosaic
 tiling constraint (last two block dims divisible by (8, 128)) forbids
 1-D row vectors, and this is the same convention jax's in-tree flash
-kernel uses.
+kernel uses.  The dK/dV kernel alone reads ``lse`` and ``delta`` as
+lane-dense rows, one (1, bq) row a Q block: it holds its score tile
+transposed.
 """
 from __future__ import annotations
 
@@ -154,6 +162,113 @@ NEG_INF = -1e30
 # ---------------------------------------------------------------------------
 # Flash attention
 # ---------------------------------------------------------------------------
+# One schedule for the three kernels.  The score matrix of a (batch, head)
+# is cut into (bq, bk) blocks chosen from the shape (``_flash_blocks``); a
+# grid step owns one block.  Under the causal mask a block lies wholly
+# under the diagonal (no mask built), across it (the only blocks that
+# build one) or wholly above it: those steps run no body and, because the
+# index maps clamp to the last block the row (column) needs, fetch
+# nothing — consecutive steps name the same block and Pallas skips the
+# copy.
+
+# index-map arithmetic that serves traced grid indices and the plain
+# Python ints graftkern evaluates the same maps with (analysis/kern/)
+def _idiv(a, b):
+    return a // b if isinstance(a, int) else jax.lax.div(a, b)
+
+
+def _imin(a, b):
+    return min(a, b) if isinstance(a, int) else jnp.minimum(a, b)
+
+
+def _imax(a, b):
+    return max(a, b) if isinstance(a, int) else jnp.maximum(a, b)
+
+
+def _causal_last_k(i, bq, bk):
+    """Last K block a causal Q block ``i`` has a visible key in."""
+    return _idiv(i * bq + bq - 1, bk)
+
+
+def _causal_first_q(j, bq, bk):
+    """First Q block that sees a key of causal K block ``j``."""
+    return _idiv(j * bk, bq)
+
+
+_DIAG = "diag"        # mask tag: the slab's own square lies on the diagonal
+
+
+def _flash_slabs(b):
+    """Slabs the backward kernels work a diagonal block of ``b`` rows
+    in (below): four or two, each a whole number of lane tiles, else
+    the block whole.  On the chip at 1024 rows four beat two beat one
+    by a tenth each time for dQ and dK/dV; the forward, which carries
+    its row statistics through every slab, is fastest with the block
+    whole (PERF.md, PR 28)."""
+    return 4 if b % (4 * LANES) == 0 else 2 if b % (2 * LANES) == 0 else 1
+
+
+def _flash_block_cases(causal, qi, ki, bq, bk, update, k_major=False,
+                       slabs=True):
+    """Drive ``update(q_rows, k_rows, mask)`` over score block (qi, ki).
+
+    Every key visible (no mask, or the block wholly under the
+    diagonal): one update of the whole block, ``mask`` None.  The block
+    wholly above the diagonal: nothing.  The diagonal crosses it: the
+    only updates that build a mask.  A square block on the diagonal
+    (bq == bk: its offset is 0, known at trace time) is, with
+    ``slabs``, worked in slabs — of Q rows against the keys up to the
+    slab's own square (of K rows against the queries from it on, for
+    dK/dV: ``k_major``) — so the half above the diagonal costs no
+    product and no exponential, and only the slab's square (``mask``
+    _DIAG) is masked.  Any other
+    crossing block takes the whole-block update with the diagonal's
+    offset as ``mask``: query row r sees key c iff r - c >= mask."""
+    full = slice(None)
+    if not causal:
+        update(full, full, None)
+        return
+    shift = ki * bk - qi * bq
+    pl.when(shift + bk - 1 <= 0)(lambda: update(full, full, None))
+    crossing = jnp.logical_and(shift + bk - 1 > 0, shift <= bq - 1)
+    if bq != bk:
+        pl.when(crossing)(lambda: update(full, full, shift))
+        return
+
+    @pl.when(crossing)
+    def _diagonal():
+        n = _flash_slabs(bq) if slabs else 1
+        r = bq // n
+        for i in range(n):
+            own = slice(i * r, (i + 1) * r)
+            if k_major:
+                update(slice(i * r, bq), own, _DIAG)
+            else:
+                update(own, slice(0, (i + 1) * r), _DIAG)
+
+
+def _flash_scores(a, b, scale, mask, rows_are_q):
+    """float32 scores ``a @ b.T * scale`` of a block or slab, the
+    invisible keys at NEG_INF.  Rows are queries and columns keys, or
+    the transpose (dK/dV).  ``mask``: None, the diagonal's offset, or
+    _DIAG — the square at the end of a Q slab's keys (at the start of a
+    K slab's queries) is the one on the diagonal."""
+    s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if mask is None:
+        return s
+    n = s.shape[0]
+    sq = s if mask is not _DIAG else s[:, -n:] if rows_are_q else s[:, :n]
+    r = jax.lax.broadcasted_iota(jnp.int32, sq.shape, 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, sq.shape, 1)
+    sq = jnp.where((r - c if rows_are_q else c - r)
+                   >= (0 if mask is _DIAG else mask), sq, NEG_INF)
+    if sq.shape == s.shape:
+        return sq
+    return jnp.concatenate([s[:, :-n], sq] if rows_are_q
+                           else [sq, s[:, n:]], axis=1)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                       l_ref, *, scale, causal, bq, bk, nk):
     """Grid (BH, nQ, nK); accumulate across the sequential nK dimension in
@@ -168,30 +283,21 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # causal: skip K blocks entirely above the diagonal
-    run = True if not causal else (ki * bk <= qi * bq + bq - 1)
-
-    @pl.when(run)
-    def _block():
-        q = q_ref[:]                                    # (BQ, D)
-        k = k_ref[:]                                    # (BK, D)
-        v = v_ref[:]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
-        m_prev = m_ref[:, :1]                           # (BQ, 1)
+    def _update(qs, ks, mask):
+        v = v_ref[ks]
+        s = _flash_scores(q_ref[qs], k_ref[ks], scale, mask, True)
+        m_prev = m_ref[qs, :1]                          # (rows, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)                 # (BQ, 1)
-        l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+        alpha = jnp.exp(m_prev - m_new)                 # (rows, 1)
+        l_new = l_ref[qs, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[qs] = acc_ref[qs] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        m_ref[qs] = jnp.broadcast_to(m_new, (m_new.shape[0], LANES))
+        l_ref[qs] = jnp.broadcast_to(l_new, (l_new.shape[0], LANES))
+
+    _flash_block_cases(causal, qi, ki, bq, bk, _update, slabs=False)
 
     @pl.when(ki == nk - 1)
     def _final():
@@ -209,26 +315,19 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    run = True if not causal else (ki * bk <= qi * bq + bq - 1)
-
-    @pl.when(run)
-    def _block():
-        q = q_ref[:]
-        k = k_ref[:]
-        v = v_ref[:]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[:, :1])
-        dp = jax.lax.dot_general(do_ref[:], v, (((1,), (1,)), ((), ())),
+    def _update(qs, ks, mask):
+        k = k_ref[ks]
+        s = _flash_scores(q_ref[qs], k, scale, mask, True)
+        p = jnp.exp(s - lse_ref[qs, :1])
+        dp = jax.lax.dot_general(do_ref[qs], v_ref[ks],
+                                 (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[:, :1]) * scale
-        acc_ref[:] += jax.lax.dot_general(
+        ds = p * (dp - delta_ref[qs, :1]) * scale
+        acc_ref[qs] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _flash_block_cases(causal, qi, ki, bq, bk, _update)
 
     @pl.when(ki == nk - 1)
     def _final():
@@ -238,6 +337,10 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
                           bq, bk, nq):
+    """Grid (BH, nK, nQ).  The block is held transposed, (bk, bq):
+    ``lse`` and ``delta`` arrive as rows (1, bq), so the scores, ``p``
+    and ``ds`` come out of plain products in the orientation the dV and
+    dK products consume — no transpose of a score tile."""
     ki = pl.program_id(1)
     qi = pl.program_id(2)
 
@@ -246,30 +349,22 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    run = True if not causal else (ki * bk <= qi * bq + bq - 1)
+    def _update(qs, ks, mask):
+        q = q_ref[qs]
+        do = do_ref[qs]
+        st = _flash_scores(k_ref[ks], q, scale, mask, False)
+        pt = jnp.exp(st - lse_ref[:, qs])               # (rows of K, Q)
+        dv_acc[ks] += jax.lax.dot_general(
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(v_ref[ks], do, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        dst = pt * (dpt - delta_ref[:, qs]) * scale
+        dk_acc[ks] += jax.lax.dot_general(
+            dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(run)
-    def _block():
-        q = q_ref[:]
-        k = k_ref[:]
-        v = v_ref[:]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[:, :1])                      # (BQ, BK)
-        do = do_ref[:]
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[:, :1]) * scale             # (BQ, BK)
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _flash_block_cases(causal, qi, ki, bq, bk, _update, k_major=True)
 
     @pl.when(qi == nq - 1)
     def _final():
@@ -299,6 +394,26 @@ def flash_seq_ok(t, dtype, pref=128):
     return b == t or b % _sublane(dtype) == 0
 
 
+def _export_flash_gauges(kernel, bq, bk, grid):
+    """What the newest instantiation of ``kernel`` was tiled into (trace
+    time, beside ``_count``)."""
+    from .. import telemetry
+    if not telemetry.enabled():
+        return
+    rows = telemetry.gauge(
+        "mxnet_flash_block_rows",
+        "rows of the score block the newest flash-attention kernel "
+        "instantiation works on, by kernel (fwd / dq / dkv) and side "
+        "(q / k)")
+    rows.labels(kernel=kernel, side="q").set(bq)
+    rows.labels(kernel=kernel, side="k").set(bk)
+    telemetry.gauge(
+        "mxnet_flash_grid_steps",
+        "grid steps of the newest flash-attention kernel instantiation "
+        "(batch x heads x Q blocks x K blocks), by kernel").labels(
+            kernel=kernel).set(math.prod(grid))
+
+
 # one operand block, counted at the f32 width the kernels compute in:
 # in + out blocks double-buffered stay a small fraction of the 16 MiB
 # of scoped VMEM whatever the channel count
@@ -322,78 +437,216 @@ def _row_block(n, c, dtype, pref):
     return cap
 
 
-def _qspec(bq, d):
-    return pl.BlockSpec((None, bq, d), lambda b, i, j: (b, i, 0))
-
-
-def _kspec(bk, d):
-    return pl.BlockSpec((None, bk, d), lambda b, i, j: (b, j, 0))
-
-
-def _lmspec(bq):
-    return pl.BlockSpec((None, bq, LANES), lambda b, i, j: (b, i, 0))
-
-
 # Kernel plans: each family's grid / BlockSpecs / operand shapes as one
 # declarative dict, built by the SAME function the dispatch consumes —
 # graftkern (analysis/kern/) abstractly interprets these plans, so the
 # verifier checks exactly the grid and index maps the kernel runs (no
 # drift by construction).  Shapes are the PADDED shapes the pallas_call
-# sees; "scratch" lists fp32 VMEM scratch shapes.
+# sees; "scratch" lists fp32 VMEM scratch shapes; a flash plan also
+# names its operands' "dtypes" (inputs, then outputs) and the (shape,
+# dtype) score "tiles" a step holds besides.
 
-def flash_fwd_plan(bh, tq, tk, d, bq, bk):
+def _flash_tiles(kernel, bq, bk, dtype):
+    """Score tiles a grid step keeps beside its operand blocks: the
+    float32 scores (``p`` overwrites them) and one float32 temporary —
+    the exponential's argument, ``dp`` / ``ds`` — and, in the backward,
+    ``p`` / ``ds`` again in the operand dtype for their products."""
+    shape = (bk, bq) if kernel == "dkv" else (bq, bk)
+    tiles = [(shape, "float32")] * 2
+    if kernel != "fwd":
+        tiles.append((shape, jnp.dtype(dtype).name))
+    return tiles
+
+
+_FLASH_SEMANTICS = ("parallel", "parallel", "arbitrary")
+
+
+def _flash_q_major_specs(d, bq, bk, causal):
+    """Specs of the kernels on grid (BH, nQ, nK): the Q side follows
+    i; the K side follows j, held at the last block row i needs under
+    the causal mask so the masked steps fetch nothing."""
+    def kmap(b, i, j):
+        return (b, _imin(j, _causal_last_k(i, bq, bk)) if causal else j, 0)
+    return (pl.BlockSpec((None, bq, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((None, bk, d), kmap),
+            pl.BlockSpec((None, bq, LANES), lambda b, i, j: (b, i, 0)))
+
+
+def flash_fwd_plan(bh, tq, tk, d, bq, bk, causal=False,
+                   dtype=jnp.float32):
     """Plan of the flash-attention forward kernel (q, k, v -> o, lse)."""
+    qspec, kspec, lmspec = _flash_q_major_specs(d, bq, bk, causal)
     return {
         "grid": (bh, tq // bq, tk // bk),
-        "in_specs": [_qspec(bq, d), _kspec(bk, d), _kspec(bk, d)],
+        "in_specs": [qspec, kspec, kspec],
         "in_shapes": [(bh, tq, d), (bh, tk, d), (bh, tk, d)],
-        "out_specs": [_qspec(bq, d), _lmspec(bq)],
+        "out_specs": [qspec, lmspec],
         "out_shapes": [(bh, tq, d), (bh, tq, LANES)],
         "scratch": [(bq, d), (bq, LANES), (bq, LANES)],
+        "dtypes": [dtype] * 4 + [jnp.float32],
+        "tiles": _flash_tiles("fwd", bq, bk, dtype),
     }
 
 
-def flash_bwd_dq_plan(bh, tq, tk, d, bq, bk):
+def flash_bwd_dq_plan(bh, tq, tk, d, bq, bk, causal=False,
+                      dtype=jnp.float32):
     """Plan of the dq backward kernel
     (q, k, v, do, lse, delta -> dq)."""
+    qspec, kspec, lmspec = _flash_q_major_specs(d, bq, bk, causal)
     return {
         "grid": (bh, tq // bq, tk // bk),
-        "in_specs": [_qspec(bq, d), _kspec(bk, d), _kspec(bk, d),
-                     _qspec(bq, d), _lmspec(bq), _lmspec(bq)],
+        "in_specs": [qspec, kspec, kspec, qspec, lmspec, lmspec],
         "in_shapes": [(bh, tq, d), (bh, tk, d), (bh, tk, d),
                       (bh, tq, d), (bh, tq, LANES), (bh, tq, LANES)],
-        "out_specs": [_qspec(bq, d)],
+        "out_specs": [qspec],
         "out_shapes": [(bh, tq, d)],
         "scratch": [(bq, d)],
+        "dtypes": [dtype] * 4 + [jnp.float32] * 2 + [dtype],
+        "tiles": _flash_tiles("dq", bq, bk, dtype),
     }
 
 
-def flash_bwd_dkv_plan(bh, tq, tk, d, bq, bk):
+def flash_bwd_dkv_plan(bh, tq, tk, d, bq, bk, causal=False,
+                       dtype=jnp.float32):
     """Plan of the dk/dv backward kernel — grid (BH, nK, nQ), so the
-    q-side specs transpose their two minor grid coordinates."""
-    qspec_t = pl.BlockSpec((None, bq, d), lambda b, j, i: (b, i, 0))
+    q-side specs transpose their two minor grid coordinates, and under
+    the causal mask hold at the first Q block column j needs.  ``lse``
+    and ``delta`` are rows here: (BH, nQ, 1, bq), one row a Q block."""
+    nq = tq // bq
+
+    def qblock(j, i):
+        if not causal:
+            return i
+        return _imin(_imax(i, _causal_first_q(j, bq, bk)), nq - 1)
+    qspec_t = pl.BlockSpec((None, bq, d),
+                           lambda b, j, i: (b, qblock(j, i), 0))
     kspec_t = pl.BlockSpec((None, bk, d), lambda b, j, i: (b, j, 0))
-    lmspec_t = pl.BlockSpec((None, bq, LANES), lambda b, j, i: (b, i, 0))
+    rowspec_t = pl.BlockSpec((None, None, 1, bq),
+                             lambda b, j, i: (b, qblock(j, i), 0, 0))
     return {
-        "grid": (bh, tk // bk, tq // bq),
-        "in_specs": [qspec_t, kspec_t, kspec_t, qspec_t, lmspec_t,
-                     lmspec_t],
+        "grid": (bh, tk // bk, nq),
+        "in_specs": [qspec_t, kspec_t, kspec_t, qspec_t, rowspec_t,
+                     rowspec_t],
         "in_shapes": [(bh, tq, d), (bh, tk, d), (bh, tk, d),
-                      (bh, tq, d), (bh, tq, LANES), (bh, tq, LANES)],
+                      (bh, tq, d), (bh, nq, 1, bq), (bh, nq, 1, bq)],
         "out_specs": [kspec_t, kspec_t],
         "out_shapes": [(bh, tk, d), (bh, tk, d)],
         "scratch": [(bk, d), (bk, d)],
+        "dtypes": [dtype] * 4 + [jnp.float32] * 2 + [dtype] * 2,
+        "tiles": _flash_tiles("dkv", bq, bk, dtype),
     }
 
 
+# rows a side: the sweep on the chip (PERF.md, PR 28) has every kernel
+# fastest at 1024 x 1024 or within a few per cent of it
+_FLASH_MAX_ROWS = 1024
+
+_FLASH_PLANS = {"fwd": flash_fwd_plan, "dq": flash_bwd_dq_plan,
+                "dkv": flash_bwd_dkv_plan}
+
+
+def _flash_vmem_bytes(plan):
+    """What one grid step of a flash plan holds in VMEM: its operand
+    blocks double-buffered, its float32 scratch twice (the product
+    added to an accumulator is as large), its score tiles.  Held
+    against every verdict of the chip's compiler over head sizes
+    64-256, bf16 and float32, blocks 512-1024: the compiler refuses
+    nothing this puts under the budget (PERF.md, PR 28)."""
+    def nbytes(shape, dtype):
+        return math.prod(b for b in shape if b is not None) \
+            * jnp.dtype(dtype).itemsize
+    specs = plan["in_specs"] + plan["out_specs"]
+    return (2 * sum(nbytes(sp.block_shape, t)
+                    for sp, t in zip(specs, plan["dtypes"]))
+            + 2 * sum(nbytes(sh, jnp.float32) for sh in plan["scratch"])
+            + sum(nbytes(sh, t) for sh, t in plan["tiles"]))
+
+
+def _block_choices(t, sub):
+    """Legal row blocks of a length-``t`` side, largest first: the
+    whole side when it is short, then the divisors of ``t`` in whole
+    lane tiles (a block is the lane dimension of a score tile too),
+    else in whole sublane tiles; the halving choice where none of those
+    exists (interpret mode only: ``flash_seq_ok`` is the native gate)."""
+    cap = _FLASH_MAX_ROWS
+    whole = [t] if t <= cap else []
+    for step in (LANES, sub):
+        divs = [b for b in range(min(cap, t) // step * step, 0, -step)
+                if t % b == 0 and b != t]
+        if divs:
+            return whole + divs
+    return whole or [_pick_block(t, cap)]
+
+
+def _flash_blocks(tq, tk, d, dtype, kernel):
+    """(bq, bk) for one flash kernel from what the call can see: the
+    largest blocks, in whole tiles and dividing T, whose plan fits the
+    scoped-VMEM budget graftkern holds the plans to
+    (``MXNET_KERN_VMEM_BYTES``).  Among equal areas the squarer pair
+    wins (a square block on the diagonal is worked in slabs), then the
+    wider Q side."""
+    from .. import config as _config
+    budget = int(_config.get("MXNET_KERN_VMEM_BYTES"))
+    sub = _sublane(dtype)
+    qs, ks = _block_choices(tq, sub), _block_choices(tk, sub)
+    fits = [(bq * bk, -abs(bq - bk), bq, bk) for bq in qs for bk in ks
+            if _flash_vmem_bytes(_FLASH_PLANS[kernel](
+                1, tq, tk, d, bq, bk, dtype=dtype)) < budget]
+    if not fits:
+        return qs[-1], ks[-1]
+    return max(fits)[2:]
+
+
+def _flash_plan(kernel, q, k, causal, block_q, block_k):
+    """(bq, bk, plan) of one kernel for this call: a caller's explicit
+    blocks are honoured (halved until they divide T, as ever), a side
+    left None is picked from the shape; the choice is exported."""
+    bh, tq, d = q.shape
+    tk = k.shape[1]
+    bq, bk = _flash_blocks(tq, tk, d, q.dtype, kernel)
+    if block_q is not None:
+        bq = _pick_block(tq, block_q)
+    if block_k is not None:
+        bk = _pick_block(tk, block_k)
+    plan = _FLASH_PLANS[kernel](bh, tq, tk, d, bq, bk, causal, q.dtype)
+    _export_flash_gauges(kernel, bq, bk, plan["grid"])
+    return bq, bk, plan
+
+
+def _flash_call(kernel, plan, *operands):
+    """One flash ``pallas_call`` off its plan, named after its kernel
+    (the benchmark's trace readers find the three by name)."""
+    n_in = len(plan["in_specs"])
+    return pl.pallas_call(
+        kernel,
+        grid=plan["grid"],
+        in_specs=plan["in_specs"],
+        out_specs=plan["out_specs"],
+        out_shape=[jax.ShapeDtypeStruct(s, t) for s, t in zip(
+            plan["out_shapes"], plan["dtypes"][n_in:])],
+        scratch_shapes=[pltpu.VMEM(s, jnp.float32)
+                        for s in plan["scratch"]],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_FLASH_SEMANTICS),
+        name=kernel.func.__name__,
+        interpret=_interpret(),
+    )(*operands)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
-                    block_k=128):
+def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
+                    block_k=None):
     """Blockwise online-softmax attention.
 
     q, k, v: (BH, T, D) — fold batch and heads into the leading dim.
-    Returns (BH, T, D).  O(T) memory; causal masking skips upper-
-    triangular K blocks entirely.
+    Returns (BH, T, D).  O(T) memory.  The (block_q, block_k) score
+    blocks are chosen per kernel from the shape — the largest whole-
+    tile divisors of T that fit the scoped-VMEM budget
+    (``_flash_blocks``); an explicit ``block_q`` / ``block_k`` is
+    honoured.  Causal masking skips the blocks above the diagonal
+    entirely (no body, no fetch), builds a mask only in the blocks the
+    diagonal crosses, and in the backward skips the upper half of a
+    square diagonal block slab by slab.
     """
     o, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k)
     return o
@@ -401,29 +654,12 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
     _count("flash_attention_fwd")
-    bh, tq, d = q.shape
-    tk = k.shape[1]
-    scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    bq = _pick_block(tq, block_q)
-    bk = _pick_block(tk, block_k)
-    nk = tk // bk
-    kernel = functools.partial(_flash_fwd_kernel, scale=scale, causal=causal,
-                               bq=bq, bk=bk, nk=nk)
-    plan = flash_fwd_plan(bh, tq, tk, d, bq, bk)
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=plan["grid"],
-        in_specs=plan["in_specs"],
-        out_specs=plan["out_specs"],
-        out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((bh, tq, LANES), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM(s, jnp.float32)
-                        for s in plan["scratch"]],
-        name="_flash_fwd_kernel",
-        interpret=_interpret(),
-    )(q, k, v)
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    bq, bk, plan = _flash_plan("fwd", q, k, causal, block_q, block_k)
+    o, lse = _flash_call(
+        functools.partial(_flash_fwd_kernel, scale=scale, causal=causal,
+                          bq=bq, bk=bk, nk=k.shape[1] // bk),
+        plan, q, k, v)
     return o, (q, k, v, o, lse)
 
 
@@ -436,42 +672,20 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, res, do):
     _count("flash_attention_bwd")
     q, k, v, o, lse = res
     bh, tq, d = q.shape
-    tk = k.shape[1]
     s = scale if scale is not None else 1.0 / math.sqrt(d)
-    bq = _pick_block(tq, block_q)
-    bk = _pick_block(tk, block_k)
-    nq, nk = tq // bq, tk // bk
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    delta = jnp.broadcast_to(delta[..., None], (bh, tq, LANES))
-    dq_plan = flash_bwd_dq_plan(bh, tq, tk, d, bq, bk)
-    dq = pl.pallas_call(
+    bq, bk, plan = _flash_plan("dq", q, k, causal, block_q, block_k)
+    dq, = _flash_call(
         functools.partial(_flash_bwd_dq_kernel, scale=s, causal=causal,
-                          bq=bq, bk=bk, nk=nk),
-        grid=dq_plan["grid"],
-        in_specs=dq_plan["in_specs"],
-        out_specs=dq_plan["out_specs"][0],
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM(sh, jnp.float32)
-                        for sh in dq_plan["scratch"]],
-        name="_flash_bwd_dq_kernel",
-        interpret=_interpret(),
-    )(q, k, v, do, lse, delta)
-    dkv_plan = flash_bwd_dkv_plan(bh, tq, tk, d, bq, bk)
-    dk, dv = pl.pallas_call(
+                          bq=bq, bk=bk, nk=k.shape[1] // bk),
+        plan, q, k, v, do, lse,
+        jnp.broadcast_to(delta[..., None], (bh, tq, LANES)))
+    bq, bk, plan = _flash_plan("dkv", q, k, causal, block_q, block_k)
+    rows = (bh, tq // bq, 1, bq)        # dK/dV reads its statistics as rows
+    dk, dv = _flash_call(
         functools.partial(_flash_bwd_dkv_kernel, scale=s, causal=causal,
-                          bq=bq, bk=bk, nq=nq),
-        grid=dkv_plan["grid"],
-        in_specs=dkv_plan["in_specs"],
-        out_specs=dkv_plan["out_specs"],
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM(sh, jnp.float32)
-                        for sh in dkv_plan["scratch"]],
-        name="_flash_bwd_dkv_kernel",
-        interpret=_interpret(),
-    )(q, k, v, do, lse, delta)
+                          bq=bq, bk=bk, nq=tq // bq),
+        plan, q, k, v, do, lse[:, :, 0].reshape(rows), delta.reshape(rows))
     return dq, dk, dv
 
 

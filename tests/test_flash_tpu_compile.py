@@ -1,0 +1,60 @@
+"""The flash kernels compiled by the TPU's own compiler for a DESCRIBED
+v5e (no chip attached), at the blocks ``_flash_blocks`` picks: what
+interpret mode cannot show — a tile the compiler refuses, a block that
+overruns scoped VMEM.  Nothing runs and no time comes out of it.
+
+The topology is described inside a fixture, never at import: only one
+process may hold the TPU's library, and every xdist worker imports this
+file (``on-chip-measurement`` guide, section 2).  Keep such tests in
+this one file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from mxnet_tpu.ops import pallas_kernels as pk
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# (BH, Tq, Tk, D, dtype, causal): the two LM cells of the benchmark, a
+# longer sequence, float32 operands at a wide head (the blocks shrink to
+# fit VMEM), a sequence of no whole lane tile, and Tq != Tk
+@pytest.mark.parametrize("bh,tq,tk,d,dtype,causal", [
+    (64, 2048, 2048, 64, "bfloat16", True),
+    (16, 2048, 2048, 128, "bfloat16", True),
+    (2, 8192, 8192, 128, "bfloat16", True),
+    (2, 2048, 2048, 256, "float32", True),
+    (2, 200, 200, 64, "float32", True),
+    (2, 384, 128, 128, "bfloat16", False),
+])
+def test_flash_kernels_compile_for_the_chip(one_chip, monkeypatch, bh, tq,
+                                            tk, d, dtype, causal):
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+
+    def loss(q, k, v):
+        o = pk.flash_attention(q, k, v, causal)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    def aval(t):
+        return jax.ShapeDtypeStruct((bh, t, d), jnp.dtype(dtype),
+                                    sharding=one_chip)
+    # the suite asks for float32 products (conftest.py); the chip runs
+    # with the default, and Mosaic takes no bf16 operand at "highest"
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+            aval(tq), aval(tk), aval(tk)).compile()
+    text = compiled.as_text()
+    for name in ("_flash_fwd_kernel", "_flash_bwd_dq_kernel",
+                 "_flash_bwd_dkv_kernel"):
+        assert name in text

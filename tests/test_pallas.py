@@ -14,43 +14,88 @@ import pytest
 from mxnet_tpu.ops import pallas_kernels as pk
 
 
-def _ref_attn(q, k, v, causal, T, D):
-    s = jnp.einsum("bqd,bkd->bqk", q, k) / math.sqrt(D)
+def _ref_attn(q, k, v, causal):
+    """Dense float32 attention, the causal mask top-left aligned as the
+    kernels': query row r sees key c iff r >= c."""
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    s = jnp.einsum("bqd,bkd->bqk", q, k) / math.sqrt(q.shape[-1])
     if causal:
-        mask = jnp.tril(jnp.ones((T, T), bool))
+        mask = jnp.tril(jnp.ones((q.shape[1], k.shape[1]), bool))
         s = jnp.where(mask[None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bqk,bkd->bqd", p, v)
 
 
+def _qkv(seed, bh, tq, tk, d, dtype):
+    rng = np.random.RandomState(seed)
+    return (jnp.asarray(rng.randn(bh, tq, d), dtype),
+            jnp.asarray(rng.randn(bh, tk, d), dtype),
+            jnp.asarray(rng.randn(bh, tk, d), dtype))
+
+
+def _rel(a, b):
+    b = np.asarray(b, np.float32)
+    return float(np.abs(np.asarray(a, np.float32) - b).max()
+                 / np.abs(b).max())
+
+
+# (Tq, Tk, D, dtype, block_q, block_k): explicit small blocks, and the
+# blocks picked from the shape (None) — a T of several picked blocks
+# (2048 = 2..4 blocks a side), one that fits a single block, Tq != Tk
+_FLASH_CASES = [
+    (64, 64, 16, "float32", 16, 16),
+    (48, 48, 8, "float32", 16, 8),
+    (2048, 2048, 64, "bfloat16", None, None),
+    (2048, 2048, 128, "float32", None, None),
+    (256, 256, 64, "float32", None, None),
+    (256, 256, 128, "bfloat16", None, None),
+    (128, 384, 64, "float32", None, None),
+    (384, 128, 64, "bfloat16", None, None),
+]
+_FLASH_IDS = ["%dx%d-d%d-%s-%s" % (tq, tk, d, dt, "picked" if bq is None
+                                   else "b%dx%d" % (bq, bk))
+              for tq, tk, d, dt, bq, bk in _FLASH_CASES]
+
+
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("T,D,bq,bk", [(64, 16, 16, 16), (48, 8, 16, 8)])
-def test_flash_attention_forward(causal, T, D, bq, bk):
-    rng = np.random.RandomState(0)
-    q, k, v = (jnp.asarray(rng.randn(3, T, D).astype(np.float32))
-               for _ in range(3))
+@pytest.mark.parametrize("Tq,Tk,D,dtype,bq,bk", _FLASH_CASES,
+                         ids=_FLASH_IDS)
+def test_flash_attention_forward(causal, Tq, Tk, D, dtype, bq, bk):
+    q, k, v = _qkv(0, 2 if Tq >= 2048 else 3, Tq, Tk, D, dtype)
     o = pk.flash_attention(q, k, v, causal, None, bq, bk)
-    r = _ref_attn(q, k, v, causal, T, D)
-    assert float(jnp.abs(o - r).max()) < 1e-5
+    assert o.dtype == q.dtype
+    r = _ref_attn(q, k, v, causal)
+    if dtype == "float32":
+        assert float(jnp.abs(o - r).max()) < 1e-5
+    else:
+        assert _rel(o, r) < 1e-2
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_attention_grads(causal):
-    T, D = 32, 8
-    rng = np.random.RandomState(1)
-    q, k, v = (jnp.asarray(rng.randn(2, T, D).astype(np.float32))
-               for _ in range(3))
+@pytest.mark.parametrize("Tq,Tk,D,dtype,bq,bk",
+                         [(32, 32, 8, "float32", 8, 8)] + _FLASH_CASES[2:],
+                         ids=["32x32-d8-float32-b8x8"] + _FLASH_IDS[2:])
+def test_flash_attention_grads(causal, Tq, Tk, D, dtype, bq, bk):
+    """dq, dk and dv against the dense reference's, at explicit blocks
+    and at the blocks each backward kernel picks from the shape."""
+    q, k, v = _qkv(1, 2, Tq, Tk, D, dtype)
 
     def loss_flash(q, k, v):
-        return jnp.sum(pk.flash_attention(q, k, v, causal, None, 8, 8) ** 2)
+        o = pk.flash_attention(q, k, v, causal, None, bq, bk)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
 
     def loss_ref(q, k, v):
-        return jnp.sum(_ref_attn(q, k, v, causal, T, D) ** 2)
+        return jnp.sum(_ref_attn(q, k, v, causal) ** 2)
 
     gf = jax.grad(loss_flash, (0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, (0, 1, 2))(q, k, v)
+    gr = jax.grad(loss_ref, (0, 1, 2))(
+        *(a.astype(jnp.float32) for a in (q, k, v)))
     for a, b in zip(gf, gr):
-        assert float(jnp.abs(a - b).max()) < 1e-4
+        assert a.dtype == q.dtype
+        if dtype == "float32":
+            assert _rel(a, b) < 1e-4
+        else:
+            assert _rel(a, b) < 3e-2
 
 
 def test_flash_attention_numerically_stable():
@@ -478,6 +523,88 @@ def test_norm_block_rows_follow_the_dtype_sublane():
     # an explicit value is clamped to whole tiles too
     assert pk._norm_block_rows(256, 1024, knob, value=8,
                                dtype=jnp.bfloat16) == 16
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+def test_flash_blocks_picked_from_the_shape(kernel, d):
+    """The two LM cells' shapes: blocks in whole sublane tiles that
+    divide T, inside the scoped-VMEM budget, and at most 1,024 grid
+    steps a call at 64 (batch x heads) where 128-row blocks took
+    16,384."""
+    from mxnet_tpu import config
+    T, dtype = 2048, jnp.bfloat16
+    bq, bk = pk._flash_blocks(T, T, d, dtype, kernel)
+    for b in (bq, bk):
+        assert T % b == 0 and b % pk._sublane(dtype) == 0
+    plan = pk._FLASH_PLANS[kernel](64, T, T, d, bq, bk, True, dtype)
+    assert pk._flash_vmem_bytes(plan) <= config.get("MXNET_KERN_VMEM_BYTES")
+    assert math.prod(plan["grid"]) <= 1024
+    # a short sequence is one block, whatever its length
+    assert pk._flash_blocks(48, 48, d, jnp.float32, kernel) == (48, 48)
+    assert pk._flash_blocks(8, 136, d, dtype, kernel) == (8, 136)
+
+
+def test_flash_explicit_blocks_are_honoured():
+    def blocks(kernel, tq, d, dtype, block_q, block_k):
+        a = jax.ShapeDtypeStruct((2, tq, d), dtype)
+        return pk._flash_plan(kernel, a, a, True, block_q, block_k)[:2]
+    assert blocks("fwd", 2048, 64, jnp.bfloat16, 128, 256) == (128, 256)
+    # halved until they divide T, as ever
+    assert blocks("dkv", 48, 8, jnp.float32, 16, 32) == (16, 16)
+    # one side given: the other is picked
+    bq, bk = pk._flash_blocks(2048, 2048, 64, jnp.bfloat16, "dq")
+    assert blocks("dq", 2048, 64, jnp.bfloat16, None, 128) == (bq, 128)
+
+
+def test_flash_causal_index_maps_hold_at_the_diagonal():
+    """A masked step names the block the step before it named, so
+    Pallas copies nothing for it: K blocks stop at the last one a Q row
+    needs, Q blocks of dK/dV start at the first one a K column needs."""
+    fwd = pk.flash_fwd_plan(1, 1024, 1024, 64, 256, 128, True)
+    kmap = fwd["in_specs"][1].index_map
+    assert [kmap(0, 1, j)[1] for j in range(8)] == [0, 1, 2, 3, 3, 3, 3, 3]
+    assert [kmap(0, 3, j)[1] for j in range(8)] == list(range(8))
+    dkv = pk.flash_bwd_dkv_plan(1, 1024, 1024, 64, 256, 128, True)
+    qmap, rowmap = (dkv["in_specs"][i].index_map for i in (0, 4))
+    assert [qmap(0, 5, i)[1] for i in range(4)] == [2, 2, 2, 3]
+    assert [rowmap(0, 5, i)[1] for i in range(4)] == [2, 2, 2, 3]
+    # keys past the last query row (Tk > Tq) stay inside the array
+    wide = pk.flash_bwd_dkv_plan(1, 256, 512, 64, 128, 128, True)
+    assert [wide["in_specs"][0].index_map(0, 3, i)[1]
+            for i in range(2)] == [1, 1]
+    # without the mask the maps are the identity
+    plain = pk.flash_fwd_plan(1, 1024, 1024, 64, 256, 128)
+    assert [plain["in_specs"][1].index_map(0, 1, j)[1]
+            for j in range(8)] == list(range(8))
+
+
+def test_flash_gauges_read_the_picked_blocks():
+    """mxnet_flash_block_rows / mxnet_flash_grid_steps after a trace of
+    the forward and the backward (nothing runs: eval_shape)."""
+    from mxnet_tpu import telemetry
+    bh, T, d = 4, 2048, 64
+    a = jax.ShapeDtypeStruct((bh, T, d), jnp.bfloat16)
+    telemetry.enable()
+    try:
+        jax.eval_shape(jax.grad(
+            lambda q, k, v: jnp.sum(pk.flash_attention(
+                q, k, v, True).astype(jnp.float32)), (0, 1, 2)), a, a, a)
+        rows = telemetry.gauge("mxnet_flash_block_rows")
+        steps = telemetry.gauge("mxnet_flash_grid_steps")
+        for kernel in ("fwd", "dq", "dkv"):
+            bq, bk = pk._flash_blocks(T, T, d, jnp.bfloat16, kernel)
+            assert rows.labels(kernel=kernel, side="q").value == bq
+            assert rows.labels(kernel=kernel, side="k").value == bk
+            assert steps.labels(kernel=kernel).value \
+                == bh * (T // bq) * (T // bk)
+        # the newest instantiation wins: explicit 128-row blocks
+        jax.eval_shape(lambda q, k, v: pk.flash_attention(
+            q, k, v, True, None, 128, 128), a, a, a)
+        assert rows.labels(kernel="fwd", side="q").value == 128
+        assert steps.labels(kernel="fwd").value == bh * 16 * 16
+    finally:
+        telemetry.disable()
 
 
 def test_flash_eligibility_knows_the_sublane_tile():
