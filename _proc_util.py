@@ -1,4 +1,4 @@
-"""Shared helper for the driver entry points (bench.py, __graft_entry__.py).
+"""Shared helper for the driver entry point (__graft_entry__.py).
 
 Children run in their own session with a process-group kill on the
 deadline — ``subprocess.run(timeout=...)`` only kills the direct child

@@ -38,7 +38,7 @@ burst with tracing disarmed vs armed at the default tail-sample rate,
 recording both throughputs and asserting the armed overhead stays
 within 3% req/s (the disarmed path is one boolean check per seam).
 
-Methodology mirrors bench.py: warmup excluded from measurement (every
+Methodology: warmup excluded from measurement (every
 bucket compiled by ``warmup()`` before the clock starts), ONE JSON
 line on stdout win or lose, details written incrementally to
 BENCH_SERVING.json.  The stdout line and the JSON file carry the
@@ -46,8 +46,7 @@ device jax reported (platform, device_kind, count): a number taken on
 ``cpu`` is a host number, never a device metric, and only the relative
 claim (batched vs sequential on the SAME device) carries across
 platforms.  Small hosts are noisy (the capture box has 2
-cores shared by 64 client threads), so like bench.py's
-discard-first/median-of-readings rule each number is a multi-pass
+cores shared by 64 client threads), so each number is a multi-pass
 reading: the sequential baseline is the median of 3 passes, each
 serving leg the better of 2 (first pass carries thread/cache
 warm-in); all passes are recorded in the JSON.
@@ -99,8 +98,8 @@ def _device():
 
 def _build_model():
     """Model-zoo ResNet-20 with randomly initialized params (synthetic
-    weights, like bench.py's synthetic data: serving throughput does
-    not depend on what the weights converged to)."""
+    weights: serving throughput does not depend on what the weights
+    converged to)."""
     import resnet as resnet_zoo
 
     import mxnet_tpu as mx
@@ -613,7 +612,7 @@ def main():
             leg["passes"] = [p.get("req_per_sec", p.get("error"))
                              for p in (first, second)]
             result["serving"].append(leg)
-            checkpoint()                 # incremental, like bench.py legs
+            checkpoint()                 # incremental
         result["stats"] = srv.stats()
         checkpoint()
     except Exception as exc:   # noqa: BLE001

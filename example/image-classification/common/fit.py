@@ -110,9 +110,9 @@ def fit(args, network, data_loader, **kwargs):
     head = "%(asctime)-15s Node[" + str(kv.rank) + "] %(message)s"
     logging.basicConfig(level=logging.INFO, format=head)
     logging.info("start with arguments %s", args)
-    # what jax gave this process: bench.py reads this line and refuses
-    # a number from anything but a TPU; mx.tpu() below names a host
-    # device when there is no accelerator (how the CPU tests run)
+    # what jax gave this process: mx.tpu() below names a host device
+    # when there is no accelerator (how the CPU tests run), so a log
+    # with a speed in it says which platform the speed is of
     import jax
     devs = jax.devices()
     logging.info("device platform=%s kind=%s count=%d", devs[0].platform,

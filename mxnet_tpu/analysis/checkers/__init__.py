@@ -18,4 +18,3 @@ from . import span_discipline    # noqa: F401
 from . import stale_suppression  # noqa: F401
 from . import swallowed_exception  # noqa: F401
 from . import tracer_escape      # noqa: F401
-from . import tune_knobs         # noqa: F401

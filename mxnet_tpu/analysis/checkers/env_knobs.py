@@ -148,26 +148,22 @@ class EnvKnobChecker(Checker):
         return out
 
 
-def drift_report(prefix=None, root=None, extra_sources=()):
+def drift_report(prefix=None, root=None):
     """One-call report for the test-suite wrappers.
 
     Returns ``{"used": {...}, "unregistered": [...], "undocumented":
-    [...], "registered_undocumented": [...]}`` over the whole package
-    plus ``extra_sources`` (paths outside ``mxnet_tpu/``, e.g.
-    ``bench.py``).  ``prefix`` (a str or tuple) restricts the *used*
-    directions to matching names — each legacy guard scoped itself to
-    its own knob family."""
+    [...], "registered_undocumented": [...]}`` over the whole package.
+    ``prefix`` (a str or tuple) restricts the *used* directions to
+    matching names — each legacy guard scoped itself to its own knob
+    family."""
     from ..core import repo_root, iter_source_files
     root = root or repo_root()
     config_path = os.path.join(root, "mxnet_tpu", "config.py")
     doc_path = os.path.join(root, "docs", "faq", "env_var.md")
     registered = registered_names(config_path)
     documented = documented_names(doc_path)
-    paths = [os.path.join(root, "mxnet_tpu")] + [
-        p if os.path.isabs(p) else os.path.join(root, p)
-        for p in extra_sources]
     used = {}
-    for path in iter_source_files(paths):
+    for path in iter_source_files([os.path.join(root, "mxnet_tpu")]):
         if not path.endswith(".py"):
             continue
         with open(path, encoding="utf-8", errors="replace") as f:

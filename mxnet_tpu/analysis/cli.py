@@ -47,22 +47,8 @@ def _changed_paths(root, ref):
     picked = []
     analysis_dir = os.path.join(root, "mxnet_tpu", "analysis")
 
-    def _pair(rel_n):
-        """tune-knob-drift is a TWO-file contract: an edit on either
-        side (the tuning space or the config registry) re-lints the
-        other so both drift directions are judged, not just the one
-        whose file changed."""
-        if rel_n == "mxnet_tpu/config.py" \
-                or rel_n.startswith("mxnet_tpu/tune/"):
-            for other in (os.path.join(root, "mxnet_tpu", "config.py"),
-                          os.path.join(root, "mxnet_tpu", "tune",
-                                       "space.py")):
-                if os.path.exists(other) and other not in picked:
-                    picked.append(other)
-
     for rel in sorted(names):
         rel_n = rel.replace(os.sep, "/")
-        _pair(rel_n)
         # analysis fixtures (plan-spec corpora, checker inputs) under
         # tests/fixtures/ feed the checker tests' lint paths: a
         # fixture-only edit re-lints the analysis package instead of
@@ -82,8 +68,8 @@ def _changed_paths(root, ref):
         if not rel_n.startswith("mxnet_tpu/"):
             continue
         full = os.path.join(root, rel)
-        if os.path.exists(full) and full not in picked:
-            picked.append(full)         # deletions need no lint
+        if os.path.exists(full):        # deletions need no lint
+            picked.append(full)
     return picked
 
 

@@ -6,8 +6,8 @@ LogValidationMetricsCallback.
 
 Log-format contract: the ``Epoch[%d] ... Speed: ... samples/sec``,
 ``Train-<metric>=``, ``Validation-<metric>=`` and ``Time cost=`` line
-shapes are machine-parsed (tools/parse_log.py, bench.py, and the
-reference's own tooling) and must not be reworded; everything else here
+shapes are machine-parsed (tools/parse_log.py and the reference's
+own tooling) and must not be reworded; everything else here
 is free-form.
 """
 from __future__ import annotations

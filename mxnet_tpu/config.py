@@ -17,33 +17,26 @@ from collections import OrderedDict
 
 from .base import getenv
 
-__all__ = ["register_env", "get", "tuned", "tuned_info", "list_env",
-           "check_unknown", "EnvVar"]
+__all__ = ["register_env", "get", "list_env", "check_unknown", "EnvVar"]
 
 
 class EnvVar:
-    __slots__ = ("name", "typ", "default", "description", "tunable")
+    __slots__ = ("name", "typ", "default", "description")
 
-    def __init__(self, name, typ, default, description, tunable=False):
+    def __init__(self, name, typ, default, description):
         self.name = name
         self.typ = typ
         self.default = default
         self.description = description
-        self.tunable = tunable
 
 
 _REGISTRY = OrderedDict()
 
 
-def register_env(name, typ=str, default=None, description="",
-                 tunable=False):
+def register_env(name, typ=str, default=None, description=""):
     """Declare a configuration variable (reference: the dmlc::GetEnv
-    call-site + env_var.md doc-entry pair).  ``tunable=True`` marks
-    the knob as swept by grafttune — graftlint's ``tune-knob-drift``
-    checker holds this flag and the ``tune/space.py`` registry in
-    two-way agreement."""
-    _REGISTRY[name] = EnvVar(name, typ, default, description,
-                             tunable=bool(tunable))
+    call-site + env_var.md doc-entry pair)."""
+    _REGISTRY[name] = EnvVar(name, typ, default, description)
     return _REGISTRY[name]
 
 
@@ -54,59 +47,6 @@ def get(name):
                        "register_env" % name)
     var = _REGISTRY[name]
     return getenv(name, var.default, var.typ)
-
-
-def _convert(var, value):
-    """Apply a registered variable's type discipline to a NON-env value
-    (a tuning-DB entry) — the same conversion ``base.getenv`` applies
-    to the string from the environment."""
-    if value is None:
-        return None
-    if var.typ is bool:
-        return value if isinstance(value, bool) \
-            else str(value).lower() in ("1", "true", "yes", "on")
-    if var.typ in (int, float):
-        return var.typ(value)
-    return str(value)
-
-
-def tuned_info(name, program=None, mesh_shape=None, backend=None):
-    """Resolve a tunable knob with provenance:
-    ``{"value", "source": "env" | "db" | "default"}``.
-
-    Resolution order (docs/faq/tune.md): an explicit environment
-    variable ALWAYS wins (the operator's override); else, when
-    ``MXNET_TUNE`` is on and a ``program`` key is given, the tuning DB
-    is consulted (``tune/db.py`` — keyed by program x backend x mesh
-    shape x jax version, corrupt entries degrade with a counted
-    warning); else the registered default.  Never raises past a bad DB
-    entry — bind sites must stay constructible with an empty or broken
-    DB."""
-    if name not in _REGISTRY:
-        raise KeyError("unregistered env var %r; declare it with "
-                       "register_env" % name)
-    var = _REGISTRY[name]
-    if os.environ.get(name) is not None:
-        return {"value": getenv(name, var.default, var.typ),
-                "source": "env"}
-    if program and get("MXNET_TUNE"):
-        try:
-            from .tune import db as _tune_db
-            values = _tune_db.lookup(program, backend=backend,
-                                     mesh_shape=mesh_shape)
-        except Exception:
-            values = None
-        if values and name in values:
-            return {"value": _convert(var, values[name]),
-                    "source": "db"}
-    return {"value": var.default, "source": "default"}
-
-
-def tuned(name, program=None, mesh_shape=None, backend=None):
-    """The value leg of :func:`tuned_info` — drop-in for :func:`get`
-    at bind sites that participate in grafttune."""
-    return tuned_info(name, program=program, mesh_shape=mesh_shape,
-                      backend=backend)["value"]
 
 
 def list_env():
@@ -154,20 +94,6 @@ register_env("MXNET_NATIVE_DISABLE", bool, False,
 register_env("MXNET_KVSTORE_HEARTBEAT_DIR", str, None,
              "shared directory for dist-kvstore worker heartbeats "
              "(enables get_num_dead_node)")
-register_env("MXNET_CONV_LAYOUT", str, None,
-             "set to NHWC to run 2-D conv/pool internally channel-last "
-             "(layout experiment; XLA folds the boundary transposes)")
-register_env("MXNET_BENCH_SECONDARY_BUDGET_S", float, 600.0,
-             "bench.py wall budget for the secondary NHWC/rider legs; "
-             "legs that no longer fit are marked skipped in the side "
-             "JSON files instead of risking an external kill")
-register_env("MXNET_FUSED_METRIC", str, None,
-             "set to 0 to disable the one-dispatch jitted Accuracy "
-             "accumulate (falls back to per-op device calls)")
-register_env("MXNET_STEM_SPACE_TO_DEPTH", str, None,
-             "set to 1 to rewrite 7x7/s2/p3 few-channel stem convs as "
-             "space-to-depth + 4x4/s1 conv (MXU-fill experiment, "
-             "docs/faq/perf.md)")
 register_env("MXNET_KVSTORE_ASYNC_DIR", str, None,
              "shared spool directory for the dist_async parameter "
              "server (coordinator applies pushes on arrival)")
@@ -180,8 +106,7 @@ register_env("MXNET_KVSTORE_ASYNC_BACKPRESSURE_TIMEOUT", float, 120.0,
              "before raising (a dead server thread, not staleness)")
 register_env("MXNET_SERVING_MAX_BATCH", int, 8,
              "largest serving shape bucket; the micro-batcher coalesces "
-             "concurrent requests up to this many rows per dispatch",
-             tunable=True)
+             "concurrent requests up to this many rows per dispatch")
 register_env("MXNET_SERVING_QUEUE_DEPTH", int, 256,
              "bounded serving request queue; submissions beyond this "
              "depth are rejected with QueueFull (explicit backpressure)")
@@ -263,27 +188,23 @@ register_env("MXNET_PARALLEL_BUCKET_BYTES", int, 4194304,
              "replicated params are fused into flat buckets of at most "
              "this many bytes so each bucket's reduce can overlap the "
              "remaining backward (docs/faq/parallel.md); <= 0 puts "
-             "everything in one monolithic bucket",
-             tunable=True)
+             "everything in one monolithic bucket")
 register_env("MXNET_PARALLEL_BUCKET_FIRST_BYTES", int, 1048576,
              "size cap of the FIRST bucket (the output-side params whose "
              "gradients finish earliest in backward); smaller than "
              "MXNET_PARALLEL_BUCKET_BYTES so the first collective "
-             "launches as early as possible",
-             tunable=True)
+             "launches as early as possible")
 register_env("MXNET_PARALLEL_ZERO", int, 0,
              "default ZeRO stage for ParallelTrainer: 0 replicates "
              "optimizer state (monolithic all-reduce), 1 shards "
              "optimizer slots 1/mesh (full-gradient all-reduce), 2 also "
              "reduce-scatters gradients into the shards "
-             "(docs/faq/parallel.md)",
-             tunable=True)
+             "(docs/faq/parallel.md)")
 register_env("MXNET_PARALLEL_COMPRESSION", str, None,
              "default gradient-compression codec for ParallelTrainer "
              "bucket reductions: 2bit (reference kvstore quantizer), "
              "bf16, or fp8 — all with error-feedback residuals carried "
-             "in trainer state; unset sends fp32",
-             tunable=True)
+             "in trainer state; unset sends fp32")
 register_env("MXNET_PARALLEL_COMPRESSION_THRESHOLD", float, 0.5,
              "quantization threshold of the 2bit codec (reference "
              "gradient_compression.cc pos/neg threshold)")
@@ -380,23 +301,19 @@ register_env("MXNET_PALLAS_BN_RELU", str, "auto",
 register_env("MXNET_PALLAS_OPT_BLOCK_ELEMS", int, 0,
              "elements per grid step of the fused optimizer sweep "
              "kernels (rounded to whole (8,128) fp32 tiles); 0 picks "
-             "the 128Ki-element default",
-             tunable=True)
+             "the 128Ki-element default")
 register_env("MXNET_PALLAS_NORM_BLOCK_ROWS", int, 0,
              "rows per grid step of the fused layernorm kernels; 0 "
-             "sizes blocks to ~512 KiB of VMEM per operand",
-             tunable=True)
+             "sizes blocks to ~512 KiB of VMEM per operand")
 register_env("MXNET_PALLAS_SOFTMAX_BLOCK_ROWS", int, 0,
              "rows per grid step of the fused softmax kernels; 0 "
-             "sizes blocks to ~512 KiB of VMEM per operand",
-             tunable=True)
+             "sizes blocks to ~512 KiB of VMEM per operand")
 register_env("MXNET_PALLAS_OPT_BUCKET_BYTES", int, 0,
              "bucket size cap for the executor fused step's optimizer "
              "sweep (the 1-D leaves of each (lr_mult, wd_mult) group "
              "concatenated into contiguous fp32 buckets; N-D weights "
              "are never bucketed); <= 0 sweeps each group as one "
-             "monolithic bucket",
-             tunable=True)
+             "monolithic bucket")
 register_env("MXNET_FAULT_PLAN", str, None,
              "deterministic fault-injection schedule (graftfault): "
              "inline JSON or @/path/to/plan.json; armed at import, "
@@ -482,8 +399,7 @@ register_env("MXNET_SERVING_GEN_MAX_LEN", int, 0,
              "wrap-around); 0 uses the model's positional-table size")
 register_env("MXNET_SERVING_GEN_MAX_NEW_TOKENS", int, 64,
              "default generation budget when infer_stream passes no "
-             "max_new_tokens; a slot always frees at EOS or budget",
-             tunable=True)
+             "max_new_tokens; a slot always frees at EOS or budget")
 register_env("MXNET_SERVING_GEN_PREFILL_BATCH", int, 4,
              "max prompts coalesced into one prefill program; sets "
              "the batch axis of the prefill (batch, length) grid, so "
@@ -532,10 +448,6 @@ register_env("MXNET_FLEET_SUBMIT_RETRIES", int, 3,
              "request id to another replica up to this many times "
              "(honoring the remote retry_after_s hint); the ledger "
              "dedups, so a client never sees a duplicate")
-register_env("MXNET_BENCH_SKIP_NHWC", str, None,
-             "set to 1 to skip bench.py's secondary NHWC layout leg")
-register_env("MXNET_BENCH_SKIP_RIDERS", str, None,
-             "set to 1 to skip bench.py's rider benchmark legs")
 register_env("MXNET_TRACE", bool, False,
              "master switch for graftrace request tracing + the flight "
              "recorder (telemetry/tracing.py): off, every span call "
@@ -577,30 +489,3 @@ register_env("MXNET_TELEMETRY_LABEL_CAP", int, 256,
              "__overflow__ child and "
              "mxnet_telemetry_label_overflow_total{metric=...} counts "
              "the spill (0 = uncapped)")
-register_env("MXNET_TUNE", bool, False,
-             "enable tuning-DB resolution at bind sites: when on, "
-             "knobs not pinned by an explicit env var read the "
-             "grafttune DB (tune/db.py) before falling back to "
-             "defaults (config.tuned; docs/faq/tune.md)")
-register_env("MXNET_TUNE_DB_DIR", str, None,
-             "directory of the fleet-shared tuning database; unset "
-             "defaults to ~/.cache/mxnet_tpu/tune.  Entries are keyed "
-             "by program x backend x mesh shape x jax version and "
-             "committed atomically, so replicas can share one dir")
-register_env("MXNET_TUNE_BUDGET", int, 32,
-             "candidate budget of one grafttune sweep "
-             "(tune/search.py run_sweep); the seeded proposal stream "
-             "is journaled per k, so a resumed sweep continues where "
-             "the budget cut it off")
-register_env("MXNET_TUNE_SEED", int, 0,
-             "seed of the grafttune proposal stream — candidate k is "
-             "a pure function of (seed, k), so the same seed replays "
-             "the same sweep on any machine")
-register_env("MXNET_TUNE_PRUNE_ONLY", bool, False,
-             "stop a grafttune sweep after the static verdicts: "
-             "candidates are judged and journaled (prune rate + rule "
-             "histogram) but nothing is compiled or measured")
-register_env("MXNET_TUNE_MEASURE_SPEC", str, None,
-             "internal side-channel of tune/measure.py: the JSON "
-             "measurement spec the bounded subprocess reads; set by "
-             "measure_candidate, not by operators")

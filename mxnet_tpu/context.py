@@ -63,7 +63,7 @@ class Context:
             # CPU-only process (the tests) that backend IS the host, so
             # a tpu context names a host device — entry points that
             # report numbers say which platform they ran on
-            # (common/fit.py's header line, bench.py's "device").
+            # (common/fit.py's header line, perfbench/run.py's "device").
             devs = jax.devices()
         if self.device_id >= len(devs):
             raise MXNetError(

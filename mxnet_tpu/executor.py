@@ -282,8 +282,7 @@ class Executor:
             groups.setdefault(key, []).append(j)
         if not groups:
             return None
-        cap = _config.tuned("MXNET_PALLAS_OPT_BUCKET_BYTES",
-                            program="executor-fused-step")
+        cap = _config.get("MXNET_PALLAS_OPT_BUCKET_BYTES")
         plan = []
         for key in sorted(groups):
             idxs = groups[key]

@@ -38,16 +38,6 @@ def check_label_shapes(labels, preds, shape=False):
                          "predictions {}".format(label_shape, pred_shape))
 
 
-def _fused_metric_disabled():
-    """A/B knob (docs/faq/perf.md): MXNET_FUSED_METRIC=0 falls back to
-    the per-op device accumulate path."""
-    from . import config as _config
-    try:
-        return _config.get("MXNET_FUSED_METRIC") == "0"
-    except KeyError:  # pragma: no cover - registry not loaded yet
-        return False
-
-
 def _acc_accum(pred, label, total, axis):
     """One fused device program for Accuracy's per-batch accumulate
     (argmax + compare + sum + add); jit-cached per (shape, axis)."""
@@ -211,23 +201,6 @@ class Accuracy(EvalMetric):
     def update(self, labels, preds):
         check_label_shapes(labels, preds)
         for label, pred_label in zip(labels, preds):
-            if isinstance(pred_label, NDArray) and isinstance(label, NDArray) \
-                    and _fused_metric_disabled():
-                # A/B fallback: the pre-fusion device-lazy path — same
-                # math as below but dispatched as ~8 separate device ops
-                import jax.numpy as jnp
-                p = pred_label._data
-                lab = label._data
-                if p.ndim > 1 and \
-                        p.shape[-1 if self.axis == -1 else self.axis] > 1 \
-                        and p.ndim != lab.ndim:
-                    p = jnp.argmax(p, axis=self.axis)
-                p = p.astype(jnp.int32).ravel()
-                lab = lab.astype(jnp.int32).ravel()
-                check_label_shapes(lab, p, shape=True)
-                self.sum_metric = self.sum_metric + (p == lab).sum()
-                self.num_inst += int(p.shape[0])
-                continue
             if isinstance(pred_label, NDArray) and isinstance(label, NDArray):
                 # device path: argmax/compare/sum/accumulate run as ONE
                 # jitted program on the accelerator into a lazy device

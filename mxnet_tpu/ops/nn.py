@@ -31,59 +31,6 @@ from .registry import register, Param as P, normalize_tuple
 from ..base import MXNetError
 
 
-def _internal_nhwc():
-    """Layout experiment toggle (docs/faq/perf.md): run 2-D conv/pool
-    internally in NHWC with boundary transposes XLA folds away."""
-    from .. import config as _config
-    try:
-        return (_config.get("MXNET_CONV_LAYOUT") or "").upper() == "NHWC"
-    except KeyError:  # pragma: no cover - registry not loaded yet
-        return False
-
-
-def _stem_s2d_enabled():
-    """MFU experiment toggle (docs/faq/perf.md): rewrite the ResNet-style
-    7x7/s2/p3 few-channel stem conv as space-to-depth + 4x4/s1 conv."""
-    from .. import config as _config
-    try:
-        return _config.get("MXNET_STEM_SPACE_TO_DEPTH") == "1"
-    except KeyError:  # pragma: no cover - registry not loaded yet
-        return False
-
-
-def _conv_stem_s2d(data, weight, bias, no_bias):
-    """7x7/stride-2/pad-3 stem conv via space-to-depth (MLPerf trick).
-
-    The 7x7 kernel over C<=4 input channels under-fills the 128x128 MXU
-    contraction (round-2 trace's named loss).  Equivalent program: pad
-    the kernel to 8x8 (zero top-left row/col, which shifts effective
-    padding 3 -> 4), 2x2-space-to-depth both operands, and run a 4x4
-    stride-1 conv over 4*C channels — identical math, MXU-friendlier
-    tiling.  All rearrangement is traced, so autodiff and bf16 flow
-    through unchanged.
-    """
-    N, C, H, W = data.shape
-    F = weight.shape[0]
-    # kernel: zeros at top/left make k=8 pad=4 reproduce k=7 pad=3
-    w8 = jnp.pad(weight, ((0, 0), (0, 0), (1, 0), (1, 0)))
-    w_s2d = w8.reshape(F, C, 4, 2, 4, 2).transpose(0, 1, 3, 5, 2, 4) \
-              .reshape(F, C * 4, 4, 4)
-    xp = jnp.pad(data, ((0, 0), (0, 0), (4, 4), (4, 4)))
-    Hp, Wp = H + 8, W + 8
-    xs = xp.reshape(N, C, Hp // 2, 2, Wp // 2, 2) \
-           .transpose(0, 1, 3, 5, 2, 4).reshape(N, C * 4, Hp // 2, Wp // 2)
-    dn = lax.conv_dimension_numbers(xs.shape, w_s2d.shape,
-                                    ("NCHW", "OIHW", "NCHW"))
-    out = lax.conv_general_dilated(xs, w_s2d, (1, 1), [(0, 0), (0, 0)],
-                                   dimension_numbers=dn)
-    # symmetric (4,4) padding overshoots the original (4,3) by one output
-    # row/col of pure padding; the original output is exactly H/2 x W/2
-    out = out[:, :, :H // 2, :W // 2]
-    if not no_bias and bias is not None:
-        out = out + bias.reshape(1, -1, 1, 1)
-    return out
-
-
 # -- FullyConnected ---------------------------------------------------------
 @register("FullyConnected", params=[
     P("num_hidden", int, required=True, low=1,
@@ -219,28 +166,6 @@ def _convolution(data, weight, bias=None, kernel=None, stride=None, dilate=None,
     stride = normalize_tuple(stride, nd) if stride else (1,) * nd
     dilate = normalize_tuple(dilate, nd) if dilate else (1,) * nd
     pad = normalize_tuple(pad, nd) if pad else (0,) * nd
-    if (nd == 2 and layout in (None, "NCHW") and _stem_s2d_enabled()
-            and kernel == (7, 7) and stride == (2, 2) and pad == (3, 3)
-            and dilate == (1, 1) and num_group == 1
-            and data.shape[1] <= 4
-            and data.shape[2] % 2 == 0 and data.shape[3] % 2 == 0):
-        return _conv_stem_s2d(data, weight, bias, no_bias)
-    if nd == 2 and layout in (None, "NCHW") and _internal_nhwc():
-        # layout experiment (MXNET_CONV_LAYOUT=NHWC): run the conv in
-        # NHWC with boundary transposes.  XLA folds the transposes
-        # between consecutive NHWC-internal ops, so a conv/pool stack
-        # becomes globally NHWC — the layout the TPU convolution units
-        # prefer — while the user-facing NCHW contract is unchanged.
-        x = jnp.transpose(data, (0, 2, 3, 1))
-        w = jnp.transpose(weight, (2, 3, 1, 0))           # OIHW -> HWIO
-        out = lax.conv_general_dilated(
-            x, w, window_strides=stride,
-            padding=[(p, p) for p in pad], rhs_dilation=dilate,
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            feature_group_count=num_group)
-        if not no_bias and bias is not None:
-            out = out + bias
-        return jnp.transpose(out, (0, 3, 1, 2))
     dn = lax.conv_dimension_numbers(data.shape, weight.shape,
                                     _conv_dn(nd, layout))
     # bf16 in -> bf16 out: the TPU MXU accumulates in fp32 internally, and
@@ -326,39 +251,18 @@ def _pooling(data, kernel=None, pool_type="max", stride=None, pad=None,
         kernel = normalize_tuple(kernel)
         stride = normalize_tuple(stride, nd) if stride else (1,) * nd
         pad = normalize_tuple(pad, nd) if pad else (0,) * nd
-    if nd == 2 and _internal_nhwc():
-        x = jnp.transpose(data, (0, 2, 3, 1))
-        out = _pool_core(x, kernel, stride, pad, pool_type,
-                         pooling_convention, count_include_pad,
-                         global_pool, channel_last=True)
-        return jnp.transpose(out, (0, 3, 1, 2))
-    return _pool_core(data, kernel, stride, pad, pool_type,
-                      pooling_convention, count_include_pad, global_pool,
-                      channel_last=False)
-
-
-def _pool_core(data, kernel, stride, pad, pool_type, pooling_convention,
-               count_include_pad, global_pool, channel_last):
-    nd = len(kernel)
-    if channel_last:
-        window = (1,) + tuple(kernel) + (1,)
-        strides = (1,) + tuple(stride) + (1,)
-        base_pad = [(0, 0)] + [(p, p) for p in pad] + [(0, 0)]
-        sdim = 1
-    else:
-        window = (1, 1) + tuple(kernel)
-        strides = (1, 1) + tuple(stride)
-        base_pad = [(0, 0), (0, 0)] + [(p, p) for p in pad]
-        sdim = 2
+    window = (1, 1) + tuple(kernel)
+    strides = (1, 1) + tuple(stride)
+    base_pad = [(0, 0), (0, 0)] + [(p, p) for p in pad]
     if pooling_convention == "full" and not global_pool:
         # ceil-mode: add extra right-pad so ceil((x+2p-k)/s)+1 windows fit
         for i in range(nd):
-            x = data.shape[sdim + i]
+            x = data.shape[2 + i]
             p, k, s = pad[i], kernel[i], stride[i]
             out_full = int(np.ceil((x + 2 * p - k) / s)) + 1
             need = (out_full - 1) * s + k - (x + 2 * p)
-            lo, hi = base_pad[sdim + i]
-            base_pad[sdim + i] = (lo, hi + max(need, 0))
+            lo, hi = base_pad[2 + i]
+            base_pad[2 + i] = (lo, hi + max(need, 0))
     if pool_type == "max":
         init = -jnp.inf if jnp.issubdtype(data.dtype, jnp.floating) else jnp.iinfo(data.dtype).min
         return lax.reduce_window(data, init, lax.max, window, strides, base_pad)

@@ -84,11 +84,8 @@ def _count(kernel):
 
 
 def _knob(name):
-    # env > tuning DB (MXNET_TUNE; the "pallas-kernels" program) >
-    # default — block-size knobs the grafttune sweep won bind here
-    # without any env plumbing, while an explicit env var still wins
     from .. import config as _config
-    return _config.tuned(name, program="pallas-kernels")
+    return _config.get(name)
 
 
 def family_enabled(knob):
@@ -821,7 +818,7 @@ def _adam_kernel(h_ref, w_ref, g_ref, m_ref, v_ref, ow_ref, om_ref,
     ov_ref[:] = nv
 
 
-def sweep_plan(n, n_ins, n_outs, block_elems=None):
+def sweep_plan(n, n_ins, n_outs):
     """Plan of one optimizer sweep over ``n``-element flat buffers:
     the (rows, LANES) layout, 1-D row-block grid, the ONE block-local
     spec every operand shares, and the scalar-prefetch slot.  Built by
@@ -829,9 +826,8 @@ def sweep_plan(n, n_ins, n_outs, block_elems=None):
     graftkern — the ``kern-shard-safety`` verdict that unlocks
     :func:`mesh_sweep_safe` reads index maps from THIS plan, so the
     proof is about the grid the kernel actually runs."""
-    if block_elems is None:
-        block_elems = _knob("MXNET_PALLAS_OPT_BLOCK_ELEMS")
-    padded_rows, block_rows = _sweep_layout(n, block_elems)
+    padded_rows, block_rows = _sweep_layout(
+        n, _knob("MXNET_PALLAS_OPT_BLOCK_ELEMS"))
     spec = pl.BlockSpec((block_rows, LANES), lambda i, h: (i, 0))
     return {
         "grid": (padded_rows // block_rows,),
@@ -845,12 +841,12 @@ def sweep_plan(n, n_ins, n_outs, block_elems=None):
     }
 
 
-def _sweep_call_single(kernel, hyper, *flats, n_outs, block_elems):
+def _sweep_call_single(kernel, hyper, *flats, n_outs):
     """One-device sweep dispatch (also the shard-local body under
     ``shard_map``): pad + reshape to rows, run the kernel over the
     plan's grid, slice the logical elements back out."""
     n = flats[0].shape[0]
-    plan = sweep_plan(n, len(flats), n_outs, block_elems)
+    plan = sweep_plan(n, len(flats), n_outs)
     padded_rows = plan["out_shapes"][0][0]
     outs = pl.pallas_call(
         kernel,
@@ -866,7 +862,7 @@ def _sweep_call_single(kernel, hyper, *flats, n_outs, block_elems):
     return tuple(o.reshape(-1)[:n] for o in outs)
 
 
-def _sweep_call(kernel, hyper, flats, n_outs, block_elems, mesh=None):
+def _sweep_call(kernel, hyper, flats, n_outs, mesh=None):
     """Dispatch one optimizer-sweep kernel over flat fp32 buffers.
 
     With a multi-device ``mesh`` the sweep runs under ``shard_map``:
@@ -890,21 +886,18 @@ def _sweep_call(kernel, hyper, flats, n_outs, block_elems, mesh=None):
         from jax.sharding import PartitionSpec
         axes = PartitionSpec(tuple(mesh.axis_names))
         local = functools.partial(_sweep_call_single, kernel,
-                                  n_outs=n_outs,
-                                  block_elems=block_elems)
+                                  n_outs=n_outs)
         outs = jax.shard_map(
             local, mesh=mesh,
             in_specs=(PartitionSpec(),) + (axes,) * len(flats),
             out_specs=(axes,) * n_outs,
             check_vma=False)(hyper, *flats)
         return list(outs)
-    return list(_sweep_call_single(kernel, hyper, *flats, n_outs=n_outs,
-                                   block_elems=block_elems))
+    return list(_sweep_call_single(kernel, hyper, *flats, n_outs=n_outs))
 
 
 def fused_sgd_momentum(w, g, mom=None, lr=0.01, momentum=0.0, wd=0.0,
-                       rescale=1.0, clip=None, block_elems=None,
-                       mesh=None):
+                       rescale=1.0, clip=None, mesh=None):
     """One-sweep SGD(+momentum) over a flat fp32 bucket.
 
     ``w``/``g``/``mom`` are contiguous 1-D same-layout buffers; returns
@@ -918,28 +911,24 @@ def fused_sgd_momentum(w, g, mom=None, lr=0.01, momentum=0.0, wd=0.0,
     :func:`_sweep_call`): every update is elementwise, so per-shard
     re-padding changes nothing and the sharded result stays
     bit-identical too."""
-    if block_elems is None:
-        block_elems = _knob("MXNET_PALLAS_OPT_BLOCK_ELEMS")
     use_clip = clip is not None
     if mom is None:
         _count("fused_sgd")
         hyper = _hyper_vec([lr, wd, rescale] + ([clip] if use_clip else []))
         kernel = functools.partial(_sgd_kernel, use_clip=use_clip)
-        (nw,) = _sweep_call(kernel, hyper, [w, g], 1, block_elems,
-                            mesh=mesh)
+        (nw,) = _sweep_call(kernel, hyper, [w, g], 1, mesh=mesh)
         return nw, None
     _count("fused_sgd_momentum")
     hyper = _hyper_vec([lr, momentum, wd, rescale]
                        + ([clip] if use_clip else []))
     kernel = functools.partial(_sgd_mom_kernel, use_clip=use_clip)
-    nw, nm = _sweep_call(kernel, hyper, [w, g, mom], 2, block_elems,
-                         mesh=mesh)
+    nw, nm = _sweep_call(kernel, hyper, [w, g, mom], 2, mesh=mesh)
     return nw, nm
 
 
 def fused_adam(w, g, mean, var, lr_eff=0.001, beta1=0.9, beta2=0.999,
                epsilon=1e-8, wd=0.0, rescale=1.0, clip=None,
-               block_elems=None, mesh=None):
+               mesh=None):
     """One-sweep Adam over a flat fp32 bucket.
 
     ``lr_eff`` is the EFFECTIVE learning rate — the caller folds in the
@@ -953,8 +942,6 @@ def fused_adam(w, g, mean, var, lr_eff=0.001, beta1=0.9, beta2=0.999,
     is -lr*0/(sqrt(0)+eps) == 0.  A multi-device ``mesh`` shard_maps
     the sweep (see :func:`_sweep_call`) with the same bit-parity
     argument as :func:`fused_sgd_momentum`."""
-    if block_elems is None:
-        block_elems = _knob("MXNET_PALLAS_OPT_BLOCK_ELEMS")
     _count("fused_adam")
     use_clip = clip is not None
     hyper = _hyper_vec(
@@ -962,7 +949,7 @@ def fused_adam(w, g, mean, var, lr_eff=0.001, beta1=0.9, beta2=0.999,
          epsilon, wd, rescale] + ([clip] if use_clip else []))
     kernel = functools.partial(_adam_kernel, use_clip=use_clip)
     nw, nm, nv = _sweep_call(kernel, hyper, [w, g, mean, var], 3,
-                             block_elems, mesh=mesh)
+                             mesh=mesh)
     return nw, nm, nv
 
 
@@ -978,11 +965,9 @@ def _pad_rows(x2, br):
     return x2
 
 
-def _norm_block_rows(r, c, knob, value=None, dtype=jnp.float32):
-    # `value` lets grafttune price a CANDIDATE block size through the
-    # exact production clamp without touching the process env
+def _norm_block_rows(r, c, knob, dtype=jnp.float32):
     sub = _sublane(dtype)
-    br = _knob(knob) if value is None else value
+    br = _knob(knob)
     if not br or br <= 0:
         br = min(256, _BLOCK_BYTES // max(4 * c, 1))
     br = max(sub, int(br) // sub * sub)

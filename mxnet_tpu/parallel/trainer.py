@@ -228,22 +228,9 @@ class ParallelTrainer:
         else:
             raise MXNetError("unsupported trainer dtype: %r" % (dtype,))
 
-        # -- reduction-path knobs ------------------------------------------
-        # explicit args > env > tuning DB (MXNET_TUNE, keyed by this
-        # mesh's shape) > registered default; provenance recorded per
-        # knob in self._tuned and surfaced through plan_spec()
-        mesh_shape = [[str(a), int(self._mesh.shape[a])]
-                      for a in self._mesh.axis_names]
-        self._tuned = {}
-
+        # -- reduction-path knobs (args override MXNET_PARALLEL_*) ----------
         def _knob(name, arg):
-            if arg is not None:
-                self._tuned[name] = {"value": arg, "source": "arg"}
-                return arg
-            info = _config.tuned_info(name, program="parallel-trainer",
-                                      mesh_shape=mesh_shape)
-            self._tuned[name] = info
-            return info["value"]
+            return arg if arg is not None else _config.get(name)
 
         self._zero = int(_knob("MXNET_PARALLEL_ZERO", zero))
         if self._zero not in (0, 1, 2):
@@ -421,8 +408,6 @@ class ParallelTrainer:
             "codec": ({"name": self._codec.name}
                       if self._codec is not None else None),
             "batch": {"axes": ["dp", "fsdp"]},
-            "tuned_config": {k: dict(v)
-                             for k, v in sorted(self._tuned.items())},
         }
 
     def optimizer_state_bytes(self):

@@ -213,14 +213,6 @@ def spawn_replica(root, rid, world, seed=0, env=None, fault_plan=None):
     else:
         child = dict(env)
         child.setdefault("JAX_PLATFORMS", "cpu")
-    # replicas load the fleet-shared tuning DB at spawn: a custom env
-    # inherits the parent's MXNET_TUNE switch and DB location unless
-    # the caller pinned them, so one committed winner reaches every
-    # replica without per-child plumbing (docs/faq/tune.md)
-    for tune_key in ("MXNET_TUNE", "MXNET_TUNE_DB_DIR"):
-        val = os.environ.get(tune_key)
-        if val is not None:
-            child.setdefault(tune_key, val)
     repo = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     child["PYTHONPATH"] = repo + os.pathsep + child.get("PYTHONPATH", "")
@@ -674,9 +666,6 @@ def _replica_main(argv):
     rng = np.random.RandomState(args.seed)
     params = {"fc_weight": nd.array(rng.randn(4, 6).astype(np.float32)),
               "fc_bias": nd.array(rng.randn(4).astype(np.float32))}
-    # max_batch resolves through config.tuned_info inside ModelServer
-    # (env > shared tuning DB > default) — the fleet's replicas bind
-    # the committed serving-ladder winner at spawn
     srv = ModelServer(batch_wait_ms=1.0, queue_depth=64,
                       default_timeout_ms=30000.0)
     srv.add_model("m", out, params, {}, {"data": (1, 6)})

@@ -70,8 +70,7 @@ class DecodeScheduler:
             queue_depth if queue_depth is not None
             else _cfg.get("MXNET_SERVING_GEN_QUEUE_DEPTH"))
         self.default_new_tokens = int(
-            _cfg.tuned("MXNET_SERVING_GEN_MAX_NEW_TOKENS",
-                       program="serving-ladder"))
+            _cfg.get("MXNET_SERVING_GEN_MAX_NEW_TOKENS"))
         self.brownout_ms = float(
             brownout_ms if brownout_ms is not None
             else _cfg.get("MXNET_SERVING_GEN_BROWNOUT_MS"))

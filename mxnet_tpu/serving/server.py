@@ -91,16 +91,6 @@ def _now_ms():
     return time.monotonic() * 1000.0
 
 
-def _tune_db_counts():
-    """Tuning-DB event counts for the /stats tuned_config block —
-    import-light so a server without grafttune on disk still serves."""
-    try:
-        from ..tune import db as _tune_db
-        return _tune_db.counts()
-    except Exception:
-        return {}
-
-
 class InferenceFuture:
     """Result handle for one queued request.
 
@@ -240,19 +230,9 @@ class ModelServer:
                     "conflicting config: max_batch=%d but the explicit "
                     "bucket ladder tops out at %d"
                     % (int(max_batch), self._buckets[-1]))
-            self._tuned_config = {
-                "MXNET_SERVING_MAX_BATCH":
-                    {"value": self._buckets[-1], "source": "arg"}}
         else:
-            if max_batch is not None:
-                mb = max_batch
-                mb_info = {"value": int(mb), "source": "arg"}
-            else:
-                # env > tuning DB ("serving-ladder" program) > default
-                mb_info = config.tuned_info("MXNET_SERVING_MAX_BATCH",
-                                            program="serving-ladder")
-                mb = mb_info["value"]
-            self._tuned_config = {"MXNET_SERVING_MAX_BATCH": mb_info}
+            mb = max_batch if max_batch is not None \
+                else config.get("MXNET_SERVING_MAX_BATCH")
             self._buckets = shape_buckets(mb)
         self._max_batch = self._buckets[-1]
         self._queue_depth = int(queue_depth if queue_depth is not None
@@ -1684,13 +1664,6 @@ class ModelServer:
                         "occupancy": occupancy},
             "buckets": list(self._buckets),
             "brownout": brownout,
-            # knob provenance (docs/faq/tune.md): where the ladder's
-            # defining knob came from — arg | env | db | default —
-            # plus this process's tuning-DB event counts
-            "tuned_config": {
-                "knobs": {k: dict(v) for k, v
-                          in sorted(self._tuned_config.items())},
-                "db": _tune_db_counts()},
         }
         snap["latency_ms"] = {
             "count": len(lats),

@@ -386,22 +386,3 @@ def test_judge_probe_values_raise():
                      nd.zeros(3), nd.ones(3), eps=-1e-3)
     with pytest.raises(MXNetError, match="lr"):
         nd.sgd_update(nd.ones((3,)), nd.ones((3,)), lr=-0.1)
-
-
-def test_op_layer_knobs_registered_and_documented():
-    """Env-drift guard for the op-layer experiment knobs (layout,
-    stem rewrite, fused metric) — thin wrapper over the graftlint
-    env-knob-drift checker (single source of truth,
-    docs/faq/static_analysis.md)."""
-    from mxnet_tpu.analysis.checkers import env_knobs
-    rep = env_knobs.drift_report(prefix=("MXNET_CONV_LAYOUT",
-                                         "MXNET_STEM_SPACE_TO_DEPTH",
-                                         "MXNET_FUSED_METRIC"))
-    assert {"MXNET_CONV_LAYOUT", "MXNET_STEM_SPACE_TO_DEPTH",
-            "MXNET_FUSED_METRIC"} <= set(rep["used"])
-    assert not rep["unregistered"], \
-        "op-layer knobs referenced but never register_env'd: %s" \
-        % rep["unregistered"]
-    assert not rep["undocumented"], \
-        "op-layer knobs missing from docs/faq/env_var.md: %s" \
-        % rep["undocumented"]

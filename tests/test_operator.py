@@ -102,6 +102,14 @@ def test_pooling():
                         rtol=1e-5)
     out = mx.nd.Pooling(a, global_pool=True, pool_type="max", kernel=(1, 1))
     assert_almost_equal(out, x.max(axis=(2, 3), keepdims=True), rtol=1e-5)
+    # ceil mode: a 5x5 input keeps its ragged last window
+    out = mx.nd.Pooling(nd.array(x[:, :, :5, :5]), kernel=(2, 2),
+                        stride=(2, 2), pool_type="max",
+                        pooling_convention="full")
+    padded = np.pad(x[:, :, :5, :5], ((0, 0), (0, 0), (0, 1), (0, 1)),
+                    constant_values=-np.inf)
+    assert_almost_equal(out, padded.reshape(2, 3, 3, 2, 3, 2).max(axis=(3, 5)),
+                        rtol=1e-5)
 
 
 def test_batchnorm_inference_train():
@@ -265,70 +273,3 @@ def test_dropout_modes():
     assert 0.2 < frac_zero < 0.4
     kept = m[m != 0]
     assert_almost_equal(kept, np.full_like(kept, 1 / 0.7), rtol=1e-4)
-
-
-def test_conv_layout_experiment_matches(monkeypatch):
-    """MXNET_CONV_LAYOUT=NHWC runs conv/pool internally channel-last;
-    outputs and gradients must be identical to the NCHW default."""
-    import numpy as np
-    from mxnet_tpu import autograd
-
-    def stack():
-        rng = np.random.RandomState(0)
-        x = nd.array(rng.rand(2, 3, 10, 10).astype(np.float32))
-        x.attach_grad()
-        w = nd.array(rng.randn(8, 3, 3, 3).astype(np.float32) * 0.2)
-        w.attach_grad()
-        b = nd.array(rng.randn(8).astype(np.float32) * 0.1)
-        with autograd.record():
-            h = nd.Convolution(x, w, b, kernel=(3, 3), num_filter=8,
-                               pad=(1, 1))
-            h = nd.Pooling(h, kernel=(2, 2), stride=(2, 2),
-                           pool_type="max")
-            h = nd.Pooling(h, kernel=(2, 2), stride=(2, 2),
-                           pool_type="avg", pooling_convention="full")
-            loss = (h * h).sum()
-        loss.backward()
-        return h.asnumpy(), x.grad.asnumpy(), w.grad.asnumpy()
-
-    ref = stack()
-    monkeypatch.setenv("MXNET_CONV_LAYOUT", "NHWC")
-    got = stack()
-    for r, g in zip(ref, got):
-        assert np.allclose(r, g, atol=1e-5)
-
-
-def test_stem_space_to_depth_matches(monkeypatch):
-    """MXNET_STEM_SPACE_TO_DEPTH=1 rewrites the 7x7/s2/p3 stem conv as
-    s2d + 4x4/s1 (docs/faq/perf.md MXU-fill experiment); outputs and
-    gradients must be identical to the direct conv."""
-    import numpy as np
-    from mxnet_tpu import autograd
-
-    def stem(h_in=20, w_in=16):
-        rng = np.random.RandomState(3)
-        x = nd.array(rng.rand(2, 3, h_in, w_in).astype(np.float32))
-        x.attach_grad()
-        w = nd.array(rng.randn(8, 3, 7, 7).astype(np.float32) * 0.1)
-        w.attach_grad()
-        b = nd.array(rng.randn(8).astype(np.float32) * 0.1)
-        with autograd.record():
-            h = nd.Convolution(x, w, b, kernel=(7, 7), num_filter=8,
-                               stride=(2, 2), pad=(3, 3))
-            loss = (h * h).sum()
-        loss.backward()
-        return h.asnumpy(), x.grad.asnumpy(), w.grad.asnumpy()
-
-    ref = stem()
-    monkeypatch.setenv("MXNET_STEM_SPACE_TO_DEPTH", "1")
-    got = stem()
-    assert ref[0].shape == got[0].shape == (2, 8, 10, 8)
-    for r, g in zip(ref, got):
-        assert np.allclose(r, g, atol=1e-4), np.abs(r - g).max()
-    # non-matching convs (stride 1) must not be rewritten: identical too
-    rng = np.random.RandomState(5)
-    x = nd.array(rng.rand(1, 3, 14, 14).astype(np.float32))
-    w = nd.array(rng.randn(4, 3, 3, 3).astype(np.float32))
-    out = nd.Convolution(x, w, kernel=(3, 3), num_filter=4, pad=(1, 1),
-                         no_bias=True)
-    assert out.shape == (1, 4, 14, 14)

@@ -515,14 +515,27 @@ def test_row_block_is_width_and_dtype_aware():
                                   np.maximum(np.asarray(x), 0))
 
 
-def test_norm_block_rows_follow_the_dtype_sublane():
+def test_norm_block_rows_follow_the_dtype_sublane(monkeypatch):
     knob = "MXNET_PALLAS_SOFTMAX_BLOCK_ROWS"
     assert pk._norm_block_rows(1, 1024, knob) == 8
     assert pk._norm_block_rows(1, 1024, knob, dtype=jnp.bfloat16) == 16
     assert pk._norm_block_rows(256, 1024, knob, dtype=jnp.bfloat16) == 128
     # an explicit value is clamped to whole tiles too
-    assert pk._norm_block_rows(256, 1024, knob, value=8,
-                               dtype=jnp.bfloat16) == 16
+    monkeypatch.setenv(knob, "8")
+    assert pk._norm_block_rows(256, 1024, knob, dtype=jnp.bfloat16) == 16
+
+
+@pytest.mark.parametrize("value,enabled", [
+    (None, False), ("auto", False), ("1", True), ("0", False)])
+def test_family_enabled_reads_the_environment(value, enabled, monkeypatch):
+    """``auto`` (and unset) is native-only, so off on the CPU; ``1``
+    forces the family on in interpret mode; ``0`` turns it off."""
+    knob = "MXNET_PALLAS_NORM"
+    if value is None:
+        monkeypatch.delenv(knob, raising=False)
+    else:
+        monkeypatch.setenv(knob, value)
+    assert pk.family_enabled(knob) is enabled
 
 
 @pytest.mark.parametrize("d", [64, 128])

@@ -115,6 +115,60 @@ def test_fsdp_sharding():
     assert l1 < l0
 
 
+def _n_buckets(tr):
+    return len(tr.bucket_plan)
+
+
+def _codec_name(tr):
+    codec = tr.plan_spec()["codec"]
+    return codec and codec["name"]
+
+
+# knob -> (constructor argument, fixed arguments that make it visible,
+#          what to read, {setting: reading}); four 16x16 f32 weights are
+#          1024 bytes each, walked last layer first
+_REDUCTION_KNOBS = {
+    "MXNET_PARALLEL_ZERO":
+        ("zero", {}, lambda tr: tr.zero, {None: 0, 1: 1, 2: 2}),
+    "MXNET_PARALLEL_BUCKET_BYTES":
+        ("bucket_bytes", {"first_bucket_bytes": 1024}, _n_buckets,
+         {None: 2, 1024: 4, 2048: 3}),
+    "MXNET_PARALLEL_BUCKET_FIRST_BYTES":
+        ("first_bucket_bytes", {"bucket_bytes": 1024}, _n_buckets,
+         {None: 1, 1024: 4, 2048: 3}),
+    "MXNET_PARALLEL_COMPRESSION":
+        ("compression", {}, _codec_name,
+         {None: None, "bf16": "bf16", "fp8": "fp8"}),
+}
+
+
+@pytest.mark.parametrize("order", ["env_beats_default", "arg_beats_env"])
+@pytest.mark.parametrize("knob", sorted(_REDUCTION_KNOBS))
+def test_reduction_knob_resolution(knob, order, monkeypatch):
+    """A ParallelTrainer reduction knob is its constructor argument,
+    else the environment, else the registered default."""
+    arg, fixed, read, readings = _REDUCTION_KNOBS[knob]
+    env_value, arg_value = [v for v in readings if v is not None]
+    assert len(set(readings.values())) == 3
+
+    def build(**kwargs):
+        net = nn.Sequential()
+        for _ in range(4):
+            net.add(nn.Dense(16, in_units=16, use_bias=False))
+        net.initialize(mx.init.Normal(0.1))
+        return parallel.ParallelTrainer(
+            net, gluon.loss.L2Loss(), "sgd", {"learning_rate": 0.1},
+            **fixed, **kwargs)
+
+    monkeypatch.delenv(knob, raising=False)
+    assert read(build()) == readings[None]
+    monkeypatch.setenv(knob, str(env_value))
+    if order == "env_beats_default":
+        assert read(build()) == readings[env_value]
+    else:
+        assert read(build(**{arg: arg_value})) == readings[arg_value]
+
+
 def _full_attention_ref(q, k, v, causal=False):
     d = q.shape[-1]
     logits = np.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)

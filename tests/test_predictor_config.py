@@ -118,13 +118,12 @@ def test_every_registered_env_var_is_documented():
 
 def test_telemetry_knobs_registered_and_documented():
     """Registry-drift guard for the telemetry knob family: every
-    MXNET_TELEMETRY* name the source (or bench.py) reads must be
+    MXNET_TELEMETRY* name the source reads must be
     register_env'd AND documented.  Thin wrapper over the graftlint
     env-knob-drift checker — the enforcement logic lives once, in
     mxnet_tpu/analysis/checkers/env_knobs.py."""
     from mxnet_tpu.analysis.checkers import env_knobs
-    rep = env_knobs.drift_report(prefix="MXNET_TELEMETRY",
-                                 extra_sources=("bench.py",))
+    rep = env_knobs.drift_report(prefix="MXNET_TELEMETRY")
     # sanity: the scan really sees the family before asserting clean
     assert {"MXNET_TELEMETRY", "MXNET_TELEMETRY_STEP_LOG",
             "MXNET_TELEMETRY_STEP_INTERVAL",
