@@ -69,7 +69,9 @@ def actual_multiset(report, spec):
     of the jaxpr; wire bytes are recomputed with the SAME codec + ring
     model the schedule uses (``plan/schedule.py``), so equality means
     "the collectives in the program match the plan", not "two copies
-    of one formula agree about nothing"."""
+    of one formula agree about nothing".  A site's ``elems`` is the
+    constrained buffer's element count whatever its rank: a native
+    bucket's ``(rows, C)`` counts as its flat form would."""
     from ..plan.schedule import (codec_wire_bytes, ring_all_reduce_bytes,
                                  ring_shard_bytes)
     mesh = spec.mesh

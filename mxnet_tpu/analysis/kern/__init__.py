@@ -34,9 +34,14 @@ def sweep_shard_verdict():
     family, as consumed by ``ops/pallas_kernels.py mesh_sweep_safe``:
     ``{"safe": bool, "kernels": {name: per-kernel verdict}}``.  Safe
     only when EVERY sweep kernel's index maps are block-local along
-    the sharded rows axis — one unprovable kernel keeps the whole
-    family on the tree_map path."""
+    the sharded rows axis, over the flat AND the native bucket plan (a
+    kernel's entry is the first of its plans that is not safe, else
+    its last) — one unprovable kernel keeps the whole family on the
+    tree_map path."""
     from ..checkers.kern_rules import shard_safety
-    per = {r["name"]: shard_safety(r) for r in sweep_reports()}
+    per = {}
+    for r in sweep_reports():
+        if per.get(r["name"], {"safe": True})["safe"]:
+            per[r["name"]] = shard_safety(r)
     return {"safe": bool(per) and all(v["safe"] for v in per.values()),
             "kernels": per}

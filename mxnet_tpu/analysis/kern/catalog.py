@@ -139,16 +139,22 @@ _SWEEPS = (
 
 
 def sweep_reports(n=None):
-    """The three optimizer-sweep kernels at a representative bucket
-    size — a NON-lane-divisible element count, so the padded-tail
-    contract is part of what gets verified."""
+    """The three optimizer-sweep kernels over both bucket layouts: a
+    flat bucket at a representative size — a NON-lane-divisible element
+    count, so the padded-tail contract is part of what gets verified —
+    and a native ``(rows, C)`` bucket whose rows the blocks do not
+    divide (OPT-1.3B's embedding), so the clipped last block is."""
     from mxnet_tpu.ops import pallas_kernels as pk
     if n is None:
         n = 8 * pk._OPT_BLOCK_ELEMS - 37
+    rows, cols = 50272, 2048
     reports = []
-    for name, ins, outs, hyper_names in _SWEEPS:
-        plan = pk.sweep_plan(n, len(ins), len(outs))
-        padded = plan["out_shapes"][0][0] * pk.LANES
+    for (name, ins, outs, hyper_names), shape in itertools.product(
+            _SWEEPS, ((n,), (rows, cols))):
+        plan = pk.sweep_plan(shape, len(ins), len(outs))
+        flat = len(shape) == 1
+        swept = plan["grid"][0] * plan["block_rows"] \
+            * plan["out_shapes"][0][1]
         reports.append(_report(
             name, "MXNET_PALLAS_FUSED_OPT", plan, ins, outs,
             hyper={"transport": "scalar_prefetch",
@@ -160,14 +166,18 @@ def sweep_reports(n=None):
                            "rides scalar prefetch)"}],
             shard={"axis": 0,
                    "operands": list(ins) + list(outs),
-                   "why": "ZeRO flat buckets shard the rows axis "
-                          "1/mesh across the trainer mesh "
+                   "why": "ZeRO buckets, flat and native, shard the "
+                          "rows axis 1/mesh across the trainer mesh "
                           "(parallel/trainer.py _make_step_zero)"},
-            tail={"logical_elems": int(n), "padded_elems": int(padded),
+            tail={"logical_elems": int(n if flat else rows * cols),
+                  "padded_elems": int(swept),
                   "masked": True,
                   "how": "host zero-pad (_to_rows); every sweep "
                          "update maps 0 -> 0 exactly, pad sliced "
-                         "away on return"}))
+                         "away on return" if flat else
+                         "no padding: Pallas clips the last block at "
+                         "the array's edge, and an elementwise update "
+                         "of rows past it is never written"}))
     return reports
 
 

@@ -15,7 +15,8 @@ __all__ = ["check_divisibility", "check_schedule", "ladder_report",
 
 def check_divisibility(spec):
     """Every sharded dim must divide the product of its mesh axes;
-    fused buckets must pad to the mesh size; the batch must divide its
+    flat buckets must pad to the mesh size and a native bucket's rows
+    must cut into whole (8, 128)-tile shards; the batch must divide its
     sharding axes.  GSPMD rejects (or silently round-trips through
     padded halos) anything else — at compile time; this is the same
     verdict before any compile."""
@@ -45,6 +46,18 @@ def check_divisibility(spec):
                 "detail": "bucket %d padded length %d does not divide "
                           "the %d-way mesh" % (b["index"],
                                                b["padded_n"], n)})
+        if b.get("layout") == "native":
+            rows, cols = (int(d) for d in b["buffer_shape"])
+            if rows % (8 * n) or cols % 128 \
+                    or rows * cols != int(b["padded_n"]):
+                problems.append({
+                    "contract": "divisibility", "param": "bucket %d"
+                    % b["index"],
+                    "detail": "native bucket %d keeps its leaf as (%d, "
+                              "%d): the %d-way mesh needs rows in "
+                              "multiples of %d, whole 128-lane columns "
+                              "and no padding" % (b["index"], rows, cols,
+                                                  n, 8 * n)})
     if spec.batch:
         bshape = tuple(spec.batch.get("shape") or ())
         f = 1

@@ -67,8 +67,10 @@ def predict_opt_state(spec):
             total += int(nbytes)
             per_dev += int(nbytes)
     else:
-        # fused subtree: one (padded_n,) fp32 leaf per bucket per slot,
-        # sharded 1/mesh over every axis
+        # fused subtree: one fp32 leaf of padded_n elements per bucket
+        # per slot — 1-D for a flat bucket, the leaf's own (rows, C)
+        # for a native one, the same bytes — sharded 1/mesh over every
+        # axis along dimension 0
         for b in spec.buckets:
             nb = 4 * int(b["padded_n"])
             for _s in slots:
@@ -167,7 +169,8 @@ def predict_memory(spec):
     for p in spec.params:
         params += _param_bytes(p) // _shard_factor(mesh, p.get("spec"))
     opt = predict_opt_state(spec)["per_device"]
-    # collective staging: each bucket's fused fp32 cotangent buffer
+    # collective staging: each bucket's fp32 cotangent buffer (a flat
+    # bucket's fusion, a native bucket's gradient as it is produced)
     # materializes before (or while) its collective runs, plus the
     # codec's wire payload when compression is on
     staging = 0

@@ -17,7 +17,8 @@ Vocabulary:
   the serialized ``PartitionSpec``), and whether the param rides the
   fused bucket path;
 - ``buckets`` — the gradient bucket plan (``parallel.collectives.
-  build_bucket_plan`` serialized): names/shapes/sizes/offsets and the
+  build_bucket_plan`` serialized): names/shapes/sizes/offsets, the
+  bucket's ``layout`` (``flat`` 1-D or ``native`` ``buffer_shape``) and the
   mesh-padded flat length;
 - ``optimizer`` — the slot spec (``PureSGD.slot_spec()`` /
   ``PureAdam.slot_spec()``): per-param slot names plus scalar slots

@@ -378,11 +378,13 @@ def builtin_workload():
 
         # -- bucketed ParallelTrainer step (collectives.flatten_bucket
         # -- runs at trace time; 1-device mesh, zero=2 so the fused
-        # -- bucket path is live) --------------------------------------
+        # -- bucket path is live: the (128, 8) weight and the biases
+        # -- ride flat buckets, the (8, 128) weight a native one) ------
         import jax as _jax
         from mxnet_tpu import parallel
         pnet = mx.gluon.nn.HybridSequential()
-        pnet.add(mx.gluon.nn.Dense(4, in_units=8))
+        pnet.add(mx.gluon.nn.Dense(128, in_units=8),
+                 mx.gluon.nn.Dense(8, in_units=128))
         pnet.initialize()
         ptr = parallel.ParallelTrainer(
             pnet, mx.gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",

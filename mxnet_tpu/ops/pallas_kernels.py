@@ -23,8 +23,9 @@ when-does-it-fuse table and the ``MXNET_PALLAS_*`` knobs):
   ``_contrib_fused_bn_relu`` operator and the executor's inference
   BatchNorm→Activation peephole (symbol.py ``build_graph_fn``).
 - ``fused_sgd_momentum`` / ``fused_adam`` — the one-sweep fused
-  optimizer: an ENTIRE flat 1-D bucket (params, grads and optimizer
-  slots as contiguous same-layout buffers) updated in one VMEM-resident
+  optimizer: an ENTIRE bucket (params, grads and optimizer slots as
+  same-layout buffers: a flat 1-D fusion of small leaves, or one
+  matrix in its own ``(rows, C)`` layout) updated in one VMEM-resident
   pass, grid over row blocks.  Hyperparameters (lr/momentum/betas/wd/
   clip) ride ONE scalar-prefetch operand, so an lr-schedule change is a
   new argument value, not a new XLA program.  The kernel math mirrors
@@ -740,18 +741,29 @@ def fused_scale_bias_relu(x, scale, bias, relu=True, block=1024):
 
 
 # ---------------------------------------------------------------------------
-# One-sweep fused optimizer over flat param buckets
+# One-sweep fused optimizer over param buckets
 # ---------------------------------------------------------------------------
 # The trainer's ZeRO path and the executor's fused step hand the update
-# contiguous 1-D fp32 buffers (params / grads / slots in the SAME flat
-# layout — parallel/collectives.py buckets).  One kernel sweeps a whole
-# bucket: each grid step loads a (rows, 128) tile of every buffer into
-# VMEM, applies the exact per-element expressions of the tree_map path,
-# and writes the new tile — no per-parameter kernel launches, no HBM
-# round-trips between the update's elementwise stages.  Hyperparameters
-# arrive as ONE scalar-prefetch vector so schedule changes never retrace.
+# fp32 buffers (params / grads / slots in the SAME layout —
+# parallel/collectives.py buckets).  A FLAT bucket is a contiguous 1-D
+# buffer, viewed here as (rows, 128); a NATIVE bucket is one leaf as it
+# lies in HBM, (rows, C) with C a multiple of 128, and reaches the
+# kernel with no pad, no reshape and no slice (on the TPU any reshape
+# that changes an f32 matrix's minor dimension is an element-wise
+# re-layout).  One kernel sweeps a whole bucket: each grid step loads a
+# (block_rows, C) tile of every buffer into VMEM, applies the exact
+# per-element expressions of the tree_map path, and writes the new tile
+# — no per-parameter kernel launches, no HBM round-trips between the
+# update's elementwise stages.  Hyperparameters arrive as ONE
+# scalar-prefetch vector so schedule changes never retrace.
 
 _OPT_BLOCK_ELEMS = 128 * 1024     # default elems per grid step (auto)
+
+
+def _block_elems():
+    be = _knob("MXNET_PALLAS_OPT_BLOCK_ELEMS")
+    be = int(be) if be else 0
+    return be if be > 0 else _OPT_BLOCK_ELEMS
 
 
 def _sweep_layout(n, block_elems):
@@ -759,14 +771,26 @@ def _sweep_layout(n, block_elems):
     ``n``-element flat buffer, rows padded to a whole number of
     ``block_rows``-row grid steps (block_rows itself a multiple of the
     fp32 sublane tile, 8)."""
-    be = int(block_elems) if block_elems else 0
-    if be <= 0:
-        be = _OPT_BLOCK_ELEMS
-    block_rows = max(8, (be // LANES) // 8 * 8)
+    block_rows = max(8, (block_elems // LANES) // 8 * 8)
     rows = -(-n // LANES)
     block_rows = min(block_rows, -(-rows // 8) * 8)
     padded_rows = -(-rows // block_rows) * block_rows
     return padded_rows, block_rows
+
+
+def sweep_native_rows(shape, shards=1):
+    """``(rows, C)`` if the sweep can tile an fp32 leaf of ``shape`` as
+    it stands — leading dimensions collapsed into rows, each of
+    ``shards`` equal row shards a whole number of (8, 128) tiles, and
+    eight rows no more than a grid step's elements — else None (the
+    leaf rides a flat bucket)."""
+    if len(shape) < 2:
+        return None
+    c, rows = int(shape[-1]), math.prod(int(d) for d in shape[:-1])
+    if c <= 0 or c % LANES or 8 * c > _block_elems() \
+            or rows <= 0 or rows % (8 * int(shards)):
+        return None
+    return rows, c
 
 
 def _to_rows(flat, padded_rows):
@@ -818,56 +842,83 @@ def _adam_kernel(h_ref, w_ref, g_ref, m_ref, v_ref, ow_ref, om_ref,
     ov_ref[:] = nv
 
 
-def sweep_plan(n, n_ins, n_outs):
-    """Plan of one optimizer sweep over ``n``-element flat buffers:
-    the (rows, LANES) layout, 1-D row-block grid, the ONE block-local
-    spec every operand shares, and the scalar-prefetch slot.  Built by
-    the dispatch (:func:`_sweep_call`) and abstractly interpreted by
-    graftkern — the ``kern-shard-safety`` verdict that unlocks
-    :func:`mesh_sweep_safe` reads index maps from THIS plan, so the
-    proof is about the grid the kernel actually runs."""
-    padded_rows, block_rows = _sweep_layout(
-        n, _knob("MXNET_PALLAS_OPT_BLOCK_ELEMS"))
-    spec = pl.BlockSpec((block_rows, LANES), lambda i, h: (i, 0))
+def sweep_plan(shape, n_ins, n_outs):
+    """Plan of one optimizer sweep over buffers of ``shape``: ``(n,)``,
+    a flat bucket laid out as (rows, LANES) with a zero-padded tail, or
+    ``(rows, C)``, a native bucket swept as it stands (the last block
+    may overhang the rows; Pallas clips it at the array's edge).
+    Either way a 1-D row-block grid, the ONE block-local spec every
+    operand shares, and the scalar-prefetch slot.  Built by the dispatch
+    (:func:`_sweep_call`) and abstractly interpreted by graftkern — the
+    ``kern-shard-safety`` verdict that unlocks :func:`mesh_sweep_safe`
+    reads index maps from THIS plan, so the proof is about the grid the
+    kernel actually runs."""
+    if len(shape) == 1:
+        rows, block_rows = _sweep_layout(int(shape[0]), _block_elems())
+        cols = LANES
+    else:
+        rows_c = sweep_native_rows(shape) if len(shape) == 2 else None
+        if rows_c is None:
+            raise ValueError(
+                "the optimizer sweep takes a flat 1-D buffer or a (rows, "
+                "C) buffer of whole (8, 128) tiles; got shape %s"
+                % (tuple(shape),))
+        rows, cols = rows_c
+        block_rows = min(rows, max(8, (_block_elems() // cols) // 8 * 8))
+    spec = pl.BlockSpec((block_rows, cols), lambda i, h: (i, 0))
     return {
-        "grid": (padded_rows // block_rows,),
+        "grid": (-(-rows // block_rows),),
         "num_scalar_prefetch": 1,
         "in_specs": [spec] * n_ins,
-        "in_shapes": [(padded_rows, LANES)] * n_ins,
+        "in_shapes": [(rows, cols)] * n_ins,
         "out_specs": [spec] * n_outs,
-        "out_shapes": [(padded_rows, LANES)] * n_outs,
+        "out_shapes": [(rows, cols)] * n_outs,
         "scratch": [],
         "block_rows": block_rows,
     }
 
 
-def _sweep_call_single(kernel, hyper, *flats, n_outs):
+def _sweep_call_single(kernel, hyper, *bufs, n_outs):
     """One-device sweep dispatch (also the shard-local body under
-    ``shard_map``): pad + reshape to rows, run the kernel over the
-    plan's grid, slice the logical elements back out."""
-    n = flats[0].shape[0]
-    plan = sweep_plan(n, len(flats), n_outs)
-    padded_rows = plan["out_shapes"][0][0]
+    ``shard_map``).  A flat buffer is padded and reshaped to rows, swept
+    over the plan's grid, and its logical elements sliced back out; a
+    native ``(rows, C)`` buffer goes to the kernel and comes back as it
+    is."""
+    shape = bufs[0].shape
+    plan = sweep_plan(shape, len(bufs), n_outs)
+    rows, cols = plan["out_shapes"][0]
+    flat = len(shape) == 1
+    # a native bucket's weight and slots are the step's own (donated)
+    # arrays: each output takes its input's buffer — new w over w, new
+    # slot k over slot k (operands: hyper, w, g, slots...) — so nothing
+    # is copied back; a block is read whole before it is written
+    aliases = {} if flat else \
+        {1: 0, **{k + 2: k for k in range(1, n_outs)}}
     outs = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=plan["num_scalar_prefetch"],
             grid=plan["grid"],
             in_specs=plan["in_specs"], out_specs=plan["out_specs"]),
-        out_shape=[jax.ShapeDtypeStruct((padded_rows, LANES),
+        out_shape=[jax.ShapeDtypeStruct((rows, cols),
                                         jnp.float32)] * n_outs,
+        input_output_aliases=aliases,
         name=kernel.func.__name__,
         interpret=_interpret(),
-    )(hyper, *[_to_rows(f, padded_rows) for f in flats])
-    return tuple(o.reshape(-1)[:n] for o in outs)
+    )(hyper, *([_to_rows(f, rows) for f in bufs] if flat else bufs))
+    if flat:
+        return tuple(o.reshape(-1)[:shape[0]] for o in outs)
+    return tuple(outs)
 
 
-def _sweep_call(kernel, hyper, flats, n_outs, mesh=None):
-    """Dispatch one optimizer-sweep kernel over flat fp32 buffers.
+def _sweep_call(kernel, hyper, bufs, n_outs, mesh=None):
+    """Dispatch one optimizer-sweep kernel over fp32 bucket buffers
+    (flat 1-D or native ``(rows, C)``, all of one shape).
 
     With a multi-device ``mesh`` the sweep runs under ``shard_map``:
-    every chip sweeps its contiguous 1/mesh shard of each buffer with
-    the same kernel (hyperparameters replicated), the exact ZeRO
+    every chip sweeps its contiguous 1/mesh shard of each buffer —
+    dimension 0, elements of a flat bucket and rows of a native one —
+    with the same kernel (hyperparameters replicated), the exact ZeRO
     layout the trainer's bucket plan hands in.  ``check_vma=False`` is
     mandatory — pallas_call has no replication rule — which is
     precisely the unproven-safety gap graftkern closes: the
@@ -876,11 +927,11 @@ def _sweep_call(kernel, hyper, flats, n_outs, mesh=None):
     shard-local sweeps touch disjoint data, and zero-padded shard
     tails update to exactly zero just like the global tail."""
     if mesh is not None and getattr(mesh, "size", 1) > 1:
-        n = flats[0].shape[0]
+        n = bufs[0].shape[0]
         if n % mesh.size:
             raise ValueError(
-                "fused sweep over a %d-device mesh needs the flat "
-                "bucket length (%d) padded to a mesh multiple — the "
+                "fused sweep over a %d-device mesh needs the bucket's "
+                "leading dimension (%d) to be a mesh multiple — the "
                 "bucket plan's pad_multiple contract"
                 % (mesh.size, n))
         from jax.sharding import PartitionSpec
@@ -889,18 +940,19 @@ def _sweep_call(kernel, hyper, flats, n_outs, mesh=None):
                                   n_outs=n_outs)
         outs = jax.shard_map(
             local, mesh=mesh,
-            in_specs=(PartitionSpec(),) + (axes,) * len(flats),
+            in_specs=(PartitionSpec(),) + (axes,) * len(bufs),
             out_specs=(axes,) * n_outs,
-            check_vma=False)(hyper, *flats)
+            check_vma=False)(hyper, *bufs)
         return list(outs)
-    return list(_sweep_call_single(kernel, hyper, *flats, n_outs=n_outs))
+    return list(_sweep_call_single(kernel, hyper, *bufs, n_outs=n_outs))
 
 
 def fused_sgd_momentum(w, g, mom=None, lr=0.01, momentum=0.0, wd=0.0,
                        rescale=1.0, clip=None, mesh=None):
-    """One-sweep SGD(+momentum) over a flat fp32 bucket.
+    """One-sweep SGD(+momentum) over one fp32 bucket.
 
-    ``w``/``g``/``mom`` are contiguous 1-D same-layout buffers; returns
+    ``w``/``g``/``mom`` are same-layout buffers, flat 1-D or native
+    ``(rows, C)`` (:func:`sweep_plan`); returns
     ``(new_w, new_mom)`` (``new_mom`` is None when ``mom`` is None —
     plain SGD carries no slot).  Scalars may be Python floats or traced
     values; all ride the scalar-prefetch operand.  Bit-identical to the
@@ -929,7 +981,8 @@ def fused_sgd_momentum(w, g, mom=None, lr=0.01, momentum=0.0, wd=0.0,
 def fused_adam(w, g, mean, var, lr_eff=0.001, beta1=0.9, beta2=0.999,
                epsilon=1e-8, wd=0.0, rescale=1.0, clip=None,
                mesh=None):
-    """One-sweep Adam over a flat fp32 bucket.
+    """One-sweep Adam over one fp32 bucket (flat 1-D or native
+    ``(rows, C)`` buffers, :func:`sweep_plan`).
 
     ``lr_eff`` is the EFFECTIVE learning rate — the caller folds in the
     bias-correction factor (``lr * sqrt(1-b2^t)/(1-b1^t)``, computed

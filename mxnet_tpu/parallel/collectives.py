@@ -7,13 +7,20 @@ all-reduce at the end; this module holds the pieces of that rebuild
 that are pure planning — no jax tracing:
 
 - :func:`build_bucket_plan` — partition the replicated trainable
-  params into size-capped flat buckets, REVERSE registration order
+  params into size-capped buckets, REVERSE registration order
   (output-side layers' gradients finish first in backward, so bucket 0
   is ready earliest), with a smaller first bucket so the first
   collective launches as early as possible (the DDP first-bucket
   trick);
-- :func:`flatten_bucket` / :func:`unflatten_bucket` — the fused 1-D
-  buffer view of one bucket, padded so it shards evenly over the mesh;
+- :func:`flatten_bucket` / :func:`unflatten_bucket` — the buffer view
+  of one bucket.  A FLAT bucket fuses its leaves into one 1-D buffer,
+  padded so it shards evenly over the mesh.  A NATIVE bucket
+  (``Bucket.layout``) holds exactly one leaf whose shape the optimizer
+  sweep can tile as it stands, and its buffer is that leaf viewed as
+  ``(rows, C)`` — leading dimensions collapsed, for a matrix the array
+  itself — so neither function moves an element: on the TPU an f32
+  ``[R, C]`` matrix lives in (8, 128) tiles and its 1-D form is an
+  element-wise re-layout, not a bitcast;
 - :func:`comm_stats` — the per-step per-device wire model (ring
   collectives) behind ``mxnet_collective_{ops,bytes}_total`` and the
   scaling bench's byte columns.  The model is documented, not
@@ -33,22 +40,37 @@ __all__ = ["Bucket", "build_bucket_plan", "flatten_bucket",
 
 
 class Bucket:
-    """One fused gradient bucket: a contiguous 1-D view over a fixed
-    set of parameters, padded to ``pad_multiple`` so the flat buffer
-    divides evenly across every mesh axis."""
+    """One fused gradient bucket over a fixed set of parameters.
+
+    ``layout == "flat"``: a contiguous 1-D view, padded to
+    ``pad_multiple`` so the buffer divides evenly across every mesh
+    axis.  ``layout == "native"`` (only where the plan's builder allows
+    it, ``native=True``): the bucket's ONE leaf viewed as ``(rows, C)``
+    — eligible when the optimizer sweep can tile each of the
+    ``pad_multiple`` row shards as it stands
+    (``ops/pallas_kernels.py`` ``sweep_native_rows``).  Either way
+    ``names / shapes / sizes / offsets / n / padded_n`` count the same
+    elements in the same row-major order; only ``buffer_shape``
+    differs."""
 
     __slots__ = ("index", "names", "shapes", "sizes", "offsets",
-                 "n", "padded_n")
+                 "n", "padded_n", "layout", "buffer_shape")
 
-    def __init__(self, index, names, shapes, pad_multiple):
+    def __init__(self, index, names, shapes, pad_multiple, native=False):
         self.index = index
         self.names = list(names)
         self.shapes = [tuple(s) for s in shapes]
         self.sizes = [int(np.prod(s)) if s else 1 for s in self.shapes]
         self.offsets = np.cumsum([0] + self.sizes).tolist()
         self.n = int(self.offsets[-1])
-        pad = (-self.n) % max(int(pad_multiple), 1)
-        self.padded_n = self.n + pad
+        shards = max(int(pad_multiple), 1)
+        self.padded_n = self.n + (-self.n) % shards
+        rows_c = None
+        if native and len(self.shapes) == 1:
+            from ..ops.pallas_kernels import sweep_native_rows
+            rows_c = sweep_native_rows(self.shapes[0], shards=shards)
+        self.layout = "flat" if rows_c is None else "native"
+        self.buffer_shape = rows_c or (self.padded_n,)
 
     @property
     def nbytes(self):
@@ -61,20 +83,25 @@ class Bucket:
                 "shapes": [list(s) for s in self.shapes],
                 "sizes": list(self.sizes),
                 "offsets": list(self.offsets),
-                "n": self.n, "padded_n": self.padded_n}
+                "n": self.n, "padded_n": self.padded_n,
+                "layout": self.layout,
+                "buffer_shape": list(self.buffer_shape)}
 
     def __repr__(self):
-        return "Bucket(%d: %d params, %d elems, %d padded)" % (
-            self.index, len(self.names), self.n, self.padded_n)
+        return "Bucket(%d: %d params, %d elems, %d padded, %s)" % (
+            self.index, len(self.names), self.n, self.padded_n,
+            self.layout)
 
 
 def build_bucket_plan(names, shapes, bucket_bytes, first_bucket_bytes=None,
-                      pad_multiple=1):
+                      pad_multiple=1, native=False):
     """Partition ``names`` (registration order) into size-capped
     buckets, walking in REVERSE so bucket 0 holds the params whose
     gradients complete earliest in backward.  ``bucket_bytes <= 0``
     yields one monolithic bucket (the pre-bucketing behavior, kept as
-    the A/B baseline)."""
+    the A/B baseline).  ``native`` lets a one-leaf bucket keep its
+    leaf's layout (:class:`Bucket`); membership, indices and order are
+    the same with and without it."""
     names = list(names)
     shapes = [tuple(s) for s in shapes]
     if not names:
@@ -97,13 +124,18 @@ def build_bucket_plan(names, shapes, bucket_bytes, first_bucket_bytes=None,
         if cur:
             groups.append(cur)
     return [Bucket(bi, [names[i] for i in idxs],
-                   [shapes[i] for i in idxs], pad_multiple)
+                   [shapes[i] for i in idxs], pad_multiple, native=native)
             for bi, idxs in enumerate(groups)]
 
 
 def flatten_bucket(values, bucket):
-    """Fuse one bucket's per-param arrays into its padded 1-D fp32
-    buffer (traceable: used inside the compiled step)."""
+    """One bucket's per-param arrays as its buffer (traceable: used
+    inside the compiled step): the padded 1-D fp32 fusion of a flat
+    bucket, the leaf itself — leading dimensions collapsed, a bitcast —
+    of a native one."""
+    if bucket.layout == "native":
+        (leaf,) = values
+        return leaf.reshape(bucket.buffer_shape)
     # `values` is a Python LIST of arrays — its truthiness is its
     # length, static at trace time (an empty bucket never reads an
     # array's value)
@@ -121,7 +153,9 @@ def flatten_bucket(values, bucket):
 
 
 def unflatten_bucket(flat, bucket):
-    """Split a fused buffer back into ``{name: array}`` views."""
+    """Split a bucket's buffer back into ``{name: array}`` views."""
+    if bucket.layout == "native":
+        return {bucket.names[0]: flat.reshape(bucket.shapes[0])}
     out = {}
     with jax.named_scope(_phases.UNFLATTEN_SCOPE):
         for name, shape, off, sz in zip(bucket.names, bucket.shapes,

@@ -9,9 +9,11 @@ update fuses into the train-step XLA program — the reference's
 update-on-kvstore collapses into the compiled step.
 
 One-sweep fused path (the MPK mega-kernel leg, ROADMAP item 3): when
-the trainer hands ``apply`` bucketed FLAT views (``flat=True`` — 1-D
-fp32 buffers with slots allocated bucket-major, still ZeRO-sharded
-1/mesh) and ``MXNET_PALLAS_FUSED_OPT`` is on, each bucket updates in
+the trainer hands ``apply`` bucket BUFFERS (``flat=True`` — fp32
+buffers, a flat bucket's 1-D fusion or a native bucket's one leaf as
+``(rows, C)``, with slots allocated bucket-major in the same layout,
+still ZeRO-sharded 1/mesh over dimension 0) and
+``MXNET_PALLAS_FUSED_OPT`` is on, each bucket updates in
 ONE Pallas kernel (``ops/pallas_kernels.py`` ``fused_sgd_momentum`` /
 ``fused_adam``): params, grads and slots stream through VMEM once
 instead of XLA's per-stage elementwise kernels, and lr/betas/wd ride a
@@ -92,8 +94,9 @@ class PureSGD:
 
     def apply(self, params, grads, state, lr=None, flat=False,
               mesh=None):
-        """``flat=True`` marks the leaves as bucketed flat views (1-D
-        fp32 buffers, slots bucket-major) — the contract under which
+        """``flat=True`` marks the leaves as bucket buffers (fp32, flat
+        1-D or native ``(rows, C)``; slots bucket-major in the same
+        layout) — the contract under which
         the one-sweep Pallas path may take over; the per-array
         ``tree_map`` below is its bit-parity oracle.  ``mesh`` (a
         multi-chip trainer mesh) makes the sweep run ``shard_map``-ped
@@ -104,9 +107,9 @@ class PureSGD:
         clip = self.clip_gradient
 
         if _fused_sweep_on(flat):
-            # flat contract: params is a plain {bucket_key: 1-D fp32
-            # buffer} dict and slots share its keys — sweep each bucket
-            # in one kernel
+            # bucket contract: params is a plain {bucket_key: fp32
+            # buffer} dict and slots share its keys and layouts — sweep
+            # each bucket in one kernel
             from ..ops import pallas_kernels as pk
             new_params, new_mom = {}, {}
             with jax.named_scope(_phases.SWEEP_SCOPE):

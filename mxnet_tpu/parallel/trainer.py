@@ -13,17 +13,22 @@ ride ICI.
 
 Gradient-reduction path (the MPI-embedding paper's restructure, PR 7):
 
-- **buckets** — replicated trainable params are fused into size-capped
-  flat buckets (``MXNET_PARALLEL_BUCKET_BYTES`` family), REVERSE
+- **buckets** — replicated trainable params are grouped into
+  size-capped buckets (``MXNET_PARALLEL_BUCKET_BYTES`` family), REVERSE
   registration order so bucket 0 holds the output-side params whose
   gradients finish first in backward.  The step differentiates with
-  respect to the fused buffers themselves (params are reconstructed
+  respect to the buckets' buffers themselves (params are reconstructed
   from the buffers in the forward), so each bucket's gradient is ONE
   cotangent produced as soon as its backward segment completes; a
   per-bucket ``custom_vjp`` tap attaches the reduce-scatter to that
   cotangent *inside the backward stream*, leaving XLA's latency-hiding
   scheduler free to overlap each bucket's collective with the remaining
-  backward instead of one barrier all-reduce at the end.
+  backward instead of one barrier all-reduce at the end.  A bucket that
+  holds ONE leaf the sweep can tile as it stands keeps that leaf's
+  layout (a NATIVE bucket, ``parallel/collectives.py``): parameter,
+  cotangent, slots and the ZeRO shard are the leaf viewed as
+  ``(rows, C)``, sharded over rows, and nothing is re-laid around the
+  update; every other leaf rides a FLAT 1-D bucket.
 - **ZeRO stages** (``zero=``): 0 replicates optimizer slots and
   all-reduces gradients (the pre-PR-7 path); 1 shards slots 1/mesh but
   still all-reduces full gradients (memory win only); 2 reduce-scatters
@@ -44,8 +49,8 @@ Sharding policy:
   style, `fsdp>1`) and "tp" (Megatron-style, `tp>1` via simple
   largest-dim sharding — GSPMD keeps semantics, collectives appear
   where needed).
-- optimizer state follows params (zero=0) or lives in 1/mesh flat
-  shards (zero>=1).
+- optimizer state follows params (zero=0) or lives in 1/mesh shards of
+  the buckets' buffers, dimension 0 (zero>=1).
 """
 from __future__ import annotations
 
@@ -280,11 +285,13 @@ class ParallelTrainer:
             n for n in self._param_names
             if trainable[n] and n not in set(self._fused_names)]
         self._zero_spec = P(tuple(self._mesh.axis_names))
+        # the codecs' wire format is defined on the flat buffer: with a
+        # codec every bucket is flat
         self._plan = build_bucket_plan(
             self._fused_names,
             [param_values[n].shape for n in self._fused_names],
             bucket_bytes, first_bucket_bytes,
-            pad_multiple=self._mesh.size)
+            pad_multiple=self._mesh.size, native=self._codec is None)
 
         self._opt_state = self._init_opt_state()
         self._resids = self._init_residuals()
@@ -317,7 +324,7 @@ class ParallelTrainer:
                 else jax.device_put(l, rep), state)
         zero_ns = NamedSharding(mesh, self._zero_spec)
         fused_dummy = {
-            "b%d" % b.index: jax.ShapeDtypeStruct((b.padded_n,),
+            "b%d" % b.index: jax.ShapeDtypeStruct(b.buffer_shape,
                                                   jnp.float32)
             for b in self._plan}
         fused_shardings = {k: zero_ns for k in fused_dummy}
@@ -432,6 +439,20 @@ class ParallelTrainer:
             "(scope=total logical vs per_device resident)")
         g.labels(scope="total").set(sb["total"])
         g.labels(scope="per_device").set(sb["per_device"])
+        leaves = telemetry.gauge(
+            "mxnet_zero_bucket_leaves",
+            "bucketed leaves of the newest ParallelTrainer by their "
+            "bucket's layout (native: the leaf's own (rows, C); flat: "
+            "fused 1-D)")
+        nbytes = telemetry.gauge(
+            "mxnet_zero_bucket_bytes",
+            "fp32 parameter bytes of the newest ParallelTrainer's "
+            "buckets by layout")
+        for layout in ("native", "flat"):
+            mine = [b for b in self._plan if b.layout == layout]
+            leaves.labels(layout=layout).set(
+                sum(len(b.names) for b in mine))
+            nbytes.labels(layout=layout).set(sum(b.nbytes for b in mine))
 
     @property
     def mesh(self):
@@ -570,10 +591,12 @@ class ParallelTrainer:
         return step
 
     def _make_step_zero(self, loss_of, opt, trainable):
-        """zero>=1: fused flat buckets are the differentiated leaves —
+        """zero>=1: the buckets' buffers are the differentiated leaves —
         each bucket's gradient is one cotangent, reduce-scattered into
         the 1/mesh slot shard, updated shard-local, and all-gathered
-        back into the replicated master params."""
+        back into the replicated master params.  A native bucket's
+        buffer is its parameter (``flatten_bucket`` / ``unflatten_bucket``
+        move nothing) and the constraints below shard its rows."""
         mesh = self._mesh
         plan, codec, zero = self._plan, self._codec, self._zero
         from ..ops.pallas_kernels import mesh_sweep_safe
@@ -629,10 +652,10 @@ class ParallelTrainer:
             frozen = {k: v for k, v in params.items()
                       if not trainable[k] and k not in fused_set}
             pp = {n: params[n] for n in perparam_names}
-            # mx_update holds everything the flat-bucket optimizer costs:
-            # this flatten, the views f() cuts back out of the buckets
-            # (and their backward, the gradients' flatten), the sweep
-            # and the unflatten after it
+            # mx_update holds everything the bucketed optimizer costs:
+            # the flat buckets' flatten, the views f() cuts back out of
+            # them (and their backward, the gradients' flatten), the
+            # sweep and the unflatten after it
             with jax.named_scope(_phases.UPDATE_SCOPE):
                 flats = [flatten_bucket([params[n] for n in b.names], b)
                          for b in plan]
@@ -666,8 +689,9 @@ class ParallelTrainer:
                 p_shards["b%d" % b.index] = \
                     jax.lax.with_sharding_constraint(fl, zero_ns)
                 g_shards["b%d" % b.index] = gshard
-            # flat buckets (1-D fp32 views, bucket-major slots) let the
-            # optimizer take the one-sweep Pallas path
+            # bucket buffers (flat 1-D or native (rows, C) fp32 views,
+            # bucket-major slots) let the optimizer take the one-sweep
+            # Pallas path
             # (MXNET_PALLAS_FUSED_OPT; tree_map stays the parity
             # oracle).  On a multi-chip mesh the sweep runs
             # shard_map-wrapped over the 1/mesh bucket rows — only
@@ -681,7 +705,7 @@ class ParallelTrainer:
                 mesh=mesh if mesh.size > 1 else None)
             new_fused = {}
             for b in plan:
-                # the all-gather: shard-updated flat buffer back to the
+                # the all-gather: shard-updated buffer back to the
                 # replicated master layout, then split into params
                 with _coll_scope("all_gather", b.index):
                     full = jax.lax.with_sharding_constraint(
@@ -837,7 +861,7 @@ class ParallelTrainer:
                     by_bucket = {b.index: b for b in plan}
                     for key, arr in leaf.items():
                         b = by_bucket[int(key[1:])]
-                        host = np.asarray(jax.device_get(arr))
+                        host = np.asarray(jax.device_get(arr)).reshape(-1)
                         for name, shape, off, sz in zip(
                                 b.names, b.shapes, b.offsets, b.sizes):
                             dst[name] = host[off:off + sz].reshape(shape)
@@ -866,8 +890,8 @@ class ParallelTrainer:
     def load_state_dict(self, state):
         """Restore a :meth:`state_dict` snapshot into THIS trainer's
         layout (reshard-on-restore): params re-placed by this mesh's
-        specs, per-param slots re-flattened into this plan's ZeRO
-        shards.  Values are bit-identical to the snapshot — only the
+        specs, per-param slots rebuilt as this plan's bucket
+        buffers (flat or native) in their ZeRO shards.  Values are bit-identical to the snapshot — only the
         placement changes."""
         mesh = self._mesh
         params, slots = state["params"], state.get("slots", {})
@@ -900,12 +924,14 @@ class ParallelTrainer:
                 % (sorted(slots.keys()), type(self._opt).__name__,
                    want_slots))
 
-        def _fused_flat(per_param, b):
+        def _bucket_buffer(per_param, b):
+            """Per-param arrays as bucket ``b``'s buffer, flat or
+            native — the same elements in the same row-major order."""
             flat = np.zeros((b.padded_n,), np.float32)
             for name, off, sz in zip(b.names, b.offsets, b.sizes):
                 flat[off:off + sz] = np.asarray(
                     per_param[name], np.float32).reshape(-1)
-            return flat
+            return flat.reshape(b.buffer_shape)
 
         zero_ns = NamedSharding(mesh, self._zero_spec)
         rep_ns = NamedSharding(mesh, P())
@@ -937,7 +963,7 @@ class ParallelTrainer:
                     continue
                 fused[slot] = {
                     "b%d" % b.index: jax.device_put(
-                        jnp.asarray(_fused_flat(slots[slot], b)), zero_ns)
+                        jnp.asarray(_bucket_buffer(slots[slot], b)), zero_ns)
                     for b in self._plan}
             for slot, leaf in self._opt_state["perparam"].items():
                 if not isinstance(leaf, dict):
@@ -958,7 +984,7 @@ class ParallelTrainer:
             resid_ns = zero_ns if self._zero else rep_ns
             self._resids = tuple(
                 jax.device_put(
-                    jnp.asarray(_fused_flat(
+                    jnp.asarray(_bucket_buffer(
                         {n: residuals.get(
                             n, np.zeros(shape, np.float32))
                          for n, shape in zip(b.names, b.shapes)}, b)),

@@ -266,6 +266,144 @@ def test_fused_sweep_bitwise_under_zero_shardings(monkeypatch):
             assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
+# -- native buckets (PR 30): one leaf swept in its own (rows, C) layout ------
+def _native_buckets(rng, shapes):
+    return {"b%d" % i: jnp.asarray(rng.randn(*s).astype(np.float32))
+            for i, s in enumerate(shapes)}
+
+
+@pytest.mark.parametrize("cols,block_elems,block_rows", [
+    (2048, 0, 64), (8192, 0, 16), (128, 0, 1024), (256, 4096, 16),
+    (16384, 0, 8)])
+def test_native_sweep_plan_blocks(cols, block_elems, block_rows,
+                                  monkeypatch):
+    """A grid step holds about MXNET_PALLAS_OPT_BLOCK_ELEMS elements in
+    whole rows; the grid covers rows the blocks do not divide."""
+    monkeypatch.setenv("MXNET_PALLAS_OPT_BLOCK_ELEMS", str(block_elems))
+    plan = pk.sweep_plan((50272, cols), 4, 3)
+    assert plan["block_rows"] == block_rows
+    assert plan["in_specs"][0].block_shape == (block_rows, cols)
+    assert plan["out_shapes"] == [(50272, cols)] * 3
+    assert plan["grid"] == (-(-50272 // block_rows),)
+    assert plan["in_specs"][0].index_map(3, None) == (3, 0)
+    # a flat bucket's plan is what it was
+    flat = pk.sweep_plan((50272 * cols,), 4, 3)
+    assert flat["in_specs"][0].block_shape[1] == pk.LANES
+
+
+@pytest.mark.parametrize("shape", [(12, 128), (64, 100), (8, 8, 128),
+                                   (16, 32768)])
+def test_native_sweep_refuses_what_it_cannot_tile(shape):
+    w = jnp.zeros(shape, jnp.float32)
+    with pytest.raises(ValueError, match="whole"):
+        pk.fused_adam(w, w, w, w, lr_eff=0.01)
+
+
+@pytest.mark.parametrize("optimizer", ["sgd", "sgd_momentum", "adam"])
+@pytest.mark.parametrize("block_elems", [0, 4096])
+def test_native_sweep_bitwise_vs_treemap(optimizer, block_elems,
+                                         monkeypatch):
+    """ACCEPTANCE: a (rows, C) bucket swept as it stands is EXACTLY the
+    per-array tree_map path after N steps — params and slots — with one
+    block, with several, and with a last block that overhangs the rows
+    (72 rows in blocks of 16)."""
+    from mxnet_tpu.parallel.optimizer import PureAdam, PureSGD
+    monkeypatch.setenv("MXNET_PALLAS_OPT_BLOCK_ELEMS", str(block_elems))
+    rng = np.random.RandomState(6)
+    shapes = [(8, 128), (72, 256), (64, 512)]
+    params = _native_buckets(rng, shapes)
+    grads = [_native_buckets(rng, shapes) for _ in range(4)]
+    opt = PureAdam(1e-3, wd=0.01, clip_gradient=0.5) \
+        if optimizer == "adam" else \
+        PureSGD(0.1, momentum=0.9 * (optimizer == "sgd_momentum"), wd=0.01)
+    pf, sf = _drive(opt, params, grads, opt.init(params), "1", monkeypatch)
+    pu, su = _drive(opt, params, grads, opt.init(params), "0", monkeypatch)
+    for k in params:
+        assert pf[k].shape == params[k].shape
+        assert np.array_equal(np.asarray(pf[k]), np.asarray(pu[k])), k
+    for a, b in zip(jax.tree_util.tree_leaves(sf),
+                    jax.tree_util.tree_leaves(su)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("sharded", [True, False])
+@pytest.mark.parametrize("optimizer", ["sgd_momentum", "adam"])
+def test_native_sweep_bitwise_under_zero_shardings(optimizer, sharded,
+                                                   monkeypatch):
+    """The ZeRO layouts of a native bucket over four devices — rows
+    sharded 1/mesh (the slot shards, shard_map'd sweep) and replicated
+    — stay bit-identical to tree_map."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from mxnet_tpu.parallel.mesh import make_mesh
+    from mxnet_tpu.parallel.optimizer import PureAdam, PureSGD
+    mesh = make_mesh(dp=4, devices=jax.devices()[:4])
+    ns = NamedSharding(mesh, P(tuple(mesh.axis_names)) if sharded else P())
+    place = lambda t: jax.tree_util.tree_map(
+        lambda a: jax.device_put(a, ns), t)
+    rng = np.random.RandomState(7)
+    shapes = [(32, 128), (96, 256)]
+    params = place(_native_buckets(rng, shapes))
+    grads = [place(_native_buckets(rng, shapes)) for _ in range(3)]
+    opt = PureAdam(1e-3, wd=0.01) if optimizer == "adam" \
+        else PureSGD(0.1, momentum=0.9, wd=0.01)
+
+    def drive(knob):
+        monkeypatch.setenv("MXNET_PALLAS_FUSED_OPT", knob)
+        step = jax.jit(lambda p, g, s: opt.apply(
+            p, g, s, flat=True, mesh=mesh if sharded else None))
+        p, state = dict(params), opt.init(params, {k: ns for k in params})
+        for g in grads:
+            p, state = step(p, g, state)
+        return p, state
+
+    pf, sf = drive("1")
+    pu, su = drive("0")
+    for k in params:
+        assert np.array_equal(np.asarray(pf[k]), np.asarray(pu[k])), k
+    for a, b in zip(jax.tree_util.tree_leaves(sf),
+                    jax.tree_util.tree_leaves(su)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_native_sweep_keeps_its_kernels_name_and_its_inputs():
+    """The benchmark finds the sweep by the ``name=`` of its
+    ``pallas_call``, native or flat; and the outputs that take their
+    inputs' buffers never cost a caller an array it still holds."""
+    rng = np.random.RandomState(8)
+    w, g, m = (jnp.asarray(rng.randn(16, 256).astype(np.float32))
+               for _ in range(3))
+    v = jnp.abs(m)
+    keep = [np.asarray(a).copy() for a in (w, g, m, v)]
+
+    def names(fn, *args):
+        found = []
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    found.append((eqn.params["name"],
+                                  tuple(eqn.params["input_output_aliases"])))
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+        walk(jax.make_jaxpr(fn)(*args).jaxpr)
+        return found
+
+    adam = lambda *a: pk.fused_adam(*a, lr_eff=0.01)
+    mom = lambda *a: pk.fused_sgd_momentum(*a, lr=0.1, momentum=0.9)
+    assert names(adam, w, g, m, v) == \
+        [("_adam_kernel", ((1, 0), (3, 1), (4, 2)))]
+    assert names(mom, w, g, m) == [("_sgd_mom_kernel", ((1, 0), (3, 1)))]
+    flat = [a.reshape(-1) for a in (w, g, m, v)]
+    assert names(adam, *flat) == [("_adam_kernel", ())]
+    nw, nm, nv = jax.jit(adam)(w, g, m, v)
+    fw, fm, fv = jax.jit(adam)(*flat)
+    for a, b in ((nw, fw), (nm, fm), (nv, fv)):
+        assert np.array_equal(np.asarray(a).reshape(-1), np.asarray(b))
+    for a, b in zip((w, g, m, v), keep):
+        assert np.array_equal(np.asarray(a), b)
+
+
 def test_fused_sweep_scalar_prefetch_no_recompile_on_lr_change():
     """The scalar-prefetch claim at kernel level: a changed lr/wd value
     reuses the SAME compiled program — the jit cache does not grow."""

@@ -452,3 +452,317 @@ def test_trainer_fused_sweep_plan_predictions_stay_exact(monkeypatch):
         assert spec.optimizer.get("fused_sweep") is True
         assert predict_opt_state(spec) == tr.optimizer_state_bytes()
         assert predict_comm(spec) == tr.comm_stats()
+
+
+# -- native buckets (PR 30): a one-leaf bucket keeps its leaf's layout -------
+
+class _Mixed(gluon.HybridBlock):
+    """Eligible matrices beside every kind of leaf that must stay flat:
+    a conv weight, a ``rows % 8`` leaf, a ``C % 128`` leaf, a ``[1, C]``
+    gate, biases — and a head that is eligible on one device only."""
+
+    def __init__(self, **kw):
+        super().__init__(**kw)
+        with self.name_scope():
+            self.conv = nn.Conv2D(2, 3, padding=1, in_channels=1)
+            self.d1 = nn.Dense(128, in_units=256, activation="relu")
+            self.d2 = nn.Dense(100, in_units=128, activation="relu")
+            self.d3 = nn.Dense(128, in_units=100, activation="relu")
+            self.d4 = nn.Dense(256, in_units=128, activation="relu")
+            self.gate = nn.Dense(1, in_units=256)
+            self.head = nn.Dense(8, in_units=256)
+
+    def hybrid_forward(self, F, x):
+        h = self.d4(self.d3(self.d2(self.d1(self.conv(x)))))
+        return F.broadcast_mul(self.head(h), F.sigmoid(self.gate(h)))
+
+
+# leaf (by the suffix of its name) -> layout on a mesh of 1 and of 4
+_MIXED_LAYOUTS = {
+    "conv0_weight": ("flat", "flat"),       # [2, 1, 3, 3]: C = 3
+    "dense0_weight": ("native", "native"),  # [128, 256]
+    "dense1_weight": ("flat", "flat"),      # [100, 128]: rows % 8
+    "dense2_weight": ("flat", "flat"),      # [128, 100]: C % 128
+    "dense3_weight": ("native", "native"),  # [256, 128]
+    "dense4_weight": ("flat", "flat"),      # [1, 256]: one row
+    "dense5_weight": ("native", "flat"),    # [8, 256]: rows % (8 * 4)
+}
+
+
+def _mixed_net(seed=7):
+    net = _Mixed(prefix="mixed_")
+    net.initialize(mx.init.Zero())
+    r = np.random.RandomState(seed)
+    for _, p in sorted(net.collect_params().items()):
+        p.set_data(nd.array((r.randn(*p.shape) * 0.1).astype(np.float32)))
+    return net
+
+
+def _mixed_trainer(net, ndev=1, zero=2, optimizer="adam", **kw):
+    opt = {"learning_rate": 0.05, "wd": 1e-3}
+    if optimizer == "sgd":
+        opt["momentum"] = 0.9
+    kw.setdefault("bucket_bytes", 1024)
+    kw.setdefault("first_bucket_bytes", 512)
+    return parallel.ParallelTrainer(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(), optimizer, opt,
+        mesh=parallel.make_mesh(dp=ndev, devices=jax.devices()[:ndev]),
+        zero=zero, **kw)
+
+
+def _mixed_train(trainer, steps=3):
+    rng = np.random.RandomState(0)
+    x = nd.array(rng.rand(16, 1, 8, 16).astype(np.float32))
+    y = nd.array(rng.randint(0, 8, 16).astype(np.float32))
+    return [float(trainer.step(x, y).asnumpy()) for _ in range(steps)]
+
+
+def _all_flat(monkeypatch):
+    """The same plan with no native bucket: what the parent builds."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    monkeypatch.setattr(pk, "sweep_native_rows",
+                        lambda shape, shards=1: None)
+
+
+@pytest.mark.parametrize("shape,shards,rows_c", [
+    ((2048, 2048), 1, (2048, 2048)),
+    ((50272, 2048), 1, (50272, 2048)),      # the blocks need not divide it
+    ((2048, 8192), 4, (2048, 8192)),
+    ((4, 16, 256), 1, (64, 256)),           # leading dimensions collapse
+    ((1, 2048), 1, None),                   # a gate: one row
+    ((1000, 2048), 4, None),                # rows % (8 * mesh)
+    ((1000, 2048), 1, (1000, 2048)),
+    ((128, 100), 1, None),                  # C % 128
+    ((64, 3, 3, 3), 1, None),               # a conv weight
+    ((2048,), 1, None),                     # 1-D
+    ((8, 32768), 1, None),                  # eight rows over a grid step
+])
+def test_sweep_native_rows(shape, shards, rows_c):
+    from mxnet_tpu.ops.pallas_kernels import sweep_native_rows
+    assert sweep_native_rows(shape, shards=shards) == rows_c
+    (b,) = build_bucket_plan(["w"], [shape], 1 << 20, pad_multiple=shards,
+                             native=True)
+    assert b.layout == ("native" if rows_c else "flat")
+    assert b.buffer_shape == (rows_c or (b.padded_n,))
+    assert b.n == int(np.prod(shape)) and b.offsets == [0, b.n]
+    # the default plan (the executor's, a codec's) is flat whatever fits
+    (f,) = build_bucket_plan(["w"], [shape], 1 << 20, pad_multiple=shards)
+    assert f.layout == "flat" and f.buffer_shape == (f.padded_n,)
+    w = jnp.arange(b.n, dtype=jnp.float32).reshape(shape)
+    buf = flatten_bucket([w], b)
+    assert buf.shape == b.buffer_shape
+    assert np.array_equal(np.asarray(unflatten_bucket(buf, b)["w"]),
+                          np.asarray(w))
+    assert np.array_equal(np.asarray(buf).reshape(-1)[:b.n],
+                          np.asarray(flatten_bucket([w], f))[:b.n])
+
+
+def test_a_grouped_bucket_is_never_native():
+    plan = build_bucket_plan(["a", "b"], [(8, 128), (8, 128)], 1 << 20,
+                             native=True)
+    assert [b.layout for b in plan] == ["flat"]
+
+
+@pytest.mark.parametrize("ndev", [1, 4])
+def test_native_plan_keeps_membership_and_comm(ndev, monkeypatch):
+    """Which leaves ride which bucket, the order, the indices, the
+    number of collectives and their bytes: the parent's."""
+    net = _mixed_net()
+    tr = _mixed_trainer(net, ndev)
+    layouts = {n: b.layout for b in tr.bucket_plan for n in b.names}
+    for suffix, want in _MIXED_LAYOUTS.items():
+        assert layouts["mixed_" + suffix] == want[ndev == 4], suffix
+    assert all(layouts[n] == "flat" for n in layouts if n.endswith("bias"))
+    _all_flat(monkeypatch)
+    flat = _mixed_trainer(net, ndev)
+    assert {b.layout for b in flat.bucket_plan} == {"flat"}
+    keys = ("index", "names", "shapes", "sizes", "offsets", "n", "padded_n")
+    for a, b in zip(tr.bucket_plan, flat.bucket_plan):
+        da, db = a.to_dict(), b.to_dict()
+        assert [da[k] for k in keys] == [db[k] for k in keys]
+    assert tr.comm_stats() == flat.comm_stats()
+    assert tr.optimizer_state_bytes() == flat.optimizer_state_bytes()
+
+
+@pytest.mark.parametrize("zero", [1, 2])
+@pytest.mark.parametrize("ndev", [1, 4])
+@pytest.mark.parametrize("optimizer", ["adam", "sgd"])
+def test_native_step_is_bit_identical(optimizer, ndev, zero, monkeypatch):
+    """ACCEPTANCE: the step over native buckets equals, bit for bit over
+    several steps, the sweep over the same plan with every bucket flat —
+    params, slots and losses — and the ``tree_map`` oracle on the same
+    plan within the band two differently composed whole-step CPU
+    programs keep (``test_trainer_fused_sweep_matches_treemap``'s note;
+    the UPDATE against the oracle is held to the bit in
+    tests/test_pallas.py, native layouts and ZeRO shardings included)."""
+    net = _mixed_net()      # ONE net: the three trainers pair by name
+
+    def run(knob):
+        monkeypatch.setenv("MXNET_PALLAS_FUSED_OPT", knob)
+        tr = _mixed_trainer(net, ndev, zero, optimizer)
+        return tr, _mixed_train(tr), _params_np(tr), _slots_np(tr)
+
+    tr, losses, params, slots = run("1")
+    assert "native" in {b.layout for b in tr.bucket_plan}
+    fused = tr.opt_state["fused"]["mean" if optimizer == "adam" else "mom"]
+    for b in tr.bucket_plan:
+        buf = fused["b%d" % b.index]
+        assert buf.shape == b.buffer_shape
+        assert buf.sharding.shard_shape(buf.shape)[0] * ndev == buf.shape[0]
+    _, oracle_losses, oracle_params, oracle_slots = run("0")
+    _all_flat(monkeypatch)
+    _, flat_losses, flat_params, flat_slots = run("1")
+    assert losses == flat_losses
+    np.testing.assert_allclose(losses, oracle_losses, rtol=0, atol=1e-5)
+    for n, v in params.items():
+        assert np.array_equal(v, flat_params[n]), n
+        np.testing.assert_allclose(v, oracle_params[n], rtol=0, atol=1e-6,
+                                   err_msg=n)
+    for k, v in slots.items():
+        assert np.array_equal(v, flat_slots[k]), k
+        np.testing.assert_allclose(v, oracle_slots[k], rtol=0, atol=1e-6,
+                                   err_msg=str(k))
+
+
+@pytest.mark.parametrize("codec", ["2bit", "bf16"])
+def test_a_codec_keeps_the_flat_plan_and_program(codec, monkeypatch):
+    """The codecs' wire format is defined on the flat buffer: with
+    ``compression=`` no bucket is native, and the program handed to the
+    compiler is the one built with no native bucket to be had."""
+    net = _mixed_net()
+
+    def lowered():
+        tr = _mixed_trainer(net, 4, 2, compression=codec)
+        assert {b.layout for b in tr.bucket_plan} == {"flat"}
+        assert all(r.ndim == 1 for r in tr._resids)
+        jit_fn, args = tr.step_callable((16, 1, 8, 16))
+        with parallel.mesh.mesh_scope(tr.mesh):
+            return jit_fn.lower(*args).as_text()
+
+    here = lowered()
+    _all_flat(monkeypatch)
+    assert lowered() == here
+
+
+@pytest.mark.parametrize("ndev", [1, 4])
+def test_zero_bucket_gauges(ndev):
+    telemetry.enable()
+    try:
+        tr = _mixed_trainer(_mixed_net(), ndev)
+        snap = telemetry.snapshot()
+    finally:
+        telemetry.disable()
+
+    def read(metric):
+        return {v["labels"]["layout"]: v["value"]
+                for v in snap[metric]["values"]}
+
+    native = [n for n, want in _MIXED_LAYOUTS.items()
+              if want[ndev == 4] == "native"]
+    sizes = {n.split("mixed_", 1)[1]: int(np.prod(v.shape))
+             for n, v in tr.params.items()}
+    leaves, nbytes = read("mxnet_zero_bucket_leaves"), \
+        read("mxnet_zero_bucket_bytes")
+    assert leaves == {"native": len(native),
+                      "flat": len(sizes) - len(native)}
+    assert nbytes["native"] == 4 * sum(sizes[n] for n in native)
+    assert nbytes["native"] + nbytes["flat"] == 4 * sum(sizes.values())
+
+
+def _assert_same_state(sd, other):
+    for n, v in sd["params"].items():
+        np.testing.assert_array_equal(other["params"][n], v, err_msg=n)
+    for slot, per_param in sd["slots"].items():
+        for n, v in per_param.items():
+            np.testing.assert_array_equal(other["slots"][slot][n], v,
+                                          err_msg="%s/%s" % (slot, n))
+    for s, v in sd["scalars"].items():
+        np.testing.assert_array_equal(other["scalars"][s], v, err_msg=s)
+
+
+@pytest.mark.parametrize("src,dst", [
+    ((1, 1024), (1, 0)),        # native -> one flat bucket, and back
+    ((1, 0), (1, 1024)),
+    ((1, 1024), (4, 1024)),     # across mesh sizes: the head turns flat
+    ((4, 1024), (1, 1024)),
+    ((4, 1024), (4, 0)),
+])
+def test_state_round_trips_between_native_and_flat_plans(src, dst):
+    """``state_dict`` is per parameter and knows no layout: a native
+    trainer restores into a flat plan, another mesh, and back, bit for
+    bit, and steps on from there as the source does."""
+    net = _mixed_net()
+    a = _mixed_trainer(net, src[0], bucket_bytes=src[1],
+                       first_bucket_bytes=src[1] and 512)
+    _mixed_train(a, steps=2)
+    sd = a.state_dict()
+    b = _mixed_trainer(net, dst[0], bucket_bytes=dst[1],
+                       first_bucket_bytes=dst[1] and 512)
+    b.load_state_dict(sd)
+    _assert_same_state(sd, b.state_dict())
+    for bk in b.bucket_plan:
+        for slot in ("mean", "var"):
+            buf = b.opt_state["fused"][slot]["b%d" % bk.index]
+            assert buf.shape == bk.buffer_shape
+            assert buf.sharding.shard_shape(buf.shape)[0] * dst[0] \
+                == buf.shape[0]
+    back = _mixed_trainer(net, src[0], bucket_bytes=src[1],
+                          first_bucket_bytes=src[1] and 512)
+    back.load_state_dict(b.state_dict())
+    _assert_same_state(sd, back.state_dict())
+    assert _mixed_train(back, steps=2) == _mixed_train(a, steps=2)
+    _assert_same_state(a.state_dict(), back.state_dict())
+
+
+def test_a_snapshot_in_the_parents_format_loads():
+    """A snapshot as the parent wrote it — plain per-parameter arrays,
+    nothing about buckets — lands in native buffers."""
+    net = _mixed_net()
+    tr = _mixed_trainer(net, 4)
+    r = np.random.RandomState(3)
+    shapes = {n: v.shape for n, v in tr.params.items()}
+    draw = lambda: {n: r.randn(*s).astype(np.float32)
+                    for n, s in shapes.items()}
+    sd = {"params": draw(),
+          "slots": {"mean": draw(),
+                    "var": {n: np.abs(v) for n, v in draw().items()}},
+          "scalars": {"t": np.asarray(5, np.int32)},
+          "residuals": {},
+          "meta": {"zero": 2, "codec": None, "optimizer": "PureAdam"}}
+    tr.load_state_dict(sd)
+    _assert_same_state(sd, tr.state_dict())
+    for b in tr.bucket_plan:
+        if b.layout == "native":
+            buf = tr.opt_state["fused"]["var"]["b%d" % b.index]
+            assert buf.shape == b.buffer_shape and buf.ndim == 2
+            np.testing.assert_array_equal(
+                np.asarray(buf), sd["slots"]["var"][b.names[0]])
+    assert np.isfinite(_mixed_train(tr, steps=1)[0])
+
+
+@pytest.mark.parametrize("zero", [1, 2])
+@pytest.mark.parametrize("ndev", [1, 4])
+def test_native_plan_predictions_stay_exact(ndev, zero, monkeypatch):
+    """graftplan over a plan with native buckets: the predicted
+    optimizer-state bytes and collectives equal the measured ones byte
+    for byte, the spec says which bucket keeps its layout, and the
+    divisibility contract holds (and catches a native bucket whose rows
+    the mesh cannot cut into whole tiles)."""
+    from mxnet_tpu.analysis.plan import (PlanSpec, predict_comm,
+                                         predict_opt_state)
+    from mxnet_tpu.analysis.plan.contracts import check_divisibility
+    monkeypatch.setenv("MXNET_PALLAS_FUSED_OPT", "1")
+    tr = _mixed_trainer(_mixed_net(), ndev, zero)
+    spec = PlanSpec.from_trainer(tr)
+    assert predict_opt_state(spec) == tr.optimizer_state_bytes()
+    assert predict_comm(spec) == tr.comm_stats()
+    assert [b["layout"] for b in spec.buckets] \
+        == [b.layout for b in tr.bucket_plan]
+    assert [tuple(b["buffer_shape"]) for b in spec.buckets] \
+        == [b.buffer_shape for b in tr.bucket_plan]
+    assert check_divisibility(spec) == []
+    native = next(b for b in spec.buckets if b["layout"] == "native")
+    native["buffer_shape"] = [native["buffer_shape"][0] + 4,
+                              native["buffer_shape"][1]]
+    (problem,) = check_divisibility(spec)
+    assert "native bucket %d" % native["index"] in problem["detail"]

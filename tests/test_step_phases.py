@@ -13,6 +13,10 @@ program's own step spans armed by ``telemetry.enable()``.
 - the fused step never re-lays a weight (PR 26): only rank-1 operands
   enter ``mx_update/flatten``, and an N-D weight reaches its output
   through elementwise ops alone;
+- the ZeRO step never re-lays a native bucket (PR 30): only the flat
+  tail enters ``mx_update/flatten`` / ``unflatten``, a matrix reaches
+  its output through no layout op, and every sweep ``pallas_call``
+  keeps its kernel's name under ``mx_update/sweep``;
 - ``telemetry.enable()`` arms the spans, ``telemetry.disable()`` takes
   back only that.
 """
@@ -325,6 +329,103 @@ def test_fused_step_never_relays_a_weight(monkeypatch, optimizer,
                 (exe.arg_names[exe._diff_idx[j]], ops[v])
             todo.extend(ops[v][1])
         assert reached and len(seen) >= 3, (j, seen)
+
+
+def _toy_lm_trainer(monkeypatch, optimizer, ndev=1):
+    import jax
+    from mxnet_tpu.gluon.contrib.transformer import TransformerLM
+    monkeypatch.setenv("MXNET_PALLAS_FUSED_OPT", "1")
+    lm = TransformerLM(vocab_size=256, units=128, hidden_size=256,
+                       num_layers=1, num_heads=2, max_len=16, dropout=0.0)
+    lm.initialize(mx.init.Xavier())
+    lm(mx.nd.zeros((2, 16)))            # materialise the deferred shapes
+    opt = {"learning_rate": 0.01}
+    if optimizer == "sgd":
+        opt["momentum"] = 0.9
+    return parallel.ParallelTrainer(
+        lm, gluon.loss.SoftmaxCrossEntropyLoss(), optimizer, opt,
+        mesh=parallel.make_mesh(dp=ndev, devices=jax.devices()[:ndev]),
+        zero=2, bucket_bytes=2048, first_bucket_bytes=1024)
+
+
+def _pallas_calls(jaxpr, stack=""):
+    """``[(kernel name, name stack)]`` of every ``pallas_call`` in a
+    jaxpr, through pjit / shard_map / custom_vjp bodies."""
+    import jax
+    found = []
+    for eqn in jaxpr.eqns:
+        here = stack + "/" + str(eqn.source_info.name_stack)
+        if eqn.primitive.name == "pallas_call":
+            found.append((eqn.params["name"], here))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub, here)
+    return found
+
+
+@pytest.mark.parametrize("ndev", [1, 2])
+@pytest.mark.parametrize("optimizer, kernel", [("adam", "_adam_kernel"),
+                                               ("sgd", "_sgd_mom_kernel")])
+def test_zero_step_never_relays_a_native_bucket(monkeypatch, optimizer,
+                                                kernel, ndev):
+    """What the chip gain of PR 30 rests on, read off the program handed
+    to the compiler for a small LM: nothing under the ``flatten`` /
+    ``unflatten`` scopes touches a native bucket (only the flat tail's
+    instructions remain there), every matrix goes from argument to
+    output through no layout op at all, and the sweep is one
+    ``pallas_call`` a bucket, under ``mx_update/sweep`` and under its
+    kernel's own name."""
+    tr = _toy_lm_trainer(monkeypatch, optimizer, ndev)
+    plan = tr.bucket_plan
+    native = [b for b in plan if b.layout == "native"]
+    flat = [b for b in plan if b.layout == "flat"]
+    # every matrix keeps its layout; LayerNorm vectors and biases ride flat
+    assert sorted(n for b in native for n in b.names) == sorted(
+        n for n, v in tr.params.items() if v.ndim == 2)
+    assert flat and all(len(s) == 1 for b in flat for s in b.shapes)
+    jit_fn, args = tr.step_callable((2, 16), (2, 16))
+    with parallel.mesh.mesh_scope(tr.mesh):
+        traced = jit_fn.trace(*args)
+        ops, returned = _main_ops(traced.lower().as_text(debug_info=True))
+    scoped = [v for v in ops.values()
+              if "/flatten/" in v[3] or "/unflatten/" in v[3]]
+    assert scoped and all("mx_update" in v[3] for v in scoped)
+    assert all(max(ranks, default=0) <= 1 for _op, _in, ranks, _s in scoped)
+    # count them: a flat bucket cuts each leaf back out twice (for the
+    # forward, and after the sweep) by a slice, unless the leaf is the
+    # whole buffer — with the matrices native, the tail's leaves only
+    cut = sum(1 for b in flat for sz in b.sizes if sz != b.padded_n)
+    assert cut >= 4
+    assert sum(op == "slice" for op, _in, _ranks, _s in scoped) == 2 * cut
+    # each matrix: from its argument to its output through the update,
+    # no layout op between (the sweep's call is one equation here)
+    jaxpr = traced.jaxpr.jaxpr
+    made_by = {v: e for e in jaxpr.eqns for v in e.outvars}
+    names = sorted(tr.params)       # a dict flattens in key order
+    for b in native:
+        j = names.index(b.names[0])
+        seen, todo, reached = set(), [jaxpr.outvars[j]], False
+        while todo:
+            v = todo.pop()
+            reached = reached or v is jaxpr.invars[j]
+            eqn = made_by.get(v)
+            if eqn is None or id(eqn) in seen \
+                    or "mx_update" not in str(eqn.source_info.name_stack):
+                continue
+            seen.add(id(eqn))
+            assert eqn.primitive.name not in _LAYOUT_OPS | {
+                "squeeze", "expand_dims", "broadcast_in_dim"}, \
+                (b.names[0], eqn)
+            # the matrices' path (the hyperparameter vector is stacked)
+            todo.extend(x for x in eqn.invars
+                        if getattr(getattr(x, "aval", None), "ndim", 0) > 1)
+        assert reached and seen, b.names[0]
+    calls = _pallas_calls(traced.jaxpr.jaxpr)
+    sweeps = [c for c in calls if "kernel" in c[0] and "sweep" in c[1]]
+    assert [c[0] for c in sweeps] == [kernel] * len(plan)
+    assert all("mx_update/sweep" in c[1] for c in sweeps)
+    assert not [c for c in calls if c[0] in (
+        "_adam_kernel", "_sgd_mom_kernel", "_sgd_kernel")
+        and c not in sweeps]
 
 
 def _instruction_lines(text):
