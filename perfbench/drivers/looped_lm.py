@@ -6,10 +6,12 @@ cross-entropy less ``exit_entropy_beta`` times the exit distribution's
 entropy).
 
 Everything but the block and the loss is ``drivers/parallel_trainer.py``
-as it stands — the same trainer construction, placement, ``step``,
-``leaves``, ``slots`` and ``assert_fast_path`` — taken from that file's
-class by name; only :meth:`build` differs, because that file's builds a
-plain cross-entropy and raises on a ``model`` it does not know.
+as it stands — ``build``, placement, ``step``, ``leaves``, ``slots`` and
+``assert_fast_path`` — taken from that file's class by name.  The block
+names its parameters as the reference names its leaves, so ``_block``
+also holds the two to pairing BY NAME (the base pairs by position and
+shape alone: ``TransformerLM``'s generated names do not end in the
+reference's).
 """
 import os
 
@@ -38,36 +40,13 @@ class Driver(_BASE.Driver):
             epsilon=float(cfg["rms_norm_eps"]),
             rope_base=float(cfg["rope_theta"]))
         net.initialize(mx.init.Zero(), ctx=mx.cpu())
+        trainable = (k for k, p in net.collect_params().items()
+                     if p.grad_req != "null")
+        for pname, rname in zip(trainable, weights):
+            if not pname.endswith(rname):
+                raise RuntimeError("parameter %s is not the reference's %s"
+                                   % (pname, rname))
         return net
 
-    def build(self, weights):
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        import mxnet_tpu as mx
-        from mxnet_tpu.parallel import ParallelTrainer, make_mesh
-        self.mx = mx
-        cfg, tr = self.config, self.config["trainer"]
-        net = self._block(mx, weights)
-        trainable = [(k, p) for k, p in net.collect_params().items()
-                     if p.grad_req != "null"]
-        if len(trainable) != len(weights):
-            raise RuntimeError("the block has %d trainable parameters, the "
-                               "reference %d" % (len(trainable), len(weights)))
-        self._names = {}
-        for (pname, p), (rname, w) in zip(trainable, weights.items()):
-            if tuple(p.shape) != tuple(w.shape) or not pname.endswith(rname):
-                raise RuntimeError("parameter %s %s does not match the "
-                                   "reference's %s %s"
-                                   % (pname, p.shape, rname, w.shape))
-            p.set_data(mx.nd.array(w, ctx=mx.cpu()))
-            self._names[pname] = rname
-        opt = dict(cfg["optimizer"])
-        name = opt.pop("name")
-        opt.pop("wd_exempt_suffixes", None)     # the reference's business
-        mesh = make_mesh(dp=len(self.devices), devices=list(self.devices))
-        self.trainer = ParallelTrainer(
-            net, net.exit_loss(beta=float(cfg["exit_entropy_beta"])), name,
-            opt, mesh=mesh, zero=int(tr["zero"]), dtype=tr["dtype"])
-        self._batch_ns = NamedSharding(mesh, P(("dp", "fsdp")))
-        self._jax = jax
+    def _loss(self, net):
+        return net.exit_loss(beta=float(self.config["exit_entropy_beta"]))

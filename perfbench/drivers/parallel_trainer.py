@@ -5,7 +5,10 @@ in ONE compiled program (``parallel/trainer.py`` ``_build``).
 The configuration's ``model`` names a gluon block (``transformer_lm``:
 ``gluon.contrib.transformer.TransformerLM``; ``model_zoo``: a
 ``gluon.model_zoo.vision`` network) and ``trainer`` gives the mesh's
-``zero`` stage and dtype.  Parameters materialise on the HOST (no eager
+``zero`` stage and dtype.  A driver of another block under another
+objective derives from :class:`Driver` and brings ``_block`` and
+``_loss`` (``drivers/looped_lm.py``); ``rehearse_compile.py`` takes any
+such driver.  Parameters materialise on the HOST (no eager
 forward on the chip) and are then overwritten, in construction order,
 with the benchmark's own weights; the trainer places them.
 """
@@ -15,7 +18,7 @@ import numpy as np
 class Driver:
     def __init__(self, config, devices, rehearse=False):
         self.config, self.devices, self.rehearse = config, devices, rehearse
-        self.trainer = self._loss = self._names = None
+        self.trainer = self._out = self._names = None
 
     # -- build ---------------------------------------------------------------
     def _block(self, mx, weights):
@@ -49,12 +52,17 @@ class Driver:
             raise ValueError("unknown model %r" % cfg["model"])
         return net
 
+    def _loss(self, net):
+        """The objective the trainer takes beside the block; a driver of
+        another model overrides this and :meth:`_block`, nothing else."""
+        from mxnet_tpu import gluon
+        return gluon.loss.SoftmaxCrossEntropyLoss()
+
     def build(self, weights):
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         import mxnet_tpu as mx
-        from mxnet_tpu import gluon
         from mxnet_tpu.parallel import ParallelTrainer, make_mesh
         self.mx = mx
         cfg, tr = self.config, self.config["trainer"]
@@ -78,7 +86,7 @@ class Driver:
         opt.pop("wd_exempt_suffixes", None)     # the reference's business
         mesh = make_mesh(dp=len(self.devices), devices=list(self.devices))
         self.trainer = ParallelTrainer(
-            net, gluon.loss.SoftmaxCrossEntropyLoss(), name, opt, mesh=mesh,
+            net, self._loss(net), name, opt, mesh=mesh,
             zero=int(tr["zero"]), dtype=tr["dtype"])
         self._batch_ns = NamedSharding(mesh, P(("dp", "fsdp")))
         self._jax = jax
@@ -92,15 +100,15 @@ class Driver:
     def step(self, batch):
         nd = self.mx.nd
         x, y = batch.placed
-        self._loss = self.trainer.step(nd.NDArray(x), nd.NDArray(y))._data
-        return self._loss
+        self._out = self.trainer.step(nd.NDArray(x), nd.NDArray(y))._data
+        return self._out
 
     def block(self):
-        self._loss.block_until_ready()
+        self._out.block_until_ready()
 
     # -- read for `correct` (set-up only) ------------------------------------
     def loss(self):
-        return float(np.asarray(self._loss))
+        return float(np.asarray(self._out))
 
     def leaves(self):
         p = self.trainer.params
@@ -139,4 +147,4 @@ class Driver:
                                    "kernel %s" % kname)
 
     def free(self):
-        self.trainer = self._loss = None
+        self.trainer = self._out = None

@@ -233,6 +233,26 @@ def _blocks(config, quant):
             "embed_bwd": jax.jit(embed_bwd, static_argnums=0)}
 
 
+def described_programs(config, sds):
+    """``(what, lowered)`` of the two largest programs the walk runs, the
+    backward blocks, for ``rehearse_compile.py --reference``; ``sds(shape,
+    dtype=float32)`` makes an argument on the described chip."""
+    b, t, u = int(config["batch_size"]), int(config["seq_len"]), \
+        int(config["hidden_size"])
+    shapes = {n: s for n, s, _i in leaf_specs(config)}
+    layer = {k: sds(shapes["l0_" + k]) for k in LAYER_LEAVES}
+    exit_p = {k: sds(shapes[k]) for k in EXIT_LEAVES}
+    x, bt = sds((b, t, u)), sds((b, t))
+    fn = _blocks(config, False)
+    print("the walk keeps %d states of %.3f GB beside parameters and "
+          "gradients" % (int(config["total_ut_steps"])
+                         * (int(config["num_hidden_layers"]) + 1),
+                         4 * b * t * u / 1e9))
+    yield "one layer application backward", fn["layer_bwd"].lower(x, layer, x)
+    yield "one exit backward", fn["exit_bwd"].lower(x, exit_p, bt,
+                                                    (x, bt, bt))
+
+
 def loss_and_grads(params, tokens, labels, config, quant=False, blocks=None):
     """``(loss, {leaf: gradient})`` — :func:`loss_fn`'s value and
     gradient, one layer application and one exit at a time."""

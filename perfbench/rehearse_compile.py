@@ -4,25 +4,37 @@
 before any chip call, and what the chip's compiler refuses, for free.
 
     JAX_PLATFORMS=cpu python3 perfbench/rehearse_compile.py --workload <cell> \
-        [--set key=value ...] [--reference]
+        [--set key=value ...] [--reference] [--count] [--dump FILE]
 
 A script for a builder's hands, not a test and not a measurement: nothing
 runs, no time comes out of it.  ``--reference`` compiles the plain
-reference's step instead of the program's (does it fit the chip once the
-program is gone?).  ``--set num_hidden_layers=8 --set batch_size=4``
-tries other sizes without touching the configuration's file.
+reference instead of the program (does it fit the chip once the program
+is gone?): its whole loss and gradient, or, where the reference walks
+its model in blocks, the programs its ``described_programs`` names.
+``--set num_hidden_layers=8 --set batch_size=4`` tries other sizes
+without touching the configuration's file.  ``--count`` counts in the
+optimized module what the per-layer readers will look for — the
+instructions under each class of each of the program's maps
+(``telemetry.phases`` ``instruction_*``) and the opcode histogram, which
+holds the ``while`` loops and every Pallas call by its kernel's name;
+``--dump`` writes the module's text.
 
 The program builds its mesh from real devices and places its own
 parameters, so for the described chip this script stands in for
 ``jax.device_put`` while the trainer is constructed (shapes with
-shardings instead of arrays) and tells the Pallas wrappers that the
-target is a TPU.  Only ``parallel_trainer`` cells and references are
-covered; the ``module_fit`` step binds through ``mx.tpu()`` contexts
+shardings instead of arrays), tells the Pallas wrappers that the target
+is a TPU, and answers for ``parallel/attention.py``, which asks
+``jax.default_backend()`` before it takes the flash kernels and would
+hear "cpu".  Any cell whose driver's class derives from
+``drivers/parallel_trainer.py``'s ``Driver`` is covered, and every
+reference; the ``module_fit`` step binds through ``mx.tpu()`` contexts
 that cannot be described (PERF.md, Open questions).
 """
 import argparse
+import collections
 import json
 import os
+import re
 import sys
 import time
 
@@ -60,28 +72,73 @@ def report(compiled, what):
 def compile_reference(cell, config, devices):
     import jax
     import jax.numpy as jnp
+    import numpy as np
     from jax.sharding import SingleDeviceSharding
     ref = cell.reference()
     one = SingleDeviceSharding(devices[0])
-    params = {n: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one)
-              for n, s, _i in ref.leaf_specs(config)}
-    import numpy as np
-    import traffic
-    feed = traffic.Feed(cell.traffic, config, 0)
-    x, y = feed._draw()
-    xs = jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
-    ys = jax.ShapeDtypeStruct(y.shape, y.dtype, sharding=one)
 
-    def grad(p, x, y):
-        return jax.value_and_grad(ref.loss_fn)(p, x, y, config, False)
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    with jax.default_matmul_precision("highest"):
-        c = jax.jit(grad).lower(params, xs, ys).compile()
     n = sum(int(np.prod(s)) for _n, s, _i in ref.leaf_specs(config))
-    print("reference: loss and gradients only; add its optimizer state, "
-          "%.2f GB for SGD's one slot or %.2f GB for Adam's two"
+    print("reference: beside what is compiled here it holds its optimizer "
+          "state, %.2f GB for SGD's one slot or %.2f GB for Adam's two"
           % (4 * n / 1e9, 8 * n / 1e9))
+    with jax.default_matmul_precision("highest"):
+        if hasattr(ref, "described_programs"):  # it walks its model in blocks
+            for what, lowered in ref.described_programs(config, sds):
+                report(lowered.compile(), "reference: %s, float32 highest"
+                       % what)
+            return
+        params = {name: sds(s) for name, s, _i in ref.leaf_specs(config)}
+        import traffic
+        x, y = traffic.Feed(cell.traffic, config, 0)._draw()
+
+        def grad(p, x, y):
+            return jax.value_and_grad(ref.loss_fn)(p, x, y, config, False)
+
+        c = jax.jit(grad).lower(params, sds(x.shape, x.dtype),
+                                sds(y.shape, y.dtype)).compile()
     return report(c, "reference loss+grad, float32 highest")
+
+
+def derives_from_parallel_trainer(driver_cls):
+    """``loader.load_module`` gives every import of a file a module of
+    its own, so the base is known by the name it is loaded under."""
+    return any(c.__name__ == "Driver"
+               and c.__module__ == "perfbench_drivers_parallel_trainer"
+               for c in driver_cls.__mro__)
+
+
+def flash_on_the_described_chip():
+    """``parallel/attention.py`` ``_flash_eligible`` without its question
+    to ``jax.default_backend()``: what it answers on the chip."""
+    from mxnet_tpu.ops.pallas_kernels import flash_seq_ok
+    from mxnet_tpu.parallel import attention
+
+    def eligible(q, k, causal, q_offset, kv_offset):
+        if causal and (q_offset != 0 or kv_offset != 0):
+            return False
+        return flash_seq_ok(q.shape[1], q.dtype) \
+            and flash_seq_ok(k.shape[1], k.dtype)
+
+    attention._flash_eligible = eligible
+
+
+def count(text):
+    """What the per-layer readers will find in the optimized module."""
+    import trace_reduce
+    from mxnet_tpu.telemetry import phases
+    for name in sorted(n for n in dir(phases) if n.startswith("instruction_")):
+        classes = collections.Counter(
+            str(c) for c in getattr(phases, name)(text).values())
+        print("%s: %s" % (name, json.dumps(dict(sorted(classes.items())))))
+    lines = [m.group(0).strip() for m in re.finditer(
+        r"^\s+(?:ROOT )?%[\w.\-]+ = .*$", text, re.M)]
+    opcodes = collections.Counter(trace_reduce.opcode(line) for line in lines)
+    # ``while`` counts the loops, ``custom-call:_<kernel>`` the Pallas calls
+    print("opcodes (%d instructions): %s"
+          % (len(lines), json.dumps(dict(sorted(opcodes.items())))))
 
 
 def compile_parallel_trainer(cell, config, devices):
@@ -108,6 +165,7 @@ def compile_parallel_trainer(cell, config, devices):
             return real_put(x, sharding, **kw)
         return Abstract(x.shape, x.dtype, sharding=sharding)
 
+    flash_on_the_described_chip()
     driver = cell.driver().Driver(config, devices, rehearse=True)
     weights = {n: np.zeros(s, np.float32)
                for n, s, _i in cell.reference().leaf_specs(config)}
@@ -141,6 +199,10 @@ def main(argv=None):
     ap.add_argument("--set", action="append", default=[],
                     metavar="key=value")
     ap.add_argument("--reference", action="store_true")
+    ap.add_argument("--count", action="store_true",
+                    help="count scopes, while loops, Pallas calls, opcodes")
+    ap.add_argument("--dump", default=None,
+                    help="write the optimized module's text here")
     args = ap.parse_args(argv)
     if os.environ.get("JAX_PLATFORMS", "") != "cpu":
         sys.exit("rehearse_compile: run with JAX_PLATFORMS=cpu (it must "
@@ -156,8 +218,13 @@ def main(argv=None):
     t0 = time.time()
     if args.reference:
         compile_reference(cell, config, devices)
-    elif config["driver"] == "parallel_trainer":
-        compile_parallel_trainer(cell, config, devices)
+    elif derives_from_parallel_trainer(cell.driver().Driver):
+        text = compile_parallel_trainer(cell, config, devices).as_text()
+        if args.count:
+            count(text)
+        if args.dump:
+            with open(args.dump, "w") as f:
+                f.write(text)
     else:
         sys.exit("rehearse_compile: no way to describe a chip to driver %r"
                  % config["driver"])

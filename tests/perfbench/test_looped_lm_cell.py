@@ -10,13 +10,13 @@ import time that touches jax or libtpu):
   ``recompute_ms``) on the optimized module of a small looped step,
   and on a module without the scopes;
 - a ``while``'s own event against its body's events
-  (``loop_reduce.control_cover``);
+  (``phase_reduce.control_cover``), and what they leave uncovered counted
+  as unattributed;
 - the manifest lists the cell under every per-layer metric it reports.
 """
 import argparse
 import copy
 import gzip
-import json
 import os
 import sys
 
@@ -27,8 +27,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 PB = os.path.join(ROOT, "perfbench")
 CELL = "ouro2p6b-train-s2048"
 NEW_READERS = ("loop_ms", "exit_ms", "recompute_ms")
-PHASE_READERS = ("fwd_ms", "bwd_ms", "update_ms", "phase_unattributed_share",
-                 "step_host_ms")
+# what the cell's traced line carries, in the manifest's order: seven
+# shared metrics (PR 27), the five phase readers (joined in PR 31), its own
+REPORTED = ("step_mfu", "device_idle_share", "hbm_peak_share", "dispatch_ms",
+            "compiles_in_window", "sweep_roofline", "flash_roofline",
+            "fwd_ms", "bwd_ms", "update_ms", "phase_unattributed_share",
+            "step_host_ms") + NEW_READERS
 
 
 @pytest.fixture(scope="module")
@@ -240,53 +244,90 @@ def test_new_readers_return_nothing_without_the_scopes(pb, name):
     assert reader.read(ctx) is None
     assert pb.bench.metric_reader("fwd_ms").read(dict(ctx)) is not None
     # a program from before the scopes: no module text at all
-    assert reader.read(dict(ctx, program_hlo=[], _loop_parts=None)) is None
+    assert reader.read(dict(ctx, program_hlo=[])) is None
+
+
+LOOP = "%while.1 = (s32[], f32[4]{0}) while(%tuple.2), condition=%c"
+BODY = "%fusion.{0} = f32[4]{{0}} fusion(%p.{0}), kind=kLoop"
 
 
 def test_a_loops_event_is_weighed_against_its_bodys_events(pb):
     """The readers count a ``while``'s body, not the ``while``: what the
     traced run prints to back that is the loop's own time and the time
     of the other events inside it."""
-    import loop_reduce          # ``pb`` holds perfbench/ on the path
-    loop = "%while.1 = (s32[], f32[4]{0}) while(%tuple.2), condition=%c"
-    body = "%fusion.{0} = f32[4]{{0}} fusion(%p.{0}), kind=kLoop"
-    events = [(body.format(0), 0, 100),                    # before the loop
-              (loop, 100, 1100),
-              (body.format(1), 100, 500), (body.format(2), 500, 1000),
-              ("%call.3 = f32[4]{0} call(%x), to_apply=%f", 2000, 2500)]
-    control, covered = loop_reduce.control_cover(events)
-    # the loop is covered but for its last 100 ns; the call, whose body
+    cover = pb.phase_reduce.control_cover
+    rest = [(0, 100),                                   # before the loop
+            (100, 500), (500, 1000)]                    # its body
+    # the loop is covered but for its last 100 ns; a call whose body
     # left no events, not at all
+    control, covered = cover([(100, 1100), (2000, 2500)], rest)
     assert control == pytest.approx(1500e-9)
     assert covered == pytest.approx(900e-9)
-    assert loop_reduce.control_cover(events[:1]) == (0.0, 0.0)
+    # a loop in a loop counts once
+    assert cover([(100, 1100), (200, 900)], rest)[0] == pytest.approx(1e-6)
+    assert cover([], rest) == (0.0, 0.0)
+
+
+def test_what_a_loops_body_leaves_uncovered_is_unattributed(pb):
+    """``phase_unattributed_share`` guards ``loop_ms`` / ``exit_ms``: the
+    part of a ``control`` event that no other event covers is charged to
+    ``other``, the covered part to nobody (the body's events carry it)."""
+    hlo = """HloModule jit_step, is_scheduled=true
+
+%body.1 (p.0: f32[4]) -> f32[4] {
+  %p.0 = f32[4]{0} parameter(0)
+  ROOT %fusion.1 = f32[4]{0} fusion(%p.0), kind=kLoop, calls=%f, metadata={op_name="jit(step)/jvp(mx_fwd)/mx_loop/while/body/mul"}
+}
+
+ENTRY %main.4 (w.1: f32[4]) -> f32[4] {
+  %w.1 = f32[4]{0} parameter(0), metadata={op_name="w"}
+  %while.1 = f32[4]{0} while(%w.1), condition=%cond.1, body=%body.1, metadata={op_name="jit(step)/jvp(mx_fwd)/mx_loop/while"}
+  ROOT %fusion.2 = f32[4]{0} fusion(%while.1), kind=kLoop, calls=%f, metadata={op_name="jit(step)/mx_update/sweep/mul"}
+}
+"""
+    ops = [(LOOP, 100, 1100),
+           (BODY.format(1), 100, 500), (BODY.format(1), 500, 1000),
+           ("%fusion.2 = f32[4]{0} fusion(%while.1)", 1100, 1300)]
+    ctx = {"steps": 1, "chips": 1, "program_hlo": [hlo],
+           "trace": {"busy_s": 1200e-9, "ops_by_device": {0: ops},
+                     "modules_by_device": {0: [("jit_step(1)", 0, 2000)]}}}
+    read = {n: pb.bench.metric_reader(n).read(ctx) for n in
+            ("fwd_ms", "update_ms", "phase_unattributed_share", "loop_ms")}
+    assert read["fwd_ms"] == read["loop_ms"] == pytest.approx(900e-6)
+    assert read["update_ms"] == pytest.approx(200e-6)
+    assert read["phase_unattributed_share"] == pytest.approx(100 * 100 / 1200)
+    # the phases sum to busy time: nothing counted twice, nothing lost
+    seconds = ctx["_phases"]["seconds"]
+    assert "control" not in seconds
+    assert sum(seconds.values()) == pytest.approx(ctx["trace"]["busy_s"])
 
 
 # ---------------------------------------------------------------------------
-# the manifest
+# the manifest: rules on ``pb.bench`` (any checkout's), which an addition
+# keeps (tests/perfbench/test_manifest_addition.py runs them over one)
 # ---------------------------------------------------------------------------
-def test_the_manifest_lists_the_cell_under_what_it_reports(pb):
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        m = json.load(f)
+def _in_order(part, whole):
+    """Every name of ``part`` is in ``whole``, in ``part``'s order."""
+    rest = iter(whole)
+    return all(name in rest for name in part)
+
+
+@pytest.mark.parametrize("name", REPORTED)
+def test_the_manifest_lists_the_cell_under_what_it_reports(pb, name):
+    """The cell is IN the ``workloads`` of each metric it reports, and
+    its readers hold these, in this order, among whatever a later PR
+    lists it under."""
+    specs = {s["name"]: s for s in pb.bench.manifest["per_layer"]}
+    assert CELL in specs[name]["workloads"]
+    assert specs[name]["moves"] == "train_step_ms"
+    if name in NEW_READERS:
+        assert specs[name]["source"] == "device_trace"
+    names = [s["name"] for s in pb.bench.cell(CELL).per_layer_metrics()]
+    assert _in_order(REPORTED, names), names
+
+
+def test_the_manifest_keeps_the_configuration_as_it_was_cut(pb):
     cell = pb.bench.cell(CELL)
-    names = [s["name"] for s in cell.per_layer_metrics()]
-    assert names == ["step_mfu", "device_idle_share", "hbm_peak_share",
-                     "dispatch_ms", "compiles_in_window", "sweep_roofline",
-                     "flash_roofline"] + list(NEW_READERS)
-    for spec in m["per_layer"]:
-        if spec["name"] in NEW_READERS:
-            assert spec["workloads"] == [CELL]
-            assert spec["moves"] == "train_step_ms" \
-                and spec["source"] == "device_trace"
-        elif spec["name"] in PHASE_READERS:
-            # tests/perfbench/test_phase_reduce.py pins these five lists
-            # to PR 25's two cells; this cell joins them when a
-            # ``benchmark`` PR lets that test go (PERF.md section 7)
-            assert CELL not in spec["workloads"]
-        else:
-            # appended, nothing else changed: the accepted cells first
-            assert spec["workloads"][-1] == CELL
-            assert "opt1p3b-train-s2048" in spec["workloads"][:-1]
     entry = pb.bench.config_entry(cell.config_name)
     assert entry["reduced"] == cell.config["reduced"] == [
         "num_hidden_layers"]

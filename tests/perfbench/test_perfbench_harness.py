@@ -56,8 +56,10 @@ def _manifest():
 # ---------------------------------------------------------------------------
 # the manifest
 # ---------------------------------------------------------------------------
-def test_manifest_names_and_units():
-    m = _manifest()
+# rules on ``pb.bench`` (any checkout's), which an addition keeps
+# (tests/perfbench/test_manifest_addition.py runs them over one)
+def test_manifest_names_and_units(pb):
+    m = pb.bench.manifest
     assert set(m) == {"command", "paths", "run_seconds", "configs",
                       "workloads", "end_to_end", "per_layer"}
     names = []
@@ -80,12 +82,34 @@ def test_manifest_names_and_units():
     assert "setup_s" in e2e
     for e in m["end_to_end"]:
         assert 0 < e["bound"] <= 0.1
-    cells = {w["name"] for w in m["workloads"]}
     for e in m["per_layer"]:
         assert e["moves"] in e2e
-        assert set(e.get("workloads", cells)) <= cells
-    assert sum(w["chips"] == 4 for w in m["workloads"]) <= 1
+    assert sum(w["chips"] == 4 for w in m["workloads"]) \
+        <= max(1, len(m["workloads"]) // 4)
     assert 1 <= m["run_seconds"] <= 51
+
+
+def _in_order(part, whole):
+    """Every name of ``part`` is in ``whole``, in ``part``'s order."""
+    rest = iter(whole)
+    return all(name in rest for name in part)
+
+
+def test_manifest_lists_cells_in_the_order_they_were_accepted(pb):
+    """A metric's ``workloads`` names cells of the manifest, each once,
+    in the manifest's own order: a PR appends its cell to ``workloads``
+    and to every list it joins, so the accepted cells keep their order
+    and come before a later one."""
+    m = pb.bench.manifest
+    order = [w["name"] for w in m["workloads"]]
+    for e in m["end_to_end"] + m["per_layer"]:
+        listed = e.get("workloads", order)
+        assert listed and len(set(listed)) == len(listed), e["name"]
+        assert _in_order(listed, order), e["name"]
+    for w in m["workloads"]:
+        cell = pb.bench.cell(w["name"])
+        assert cell.per_layer_metrics(), w["name"]
+        assert {"setup_s"} < {s["name"] for s in cell.end_to_end_metrics()}
 
 
 @pytest.mark.parametrize("cell_name",
@@ -152,12 +176,13 @@ def test_new_files_are_found_without_an_edit(pb, tmp_path):
     assert cell.traffic["what"] == "a new mix"
     assert cell.limits()["limits"] == {"loss1": 0.5}
     assert cell.driver().Driver and cell.reference().train_steps
-    names = [s["name"] for s in cell.per_layer_metrics()]
-    assert names == ["new_metric"]
     assert bench.metric_reader("new_metric").read({"steps": 4}) == 8.0
-    # the old cells do not see the new metric, and nothing was edited
+    # the new cell reads its metric and none that lists other cells, the
+    # old cells do not see the new metric, and nothing was edited
     old = bench.cell(m["workloads"][0]["name"])
-    assert "new_metric" not in [s["name"] for s in old.per_layer_metrics()]
+    theirs = {s["name"] for s in old.per_layer_metrics()}
+    mine = {s["name"] for s in cell.per_layer_metrics()}
+    assert "new_metric" in mine - theirs and not mine & theirs
     for p, data in before.items():
         assert open(p, "rb").read() == data
     with pytest.raises(KeyError):

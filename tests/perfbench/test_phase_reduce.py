@@ -104,16 +104,28 @@ def test_reader_returns_nothing_when_there_is_nothing(bench, name):
     assert reader.read(ctx) is None
 
 
-def test_the_manifest_lists_the_readers(bench):
-    cells = {"resnet50-train-b256", "opt1p3b-train-s2048"}
+def _in_order(part, whole):
+    """Every name of ``part`` is in ``whole``, in ``part``'s order."""
+    rest = iter(whole)
+    return all(name in rest for name in part)
+
+
+# a rule on ``bench`` (any checkout's), which an addition keeps
+# (tests/perfbench/test_manifest_addition.py runs it over one)
+@pytest.mark.parametrize("name", READERS)
+def test_the_manifest_lists_the_readers(bench, name):
+    """Each reader lists AT LEAST the two cells PR 25 gave it, and in
+    every cell it lists the five stand in their order among that cell's
+    readers, whatever else a later PR puts around them."""
     specs = {s["name"]: s for s in bench.manifest["per_layer"]}
-    for name in READERS:
-        assert set(specs[name]["workloads"]) == cells
-        assert specs[name]["moves"] == "train_step_ms"
-        assert specs[name]["better"] == "lower"
-    for cell in cells:
+    assert set(specs[name]["workloads"]) >= {"resnet50-train-b256",
+                                             "opt1p3b-train-s2048"}
+    assert specs[name]["moves"] == "train_step_ms"
+    assert specs[name]["better"] == "lower"
+    for cell in specs[name]["workloads"]:
         names = [s["name"] for s in bench.cell(cell).per_layer_metrics()]
-        assert names[-5:] == list(READERS)
+        assert _in_order([r for r in READERS
+                          if cell in specs[r]["workloads"]], names), cell
 
 
 def test_phase_split_of_the_recorded_trace(bench, tmp_path):
