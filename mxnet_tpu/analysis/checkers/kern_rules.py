@@ -142,6 +142,23 @@ def coverage_problems(op, grid):
         problems.append(
             "%d of %d output blocks are never written (first gap %s)"
             % (len(missing), len(expected), missing[0]))
+    if op.get("revisit") == "runs":
+        # a block the grid stays on over a data-dependent RUN of
+        # consecutive steps (a group's row tiles, the reduction
+        # innermost): no race iff every block's visits are one unbroken
+        # run in grid order — however long each run is
+        closed, prev = set(), None
+        for t in table:
+            if t != prev:
+                if t in closed:
+                    problems.append(
+                        "block %s is revisited after the grid left it — "
+                        "its accumulation is written back in between"
+                        % (t,))
+                    break
+                closed.add(prev)
+                prev = t
+        return problems
     revisit = 1
     affect = _affecting_dims(pts, table, len(grid))
     for d, g in enumerate(grid):

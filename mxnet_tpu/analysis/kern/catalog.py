@@ -38,7 +38,8 @@ from __future__ import annotations
 import itertools
 
 __all__ = ["kernel_reports", "sweep_reports", "flash_reports",
-           "flash_cell_reports", "scale_bias_relu_reports", "layernorm_reports",
+           "flash_cell_reports", "grouped_matmul_reports",
+           "scale_bias_relu_reports", "layernorm_reports",
            "softmax_reports", "ORIGIN"]
 
 ORIGIN = "mxnet_tpu/ops/pallas_kernels.py"
@@ -49,28 +50,33 @@ def _dtype_name(dtype):
     return np.dtype(dtype).name
 
 
-def _eval_index(spec, grid, n_prefetch):
+def _eval_index(spec, grid, n_prefetch, prefetch=None):
     """The index map evaluated at every grid point (row-major), with
     one dummy argument per scalar-prefetch operand — block-local maps
     never touch the prefetch ref, so abstract evaluation works on
     plain ints; a data-dependent map would raise here, which is
-    exactly a not-statically-analyzable kernel."""
-    extra = (None,) * n_prefetch
+    exactly a not-statically-analyzable kernel.  A kernel whose maps DO
+    read a prefetched table (the grouped product: row tile -> group) is
+    analysed at one representative table, handed in as ``prefetch``
+    (plain lists): the verdict is about that table's grid."""
+    extra = tuple(prefetch) if prefetch is not None \
+        else (None,) * n_prefetch
     return [[int(v) for v in spec.index_map(*pt, *extra)]
             for pt in itertools.product(*[range(int(g)) for g in grid])]
 
 
 def _operand(name, role, spec, shape, grid, n_prefetch,
-             dtype="float32"):
+             dtype="float32", prefetch=None):
     return {"name": name, "role": role, "dtype": dtype,
             "block": [None if b is None else int(b)
                       for b in spec.block_shape],
             "shape": [int(s) for s in shape],
-            "index": _eval_index(spec, grid, n_prefetch)}
+            "index": _eval_index(spec, grid, n_prefetch, prefetch)}
 
 
 def _report(name, family, plan, in_names, out_names, *, hyper=None,
-            python_constants=(), shard=None, tail=None):
+            python_constants=(), shard=None, tail=None, prefetch=None,
+            revisit=None):
     from mxnet_tpu import config as _config
 
     from ..checkers.kern_rules import shard_safety, vmem_bytes
@@ -90,11 +96,16 @@ def _report(name, family, plan, in_names, out_names, *, hyper=None,
     for nm, spec, shape in zip(in_names, plan["in_specs"],
                                plan["in_shapes"]):
         operands.append(_operand(nm, "in", spec, shape, grid, npf,
-                                 next(dtypes)))
+                                 next(dtypes), prefetch))
     for nm, spec, shape in zip(out_names, plan["out_specs"],
                                plan["out_shapes"]):
         operands.append(_operand(nm, "out", spec, shape, grid, npf,
-                                 next(dtypes)))
+                                 next(dtypes), prefetch))
+        if revisit:
+            # how the grid comes back to an output block, where it is
+            # not "once per unused grid step" (kern_rules
+            # coverage_problems)
+            operands[-1]["revisit"] = revisit
     report = {
         "name": name, "family": family, "origin": ORIGIN,
         "grid": grid,
@@ -237,6 +248,56 @@ def flash_cell_reports():
                             "bfloat16"))
 
 
+# -- grouped matrix product (sparse experts) --------------------------------
+
+def grouped_matmul_reports(rows=3840, c=2304, o=896, groups=4, tm=None,
+                           dtype="bfloat16"):
+    """The grouped product's three instantiations — forward, the
+    gradient for the rows, the gradient for the weights — at the
+    benchmark's expert widths (2304 -> 896) over a representative row
+    layout: four groups of 3, 1 (an EMPTY group's one zero tile), 2 and
+    1 tiles, and two unused tiles past them.  The index maps read the
+    prefetched table, so the reports hold for that table's grid."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    tm = tm or pk.GROUPED_TILE_ROWS
+    tiles = rows // tm + groups
+    tile_group = [0, 0, 0, 1, 2, 2] + [3] * (tiles - 6)
+    used = [7]
+    hyper = {"transport": "scalar_prefetch",
+             "names": ["tile_group", "used"]}
+    structural = [
+        {"name": "nk", "detail": "grid extent"},
+        {"name": "w_out_in", "detail": "structural branch: which side "
+                                       "of the weight is contracted"},
+        {"name": "tiles", "detail": "grid extent"}]
+    elems = used[0] * tm * o
+    tail = {"logical_elems": elems, "padded_elems": tiles * tm * o,
+            "masked": True,
+            "how": "a group's last tile is padded with ZERO rows by the "
+                   "layout (parallel/moe.py _layout) and the tiles past "
+                   "`used` run no product and are written as zeros; "
+                   "nothing gathers a padding row back"}
+    family = "routed_experts"
+    reports = []
+    for w_out_in, ins in ((True, ("x", "w")), (False, ("dy", "w"))):
+        reports.append(_report(
+            "_grouped_matmul_kernel", family,
+            pk.grouped_matmul_plan(rows + groups * tm, c if w_out_in else o,
+                                   o if w_out_in else c, groups, tm,
+                                   w_out_in, dtype),
+            ins, ("y" if w_out_in else "dx",), hyper=hyper,
+            python_constants=structural[:2], tail=tail,
+            prefetch=(tile_group, used)))
+    reports.append(_report(
+        "_grouped_matmul_dw_kernel", family,
+        pk.grouped_matmul_dw_plan(rows + groups * tm, c, o, groups, tm,
+                                  dtype),
+        ("dy", "x"), ("dw",), hyper=hyper,
+        python_constants=structural[2:], tail=tail,
+        prefetch=(tile_group, used), revisit="runs"))
+    return reports
+
+
 # -- inference BatchNorm+ReLU epilogue -------------------------------------
 
 def scale_bias_relu_reports(n=16 * 7 * 7, c=2048, block=1024):
@@ -318,5 +379,6 @@ def kernel_reports():
     """Every in-tree kernel family's reports — the catalog
     ``tools/lint.py --kern`` / ``--all`` judge."""
     return (sweep_reports() + flash_reports() + flash_cell_reports()
+            + grouped_matmul_reports()
             + scale_bias_relu_reports() + layernorm_reports()
             + softmax_reports())

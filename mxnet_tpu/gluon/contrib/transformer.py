@@ -31,7 +31,7 @@ from ..nn import Dense, Dropout, Embedding, HybridSequential, LayerNorm
 from ..parameter import DeferredInitializationError
 
 __all__ = ["MultiHeadAttention", "TransformerEncoderCell", "TransformerLM",
-           "LoopedLM", "looped_lm_forward",
+           "LoopedLM", "looped_lm_forward", "MoELM", "moe_lm_forward",
            "causal_attention", "cached_attention_step"]
 
 
@@ -407,3 +407,207 @@ class LoopedLM(HybridBlock):
         head: ``loss(*net(tokens), labels)``."""
         from ..loss import ExitWeightedCELoss
         return ExitWeightedCELoss(beta=beta, params=self.params, **kwargs)
+
+
+# one expert layer's leaves, in construction order
+_MOE_LAYER_LEAVES = (
+    "norm1_gamma", "q_weight", "k_weight", "v_weight", "out_weight",
+    "norm2_gamma", "router_weight", "gate_weight", "up_weight",
+    "down_weight")
+SLIDING, FULL = "sliding_attention", "full_attention"
+
+
+def moe_lm_forward(params, tokens, *, layer_types, num_heads, num_kv_heads,
+                   top_k, held, window, rope, norm_topk=True, eps=1e-6):
+    """A sparse-expert LM's trunk as ONE pure function of ``(params,
+    tokens)``: the final-normed states ``(B, T, U)`` the head reads.
+
+    ``params`` maps :class:`MoELM`'s short parameter names to jax
+    arrays; ``tokens`` is ``(B, T)`` int.  Layer ``i`` is pre-norm,
+    ``x + Attn(RMS(x))`` then ``x + Experts(RMS(x))``: grouped-query
+    causal attention with rotary q and k — on a ``sliding_attention``
+    layer a query sees its last ``window`` keys, on a ``full_attention``
+    layer every key before it; ``rope`` maps each kind to ``(base,
+    inv_freq or None, scale)`` (:func:`ops.contrib._rotary_embedding`)
+    — and one chip's share of a top-``top_k`` routed expert layer
+    (:func:`parallel.moe.routed_experts`: the router over ALL published
+    experts, ``held = (first, count)`` the ones whose stacked weights
+    are here).  Each layer is a ``jax.checkpoint``: the backward pass
+    keeps the state that enters a layer and runs the layer again."""
+    import jax
+    import jax.numpy as jnp
+
+    from ...ops.contrib import _flash_attention_op, _rotary_embedding
+    from ...ops.nn import _rms_norm
+    from ...parallel.moe import routed_experts
+    from ...telemetry import phases
+
+    def layer(x, p, kind):
+        n1, wq, wk, wv, wo, n2, wr, wg, wu, wd = p
+        b, t, u = x.shape
+        base, inv_freq, scale = rope[kind]
+        h = _rms_norm(x, n1, eps=eps)
+        heads = lambda w, n: jnp.einsum("btu,ou->bto", h, w).reshape(
+            b, t, n, w.shape[0] // n)
+        with jax.named_scope(phases.ATTN_WINDOW_SCOPE if kind == SLIDING
+                             else phases.ATTN_FULL_SCOPE):
+            turn = lambda a: _rotary_embedding(a, base=base,
+                                               inv_freq=inv_freq, scale=scale)
+            o = _flash_attention_op(
+                turn(heads(wq, num_heads)), turn(heads(wk, num_kv_heads)),
+                heads(wv, num_kv_heads), causal=True,
+                window=window if kind == SLIDING else None)
+        x = x + jnp.einsum("bto,uo->btu", o.reshape(b, t, -1), wo)
+        h = _rms_norm(x, n2, eps=eps)
+        y = routed_experts(h.reshape(b * t, u), wr, (wg, wu, wd), top_k,
+                           held, norm_topk=norm_topk)
+        return x + y.reshape(b, t, u)
+
+    x = params["embed_weight"][tokens.astype(jnp.int32)]
+    for i, kind in enumerate(layer_types):
+        p = [params["l%d_%s" % (i, n)] for n in _MOE_LAYER_LEAVES]
+        x = jax.checkpoint(layer, static_argnums=2)(x, p, kind)
+    return _rms_norm(x, params["norm_gamma"], eps=eps)
+
+
+class MoELM(HybridBlock):
+    """Decoder-only LM of sparse-expert layers under attention of two
+    kinds: ``layer_types`` names each layer ``sliding_attention`` (a
+    query sees its last ``window`` keys) or ``full_attention``; every
+    layer's feed-forward part is a top-``top_k`` routed layer of SwiGLU
+    experts of which this block HOLDS ``held = (first, count)`` of the
+    ``num_routed`` the router runs over (``parallel.moe.routed_experts``:
+    one chip's share under expert parallelism; ``(0, num_routed)`` is
+    the whole layer).  Pre-norm, two RMSNorm gains a layer and a final
+    one, grouped-query heads, rotary positions with a table per layer
+    kind (``rope``: kind -> dict with ``rope_theta`` and, for YaRN,
+    ``rope_type`` "yarn", ``factor``, ``original_max_position_embeddings``,
+    ``beta_fast``, ``beta_slow``, ``attention_factor``), no biases,
+    untied head.
+
+    Input ``(B, T)`` token ids; output the final-normed states ``(B, T,
+    U)``.  The head is a parameter of this block (``head_weight``) but
+    its product is the loss's: ``lm_loss()`` fuses it with its
+    cross-entropy (``F.contrib.linear_cross_entropy``) so the float32
+    logits are never kept, and ``logits(states)`` gives them where they
+    are wanted.  The expert weights are stacked leaves, ``l{i}_gate_weight
+    (count, F, U)``.  Each layer is rematerialised in the backward pass
+    (the block's own property, no option)."""
+
+    def __init__(self, vocab_size, units=128, expert_width=64,
+                 layer_types=(SLIDING, FULL), num_heads=4, num_kv_heads=2,
+                 head_dim=None, num_routed=8, held=None, top_k=2,
+                 window=32, rope=None, norm_topk=True, epsilon=1e-6,
+                 **kwargs):
+        super().__init__(**kwargs)
+        from ...ops.contrib import yarn_inv_freq
+        head_dim = head_dim or units // num_heads
+        held = (0, num_routed) if held is None else \
+            (int(held[0]), int(held[1]))
+        if num_heads % num_kv_heads or head_dim % 2:
+            raise ValueError("num_heads (%d) must be a multiple of "
+                             "num_kv_heads (%d), heads of even size (%d)"
+                             % (num_heads, num_kv_heads, head_dim))
+        if any(k not in (SLIDING, FULL) for k in layer_types):
+            raise ValueError("layer_types are %r or %r, got %r"
+                             % (SLIDING, FULL, list(layer_types)))
+        if held[0] < 0 or held[1] < 1 or sum(held) > num_routed \
+                or not 1 <= top_k <= num_routed:
+            raise ValueError("held experts %r and top_k %d do not fit %d "
+                             "routed experts" % (held, top_k, num_routed))
+        tables = {}
+        for kind in set(layer_types):
+            r = dict((rope or {}).get(kind) or {})
+            base = float(r.get("rope_theta", 10000.0))
+            if r.get("rope_type", "default") == "yarn":
+                tables[kind] = (base, tuple(yarn_inv_freq(
+                    head_dim, base, float(r["factor"]),
+                    float(r["original_max_position_embeddings"]),
+                    float(r.get("beta_fast", 32.0)),
+                    float(r.get("beta_slow", 1.0)))),
+                    float(r.get("attention_factor", 1.0)))
+            else:
+                tables[kind] = (base, None, 1.0)
+        self._config = dict(
+            layer_types=tuple(layer_types), num_heads=num_heads,
+            num_kv_heads=num_kv_heads, top_k=top_k, held=held,
+            window=int(window), rope=tables, norm_topk=bool(norm_topk),
+            eps=epsilon)
+        self._num_routed = num_routed
+        n, f = held[1], expert_width
+        shape = {"q_weight": (num_heads * head_dim, units),
+                 "k_weight": (num_kv_heads * head_dim, units),
+                 "v_weight": (num_kv_heads * head_dim, units),
+                 "out_weight": (units, num_heads * head_dim),
+                 "router_weight": (num_routed, units),
+                 "gate_weight": (n, f, units), "up_weight": (n, f, units),
+                 "down_weight": (n, units, f)}
+        shapes = [("embed_weight", (vocab_size, units))]
+        for i in range(len(layer_types)):
+            shapes += [("l%d_%s" % (i, k), shape.get(k, (units,)))
+                       for k in _MOE_LAYER_LEAVES]
+        shapes += [("norm_gamma", (units,)),
+                   ("head_weight", (vocab_size, units))]
+        with self.name_scope():
+            # the initializer reads the suffix: gains 1
+            for name, shp in shapes:
+                setattr(self, name, self.params.get(name, shape=shp))
+        self._export_gauges()
+
+    def _export_gauges(self):
+        from ... import telemetry
+        c = self._config
+        experts = telemetry.gauge(
+            "mxnet_moe_experts", "experts of a layer of the newest MoELM: "
+            "the router's width (published) and the ones this block holds "
+            "(held)")
+        experts.labels(which="published").set(self._num_routed)
+        experts.labels(which="held").set(c["held"][1])
+        telemetry.gauge("mxnet_moe_top_k", "experts a token is routed to "
+                        "in the newest MoELM").set(c["top_k"])
+        layers = telemetry.gauge(
+            "mxnet_attn_layers", "layers of the newest MoELM by kind of "
+            "attention (sliding_attention / full_attention)")
+        for kind in (SLIDING, FULL):
+            layers.labels(kind=kind).set(c["layer_types"].count(kind))
+        telemetry.gauge("mxnet_attn_window", "keys a query of a "
+                        "sliding_attention layer of the newest MoELM "
+                        "sees").set(c["window"])
+
+    def expected_rows(self, tokens):
+        """Rows a step of ``tokens`` tokens sends to this block's held
+        experts, a layer, in expectation under a symmetric router —
+        exported as ``mxnet_moe_expected_rows`` (what the experts'
+        products are sized against)."""
+        from ... import telemetry
+        c = self._config
+        rows = tokens * c["top_k"] * c["held"][1] / self._num_routed
+        telemetry.gauge("mxnet_moe_expected_rows", "rows a step's tokens "
+                        "send to the held experts of one layer of the "
+                        "newest MoELM, in expectation under a symmetric "
+                        "router").set(rows)
+        return rows
+
+    def hybrid_forward(self, F, tokens, **params):
+        from ...imperative import invoke_fn
+        names = [n for n in params if n != "head_weight"]
+        config = self._config
+        self.expected_rows(tokens.shape[0] * tokens.shape[1])
+
+        def forward(tokens_, *leaves):
+            return moe_lm_forward(dict(zip(names, leaves)), tokens_,
+                                  **config)
+
+        return invoke_fn(forward, [tokens] + [params[n] for n in names])
+
+    def logits(self, states):
+        """The head over final-normed states: ``(B, T, V)``."""
+        from ... import ndarray as nd
+        return nd.dot(states, self.head_weight.data(), transpose_b=True)
+
+    def lm_loss(self, **kwargs):
+        """The training objective over this block's output, sharing its
+        head: ``loss(net(tokens), labels)`` — per sequence, the mean
+        over positions of the next token's cross-entropy."""
+        from ..loss import LinearCELoss
+        return LinearCELoss(params=self.params, **kwargs)

@@ -16,7 +16,7 @@ __all__ = ["Loss", "L2Loss", "L1Loss", "SigmoidBinaryCrossEntropyLoss",
            "SigmoidBCELoss", "SoftmaxCrossEntropyLoss", "SoftmaxCELoss",
            "KLDivLoss", "CTCLoss", "HuberLoss", "HingeLoss",
            "SquaredHingeLoss", "LogisticLoss", "TripletLoss",
-           "ExitWeightedCELoss"]
+           "ExitWeightedCELoss", "LinearCELoss"]
 
 
 def _apply_weighting(F, loss, weight=None, sample_weight=None):
@@ -298,5 +298,26 @@ class ExitWeightedCELoss(Loss):
                     states[:, t], head_weight, label))
         loss = F.contrib.exit_weighted_loss(F.stack(*ce, axis=1), gates,
                                             beta=self._beta)
+        loss = _apply_weighting(F, loss, self._weight, sample_weight)
+        return _mean_all_but_batch(loss, self._batch_axis)
+
+
+class LinearCELoss(Loss):
+    """Next-token cross-entropy of a model whose output is the state its
+    head reads (no reference analogue): ``loss(states, label)`` with
+    ``states`` ``(B, T, U)`` and the head the SHARED parameter
+    ``head_weight`` ``(V, U)`` — construct the loss with
+    ``params=net.params`` (``gluon.contrib.transformer.MoELM.lm_loss()``).
+    The projection is fused with its cross-entropy
+    (``F.contrib.linear_cross_entropy``), so the float32 logits are
+    never kept for the backward pass.  Returns ``(B,)``."""
+
+    def __init__(self, weight=None, batch_axis=0, **kwargs):
+        super().__init__(weight, batch_axis, **kwargs)
+        self.head_weight = self.params.get("head_weight")
+
+    def hybrid_forward(self, F, states, label, head_weight,
+                       sample_weight=None):
+        loss = F.contrib.linear_cross_entropy(states, head_weight, label)
         loss = _apply_weighting(F, loss, self._weight, sample_weight)
         return _mean_all_but_batch(loss, self._batch_axis)

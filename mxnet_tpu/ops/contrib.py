@@ -21,6 +21,8 @@ instead, roi_pooling-inl.h kMaxIdx).
 """
 from __future__ import annotations
 
+import math
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -828,44 +830,82 @@ def _fused_bn_relu(data, gamma, beta, moving_mean, moving_var, eps=1e-5,
 # MXNet 1.2 predates attention, SURVEY.md §5.7)
 # ---------------------------------------------------------------------------
 @register("_contrib_flash_attention")
-def _flash_attention_op(q, k, v, causal=False, scale=None, **attrs):
+def _flash_attention_op(q, k, v, causal=False, scale=None, window=None,
+                        **attrs):
     """Softmax attention over (B, T, H, D) tensors; K/V may carry fewer
     heads (GQA).  Dispatches to the Pallas flash kernel on TPU (O(T)
     memory), the einsum path elsewhere (mxnet_tpu/parallel/attention.py
-    local_attention).  For sequence-sharded T use parallel.ring_attention
-    / ulysses_attention over an 'sp' mesh axis."""
+    local_attention).  ``window`` (with ``causal``): a query sees its
+    last ``window`` keys, itself among them.  For sequence-sharded T use
+    parallel.ring_attention / ulysses_attention over an 'sp' mesh axis
+    (which raise on a window)."""
     from ..parallel.attention import local_attention, ring_attention
     from ..parallel.mesh import current_mesh
     if scale is not None:
         scale = float(scale)
+    if window is not None:
+        window = int(window)
     mesh = current_mesh()
     if mesh is not None and mesh.shape.get("sp", 1) > 1:
         # an active sp mesh makes the SAME model sequence-parallel:
         # the time axis shards over the ring, K/V blocks rotate on ICI
-        return ring_attention(q, k, v, mesh=mesh,
-                              causal=_boolattr(causal), scale=scale)
-    return local_attention(q, k, v, causal=_boolattr(causal), scale=scale)
+        return ring_attention(q, k, v, mesh=mesh, causal=_boolattr(causal),
+                              scale=scale, window=window)
+    return local_attention(q, k, v, causal=_boolattr(causal), scale=scale,
+                           window=window)
 
 
 # ---------------------------------------------------------------------------
 # Today's dense block: rotary positions, gated feed-forward, and the
 # projection fused with its cross-entropy (no reference analogue)
 # ---------------------------------------------------------------------------
+def yarn_inv_freq(dim, base, factor, original_max_position, beta_fast=32.0,
+                  beta_slow=1.0):
+    """YaRN's frequency table (Peng et al., arXiv:2309.00071, as
+    ``transformers`` computes it), ``(dim/2,)`` float64: the rotary
+    frequencies ``base**(-2i/dim)`` blended with their ``1/factor``
+    interpolation by a linear ramp between the dimensions that turn
+    ``beta_fast`` and ``beta_slow`` times over ``original_max_position``
+    positions — below the first the frequency stays, above the second
+    it is divided by ``factor``."""
+    def turns(beta):
+        return dim * math.log(original_max_position / (beta * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(turns(beta_fast)), 0)
+    high = min(math.ceil(turns(beta_slow)), dim - 1)
+    inv = float(base) ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / max(high - low, 0.001), 0.0, 1.0)
+    return inv / float(factor) * ramp + inv * (1.0 - ramp)
+
+
 @register("_contrib_rotary_embedding")
-def _rotary_embedding(data, base=10000.0, **attrs):
+def _rotary_embedding(data, base=10000.0, inv_freq=None, scale=1.0, **attrs):
     """Rotary position embedding (Su et al., arXiv:2104.09864) over
     ``(B, T, H, D)`` with HALF-SPLIT pairing: element ``i < D/2`` turns
-    with element ``i + D/2`` by ``t * base**(-2i/D)``.  The
-    angles, sines and the rotation run in float32; the result is cast
-    back to ``data``'s dtype."""
+    with element ``i + D/2`` by ``t * base**(-2i/D)`` — or by ``t *
+    inv_freq[i]`` where a layer brings a frequency table of its own
+    (``D/2`` numbers: :func:`yarn_inv_freq`); cos and sin are multiplied
+    by ``scale`` (YaRN's attention factor).  The angles, sines and the
+    rotation run in float32; the result is cast back to ``data``'s
+    dtype."""
     t, d = data.shape[1], data.shape[-1]
     half = d // 2
-    inv_freq = float(base) ** (-jnp.arange(half, dtype=jnp.float32)
-                               * (2.0 / d))
+    if inv_freq is None:
+        inv_freq = float(base) ** (-jnp.arange(half, dtype=jnp.float32)
+                                   * (2.0 / d))
+    else:
+        inv_freq = jnp.asarray([float(f) for f in inv_freq], jnp.float32)
+        if inv_freq.shape != (half,):
+            raise ValueError("inv_freq holds %d frequencies, the heads "
+                             "need %d" % (inv_freq.shape[0], half))
     pos = jnp.arange(t, dtype=jnp.float32)
     ang = pos[:, None] * inv_freq[None, :]
     cos = jnp.cos(ang)[None, :, None, :]
     sin = jnp.sin(ang)[None, :, None, :]
+    if float(scale) != 1.0:
+        cos, sin = cos * float(scale), sin * float(scale)
     xf = data.astype(jnp.float32)
     x1, x2 = xf[..., :half], xf[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)

@@ -13,10 +13,19 @@ when-does-it-fuse table and the ``MXNET_PALLAS_*`` knobs):
   scoped-VMEM budget); the causal schedule fetches and runs nothing
   for a block above the diagonal, masks only the blocks the diagonal
   crosses, and in the backward works a square diagonal block in slabs,
-  so its upper half costs no product and no exponential.  This is the
+  so its upper half costs no product and no exponential.  With a
+  ``window`` (a query sees its last ``window`` keys) the visible band has
+  a lower edge too: a block wholly below it is neither fetched nor run
+  either, and only the blocks an edge crosses build a mask.  This is the
   per-device block kernel of ring/Ulysses sequence parallelism
   (parallel/attention.py); reference long-sequence analogue: the fused
   cuDNN RNN workspace kernels (src/operator/cudnn_rnn-inl.h).
+- ``grouped_matmul`` — the sparse experts' product: rows sorted by
+  group, each group's rows starting at a row tile (``parallel/moe.py``
+  lays them out), so a tile belongs to one group and its weight block
+  is named by a scalar-prefetched table, tile -> group; forward, the
+  rows' gradient and the weights' gradient (a group's block resident
+  over its run of tiles).  Tiles past the used ones run nothing.
 - ``fused_scale_bias_relu`` — the inference BatchNorm + ReLU epilogue as
   one VMEM-resident pass (reference: the BN+Activation fusion MKL-DNN
   does on CPU, nn/mkldnn/mkldnn_base-inl.h).  Call sites: the
@@ -183,6 +192,11 @@ def _imax(a, b):
     return max(a, b) if isinstance(a, int) else jnp.maximum(a, b)
 
 
+def _isel(cond, a, b):
+    return (a if cond else b) if isinstance(cond, bool) \
+        else jnp.where(cond, a, b)
+
+
 def _causal_last_k(i, bq, bk):
     """Last K block a causal Q block ``i`` has a visible key in."""
     return _idiv(i * bq + bq - 1, bk)
@@ -191,6 +205,18 @@ def _causal_last_k(i, bq, bk):
 def _causal_first_q(j, bq, bk):
     """First Q block that sees a key of causal K block ``j``."""
     return _idiv(j * bk, bq)
+
+
+def _window_first_k(i, bq, bk, window):
+    """First K block in which the first row of causal Q block ``i``
+    still sees a key, under a window of ``window`` keys a query."""
+    return _idiv(_imax(i * bq - (window - 1), 0), bk)
+
+
+def _window_last_q(j, bq, bk, window):
+    """Last Q block in which a query still sees the last key of K
+    block ``j`` (the caller holds it under the number of Q blocks)."""
+    return _idiv(j * bk + bk - 1 + window - 1, bq)
 
 
 _DIAG = "diag"        # mask tag: the slab's own square lies on the diagonal
@@ -207,7 +233,7 @@ def _flash_slabs(b):
 
 
 def _flash_block_cases(causal, qi, ki, bq, bk, update, k_major=False,
-                       slabs=True):
+                       slabs=True, window=None):
     """Drive ``update(q_rows, k_rows, mask)`` over score block (qi, ki).
 
     Every key visible (no mask, or the block wholly under the
@@ -221,12 +247,27 @@ def _flash_block_cases(causal, qi, ki, bq, bk, update, k_major=False,
     product and no exponential, and only the slab's square (``mask``
     _DIAG) is masked.  Any other
     crossing block takes the whole-block update with the diagonal's
-    offset as ``mask``: query row r sees key c iff r - c >= mask."""
+    offset as ``mask``: query row r sees key c iff r - c >= mask.
+
+    Under a ``window`` (causal, each query sees its last ``window``
+    keys, itself among them) the visible band has a lower edge too:
+    query row r sees key c iff ``shift <= r - c <= shift + window - 1``.
+    A block wholly inside the band takes the unmasked update, one wholly
+    outside it (above the diagonal or below the window) nothing, any
+    other the whole-block update with ``mask`` the pair of edges."""
     full = slice(None)
     if not causal:
         update(full, full, None)
         return
     shift = ki * bk - qi * bq
+    if window is not None:
+        inside = jnp.logical_and(shift + bk - 1 <= 0, shift >= bq - window)
+        outside = jnp.logical_or(shift > bq - 1,
+                                 shift + bk - 1 + window - 1 < 0)
+        pl.when(inside)(lambda: update(full, full, None))
+        pl.when(jnp.logical_not(jnp.logical_or(inside, outside)))(
+            lambda: update(full, full, (shift, shift + window - 1)))
+        return
     pl.when(shift + bk - 1 <= 0)(lambda: update(full, full, None))
     crossing = jnp.logical_and(shift + bk - 1 > 0, shift <= bq - 1)
     if bq != bk:
@@ -248,13 +289,21 @@ def _flash_block_cases(causal, qi, ki, bq, bk, update, k_major=False,
 def _flash_scores(a, b, scale, mask, rows_are_q):
     """float32 scores ``a @ b.T * scale`` of a block or slab, the
     invisible keys at NEG_INF.  Rows are queries and columns keys, or
-    the transpose (dK/dV).  ``mask``: None, the diagonal's offset, or
-    _DIAG — the square at the end of a Q slab's keys (at the start of a
-    K slab's queries) is the one on the diagonal."""
+    the transpose (dK/dV).  ``mask``: None, the diagonal's offset, a
+    pair ``(lo, hi)`` — a window's band, query row r sees key c iff
+    ``lo <= r - c <= hi`` — or _DIAG: the square at the end of a Q
+    slab's keys (at the start of a K slab's queries) is the one on the
+    diagonal."""
     s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if mask is None:
         return s
+    if isinstance(mask, tuple):
+        r = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        d = r - c if rows_are_q else c - r
+        return jnp.where(jnp.logical_and(d >= mask[0], d <= mask[1]),
+                         s, NEG_INF)
     n = s.shape[0]
     sq = s if mask is not _DIAG else s[:, -n:] if rows_are_q else s[:, :n]
     r = jax.lax.broadcasted_iota(jnp.int32, sq.shape, 0)
@@ -268,7 +317,7 @@ def _flash_scores(a, b, scale, mask, rows_are_q):
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
-                      l_ref, *, scale, causal, bq, bk, nk):
+                      l_ref, *, scale, causal, bq, bk, nk, window=None):
     """Grid (BH, nQ, nK); accumulate across the sequential nK dimension in
     VMEM scratch, finalize on the last K step (the canonical online-
     softmax schedule)."""
@@ -295,7 +344,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         m_ref[qs] = jnp.broadcast_to(m_new, (m_new.shape[0], LANES))
         l_ref[qs] = jnp.broadcast_to(l_new, (l_new.shape[0], LANES))
 
-    _flash_block_cases(causal, qi, ki, bq, bk, _update, slabs=False)
+    _flash_block_cases(causal, qi, ki, bq, bk, _update, slabs=False,
+                       window=window)
 
     @pl.when(ki == nk - 1)
     def _final():
@@ -305,7 +355,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, acc_ref, *, scale, causal, bq, bk, nk):
+                         dq_ref, acc_ref, *, scale, causal, bq, bk, nk,
+                         window=None):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -325,7 +376,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _flash_block_cases(causal, qi, ki, bq, bk, _update)
+    _flash_block_cases(causal, qi, ki, bq, bk, _update, window=window)
 
     @pl.when(ki == nk - 1)
     def _final():
@@ -334,7 +385,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                          bq, bk, nq):
+                          bq, bk, nq, window=None):
     """Grid (BH, nK, nQ).  The block is held transposed, (bk, bq):
     ``lse`` and ``delta`` arrive as rows (1, bq), so the scores, ``p``
     and ``ds`` come out of plain products in the orientation the dV and
@@ -362,7 +413,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _flash_block_cases(causal, qi, ki, bq, bk, _update, k_major=True)
+    _flash_block_cases(causal, qi, ki, bq, bk, _update, k_major=True,
+                       window=window)
 
     @pl.when(qi == nq - 1)
     def _final():
@@ -392,24 +444,26 @@ def flash_seq_ok(t, dtype, pref=128):
     return b == t or b % _sublane(dtype) == 0
 
 
-def _export_flash_gauges(kernel, bq, bk, grid):
+def _export_flash_gauges(kernel, bq, bk, grid, window=None):
     """What the newest instantiation of ``kernel`` was tiled into (trace
-    time, beside ``_count``)."""
+    time, beside ``_count``); a windowed instantiation is kept apart
+    from a full one by a ``window`` label of its own."""
     from .. import telemetry
     if not telemetry.enabled():
         return
+    own = {} if window is None else {"window": int(window)}
     rows = telemetry.gauge(
         "mxnet_flash_block_rows",
         "rows of the score block the newest flash-attention kernel "
         "instantiation works on, by kernel (fwd / dq / dkv) and side "
         "(q / k)")
-    rows.labels(kernel=kernel, side="q").set(bq)
-    rows.labels(kernel=kernel, side="k").set(bk)
+    rows.labels(kernel=kernel, side="q", **own).set(bq)
+    rows.labels(kernel=kernel, side="k", **own).set(bk)
     telemetry.gauge(
         "mxnet_flash_grid_steps",
         "grid steps of the newest flash-attention kernel instantiation "
         "(batch x heads x Q blocks x K blocks), by kernel").labels(
-            kernel=kernel).set(math.prod(grid))
+            kernel=kernel, **own).set(math.prod(grid))
 
 
 # one operand block, counted at the f32 width the kernels compute in:
@@ -459,21 +513,27 @@ def _flash_tiles(kernel, bq, bk, dtype):
 _FLASH_SEMANTICS = ("parallel", "parallel", "arbitrary")
 
 
-def _flash_q_major_specs(d, bq, bk, causal):
+def _flash_q_major_specs(d, bq, bk, causal, window=None):
     """Specs of the kernels on grid (BH, nQ, nK): the Q side follows
     i; the K side follows j, held at the last block row i needs under
-    the causal mask so the masked steps fetch nothing."""
+    the causal mask — and at the first one under a window — so the
+    masked steps fetch nothing."""
     def kmap(b, i, j):
-        return (b, _imin(j, _causal_last_k(i, bq, bk)) if causal else j, 0)
+        if not causal:
+            return (b, j, 0)
+        j = _imin(j, _causal_last_k(i, bq, bk))
+        if window is not None:
+            j = _imax(j, _window_first_k(i, bq, bk, window))
+        return (b, j, 0)
     return (pl.BlockSpec((None, bq, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((None, bk, d), kmap),
             pl.BlockSpec((None, bq, LANES), lambda b, i, j: (b, i, 0)))
 
 
 def flash_fwd_plan(bh, tq, tk, d, bq, bk, causal=False,
-                   dtype=jnp.float32):
+                   dtype=jnp.float32, window=None):
     """Plan of the flash-attention forward kernel (q, k, v -> o, lse)."""
-    qspec, kspec, lmspec = _flash_q_major_specs(d, bq, bk, causal)
+    qspec, kspec, lmspec = _flash_q_major_specs(d, bq, bk, causal, window)
     return {
         "grid": (bh, tq // bq, tk // bk),
         "in_specs": [qspec, kspec, kspec],
@@ -487,10 +547,10 @@ def flash_fwd_plan(bh, tq, tk, d, bq, bk, causal=False,
 
 
 def flash_bwd_dq_plan(bh, tq, tk, d, bq, bk, causal=False,
-                      dtype=jnp.float32):
+                      dtype=jnp.float32, window=None):
     """Plan of the dq backward kernel
     (q, k, v, do, lse, delta -> dq)."""
-    qspec, kspec, lmspec = _flash_q_major_specs(d, bq, bk, causal)
+    qspec, kspec, lmspec = _flash_q_major_specs(d, bq, bk, causal, window)
     return {
         "grid": (bh, tq // bq, tk // bk),
         "in_specs": [qspec, kspec, kspec, qspec, lmspec, lmspec],
@@ -505,17 +565,20 @@ def flash_bwd_dq_plan(bh, tq, tk, d, bq, bk, causal=False,
 
 
 def flash_bwd_dkv_plan(bh, tq, tk, d, bq, bk, causal=False,
-                       dtype=jnp.float32):
+                       dtype=jnp.float32, window=None):
     """Plan of the dk/dv backward kernel — grid (BH, nK, nQ), so the
     q-side specs transpose their two minor grid coordinates, and under
-    the causal mask hold at the first Q block column j needs.  ``lse``
-    and ``delta`` are rows here: (BH, nQ, 1, bq), one row a Q block."""
+    the causal mask hold at the first Q block column j needs (under a
+    window at the last one too).  ``lse`` and ``delta`` are rows here:
+    (BH, nQ, 1, bq), one row a Q block."""
     nq = tq // bq
 
     def qblock(j, i):
         if not causal:
             return i
-        return _imin(_imax(i, _causal_first_q(j, bq, bk)), nq - 1)
+        last = nq - 1 if window is None else \
+            _imin(_window_last_q(j, bq, bk, window), nq - 1)
+        return _imin(_imax(i, _causal_first_q(j, bq, bk)), last)
     qspec_t = pl.BlockSpec((None, bq, d),
                            lambda b, j, i: (b, qblock(j, i), 0))
     kspec_t = pl.BlockSpec((None, bk, d), lambda b, j, i: (b, j, 0))
@@ -595,7 +658,7 @@ def _flash_blocks(tq, tk, d, dtype, kernel):
     return max(fits)[2:]
 
 
-def _flash_plan(kernel, q, k, causal, block_q, block_k):
+def _flash_plan(kernel, q, k, causal, block_q, block_k, window=None):
     """(bq, bk, plan) of one kernel for this call: a caller's explicit
     blocks are honoured (halved until they divide T, as ever), a side
     left None is picked from the shape; the choice is exported."""
@@ -606,8 +669,9 @@ def _flash_plan(kernel, q, k, causal, block_q, block_k):
         bq = _pick_block(tq, block_q)
     if block_k is not None:
         bk = _pick_block(tk, block_k)
-    plan = _FLASH_PLANS[kernel](bh, tq, tk, d, bq, bk, causal, q.dtype)
-    _export_flash_gauges(kernel, bq, bk, plan["grid"])
+    plan = _FLASH_PLANS[kernel](bh, tq, tk, d, bq, bk, causal, q.dtype,
+                                window)
+    _export_flash_gauges(kernel, bq, bk, plan["grid"], window)
     return bq, bk, plan
 
 
@@ -631,9 +695,25 @@ def _flash_call(kernel, plan, *operands):
     )(*operands)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_window(window, causal, tq, tk):
+    """The window as the kernels take it: None where it hides nothing
+    the causal mask shows (so the call is the causal call, program and
+    all), else a whole number of keys, at least one."""
+    if window is None:
+        return None
+    window = int(window)
+    if window < 1:
+        raise ValueError("attention window must be at least 1 key, got %d"
+                         % window)
+    if not causal:
+        raise ValueError("an attention window needs causal=True: a query "
+                         "sees its last `window` keys, itself among them")
+    return None if window >= max(tq, tk) else window
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    block_k=None):
+                    block_k=None, window=None):
     """Blockwise online-softmax attention.
 
     q, k, v: (BH, T, D) — fold batch and heads into the leading dim.
@@ -644,50 +724,260 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     honoured.  Causal masking skips the blocks above the diagonal
     entirely (no body, no fetch), builds a mask only in the blocks the
     diagonal crosses, and in the backward skips the upper half of a
-    square diagonal block slab by slab.
+    square diagonal block slab by slab.  ``window`` (with ``causal``):
+    a query sees its last ``window`` keys, itself among them; a block
+    wholly below the window is neither fetched nor run either, and only
+    the blocks an edge of the band crosses build a mask.
     """
-    o, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k)
+    o, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, window)
     return o
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window=None):
     _count("flash_attention_fwd")
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    bq, bk, plan = _flash_plan("fwd", q, k, causal, block_q, block_k)
+    window = _flash_window(window, causal, q.shape[1], k.shape[1])
+    bq, bk, plan = _flash_plan("fwd", q, k, causal, block_q, block_k, window)
     o, lse = _flash_call(
         functools.partial(_flash_fwd_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, nk=k.shape[1] // bk),
+                          bq=bq, bk=bk, nk=k.shape[1] // bk, window=window),
         plan, q, k, v)
     return o, (q, k, v, o, lse)
 
 
-def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k):
-    o, res = _flash_fwd(q, k, v, causal, scale, block_q, block_k)
+def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, window):
+    o, res = _flash_fwd(q, k, v, causal, scale, block_q, block_k, window)
     return o, res
 
 
-def _flash_bwd_rule(causal, scale, block_q, block_k, res, do):
+def _flash_bwd_rule(causal, scale, block_q, block_k, window, res, do):
     _count("flash_attention_bwd")
     q, k, v, o, lse = res
     bh, tq, d = q.shape
     s = scale if scale is not None else 1.0 / math.sqrt(d)
+    window = _flash_window(window, causal, tq, k.shape[1])
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    bq, bk, plan = _flash_plan("dq", q, k, causal, block_q, block_k)
+    bq, bk, plan = _flash_plan("dq", q, k, causal, block_q, block_k, window)
     dq, = _flash_call(
         functools.partial(_flash_bwd_dq_kernel, scale=s, causal=causal,
-                          bq=bq, bk=bk, nk=k.shape[1] // bk),
+                          bq=bq, bk=bk, nk=k.shape[1] // bk, window=window),
         plan, q, k, v, do, lse,
         jnp.broadcast_to(delta[..., None], (bh, tq, LANES)))
-    bq, bk, plan = _flash_plan("dkv", q, k, causal, block_q, block_k)
+    bq, bk, plan = _flash_plan("dkv", q, k, causal, block_q, block_k, window)
     rows = (bh, tq // bq, 1, bq)        # dK/dV reads its statistics as rows
     dk, dv = _flash_call(
         functools.partial(_flash_bwd_dkv_kernel, scale=s, causal=causal,
-                          bq=bq, bk=bk, nq=tq // bq),
+                          bq=bq, bk=bk, nq=tq // bq, window=window),
         plan, q, k, v, do, lse[:, :, 0].reshape(rows), delta.reshape(rows))
     return dq, dk, dv
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+# ---------------------------------------------------------------------------
+# Grouped matrix product (sparse experts)
+# ---------------------------------------------------------------------------
+# ``P`` rows sorted by group, every group's rows starting at a multiple of
+# ``tm`` (``parallel/moe.py`` lays them out so: a group's end is padded to
+# the tile with ZERO rows, never to a capacity), so a row tile belongs to
+# ONE group and the product is a tiled matmul whose weight block is named
+# by a scalar-prefetched table, tile -> group.  ``used`` tiles hold rows;
+# the tiles past them run no product and fetch nothing (their index maps
+# hold at the last used tile's blocks) and are written as zeros.
+
+# Rows of a tile.  A held expert's rows start at a tile and its last tile
+# is padded with zero rows, so the tile prices the padding: the one cell
+# that runs the product sees about 1024 rows an expert (872 - 1175 over
+# 1344 loads), three 384-row tiles on all but 8 of them and 11 % of the
+# rows multiplied padding, where 512-row tiles flip between two and
+# three (PERF.md, Findings PR 32: 23.7 -> 26.2 % of the peak).  One
+# constant until a second load exists.
+GROUPED_TILE_ROWS = 384
+_GROUPED_MAX_COLS = 1024
+
+
+def _grouped_cols(n):
+    """Columns of a block over a side of ``n``: its largest divisor in
+    whole lane tiles up to ``_GROUPED_MAX_COLS``, else the side whole."""
+    for b in range(min(_GROUPED_MAX_COLS, n) // LANES * LANES, 0, -LANES):
+        if n % b == 0:
+            return b
+    return n
+
+
+def grouped_matmul_plan(p, c, o, groups, tm, w_out_in=True,
+                        dtype=jnp.bfloat16):
+    """Plan of ``y[rows of tile i] = x[rows of tile i] @ W[group(i)]``:
+    ``x (P, C)``, ``y (P, O)``, ``W (G, O, C)`` contracted over its last
+    side (``w_out_in``: the forward) or ``(G, C, O)`` over its middle
+    one (the gradient for ``x``).  Grid (row tiles, O blocks, C blocks),
+    the contraction innermost; scalar prefetch: ``tile_group
+    (tiles,)`` — held at the last used tile's group past ``used`` —
+    and ``used (1,)``."""
+    to, tc = _grouped_cols(o), _grouped_cols(c)
+    nj, nk = o // to, c // tc
+
+    def xmap(i, j, kk, tg, used):
+        on = i < used[0]
+        return (_isel(on, i, used[0] - 1), _isel(on, kk, nk - 1))
+
+    def wmap(i, j, kk, tg, used):
+        on = i < used[0]
+        jj, kc = _isel(on, j, nj - 1), _isel(on, kk, nk - 1)
+        return (tg[i], jj, kc) if w_out_in else (tg[i], kc, jj)
+
+    return {
+        "grid": (p // tm, nj, nk),
+        "num_scalar_prefetch": 2,
+        "in_specs": [pl.BlockSpec((tm, tc), xmap),
+                     pl.BlockSpec((None, to, tc) if w_out_in
+                                  else (None, tc, to), wmap)],
+        "in_shapes": [(p, c), (groups, o, c) if w_out_in
+                      else (groups, c, o)],
+        "out_specs": [pl.BlockSpec((tm, to),
+                                   lambda i, j, kk, tg, used: (i, j))],
+        "out_shapes": [(p, o)],
+        "scratch": [(tm, to)],
+        "dtypes": [dtype] * 3,
+        "tiles": [((tm, to), "float32")],
+    }
+
+
+def grouped_matmul_dw_plan(p, c, o, groups, tm, dtype=jnp.bfloat16):
+    """Plan of ``dW[g] = dy[rows of g].T @ x[rows of g]``, ``(G, O, C)``:
+    grid (O blocks, C blocks, row tiles), the rows innermost; a group's
+    block stays resident over its (consecutive) tiles and is written
+    once, after its last."""
+    to, tc = _grouped_cols(o), _grouped_cols(c)
+
+    def rows(side):
+        def index(j, kk, i, tg, used):
+            return (_imin(i, used[0] - 1), j if side == "o" else kk)
+        return index
+
+    return {
+        "grid": (o // to, c // tc, p // tm),
+        "num_scalar_prefetch": 2,
+        "in_specs": [pl.BlockSpec((tm, to), rows("o")),
+                     pl.BlockSpec((tm, tc), rows("c"))],
+        "in_shapes": [(p, o), (p, c)],
+        "out_specs": [pl.BlockSpec((None, to, tc),
+                                   lambda j, kk, i, tg, used:
+                                   (tg[i], j, kk))],
+        "out_shapes": [(groups, o, c)],
+        "scratch": [(to, tc)],
+        "dtypes": [dtype] * 3,
+        "tiles": [((to, tc), "float32")],
+    }
+
+
+def _grouped_matmul_kernel(tg_ref, used_ref, x_ref, w_ref, o_ref, acc_ref,
+                           *, nk, w_out_in):
+    i, kk = pl.program_id(0), pl.program_id(2)
+
+    @pl.when(kk == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    @pl.when(i < used_ref[0])
+    def _product():
+        acc_ref[:] += jax.lax.dot_general(
+            x_ref[:], w_ref[:],
+            (((1,), (1 if w_out_in else 0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(kk == nk - 1)
+    def _final():
+        o_ref[:] = acc_ref[:].astype(o_ref.dtype)
+
+
+def _grouped_matmul_dw_kernel(tg_ref, used_ref, dy_ref, x_ref, o_ref,
+                              acc_ref, *, tiles):
+    i = pl.program_id(2)
+    on = i < used_ref[0]
+    here = tg_ref[i]
+    first = jnp.logical_or(i == 0, tg_ref[jnp.maximum(i - 1, 0)] != here)
+    last = jnp.logical_or(i == used_ref[0] - 1,
+                          tg_ref[jnp.minimum(i + 1, tiles - 1)] != here)
+
+    @pl.when(jnp.logical_and(on, first))
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    @pl.when(on)
+    def _product():
+        acc_ref[:] += jax.lax.dot_general(
+            dy_ref[:], x_ref[:], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(jnp.logical_and(on, last))
+    def _final():
+        o_ref[:] = acc_ref[:].astype(o_ref.dtype)
+
+
+def _grouped_call(kernel, plan, tile_group, used, *operands):
+    n_in = len(plan["in_specs"])
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=plan["num_scalar_prefetch"],
+            grid=plan["grid"], in_specs=plan["in_specs"],
+            out_specs=plan["out_specs"],
+            scratch_shapes=[pltpu.VMEM(sh, jnp.float32)
+                            for sh in plan["scratch"]]),
+        out_shape=[jax.ShapeDtypeStruct(sh, t) for sh, t in zip(
+            plan["out_shapes"], plan["dtypes"][n_in:])],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name=kernel.func.__name__,
+        interpret=_interpret(),
+    )(tile_group, used, *operands)[0]
+
+
+def _grouped_product(x, w, tile_group, used, tm, w_out_in):
+    p, c = x.shape
+    groups, o = w.shape[0], w.shape[1 if w_out_in else 2]
+    plan = grouped_matmul_plan(p, c, o, groups, tm, w_out_in, x.dtype)
+    return _grouped_call(
+        functools.partial(_grouped_matmul_kernel, nk=plan["grid"][2],
+                          w_out_in=w_out_in),
+        plan, tile_group, used, x, w)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def grouped_matmul(x, w, tile_group, used, tm):
+    """``y (P, O)``: row tile ``i`` of ``x (P, C)`` times
+    ``w[tile_group[i]].T``, ``w (G, O, C)`` stored ``(out, in)`` like
+    FullyConnected's; tiles ``i >= used[0]`` come out zero.  Every group
+    owns at least one tile and a group's tiles are consecutive; rows
+    that pad a group's last tile are zero in ``x`` (and in the
+    cotangent), so they add nothing to the weight's gradient.  float32
+    accumulation, results in ``x``'s dtype."""
+    _count("grouped_matmul_fwd")
+    return _grouped_product(x, w.astype(x.dtype), tile_group, used, tm, True)
+
+
+def _grouped_matmul_fwd_rule(x, w, tile_group, used, tm):
+    return grouped_matmul(x, w, tile_group, used, tm), \
+        (x, w, tile_group, used)
+
+
+def _grouped_matmul_bwd_rule(tm, res, dy):
+    _count("grouped_matmul_bwd")
+    x, w, tile_group, used = res
+    dy = dy.astype(x.dtype)
+    dx = _grouped_product(dy, w.astype(x.dtype), tile_group, used, tm, False)
+    plan = grouped_matmul_dw_plan(x.shape[0], x.shape[1], w.shape[1],
+                                  w.shape[0], tm, x.dtype)
+    dw = _grouped_call(
+        functools.partial(_grouped_matmul_dw_kernel,
+                          tiles=plan["grid"][2]),
+        plan, tile_group, used, dy, x)
+    return dx, dw.astype(w.dtype), None, None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd_rule, _grouped_matmul_bwd_rule)
 
 
 # ---------------------------------------------------------------------------

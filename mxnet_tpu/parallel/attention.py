@@ -45,19 +45,25 @@ def _flash_eligible(q, k, causal, q_offset, kv_offset):
 
 
 def local_attention(q, k, v, causal=False, q_offset=0, kv_offset=0,
-                    scale=None, impl="auto", kv_len=None):
+                    scale=None, impl="auto", kv_len=None, window=None):
     """Softmax attention on local blocks.
 
     q: (B, Tq, H, D), k/v: (B, Tk, H, D).  Offsets give the global
     positions of the first query/key for causal masking across shards.
     ``kv_len`` masks out keys whose global position is >= kv_len —
     the padding mask for sequences padded up to a shard multiple.
+    ``window`` (with ``causal``): a query sees its last ``window`` keys,
+    itself among them — in the flash kernels and in the einsum form
+    alike.
 
     impl: "auto" uses the Pallas flash kernel on TPU when offsets are
     aligned and T divides into blocks (O(T) memory instead of the
     materialized (T, T) logits); "einsum"/"flash" force a path.
     """
     d = q.shape[-1]
+    if window is not None and not causal:
+        raise ValueError("an attention window needs causal=True: a query "
+                         "sees its last `window` keys, itself among them")
     k, v = _expand_kv_heads(q, k, v)
     if kv_len is not None and kv_len >= kv_offset + k.shape[1]:
         kv_len = None  # no padded keys in this block
@@ -72,7 +78,7 @@ def local_attention(q, k, v, causal=False, q_offset=0, kv_offset=0,
         fold = lambda a, t: jnp.transpose(a, (0, 2, 1, 3)).reshape(
             b * h, t, d)
         o = flash_attention(fold(q, tq), fold(k, tk), fold(v, tk),
-                            causal, scale)
+                            causal, scale, None, None, window)
         return jnp.transpose(o.reshape(b, h, tq, d), (0, 2, 1, 3))
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
@@ -81,6 +87,8 @@ def local_attention(q, k, v, causal=False, q_offset=0, kv_offset=0,
     if causal:
         qpos = q_offset + jnp.arange(q.shape[1])
         mask = qpos[:, None] >= kpos[None, :]
+        if window is not None:
+            mask = mask & (qpos[:, None] - kpos[None, :] < int(window))
     if kv_len is not None:
         valid = (kpos < kv_len)[None, :]
         mask = valid if mask is None else mask & valid
@@ -232,17 +240,29 @@ def _ring_attention_local(q, k, v, axis_name, causal, scale, kv_len=None):
     return jnp.transpose(out, (0, 2, 1, 3))  # (b, t_local, h, d)
 
 
+def _no_window_across_shards(window, what):
+    if window is not None:
+        raise NotImplementedError(
+            "%s has no attention window: its shards mask by the causal "
+            "rule alone, and ignoring window=%r would be another model"
+            % (what, window))
+
+
 def ring_attention(q, k, v, mesh=None, axis_name="sp", causal=False,
-                   scale=None):
+                   scale=None, window=None):
     """Ring attention over a sequence-sharded axis.
 
     Inputs (B, T, H, D) with T sharded over ``axis_name``; output has the
     same sharding.  Used directly or as the attention core of
-    sequence-parallel transformer layers."""
+    sequence-parallel transformer layers.  A ``window`` is served only
+    where the axis has one member (``local_attention``); across shards
+    it raises."""
     from .mesh import current_mesh
     mesh = mesh or current_mesh()
     if mesh is None or mesh.shape.get(axis_name, 1) == 1:
-        return local_attention(q, k, v, causal=causal, scale=scale)
+        return local_attention(q, k, v, causal=causal, scale=scale,
+                               window=window)
+    _no_window_across_shards(window, "ring_attention")
     sp = mesh.shape[axis_name]
     t_real = q.shape[1]
     home = _single_device_of(q)
@@ -278,13 +298,15 @@ def _ulysses_local(q, k, v, axis_name, causal, scale, kv_len=None):
 
 
 def ulysses_attention(q, k, v, mesh=None, axis_name="sp", causal=False,
-                      scale=None):
+                      scale=None, window=None):
     """DeepSpeed-Ulysses style sequence parallelism; requires
-    num_heads % sp == 0."""
+    num_heads % sp == 0.  A ``window`` as in :func:`ring_attention`."""
     from .mesh import current_mesh
     mesh = mesh or current_mesh()
     if mesh is None or mesh.shape.get(axis_name, 1) == 1:
-        return local_attention(q, k, v, causal=causal, scale=scale)
+        return local_attention(q, k, v, causal=causal, scale=scale,
+                               window=window)
+    _no_window_across_shards(window, "ulysses_attention")
     sp = mesh.shape[axis_name]
     if q.shape[2] % sp:
         raise ValueError(

@@ -22,10 +22,13 @@ import re
 
 __all__ = ["FWD_SCOPE", "LOSS_SCOPE", "UPDATE_SCOPE", "CODEC_SCOPE",
            "FLATTEN_SCOPE", "UNFLATTEN_SCOPE", "SWEEP_SCOPE",
-           "COLLECTIVE_PREFIX", "LOOP_SCOPE", "EXIT_SCOPE", "FWD", "BWD",
-           "UPDATE", "COLLECTIVE", "CONTROL", "OTHER", "LOOP", "EXIT",
+           "COLLECTIVE_PREFIX", "LOOP_SCOPE", "EXIT_SCOPE", "MOE_SCOPE",
+           "MOE_EXPERTS_SCOPE", "ATTN_WINDOW_SCOPE", "ATTN_FULL_SCOPE",
+           "FWD", "BWD", "UPDATE", "COLLECTIVE", "CONTROL", "OTHER", "LOOP",
+           "EXIT", "ROUTE", "EXPERTS", "ATTN_WINDOW", "ATTN_FULL",
            "phase_of", "instruction_phases", "loop_part_of",
-           "instruction_loop_parts", "register_program", "program_hlo",
+           "instruction_loop_parts", "block_part_of",
+           "instruction_block_parts", "register_program", "program_hlo",
            "program_names"]
 
 FWD_SCOPE, LOSS_SCOPE = "mx_fwd", "mx_loss"
@@ -37,12 +40,23 @@ COLLECTIVE_PREFIX = "mx_coll:"
 # times (gluon.contrib.transformer.LoopedLM): one pass of the stack, and
 # one exit's norm -> gate and projection -> cross-entropy
 LOOP_SCOPE, EXIT_SCOPE = "mx_loop", "mx_exit"
+# inside mx_fwd, in a block of sparse-expert layers under attention of
+# two kinds (gluon.contrib.transformer.MoELM): a layer's expert part —
+# router, top-k, dispatch, the experts' products, combine — with the
+# grouped products and their activation in an inner scope of their own
+# (parallel/moe.py routed_experts); rotary and attention of a layer whose
+# queries see a window of keys, and of one whose queries see every key
+# before them
+MOE_SCOPE, MOE_EXPERTS_SCOPE = "mx_moe", "mx_moe_experts"
+ATTN_WINDOW_SCOPE, ATTN_FULL_SCOPE = "mx_attn_window", "mx_attn_full"
 # what jax writes into the name stack of a forward that is run again in
 # the backward pass (jax.checkpoint)
 REMAT_MARK = "rematted_computation"
 FWD, BWD, UPDATE, COLLECTIVE, CONTROL, OTHER = \
     "fwd", "bwd", "update", "collective", "control", "other"
 LOOP, EXIT = "loop", "exit"
+ROUTE, EXPERTS = "route", "experts"
+ATTN_WINDOW, ATTN_FULL = "attn_window", "attn_full"
 
 
 def phase_of(op_name):
@@ -68,6 +82,21 @@ def loop_part_of(op_name):
         return None, False
     part = EXIT if EXIT_SCOPE in op_name else \
         LOOP if LOOP_SCOPE in op_name else None
+    return part, REMAT_MARK in op_name
+
+
+def block_part_of(op_name):
+    """``(part, recomputed)`` of an HLO ``op_name`` in a block of expert
+    layers under two kinds of attention: ``part`` is :data:`EXPERTS`
+    (scope ``mx_moe_experts``: the inner scope wins), :data:`ROUTE`
+    (``mx_moe`` outside it), :data:`ATTN_WINDOW`, :data:`ATTN_FULL` or
+    None; ``recomputed`` as in :func:`loop_part_of`."""
+    if not op_name:
+        return None, False
+    part = EXPERTS if MOE_EXPERTS_SCOPE in op_name else \
+        ROUTE if MOE_SCOPE in op_name else \
+        ATTN_WINDOW if ATTN_WINDOW_SCOPE in op_name else \
+        ATTN_FULL if ATTN_FULL_SCOPE in op_name else None
     return part, REMAT_MARK in op_name
 
 
@@ -181,6 +210,15 @@ def instruction_loop_parts(hlo_text):
     ``recomputed`` whether the instruction is a forward run again in the
     backward pass (:func:`loop_part_of`); the same inheritance."""
     return _classify(hlo_text, loop_part_of, (None, False), (None, False))
+
+
+def instruction_block_parts(hlo_text):
+    """``{instruction name: (part, recomputed)}`` beside
+    :func:`instruction_loop_parts`, for a program whose layers route
+    tokens to experts under window and full attention: ``part`` is
+    ``route``, ``experts``, ``attn_window``, ``attn_full`` or None
+    (:func:`block_part_of`); the same inheritance."""
+    return _classify(hlo_text, block_part_of, (None, False), (None, False))
 
 
 # name -> [jitted fn, abstract args, context factory or None, HLO text]

@@ -296,3 +296,47 @@ def test_schedule_hyperparams_vocabulary():
     assert "lr" in SCHEDULE_HYPERPARAMS
     assert "use_clip" not in SCHEDULE_HYPERPARAMS
     assert "eps" not in SCHEDULE_HYPERPARAMS
+
+
+def test_grouped_product_is_in_the_catalog_at_a_representative_table(
+        monkeypatch):
+    """The grouped product's index maps read a prefetched table (row
+    tile -> group), so its three instantiations are analysed at one
+    table — an empty group and unused tiles in it — with jit poisoned:
+    forward and the rows' gradient write every output tile once a
+    contraction step, the weights' gradient stays on a group's block
+    over that group's run of tiles."""
+    from mxnet_tpu.analysis.kern import grouped_matmul_reports
+    _poison_jit(monkeypatch)
+    reports = grouped_matmul_reports()
+    assert [r["name"] for r in reports] == [
+        "_grouped_matmul_kernel", "_grouped_matmul_kernel",
+        "_grouped_matmul_dw_kernel"]
+    assert run_kern_checkers(reports) == []
+    fwd, _dx, dw = reports
+    x, w, y = fwd["operands"][1:]
+    # tiles past the 7 used ones hold at the last used tile's blocks
+    assert x["index"][7 * 3 - 1] == x["index"][-1] == [6, 2]
+    assert w["index"][-1] == [3, 0, 2]
+    assert y["index"][-1] == [13, 0]       # 3840 / 384 + 4 groups: 14 tiles
+    out = dw["operands"][-1]
+    assert out["revisit"] == "runs"
+    assert [i[0] for i in out["index"][:14]] \
+        == [0, 0, 0, 1, 2, 2] + [3] * 8
+    for r in reports:
+        assert r["vmem"]["bytes_per_instance"] <= r["vmem"]["budget"]
+
+
+def test_a_run_of_revisits_may_not_come_back_to_a_block():
+    """``revisit: runs`` admits runs of any length and refuses a block
+    the grid leaves and returns to (its accumulation would be written
+    back in between)."""
+    op = {"name": "dw", "role": "out", "dtype": "float32",
+          "block": [None, 8, 128], "shape": [3, 8, 128], "revisit": "runs",
+          "index": [[0, 0, 0], [0, 0, 0], [1, 0, 0], [2, 0, 0], [2, 0, 0]]}
+    assert coverage_problems(op, [5]) == []
+    back = dict(op, index=[[0, 0, 0], [1, 0, 0], [0, 0, 0], [2, 0, 0],
+                           [2, 0, 0]])
+    assert any("revisited" in p for p in coverage_problems(back, [5]))
+    gap = dict(op, index=[[0, 0, 0]] * 3 + [[2, 0, 0]] * 2)
+    assert any("never written" in p for p in coverage_problems(gap, [5]))
