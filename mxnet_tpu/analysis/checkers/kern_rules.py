@@ -138,6 +138,24 @@ def coverage_problems(op, grid):
             "index map escapes the %s-block output (first out-of-range "
             "block %s)" % ("x".join(map(str, blocks)), oob[0]))
     missing = sorted(expected - set(counts))
+    if op.get("revisit") == "used":
+        # a data-dependent PREFIX of the blocks is written, once each
+        # and in order, and the grid holds at the last of them for the
+        # steps past it (which write nothing: the block is not written
+        # back until the grid ends); the blocks after are left as they
+        # are, and whoever reads the output skips them by the same count
+        order = sorted(expected)
+        seen = [t for i, t in enumerate(table) if i == 0 or t != table[i - 1]]
+        if seen != order[:len(seen)]:
+            problems.append(
+                "the written blocks are not a prefix in grid order "
+                "(first blocks %s)" % (seen[:3],))
+        early = [t for t in seen[:-1] if counts[t] != 1]
+        if early:
+            problems.append(
+                "block %s is written %d times before the grid holds at "
+                "its last block" % (early[0], counts[early[0]]))
+        return problems
     if missing:
         problems.append(
             "%d of %d output blocks are never written (first gap %s)"

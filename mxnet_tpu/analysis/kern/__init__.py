@@ -22,12 +22,13 @@ from __future__ import annotations
 
 from .catalog import (flash_cell_reports, flash_reports,
                       grouped_matmul_reports, kernel_reports,
-                      layernorm_reports, scale_bias_relu_reports,
+                      layernorm_reports, moe_mover_reports,
+                      scale_bias_relu_reports,
                       softmax_reports, sweep_reports)
 
 __all__ = ["kernel_reports", "sweep_reports", "flash_reports",
            "flash_cell_reports", "grouped_matmul_reports",
-           "scale_bias_relu_reports", "layernorm_reports",
+           "moe_mover_reports", "scale_bias_relu_reports", "layernorm_reports",
            "softmax_reports", "sweep_shard_verdict"]
 
 

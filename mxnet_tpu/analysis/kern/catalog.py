@@ -39,7 +39,7 @@ import itertools
 
 __all__ = ["kernel_reports", "sweep_reports", "flash_reports",
            "flash_cell_reports", "grouped_matmul_reports",
-           "scale_bias_relu_reports", "layernorm_reports",
+           "moe_mover_reports", "scale_bias_relu_reports", "layernorm_reports",
            "softmax_reports", "ORIGIN"]
 
 ORIGIN = "mxnet_tpu/ops/pallas_kernels.py"
@@ -67,6 +67,11 @@ def _eval_index(spec, grid, n_prefetch, prefetch=None):
 
 def _operand(name, role, spec, shape, grid, n_prefetch,
              dtype="float32", prefetch=None):
+    if spec.block_shape is None:
+        # left in HBM whole (``memory_space=pl.ANY``): the kernel
+        # fetches from it by DMA, no block of it lives in VMEM
+        return {"name": name, "role": role, "dtype": dtype, "block": None,
+                "shape": [int(s) for s in shape], "index": None}
     return {"name": name, "role": role, "dtype": dtype,
             "block": [None if b is None else int(b)
                       for b in spec.block_shape],
@@ -298,6 +303,72 @@ def grouped_matmul_reports(rows=3840, c=2304, o=896, groups=4, tm=None,
     return reports
 
 
+# -- row movers (sparse experts' dispatch and combine) ----------------------
+
+def moe_mover_reports(tokens=1024, top_k=8, units=2304, groups=4, tm=None,
+                      dtype="bfloat16"):
+    """The expert layer's row movers at the benchmark's width (rows of
+    2304 bfloat16) over a representative table: 1024 tokens, 8 slots
+    each, a buffer of ``8192 / tm + 4`` tiles of which 7 are used, one of
+    them an EMPTY group's tile with no valid row.  The buffer-side mover
+    plain (dispatch) and with a factor a row and the row dots (the
+    combine's transpose), the token-side mover, and the pass that makes
+    a source's rows fetchable (here of the sum of two buffers, as
+    dispatch's transpose asks).  A source the kernels fetch from by row
+    DMA is an un-blocked HBM operand; the landing buffers are scratch.
+    Outputs blocked by buffer tile hold at the last used tile."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+    tm = tm or pk.GROUPED_TILE_ROWS
+    slots = tokens * top_k
+    tiles = -(-slots // tm) + groups
+    p = tiles * tm
+    used = [7]
+    counts = ([tm, tm, 100, 0, tm, 50, tm] + [0] * tiles)[:tiles]
+    tables = {"transport": "scalar_prefetch",
+              "names": ["used", "counts", "src_token"]}
+    tail = {"logical_elems": used[0] * tm * units,
+            "padded_elems": p * units, "masked": True,
+            "how": "a tile's rows past its count are written as zeros; "
+                   "the tiles past `used` are not written at all, and "
+                   "every reader (the grouped product, the token-side "
+                   "mover) skips them by the same `used`"}
+    family = "routed_experts"
+    reports = []
+    for scaled, dotted, ins, outs in (
+            (False, False, ("x_words",), ("rows",)),
+            (True, True, ("g_words", "scale", "y"), ("gy", "dots"))):
+        reports.append(_report(
+            "_moe_rows_kernel", family,
+            pk.moe_rows_plan(p, tokens, units, tm, jnp.dtype(dtype),
+                             scaled, dotted),
+            ins, outs, hyper=tables, tail=tail,
+            python_constants=[
+                {"name": "scaled", "detail": "structural branch"},
+                {"name": "dotted", "detail": "structural branch"}],
+            prefetch=(used, counts, None), revisit="used"))
+    bt = pk._pick_block(tokens, pk._SLOT_TILE_TOKENS)
+    reports.append(_report(
+        "_moe_slots_kernel", family,
+        pk.moe_slots_plan(tokens, top_k, p, units, bt, jnp.dtype(dtype)),
+        ("y_words", "held", "w"), ("out",),
+        hyper={"transport": "scalar_prefetch", "names": ["held", "fetch"]},
+        tail={"logical_elems": tokens * units,
+              "padded_elems": tokens * units, "masked": True,
+              "how": "no padding: every token's row is written; a slot "
+                     "that is not held is never fetched and is selected "
+                     "away, not multiplied away"},
+        prefetch=(None, None)))
+    reports.append(_report(
+        "_moe_words_kernel", family,
+        pk.moe_words_plan(p, units, tm, jnp.dtype(dtype), sources=2),
+        ("g_gate", "g_up"), ("g_words",),
+        hyper={"transport": "scalar_prefetch", "names": ["used"]},
+        tail=tail, prefetch=(used,), revisit="used"))
+    return reports
+
+
 # -- inference BatchNorm+ReLU epilogue -------------------------------------
 
 def scale_bias_relu_reports(n=16 * 7 * 7, c=2048, block=1024):
@@ -379,6 +450,6 @@ def kernel_reports():
     """Every in-tree kernel family's reports — the catalog
     ``tools/lint.py --kern`` / ``--all`` judge."""
     return (sweep_reports() + flash_reports() + flash_cell_reports()
-            + grouped_matmul_reports()
+            + grouped_matmul_reports() + moe_mover_reports()
             + scale_bias_relu_reports() + layernorm_reports()
             + softmax_reports())

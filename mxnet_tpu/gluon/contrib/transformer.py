@@ -578,14 +578,27 @@ class MoELM(HybridBlock):
         """Rows a step of ``tokens`` tokens sends to this block's held
         experts, a layer, in expectation under a symmetric router —
         exported as ``mxnet_moe_expected_rows`` (what the experts'
-        products are sized against)."""
+        products are sized against).  Beside it the two static shapes
+        those rows lie in, of which dispatch and combine fetch the held
+        rows alone: ``mxnet_moe_buffer_rows`` (the row buffer, sized for
+        every assignment being held) and ``mxnet_moe_slot_rows`` (a row
+        a (token, slot) assignment)."""
         from ... import telemetry
+        from ...ops.pallas_kernels import GROUPED_TILE_ROWS as tm
         c = self._config
-        rows = tokens * c["top_k"] * c["held"][1] / self._num_routed
+        slots = tokens * c["top_k"]
+        rows = slots * c["held"][1] / self._num_routed
         telemetry.gauge("mxnet_moe_expected_rows", "rows a step's tokens "
                         "send to the held experts of one layer of the "
                         "newest MoELM, in expectation under a symmetric "
                         "router").set(rows)
+        telemetry.gauge("mxnet_moe_buffer_rows", "rows of the expert "
+                        "layer's row buffer in the newest MoELM: whole "
+                        "tiles for every assignment being held").set(
+                            (-(-slots // tm) + c["held"][1]) * tm)
+        telemetry.gauge("mxnet_moe_slot_rows", "(token, slot) "
+                        "assignments a step of the newest MoELM routes, "
+                        "held or not").set(slots)
         return rows
 
     def hybrid_forward(self, F, tokens, **params):

@@ -26,6 +26,15 @@ when-does-it-fuse table and the ``MXNET_PALLAS_*`` knobs):
   is named by a scalar-prefetched table, tile -> group; forward, the
   rows' gradient and the weights' gradient (a group's block resident
   over its run of tiles).  Tiles past the used ones run nothing.
+- ``moe_rows`` / ``moe_slots`` — the sparse experts' dispatch and
+  combine as row movers: one DMA for each row that is HELD (a valid row
+  of a used tile; a held slot of a token), none for the rest of the
+  static worst-case shapes an XLA gather would copy whole; the
+  token-side one sums a token's rows in float32 as they land, the
+  buffer-side one can scale a row and dot it with the matching row of a
+  second buffer (the combine's transpose).  ``_moe_words_kernel`` lays a
+  source out as 32-bit words a row first, since Mosaic slices a DMA
+  along whole tiles only.
 - ``fused_scale_bias_relu`` — the inference BatchNorm + ReLU epilogue as
   one VMEM-resident pass (reference: the BN+Activation fusion MKL-DNN
   does on CPU, nn/mkldnn/mkldnn_base-inl.h).  Call sites: the
@@ -978,6 +987,390 @@ def _grouped_matmul_bwd_rule(tm, res, dy):
 
 
 grouped_matmul.defvjp(_grouped_matmul_fwd_rule, _grouped_matmul_bwd_rule)
+
+
+# ---------------------------------------------------------------------------
+# Row movers (sparse experts' dispatch and combine)
+# ---------------------------------------------------------------------------
+# Dispatch and combine fetch ROWS: a token's state into its row of the
+# buffer, a row's output back to its token.  The buffer has a static
+# worst-case size (``parallel/moe.py`` ``_layout``) of which a chip that
+# holds a quarter of the experts fills a quarter, and an XLA gather moves
+# the whole static shape; these two kernels start one DMA a row that is
+# HELD and none for the rest.  Mosaic slices a DMA only along whole tiles
+# of the last two dimensions, so a source is handed over as 32-bit words
+# ``(rows, 1, W)`` (``_row_words``: a bfloat16 row's two halves packed
+# pairwise, a float32 row as it is): the row is then a LEADING index, its
+# words lie together in HBM, and the kernel takes the halves apart again
+# with a shift and a mask.
+
+_ROW_DTYPES = ("bfloat16", "float32")
+
+
+def row_words_ok(units, dtype):
+    """Whether a ``(rows, units)`` array of ``dtype`` can be moved row by
+    row: bfloat16 or float32, each half of the row whole lane tiles."""
+    dtype = jnp.dtype(dtype)
+    return dtype.name in _ROW_DTYPES \
+        and units % (LANES * (4 // dtype.itemsize)) == 0
+
+
+def _row_word_count(units, dtype):
+    return units * jnp.dtype(dtype).itemsize // 4
+
+
+def _half_cols(half, chunk, words):
+    """The columns of lane chunk ``chunk`` of a row's half ``half``."""
+    return pl.ds(pl.multiple_of(half * words + chunk * LANES, LANES), LANES)
+
+
+def _mover_call(kernel, plan, prefetch, operands):
+    """One of the movers' kernels over its plan; the plan's landing
+    buffer, where it has one, comes with a DMA semaphore."""
+    n_in = len(plan["in_specs"])
+    scratch = [pltpu.VMEM(shape, jnp.dtype(kind))
+               for shape, kind in plan.get("tiles", ())]
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=plan["num_scalar_prefetch"],
+            grid=plan["grid"], in_specs=plan["in_specs"],
+            out_specs=plan["out_specs"],
+            scratch_shapes=scratch + [pltpu.SemaphoreType.DMA(())]
+            if scratch else []),
+        out_shape=[jax.ShapeDtypeStruct(sh, d) for sh, d in zip(
+            plan["out_shapes"], plan["dtypes"][n_in:])],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name=kernel.func.__name__,
+        interpret=_interpret(),
+    )(*prefetch, *operands)
+
+
+_WORD_TILE_ROWS = 256
+
+
+def moe_words_plan(rows, units, tr, dtype=jnp.bfloat16, sources=1):
+    """Plan of the pass that makes rows fetchable: grid over the ``rows /
+    tr`` row tiles, scalar prefetch ``used (1,)``; ``sources`` arrays
+    ``(rows, units)`` in ``(tr, units)`` blocks, the words of their SUM
+    out in ``(tr, 1, W)`` blocks, all held at the last used tile past
+    ``used``."""
+    words = _row_word_count(units, dtype)
+
+    def tile(rank):
+        return lambda i, used: (_imin(i, used[0] - 1),) + (0,) * (rank - 1)
+
+    return {
+        "grid": (rows // tr,),
+        "num_scalar_prefetch": 1,
+        "in_specs": [pl.BlockSpec((tr, units), tile(2))] * sources,
+        "in_shapes": [(rows, units)] * sources,
+        "out_specs": [pl.BlockSpec((tr, 1, words), tile(3))],
+        "out_shapes": [(rows, 1, words)],
+        "dtypes": [dtype] * sources + [jnp.uint32],
+    }
+
+
+def _moe_words_kernel(used_ref, *refs, dtype):
+    *x_refs, o_ref = refs
+    words = o_ref.shape[-1]
+
+    @pl.when(pl.program_id(0) < used_ref[0])
+    def _tile():
+        def chunk(c, carry):
+            def bits(h):
+                cols = _half_cols(h, c, words)
+                v = x_refs[0][:, cols].astype(jnp.float32)
+                for x_ref in x_refs[1:]:
+                    # the sum XLA would make in the rows' own dtype: exact
+                    # in float32, rounded once
+                    v = (v + x_ref[:, cols].astype(jnp.float32)) \
+                        .astype(dtype).astype(jnp.float32)
+                return pltpu.bitcast(v, jnp.uint32)
+            word = bits(0)
+            if jnp.dtype(dtype).itemsize == 2:
+                # a bfloat16's bits are its float32's upper half
+                word = (word >> 16) | bits(1)
+            o_ref[:, 0, _half_cols(0, c, words)] = word
+            return carry
+        jax.lax.fori_loop(0, words // LANES, chunk, 0)
+
+
+def _row_words(xs, used=None, tr=None):
+    """``(rows, 1, W)`` uint32, the rows of the SUM of the arrays ``xs``
+    (a tuple, each ``(rows, U)``; one array is itself) as a row DMA can
+    fetch them: a float32 row's bits, or word ``j`` of a bfloat16 row
+    its elements ``j`` (low half) and ``j + W`` (high); ``used (1,)``:
+    only the first ``used[0]`` tiles of ``tr`` rows are read and written
+    (all of them without it)."""
+    rows, units = xs[0].shape
+    dtype = xs[0].dtype
+    tr = tr or _pick_block(rows, _WORD_TILE_ROWS)
+    if used is None:
+        used = jnp.full((1,), rows // tr, jnp.int32)
+    return _mover_call(
+        functools.partial(_moe_words_kernel, dtype=dtype),
+        moe_words_plan(rows, units, tr, dtype, len(xs)), (used,),
+        [x.astype(dtype) for x in xs])[0]
+
+
+def _word_values(words, dtype):
+    """The float32 values a block of words holds, one array a half."""
+    if jnp.dtype(dtype).itemsize == 4:
+        return [pltpu.bitcast(words, jnp.float32)]
+    return [pltpu.bitcast(words << 16, jnp.float32),
+            pltpu.bitcast(words & jnp.uint32(0xFFFF0000), jnp.float32)]
+
+
+_DMA_UNROLL = 8
+
+
+def _each_row(n, do):
+    """``do(r)`` for ``r`` in ``range(n)``, ``n`` a traced scalar: whole
+    groups of ``_DMA_UNROLL`` unrolled (a DMA's start or wait is a dozen
+    scalar instructions; the loop's own bookkeeping would double them),
+    then the rest one by one."""
+    groups = n // _DMA_UNROLL
+
+    def group(i, carry):
+        for u in range(_DMA_UNROLL):
+            do(i * _DMA_UNROLL + u)
+        return carry
+
+    def one(r, carry):
+        do(r)
+        return carry
+
+    jax.lax.fori_loop(0, groups, group, 0)
+    jax.lax.fori_loop(groups * _DMA_UNROLL, n, one, 0)
+
+
+def _landed_rows(buf, row0, rows, chunk):
+    """Lane chunk ``chunk`` of ``rows`` consecutive rows from ``row0`` on
+    of a landing buffer ``(n, 1, W)``: its rows lie ``W / 128`` sublanes
+    apart, so a chunk of many rows is one strided load."""
+    per = buf.shape[-1] // LANES
+    flat = buf.reshape(buf.shape[0] * per, LANES)
+    return flat[pl.ds(row0 * per + chunk, rows, stride=per), :]
+
+
+def moe_rows_plan(p, t, units, tm, dtype=jnp.bfloat16, scaled=False,
+                  dotted=False):
+    """Plan of the buffer-side mover: grid over the ``p / tm`` row tiles;
+    scalar prefetch ``used (1,)``, ``counts (tiles,)`` — a tile's valid
+    rows are its first ``counts[i]`` — and ``src_token (p,)``; the source
+    ``(t, 1, W)`` words stay in HBM.  ``scaled``: a float32 factor a row,
+    ``(p, 1)``; ``dotted``: the matching tile of ``y (p, units)`` comes
+    in and the float32 row dots ``(p, 1)`` go out.  Every blocked
+    operand holds at the last used tile past ``used``, so an unused tile
+    is neither fetched nor written."""
+    words = _row_word_count(units, dtype)
+
+    def tile(i, used, counts, src):
+        return (_imin(i, used[0] - 1), 0)
+
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+    in_shapes = [(t, 1, words)]
+    dtypes = [jnp.uint32]
+    if scaled:
+        in_specs.append(pl.BlockSpec((tm, 1), tile))
+        in_shapes.append((p, 1))
+        dtypes.append(jnp.float32)
+    if dotted:
+        in_specs.append(pl.BlockSpec((tm, units), tile))
+        in_shapes.append((p, units))
+        dtypes.append(dtype)
+    out_specs = [pl.BlockSpec((tm, units), tile)]
+    out_shapes = [(p, units)]
+    dtypes.append(dtype)
+    if dotted:
+        out_specs.append(pl.BlockSpec((tm, 1), tile))
+        out_shapes.append((p, 1))
+        dtypes.append(jnp.float32)
+    return {
+        "grid": (p // tm,),
+        "num_scalar_prefetch": 3,
+        "in_specs": in_specs, "in_shapes": in_shapes,
+        "out_specs": out_specs, "out_shapes": out_shapes,
+        "tiles": [((tm, 1, words), "uint32")],      # the landing buffer
+        "dtypes": dtypes,
+    }
+
+
+def _moe_rows_kernel(used_ref, counts_ref, src_ref, x_hbm, *refs, tm,
+                     dtype, scaled, dotted):
+    refs = list(refs)
+    scale_ref = refs.pop(0) if scaled else None
+    y_ref = refs.pop(0) if dotted else None
+    o_ref = refs.pop(0)
+    dot_ref = refs.pop(0) if dotted else None
+    buf, sem = refs
+    i = pl.program_id(0)
+    words = buf.shape[-1]
+
+    @pl.when(i < used_ref[0])
+    def _tile():
+        n = counts_ref[i]
+
+        _each_row(n, lambda r: pltpu.make_async_copy(
+            x_hbm.at[src_ref[i * tm + r]], buf.at[r], sem).start())
+        _each_row(n, lambda r: pltpu.make_async_copy(
+            x_hbm.at[0], buf.at[r], sem).wait())
+        on = jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0) < n
+
+        def chunk(c, dot):
+            vals = _word_values(_landed_rows(buf, 0, tm, c), dtype)
+            for h, v in enumerate(vals):
+                # a row past the valid ones was never written: selected
+                # away, not multiplied away
+                v = jnp.where(on, v, 0.0)
+                cols = _half_cols(h, c, words)
+                if dotted:
+                    dot += jnp.sum(v * y_ref[:, cols].astype(jnp.float32),
+                                   axis=1, keepdims=True)
+                if scaled:
+                    v = v * scale_ref[:]
+                o_ref[:, cols] = v.astype(o_ref.dtype)
+            return dot
+
+        dot = jax.lax.fori_loop(0, words // LANES, chunk,
+                                jnp.zeros((tm, 1), jnp.float32))
+        if dotted:
+            dot_ref[:] = dot
+
+
+def moe_rows(x, src_token, counts, used, tm, scale=None, y=None):
+    """``rows (P, U)``: row ``r`` of tile ``i`` is ``x[src_token[i tm +
+    r]]`` for ``r < counts[i]`` and zero for the tile's other rows — times
+    ``scale[i tm + r]`` (float32, one a row) where that is given, rounded
+    once.  With ``y (P, U)`` also ``dots (P,)``: the float32 dot of the
+    UNSCALED row with ``y``'s row.  Tiles from ``used[0]`` on are not
+    written at all: whoever reads the result skips them by ``used``, as
+    the grouped product does."""
+    _count("moe_rows")
+    return _moe_rows(x, src_token, counts, used, tm, scale, y)
+
+
+# jitted, so that a program's layers — the same shapes each — trace and
+# lower a mover ONCE: a kernel's body is some hundred equations, a step
+# holds dozens of instances, and a restart pays for each (set-up time)
+@functools.partial(jax.jit, static_argnums=(4,))
+def _moe_rows(x, src_token, counts, used, tm, scale, y):
+    p, (t, units) = src_token.shape[0], x.shape
+    plan = moe_rows_plan(p, t, units, tm, x.dtype, scale is not None,
+                         y is not None)
+    operands = [_row_words((x,))]
+    if scale is not None:
+        operands.append(scale.astype(jnp.float32).reshape(p, 1))
+    if y is not None:
+        operands.append(y.astype(x.dtype))
+    out = _mover_call(
+        functools.partial(_moe_rows_kernel, tm=tm, dtype=x.dtype,
+                          scaled=scale is not None, dotted=y is not None),
+        plan, (used, counts, src_token), operands)
+    return out[0] if y is None else (out[0], out[1].reshape(p))
+
+
+_SLOT_TILE_TOKENS = 128
+
+
+def moe_slots_plan(t, k, p, units, bt, dtype=jnp.bfloat16):
+    """Plan of the token-side mover: grid over the ``t / bt`` token
+    tiles; scalar prefetch ``held (tiles,)``, how many of a tile's ``bt
+    k`` assignments are held, and ``fetch (t k,)``, a tile's held
+    assignments first, each its row of the buffer and the place its row
+    lands in (``row << bits | place``); the rows ``(p, 1, W)`` words
+    stay in HBM; ``dst`` (-1 where a slot is not held) and the float32
+    weights come in as ``(bt, k)`` blocks, the tokens go out as ``(bt,
+    units)``.  The landing buffer is slot-major, ``(k bt, 1, W)``."""
+    words = _row_word_count(units, dtype)
+
+    def tile(i, held, fetch):
+        return (i, 0)
+
+    return {
+        "grid": (t // bt,),
+        "num_scalar_prefetch": 2,
+        "in_specs": [pl.BlockSpec(memory_space=pl.ANY),
+                     pl.BlockSpec((bt, k), tile),
+                     pl.BlockSpec((bt, k), tile)],
+        "in_shapes": [(p, 1, words), (t, k), (t, k)],
+        "out_specs": [pl.BlockSpec((bt, units), tile)],
+        "out_shapes": [(t, units)],
+        "tiles": [((k * bt, 1, words), "uint32")],  # the landing buffer
+        "dtypes": [jnp.uint32, jnp.int32, jnp.float32, dtype],
+    }
+
+
+def _moe_slots_kernel(held_ref, fetch_ref, y_hbm, dst_ref, w_ref, o_ref,
+                      buf, sem, *, bt, k, dtype):
+    i = pl.program_id(0)
+    words = buf.shape[-1]
+    bits = (bt * k - 1).bit_length()
+
+    def copy(q):
+        code = fetch_ref[i * bt * k + q]
+        return pltpu.make_async_copy(
+            y_hbm.at[code >> bits], buf.at[code & ((1 << bits) - 1)], sem)
+
+    _each_row(held_ref[i], lambda q: copy(q).start())
+    _each_row(held_ref[i], lambda q: copy(q).wait())
+    halves = 4 // jnp.dtype(dtype).itemsize
+
+    def chunk(c, carry):
+        acc = [jnp.zeros((bt, LANES), jnp.float32) for _ in range(halves)]
+        for s in range(k):
+            on = dst_ref[:, s:s + 1] >= 0
+            ws = w_ref[:, s:s + 1]
+            vals = _word_values(_landed_rows(buf, s * bt, bt, c), dtype)
+            for h, v in enumerate(vals):
+                # a slot that is not held was never fetched: selected
+                # away, not multiplied away
+                acc[h] = acc[h] + jnp.where(on, ws * v, 0.0)
+        for h, a in enumerate(acc):
+            o_ref[:, _half_cols(h, c, words)] = a.astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, words // LANES, chunk, 0)
+
+
+def moe_slots(ys, dst, w, is_held, used, tm):
+    """``(T, U)``: token ``t`` gets ``sum_s w[t, s] y[dst[t, s]]`` over
+    its HELD slots ``s`` in their order, summed in float32 and rounded
+    once; a slot that is not held is neither fetched nor added.  ``y
+    (P, U)`` is the sum of the arrays ``ys`` (a tuple), of which only
+    the first ``used[0]`` tiles of ``tm`` rows are read (every held
+    slot's row lies in one); ``dst``, ``w`` (float32), ``is_held``:
+    ``(T, k)``."""
+    _count("moe_slots")
+    return _moe_slots(tuple(ys), dst, w, is_held, used, tm)
+
+
+@functools.partial(jax.jit, static_argnums=(5,))
+def _moe_slots(ys, dst, w, is_held, used, tm):
+    (p, units), (t, k), dtype = ys[0].shape, dst.shape, ys[0].dtype
+    bt = _pick_block(t, _SLOT_TILE_TOKENS)
+    bits = (bt * k - 1).bit_length()
+    if p >= 1 << (31 - bits):
+        raise ValueError("a buffer of %d rows is too long for tiles of %d "
+                         "tokens with %d slots each" % (p, bt, k))
+    rows = jnp.where(is_held, dst, -1).astype(jnp.int32)
+    # a tile's held assignments first, in their (token, slot) order: the
+    # q-th of them is the first whose running count passes q
+    flat = rows.reshape(t // bt, bt * k)
+    seen = jnp.cumsum(flat >= 0, axis=1, dtype=jnp.int32)
+    order = jnp.minimum(jnp.sum(
+        seen[:, None, :] <= jnp.arange(bt * k, dtype=jnp.int32)[:, None],
+        axis=-1, dtype=jnp.int32), bt * k - 1)
+    place = (order % k) * bt + order // k
+    fetch = (jnp.take_along_axis(flat, order, axis=1) << bits) | place
+    held = seen[:, -1]
+    return _mover_call(
+        functools.partial(_moe_slots_kernel, bt=bt, k=k, dtype=dtype),
+        moe_slots_plan(t, k, p, units, bt, dtype),
+        (held, fetch.reshape(t * k)),
+        [_row_words(ys, used, tm), rows, w.astype(jnp.float32)])[0]
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,7 @@
 """Expert parallelism (MoE): the switch layer over the "ep" mesh axis,
-and :func:`routed_experts`, one chip's share of a top-k expert layer.
+and :func:`routed_experts`, one chip's share of a top-k expert layer
+(router, a row buffer sorted by expert, grouped products between a
+dispatch and a combine that move only the rows this chip holds).
 
 The reference has no mixture-of-experts (SURVEY.md §2.14).  This is the
 TPU-native switch-routing layer: experts are sharded over "ep", tokens
@@ -57,8 +59,9 @@ def _layout(experts, held, tm):
     the worst case, every assignment held: ``ceil(T k / tm) + count``
     tiles.  Returns int32 arrays: ``src (P,)`` the flat assignment a
     row holds, ``valid (P,)``, ``dst (T, k)`` an assignment's row (0
-    where it is not held), ``is_held (T, k)``, ``tile_group (tiles,)``
-    and ``used (1,)``."""
+    where it is not held), ``is_held (T, k)``, ``tile_group (tiles,)``,
+    ``used (1,)`` and ``counts (tiles,)``: a tile's valid rows are its
+    first ``counts[i]`` (none past the used tiles)."""
     first, count = held
     t, k = experts.shape
     a = t * k
@@ -85,58 +88,114 @@ def _layout(experts, held, tm):
     src = order[jnp.clip(starts[g] + off, 0, a - 1)]
     g_a = jnp.minimum(key, count - 1)
     dst = jnp.where(is_held.reshape(a), row0[g_a] + rank - starts[g_a], 0)
+    i = jnp.arange(tiles, dtype=jnp.int32)
+    counts = jnp.where(i < used[0], jnp.clip(
+        sizes[tile_group] - (i * tm - row0[tile_group]), 0, tm), 0)
     return (src, valid, dst.reshape(t, k), is_held, tile_group,
-            used.astype(jnp.int32))
+            used.astype(jnp.int32), counts.astype(jnp.int32))
+
+
+def _gather_rows(x, src_token, counts, used, tm, scale=None, y=None):
+    """:func:`pallas_kernels.moe_rows` in plain form: one gather over
+    the whole static buffer.  (It writes the tiles past ``used`` too, as
+    zeros; nothing reads them.)"""
+    p = src_token.shape[0]
+    valid = jnp.arange(p, dtype=jnp.int32) % tm < jnp.repeat(counts, tm)
+    rows = jnp.where(valid[:, None], x[src_token], 0)
+    out = rows if scale is None else scale[:, None] * rows
+    if y is None:
+        return out.astype(x.dtype)
+    return out.astype(x.dtype), jnp.sum(
+        rows.astype(jnp.float32) * y.astype(jnp.float32), axis=-1)
+
+
+def _gather_slots(ys, dst, w, is_held, used, tm):
+    """:func:`pallas_kernels.moe_slots` in plain form: the buffers ``ys``
+    (a tuple) summed over their whole static shape, every slot's row
+    gathered, ``(T, k, U)``, and summed over the slots in float32."""
+    y = sum(ys[1:], ys[0])
+    rows = jnp.where(is_held[..., None], y[dst], 0).astype(jnp.float32)
+    return jnp.sum(w[..., None] * rows, axis=1).astype(y.dtype)
+
+
+def _movers_run(units, dtype):
+    from ..ops import pallas_kernels as pk
+    return pk._on_tpu() and pk.row_words_ok(units, dtype)
+
+
+def _move_rows(x, *args, **kwargs):
+    """Tokens' rows into the buffer: the Pallas row mover on the TPU
+    (only the valid rows of the used tiles are fetched), the plain
+    gather off it."""
+    from ..ops import pallas_kernels as pk
+    if _movers_run(x.shape[1], x.dtype):
+        return pk.moe_rows(x, *args, **kwargs)
+    return _gather_rows(x, *args, **kwargs)
+
+
+def _move_slots(ys, *args):
+    """The rows of the buffer ``sum(ys)`` (a tuple) back to their
+    tokens: the Pallas slot mover on the TPU (only the held slots' rows
+    are fetched), the plain gather off it."""
+    from ..ops import pallas_kernels as pk
+    if _movers_run(ys[0].shape[1], ys[0].dtype):
+        return pk.moe_slots(ys, *args)
+    return _gather_slots(ys, *args)
 
 
 @jax.custom_vjp
-def _dispatch(x, src_token, valid, dst, is_held):
-    """``(P, U)``: row ``p`` is token ``src_token[p]``'s state, zero
-    where the row is padding.  Its transpose is a gather too: a token's
-    cotangent is the sum over its held slots of their rows'."""
-    return jnp.where(valid[:, None], x[src_token], 0).astype(x.dtype)
+def _dispatch(x, src_token, counts, used, dst, is_held):
+    """``(P, U)``, handed out TWICE (one array: the gate's and the up
+    product's operand): row ``p`` is token ``src_token[p]``'s state,
+    zero where the row is padding (a tile's rows past its ``counts``).
+    Its transpose moves rows too: a token's cotangent is the sum over
+    its held slots of their rows' — and the two products' cotangents
+    come back apart, to be summed where the mover reads them, over the
+    used tiles alone, where jax would add the two static buffers whole."""
+    tm = src_token.shape[0] // counts.shape[0]
+    rows = _move_rows(x, src_token, counts, used, tm)
+    return rows, rows
 
 
-def _dispatch_fwd(x, src_token, valid, dst, is_held):
-    return _dispatch(x, src_token, valid, dst, is_held), (dst, is_held)
+def _dispatch_fwd(x, src_token, counts, used, dst, is_held):
+    return (_dispatch(x, src_token, counts, used, dst, is_held),
+            (dst, is_held, used, counts))
 
 
-def _dispatch_bwd(res, g):
-    dst, is_held = res
-    dx = jnp.sum(jnp.where(is_held[..., None], g[dst], 0)
-                 .astype(jnp.float32), axis=1).astype(g.dtype)
-    return dx, None, None, None, None
+def _dispatch_bwd(res, gs):
+    dst, is_held, used, counts = res
+    dx = _move_slots(tuple(gs), dst, is_held.astype(jnp.float32), is_held,
+                     used, gs[0].shape[0] // counts.shape[0])
+    return dx, None, None, None, None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-def _weighted_sum(w, rows):
-    return jnp.sum(w[..., None] * rows.astype(jnp.float32), axis=1)
-
-
 @jax.custom_vjp
-def _combine(y, w, src, valid, dst):
-    """``(T, U)``: token ``t`` gets ``sum_s w[t, s] y[dst[t, s]]``
-    (``w`` is zero on a slot that is not held), summed in float32.
-    Transposed by gathers: row ``p``'s cotangent is its assignment's
-    weight times its token's cotangent."""
-    return _weighted_sum(w, y[dst]).astype(y.dtype)
+def _combine(y, w, src, counts, used, dst, is_held):
+    """``(T, U)``: token ``t`` gets ``sum_s w[t, s] y[dst[t, s]]`` over
+    its held slots, summed in float32.  Transposed by moving rows the
+    other way: row ``p``'s cotangent is its assignment's weight times
+    its token's cotangent, and a weight's is the dot of its row with its
+    token's cotangent (taken on the buffer's side, a number a row)."""
+    return _move_slots((y,), dst, w, is_held, used,
+                       y.shape[0] // counts.shape[0])
 
 
-def _combine_fwd(y, w, src, valid, dst):
-    rows = y[dst]           # kept: the weights' cotangent reads them again
-    return _weighted_sum(w, rows).astype(y.dtype), (rows, w, src, valid)
+def _combine_fwd(y, w, src, counts, used, dst, is_held):
+    return (_combine(y, w, src, counts, used, dst, is_held),
+            (y, w, src, counts, used, dst, is_held))
 
 
 def _combine_bwd(res, g):
-    rows, w, src, valid = res
+    y, w, src, counts, used, dst, is_held = res
     k = w.shape[1]
-    gy = jnp.where(valid[:, None],
-                   w.reshape(-1)[src][:, None] * g[src // k], 0)
-    gw = jnp.sum(g[:, None, :].astype(jnp.float32)
-                 * rows.astype(jnp.float32), axis=-1)
-    return gy.astype(rows.dtype), gw.astype(w.dtype), None, None, None
+    gy, dots = _move_rows(g.astype(y.dtype), src // k, counts, used,
+                          y.shape[0] // counts.shape[0],
+                          scale=w.reshape(-1)[src], y=y)
+    gw = jnp.where(is_held, dots[dst], 0)
+    return gy, gw.astype(w.dtype), None, None, None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -177,7 +236,13 @@ def routed_experts(x, router_w, experts, top_k, held, norm_topk=True):
     would exchange, which on one chip simply goes on.  No assignment is
     ever dropped and nothing is padded to a capacity: the row buffer is
     sized for every assignment being held (:func:`_layout`), and the
-    tiles an imbalance leaves unused are skipped by the product.
+    tiles an imbalance leaves unused are skipped by the product — and,
+    on the TPU, by dispatch and combine too: a chip that holds a quarter
+    of the experts fills a quarter of the buffer and a quarter of a
+    token's slots, and the row movers (``pallas_kernels.moe_rows`` /
+    ``moe_slots``) fetch those rows alone, forward and backward, where a
+    gather copies the whole static shape.  They leave the unused tiles
+    UNWRITTEN: whatever reads the buffer skips them by ``used``.
     ``held = (0, E)`` is the whole layer.  Returns ``(T, U)``."""
     from ..ops import pallas_kernels as pk
     gate, up, down = experts
@@ -191,15 +256,16 @@ def routed_experts(x, router_w, experts, top_k, held, norm_topk=True):
     with jax.named_scope(_phases.MOE_SCOPE):
         weights, chosen = _route_top_k(x, router_w, top_k, norm_topk)
         tm = pk.GROUPED_TILE_ROWS
-        src, valid, dst, is_held, tile_group, used = _layout(
+        src, _, dst, is_held, tile_group, used, counts = _layout(
             chosen, (first, count), tm)
-        rows = _dispatch(x, src // top_k, valid, dst, is_held)
+        rows, rows_again = _dispatch(x, src // top_k, counts, used, dst,
+                                     is_held)
         with jax.named_scope(_phases.MOE_EXPERTS_SCOPE):
             product = lambda a, w: _tile_product(a, w, tile_group, used, tm)
-            h = jax.nn.silu(product(rows, gate)) * product(rows, up)
+            h = jax.nn.silu(product(rows, gate)) * product(rows_again, up)
             y = product(h, down)
         w = jnp.where(is_held, weights, 0).astype(jnp.float32)
-        return _combine(y, w, src, valid, dst)
+        return _combine(y, w, src, counts, used, dst, is_held)
 
 
 def switch_moe(x, gate_w, expert_params, expert_fn, mesh,

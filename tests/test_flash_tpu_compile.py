@@ -1,5 +1,6 @@
-"""The flash kernels compiled by the TPU's own compiler for a DESCRIBED
-v5e (no chip attached), at the blocks ``_flash_blocks`` picks: what
+"""The flash kernels, and the expert layer's row movers, compiled by the
+TPU's own compiler for a DESCRIBED v5e (no chip attached), the flash
+kernels at the blocks ``_flash_blocks`` picks: what
 interpret mode cannot show — a tile the compiler refuses, a block that
 overruns scoped VMEM.  Nothing runs and no time comes out of it.
 
@@ -57,4 +58,44 @@ def test_flash_kernels_compile_for_the_chip(one_chip, monkeypatch, bh, tq,
     text = compiled.as_text()
     for name in ("_flash_fwd_kernel", "_flash_bwd_dq_kernel",
                  "_flash_bwd_dkv_kernel"):
+        assert name in text
+
+
+# (tokens, top_k, units, held experts, dtype): the benchmark's expert
+# layer (8192 tokens, 8 slots, rows of 2304 bfloat16, 16 held experts: a
+# buffer of 187 tiles of 384 rows), and a float32 layer of one lane tile
+@pytest.mark.parametrize("tokens,top_k,units,held,dtype", [
+    (8192, 8, 2304, 16, "bfloat16"),
+    (512, 2, 128, 2, "float32"),
+])
+def test_row_movers_compile_for_the_chip(one_chip, monkeypatch, tokens,
+                                         top_k, units, held, dtype):
+    """Mosaic slices a DMA along whole tiles only: a one-row copy out of
+    a 2-D array is refused whatever interpret mode says.  Both movers and
+    the pass that makes rows fetchable, with both gradients."""
+    from mxnet_tpu.parallel import moe
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    monkeypatch.setattr(moe, "_movers_run", pk.row_words_ok)
+    tm = pk.GROUPED_TILE_ROWS
+    tiles = -(-tokens * top_k // tm) + held
+    p = tiles * tm
+
+    def loss(x, w, src, counts, used, dst, is_held):
+        rows, again = moe._dispatch(x, src // top_k, counts, used, dst,
+                                    is_held)
+        out = moe._combine(rows * 2 + again, w, src, counts, used, dst,
+                           is_held)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    def aval(shape, kind):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(kind),
+                                    sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, (0, 1))).lower(
+        aval((tokens, units), dtype), aval((tokens, top_k), "float32"),
+        aval((p,), "int32"), aval((tiles,), "int32"), aval((1,), "int32"),
+        aval((tokens, top_k), "int32"), aval((tokens, top_k), "bool"),
+    ).compile()
+    text = compiled.as_text()
+    for name in ("_moe_rows_kernel", "_moe_slots_kernel",
+                 "_moe_words_kernel"):
         assert name in text

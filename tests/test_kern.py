@@ -327,6 +327,62 @@ def test_grouped_product_is_in_the_catalog_at_a_representative_table(
         assert r["vmem"]["bytes_per_instance"] <= r["vmem"]["budget"]
 
 
+def test_row_movers_are_in_the_catalog_at_a_representative_table(
+        monkeypatch):
+    """The expert layer's movers — the buffer-side one plain and with
+    its factor and row dots, the token-side one, the pass that makes
+    rows fetchable — analysed at one table with jit poisoned: the source
+    a kernel fetches from by row DMA is an un-blocked HBM operand, every
+    operand blocked by buffer tile holds at the last used tile, and the
+    landing buffers keep an instance under the VMEM budget."""
+    from mxnet_tpu.analysis.kern import kernel_reports, moe_mover_reports
+    _poison_jit(monkeypatch)
+    reports = moe_mover_reports()
+    assert [r["name"] for r in reports] == [
+        "_moe_rows_kernel", "_moe_rows_kernel", "_moe_slots_kernel",
+        "_moe_words_kernel"]
+    assert run_kern_checkers(reports) == []
+    assert {r["name"] for r in reports} <= {
+        r["name"] for r in kernel_reports()}
+    rows, rows_bwd, slots, words = reports
+    for r, source in ((rows, "x_words"), (rows_bwd, "g_words"),
+                      (slots, "y_words")):
+        (hbm,) = [o for o in r["operands"] if o["name"] == source]
+        assert hbm["block"] is None and hbm["index"] is None
+        assert hbm["dtype"] == "uint32" and hbm["shape"][1:] == [1, 1152]
+    # 8192 / 384 + 4 groups: 26 tiles, 7 used; past them the blocked
+    # operands stay on tile 6
+    assert [o["name"] for o in words["operands"][1:]] \
+        == ["g_gate", "g_up", "g_words"]
+    for op in rows_bwd["operands"][2:] + words["operands"][1:]:
+        assert [i[0] for i in op["index"]] == list(range(7)) + [6] * 19
+    assert [o["revisit"] for o in rows_bwd["operands"][-2:]] \
+        == ["used", "used"]
+    assert [i[0] for i in slots["operands"][-1]["index"]] == list(range(8))
+    assert slots["scratch"] == [{"shape": [8 * 128, 1, 1152],
+                                 "dtype": "uint32"}]
+    for r in reports:
+        assert r["vmem"]["bytes_per_instance"] <= r["vmem"]["budget"]
+
+
+def test_a_used_prefix_is_written_once_and_in_order():
+    """``revisit: used`` admits a prefix of the blocks written once each
+    with the grid held at the last of them, and refuses a gap, a block
+    written twice before the last, and a grid that comes back."""
+    op = {"name": "rows", "role": "out", "dtype": "float32",
+          "block": [8, 128], "shape": [40, 128], "revisit": "used",
+          "index": [[0, 0], [1, 0], [2, 0], [2, 0], [2, 0]]}
+    assert coverage_problems(op, [5]) == []
+    assert coverage_problems(
+        dict(op, index=[[i, 0] for i in range(5)]), [5]) == []
+    gap = dict(op, index=[[0, 0], [2, 0], [2, 0], [2, 0], [2, 0]])
+    assert any("prefix" in p for p in coverage_problems(gap, [5]))
+    twice = dict(op, index=[[0, 0], [0, 0], [1, 0], [2, 0], [2, 0]])
+    assert any("written 2 times" in p for p in coverage_problems(twice, [5]))
+    back = dict(op, index=[[0, 0], [1, 0], [0, 0], [1, 0], [1, 0]])
+    assert any("prefix" in p for p in coverage_problems(back, [5]))
+
+
 def test_a_run_of_revisits_may_not_come_back_to_a_block():
     """``revisit: runs`` admits runs of any length and refuses a block
     the grid leaves and returns to (its accumulation would be written

@@ -164,16 +164,26 @@ def _program(h, p, held, top_k=2):
         (held[0], held[1] - held[0]))[None]
 
 
-@pytest.fixture(params=["einsum", "pallas"])
+@pytest.fixture(params=["einsum", "pallas", "pallas+movers"])
 def product(request, monkeypatch):
-    """The experts' product in both forms, over tiles small enough that
-    an expert's rows span several: the plain one the CPU takes, and the
-    kernels the TPU takes (interpret mode here)."""
+    """The expert layer in its forms, over tiles small enough that an
+    expert's rows span several: the plain product and gathers the CPU
+    takes; the product's kernels (interpret mode here); and what the TPU
+    takes, the product's kernels between the row movers of dispatch and
+    combine — which leave the tiles past the used ones unwritten, so
+    they go with the product that skips those tiles."""
     from mxnet_tpu.parallel import moe
     monkeypatch.setattr(pk, "GROUPED_TILE_ROWS", 16)
-    if request.param == "pallas":
+    if request.param != "einsum":
         monkeypatch.setattr(moe, "_tile_product", pk.grouped_matmul)
+    if request.param == "pallas+movers":
+        _movers_on(monkeypatch)
     return request.param
+
+
+def _movers_on(monkeypatch):
+    from mxnet_tpu.parallel import moe
+    monkeypatch.setattr(moe, "_movers_run", pk.row_words_ok)
 
 
 def test_the_shares_add_up(ref, product):
@@ -256,6 +266,218 @@ def test_the_grouped_product_is_the_kernel_where_it_is_asked_for():
     dw_want = jnp.stack([x[r].sum(0)[None, :].repeat(32, 0) for r in
                          (slice(0, 16), slice(16, 24), slice(24, 32))])
     assert float(jnp.abs(dw - dw_want).max()) < 1e-4
+
+
+# ---------------------------------------------------------------------------
+# the row movers of dispatch and combine
+# ---------------------------------------------------------------------------
+def _table(case):
+    """``(experts (T, k), held, published)`` of a hand-made routing."""
+    if case == "no_row_and_every_row":      # expert 2 every token, 3 none
+        return np.tile([2, 5], (40, 1)), (2, 2), 8
+    if case == "0_and_k_held_slots":        # tokens with 2, 1 and 0 held
+        return np.array([[0, 1], [4, 5], [0, 4], [5, 1]] * 9), (0, 2), 8
+    if case == "whole_layer":               # held = (0, E): every slot
+        rng = np.random.default_rng(21)
+        return np.stack([rng.permutation(4)[:2] for _ in range(40)]), \
+            (0, 4), 4
+    if case == "one_valid_row_in_the_last_tile":    # 17 rows, tiles of 16
+        return np.tile([0, 3], (17, 1)), (0, 2), 4
+    raise KeyError(case)
+
+
+MOVER_CASES = ["no_row_and_every_row", "0_and_k_held_slots", "whole_layer",
+               "one_valid_row_in_the_last_tile"]
+
+
+def _moved(case, dtype, units, movers, monkeypatch):
+    """Dispatch, then combine, over one routing: values, and the
+    gradients of both ``custom_vjp``s (the weights' among them)."""
+    from mxnet_tpu.parallel import moe
+    experts, held, _ = _table(case)
+    t, k = experts.shape
+    tables = moe._layout(jnp.asarray(experts, jnp.int32), held, 16)
+    src, _, dst, is_held, _, used, counts = tables
+    rng = np.random.default_rng(22)
+    x = jnp.asarray(rng.normal(size=(t, units)), dtype)
+    w = jnp.where(is_held, jnp.asarray(rng.uniform(.1, 1, (t, k)),
+                                       jnp.float32), 0)
+    mix = jnp.asarray(rng.normal(size=(units,)), jnp.float32)
+    if movers:
+        _movers_on(monkeypatch)
+
+    def both(x_, w_):
+        rows, again = moe._dispatch(x_, src // k, counts, used, dst,
+                                    is_held)
+        y = (jnp.tanh(rows.astype(jnp.float32)) * mix
+             + again.astype(jnp.float32) / 4).astype(dtype)
+        out = moe._combine(y, w_, src, counts, used, dst, is_held)
+        return jnp.sum(jnp.sin(out.astype(jnp.float32))), (rows, out)
+
+    (_, (rows, out)), (gx, gw) = jax.value_and_grad(
+        both, (0, 1), has_aux=True)(x, w)
+    return {"rows": rows[:int(used[0]) * 16], "out": out, "gx": gx,
+            "gw": jnp.where(is_held, gw, 0)}
+
+
+@pytest.mark.parametrize("dtype,units", [("float32", 128),
+                                         ("bfloat16", 256)])
+@pytest.mark.parametrize("case", MOVER_CASES)
+def test_the_movers_are_the_gathers(case, dtype, units, monkeypatch):
+    """Each mover against its plain ``jnp`` form: the rows of the used
+    tiles bit for bit, the tokens and every gradient to float32's (or
+    one bfloat16's) rounding — an expert with no row and one with every
+    row, tokens with no and with ``k`` held slots, the whole layer on
+    the chip, a last tile with one valid row."""
+    want = _moved(case, dtype, units, False, monkeypatch)
+    got = _moved(case, dtype, units, True, monkeypatch)
+    assert np.array_equal(np.asarray(got["rows"], "f"),
+                          np.asarray(want["rows"], "f"))
+    tol = 1e-6 if dtype == "float32" else 2 ** -8
+    for name in ("out", "gx", "gw"):
+        a, b = (np.asarray(v[name], "f") for v in (got, want))
+        assert np.all(np.isfinite(a)), name
+        assert np.abs(a - b).max() <= tol * max(np.abs(b).max(), 1.0), name
+
+
+def test_the_movers_are_the_kernels_where_they_are_asked_for(monkeypatch):
+    from mxnet_tpu import telemetry
+    telemetry.enable()
+    try:
+        calls = telemetry.counter("mxnet_pallas_kernel_calls_total")
+        before = {k: calls.labels(kernel=k).value
+                  for k in ("moe_rows", "moe_slots")}
+        _moved("0_and_k_held_slots", "float32", 128, True, monkeypatch)
+        # dispatch and the combine's transpose; combine and dispatch's
+        assert calls.labels(kernel="moe_rows").value \
+            == before["moe_rows"] + 2
+        assert calls.labels(kernel="moe_slots").value \
+            == before["moe_slots"] + 2
+    finally:
+        telemetry.disable()
+    assert pk.row_words_ok(2304, jnp.bfloat16)
+    assert pk.row_words_ok(128, jnp.float32)
+    assert not pk.row_words_ok(128, jnp.bfloat16)   # half a lane tile
+    assert not pk.row_words_ok(256, jnp.float16)
+
+
+def test_nothing_reads_the_tiles_past_the_used_ones(ref, monkeypatch):
+    """The movers leave the buffer's tiles past ``used`` unwritten.
+    Filled with NaN — the rows that dispatch and the combine's
+    transpose make, and the rows that combine and dispatch's transpose
+    read — the layer's output and every gradient are finite and the
+    same: the products skip those tiles and no held slot points into
+    one."""
+    from mxnet_tpu.parallel import moe
+    monkeypatch.setattr(pk, "GROUPED_TILE_ROWS", 16)
+    monkeypatch.setattr(moe, "_tile_product", pk.grouped_matmul)
+    _movers_on(monkeypatch)
+    rng = np.random.default_rng(14)
+    cfg, h, whole = _expert_layer(ref, rng, (2, 4))
+    p = _share(whole, 2, 4)
+
+    def loss(h_, p_):
+        return jnp.sum(jnp.sin(_program(h_, p_, (2, 4))))
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.value_and_grad(loss, (0, 1))(h, p)
+
+    def poisoned(rows, used, tm):
+        past = jnp.arange(rows.shape[0]) >= used[0] * tm
+        return jnp.where(past[:, None], jnp.nan, rows)
+
+    def rows_then_nan(x, src_token, counts, used, tm, scale=None, y=None):
+        out = pk.moe_rows(x, src_token, counts, used, tm, scale, y)
+        if y is None:
+            return poisoned(out, used, tm)
+        return poisoned(out[0], used, tm), out[1]
+
+    def nan_then_slots(ys, dst, w, is_held, used, tm):
+        assert int(used[0]) * tm < ys[0].shape[0]   # there ARE such tiles
+        return pk.moe_slots(tuple(poisoned(y, used, tm) for y in ys), dst,
+                            w, is_held, used, tm)
+
+    monkeypatch.setattr(moe, "_move_rows", rows_then_nan)
+    monkeypatch.setattr(moe, "_move_slots", nan_then_slots)
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(loss, (0, 1))(h, p)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert np.all(np.isfinite(np.asarray(a)))
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_a_tiles_count_is_its_valid_rows():
+    from mxnet_tpu.parallel import moe
+    for case in MOVER_CASES:
+        experts, held, _ = _table(case)
+        _, valid, _, _, tile_group, used, counts = moe._layout(
+            jnp.asarray(experts, jnp.int32), held, 16)
+        by_tile = np.asarray(valid).reshape(-1, 16)
+        assert list(np.asarray(counts)) == list(by_tile.sum(1))
+        # a tile's valid rows are a prefix, and none lies past ``used``
+        for n, row in zip(np.asarray(counts), by_tile):
+            assert row[:n].all() and not row[n:].any()
+        assert not np.asarray(counts)[int(used[0]):].any()
+
+
+def test_the_dense_models_never_reach_the_movers(monkeypatch):
+    """``TransformerLM`` and ``LoopedLM`` (the two accepted LM cells'
+    blocks) trace with every entry of the expert layer poisoned: the
+    movers are new functions no dense path calls."""
+    from mxnet_tpu.gluon.contrib.transformer import LoopedLM, TransformerLM
+    from mxnet_tpu.parallel import moe
+
+    def never(*args, **kwargs):
+        raise AssertionError("the expert layer was reached")
+
+    for mod, names in ((moe, ("routed_experts", "_move_rows", "_move_slots",
+                              "_layout")),
+                       (pk, ("moe_rows", "moe_slots", "_row_words",
+                             "grouped_matmul"))):
+        for name in names:
+            monkeypatch.setattr(mod, name, never)
+    tokens = nd.array(np.random.default_rng(6).integers(0, 64, (2, 16)),
+                      dtype="int32")
+    for net in (TransformerLM(64, units=32, hidden_size=64, num_layers=2,
+                              num_heads=2, max_len=16),
+                LoopedLM(64, units=32, hidden_size=64, num_layers=2,
+                         num_heads=2)):
+        net.initialize(mx.init.Normal(0.1), ctx=mx.cpu())
+        with autograd.record():
+            out = net(tokens)
+            out = out[0] if isinstance(out, (list, tuple)) else out
+            loss = out.sum()
+        loss.backward()
+        assert np.isfinite(float(loss.asnumpy()))
+    # and the executor's step (the ResNet cell's trainer)
+    data = mx.sym.Variable("data")
+    net = mx.sym.SoftmaxOutput(mx.sym.FullyConnected(
+        data, num_hidden=4, name="fc"), name="softmax")
+    exe = net.simple_bind(mx.cpu(), data=(2, 8), grad_req="write")
+    exe.forward(is_train=True, data=nd.ones((2, 8)))
+    exe.backward()
+    assert np.isfinite(exe.grad_dict["fc_weight"].asnumpy()).all()
+
+
+def test_the_gauges_give_the_shapes_the_movers_fetch_from():
+    """Cell 4's layer: 8192 tokens, 8 slots each, 16 of 64 experts held —
+    16,384 rows expected of a buffer of 71,808 (187 tiles of 384) and of
+    65,536 slot rows."""
+    from mxnet_tpu import telemetry
+    net = MoELM(64, units=32, expert_width=16, num_heads=4, num_kv_heads=2,
+                num_routed=64, held=(0, 16), top_k=8, window=4)
+    telemetry.enable()
+    try:
+        assert net.expected_rows(8192) == 16384
+        read = {name: telemetry.gauge(name).labels().value for name in (
+            "mxnet_moe_expected_rows", "mxnet_moe_buffer_rows",
+            "mxnet_moe_slot_rows")}
+    finally:
+        telemetry.disable()
+    assert read == {"mxnet_moe_expected_rows": 16384,
+                    "mxnet_moe_buffer_rows": 187 * 384,
+                    "mxnet_moe_slot_rows": 65536}
 
 
 # ---------------------------------------------------------------------------
