@@ -200,10 +200,11 @@ def sweep_reports(n=None):
 # -- flash attention -------------------------------------------------------
 
 def flash_reports(bh=8, tq=512, tk=512, d=64, bq=128, bk=128,
-                  causal=False, dtype="float32"):
+                  causal=False, dtype="float32", dv=None):
     """The three flash kernels at one shape.  ``bq`` / ``bk`` None:
     the blocks ``_flash_blocks`` picks for each kernel from the shape,
-    as a call without explicit blocks runs them."""
+    as a call without explicit blocks runs them.  ``dv``: the values'
+    head size where it is not the keys'."""
     from mxnet_tpu.ops import pallas_kernels as pk
     structural = [
         {"name": "scale", "detail": "architecture constant (1/sqrt(d) "
@@ -231,11 +232,11 @@ def flash_reports(bh=8, tq=512, tk=512, d=64, bq=128, bk=128,
              ("q", "k", "v", "do", "lse", "delta"), ("dq",), "nk"),
             ("_flash_bwd_dkv_kernel", "dkv",
              ("q", "k", "v", "do", "lse", "delta"), ("dk", "dv"), "nq")):
-        pq, pk_ = pk._flash_blocks(tq, tk, d, dtype, kernel)
+        pq, pk_ = pk._flash_blocks(tq, tk, d, dtype, kernel, dv)
         reports.append(_report(
             name, family,
             pk._FLASH_PLANS[kernel](bh, tq, tk, d, bq or pq, bk or pk_,
-                                    causal, dtype),
+                                    causal, dtype, None, dv),
             ins, outs,
             python_constants=structural + [
                 {"name": extent, "detail": "grid extent"}],
@@ -244,13 +245,17 @@ def flash_reports(bh=8, tq=512, tk=512, d=64, bq=128, bk=128,
 
 
 def flash_cell_reports():
-    """The flash kernels as the benchmark's two LM cells run them:
-    causal bf16 at T 2048 with the blocks picked from the shape —
-    OPT-1.3B's 2 x 32 heads of 64, Ouro-2.6B's 1 x 16 heads of 128."""
+    """The flash kernels as the benchmark's LM cells run them: causal
+    bf16 with the blocks picked from the shape — OPT-1.3B's 2 x 32 heads
+    of 64 and Ouro-2.6B's 1 x 16 heads of 128 at T 2048, JoyAI-LLM-
+    Flash's 32 heads of latent attention at T 8192, keys of 192 over
+    values of 128."""
     return (flash_reports(64, 2048, 2048, 64, None, None, True,
                           "bfloat16")
             + flash_reports(16, 2048, 2048, 128, None, None, True,
-                            "bfloat16"))
+                            "bfloat16")
+            + flash_reports(32, 8192, 8192, 192, None, None, True,
+                            "bfloat16", dv=128))
 
 
 # -- grouped matrix product (sparse experts) --------------------------------

@@ -32,6 +32,7 @@ from ..parameter import DeferredInitializationError
 
 __all__ = ["MultiHeadAttention", "TransformerEncoderCell", "TransformerLM",
            "LoopedLM", "looped_lm_forward", "MoELM", "moe_lm_forward",
+           "LatentMoELM", "latent_moe_lm_forward", "latent_attention",
            "causal_attention", "cached_attention_step"]
 
 
@@ -557,14 +558,7 @@ class MoELM(HybridBlock):
     def _export_gauges(self):
         from ... import telemetry
         c = self._config
-        experts = telemetry.gauge(
-            "mxnet_moe_experts", "experts of a layer of the newest MoELM: "
-            "the router's width (published) and the ones this block holds "
-            "(held)")
-        experts.labels(which="published").set(self._num_routed)
-        experts.labels(which="held").set(c["held"][1])
-        telemetry.gauge("mxnet_moe_top_k", "experts a token is routed to "
-                        "in the newest MoELM").set(c["top_k"])
+        _export_expert_gauges(self._num_routed, c["held"][1], c["top_k"])
         layers = telemetry.gauge(
             "mxnet_attn_layers", "layers of the newest MoELM by kind of "
             "attention (sliding_attention / full_attention)")
@@ -576,30 +570,11 @@ class MoELM(HybridBlock):
 
     def expected_rows(self, tokens):
         """Rows a step of ``tokens`` tokens sends to this block's held
-        experts, a layer, in expectation under a symmetric router —
-        exported as ``mxnet_moe_expected_rows`` (what the experts'
-        products are sized against).  Beside it the two static shapes
-        those rows lie in, of which dispatch and combine fetch the held
-        rows alone: ``mxnet_moe_buffer_rows`` (the row buffer, sized for
-        every assignment being held) and ``mxnet_moe_slot_rows`` (a row
-        a (token, slot) assignment)."""
-        from ... import telemetry
-        from ...ops.pallas_kernels import GROUPED_TILE_ROWS as tm
+        experts, a layer, in expectation (:func:`_export_expert_rows`:
+        exported with the two static shapes those rows lie in)."""
         c = self._config
-        slots = tokens * c["top_k"]
-        rows = slots * c["held"][1] / self._num_routed
-        telemetry.gauge("mxnet_moe_expected_rows", "rows a step's tokens "
-                        "send to the held experts of one layer of the "
-                        "newest MoELM, in expectation under a symmetric "
-                        "router").set(rows)
-        telemetry.gauge("mxnet_moe_buffer_rows", "rows of the expert "
-                        "layer's row buffer in the newest MoELM: whole "
-                        "tiles for every assignment being held").set(
-                            (-(-slots // tm) + c["held"][1]) * tm)
-        telemetry.gauge("mxnet_moe_slot_rows", "(token, slot) "
-                        "assignments a step of the newest MoELM routes, "
-                        "held or not").set(slots)
-        return rows
+        return _export_expert_rows("MoELM", tokens, c["top_k"], c["held"],
+                                   self._num_routed)
 
     def hybrid_forward(self, F, tokens, **params):
         from ...imperative import invoke_fn
@@ -624,3 +599,372 @@ class MoELM(HybridBlock):
         over positions of the next token's cross-entropy."""
         from ..loss import LinearCELoss
         return LinearCELoss(params=self.params, **kwargs)
+
+
+# a latent-attention layer's leaves, in construction order: attention and
+# both gains, then the feed-forward part of its kind
+_LATENT_ATTN_LEAVES = (
+    "norm1_gamma", "q_a_weight", "q_a_norm_gamma", "q_b_weight",
+    "kv_a_weight", "kv_a_norm_gamma", "kv_b_weight", "out_weight",
+    "norm2_gamma")
+_LATENT_FFN_LEAVES = {
+    "dense": ("gate_weight", "up_weight", "down_weight"),
+    "sparse": ("router_weight", "router_bias", "gate_weight", "up_weight",
+               "down_weight", "shared_gate_weight", "shared_up_weight",
+               "shared_down_weight")}
+DENSE, SPARSE = "dense", "sparse"
+# a multi-token-prediction module's own leaves around its layer
+_MTP_FRONT_LEAVES = ("embed_norm_gamma", "hidden_norm_gamma", "proj_weight")
+
+
+def latent_attention(h, p, *, num_heads, nope_dim, rope_dim, v_dim,
+                     rope_base=10000.0, rope_interleaved=True, eps=1e-6):
+    """Multi-head latent attention over normed states ``h (B, T, U)``:
+    ``(B, T, U)``, the output projection included.  ``p`` holds one
+    layer's ``q_a_weight (Rq, U)``, ``q_a_norm_gamma``, ``q_b_weight (H
+    (nope + rope), Rq)``, ``kv_a_weight (Rkv + rope, U)``,
+    ``kv_a_norm_gamma``, ``kv_b_weight (H (nope + v), Rkv)`` and
+    ``out_weight (U, H v)``.  Every head's key is ``[k_nope | k_rope]``
+    with the ONE rotary key broadcast over the heads; the flash call
+    scores over ``nope + rope`` dimensions (scale their ``-0.5`` power)
+    and sums values of ``v_dim`` — neither side is padded to the other.
+    What runs around the flash call is under scope ``mx_attn_latent``,
+    the call and its rotary under ``mx_attn_full``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ...ops.contrib import _flash_attention_op, _rotary_embedding
+    from ...ops.nn import _rms_norm
+    from ...telemetry import phases
+
+    b, t, _u = h.shape
+    turn = lambda a: _rotary_embedding(a, base=rope_base,
+                                       interleaved=rope_interleaved)
+    with jax.named_scope(phases.ATTN_LATENT_SCOPE):
+        cq = _rms_norm(jnp.einsum("btu,ru->btr", h, p["q_a_weight"]),
+                       p["q_a_norm_gamma"], eps=eps)
+        q = jnp.einsum("btr,or->bto", cq, p["q_b_weight"]).reshape(
+            b, t, num_heads, nope_dim + rope_dim)
+        ckv = jnp.einsum("btu,ru->btr", h, p["kv_a_weight"])
+        kv = jnp.einsum(
+            "btr,or->bto",
+            _rms_norm(ckv[..., :-rope_dim], p["kv_a_norm_gamma"], eps=eps),
+            p["kv_b_weight"]).reshape(b, t, num_heads, nope_dim + v_dim)
+    with jax.named_scope(phases.ATTN_FULL_SCOPE):
+        q_rope = turn(q[..., nope_dim:])
+        k_rope = turn(ckv[:, :, None, -rope_dim:])
+    with jax.named_scope(phases.ATTN_LATENT_SCOPE):
+        q = jnp.concatenate([q[..., :nope_dim], q_rope], -1)
+        k = jnp.concatenate(
+            [kv[..., :nope_dim],
+             jnp.broadcast_to(k_rope, (b, t, num_heads, rope_dim))], -1)
+    with jax.named_scope(phases.ATTN_FULL_SCOPE):
+        o = _flash_attention_op(q, k, kv[..., nope_dim:], causal=True)
+    return jnp.einsum("bto,uo->btu", o.reshape(b, t, -1), p["out_weight"])
+
+
+def latent_moe_lm_forward(params, tokens, *, mlp_layer_types, num_heads,
+                          nope_dim, rope_dim, v_dim, top_k, held,
+                          scoring="sigmoid", route_scale=1.0, norm_topk=True,
+                          shared_expert=True, rope_base=10000.0,
+                          rope_interleaved=True, mtp_depth=0, eps=1e-6):
+    """A latent-attention sparse-expert LM's trunk as ONE pure function
+    of ``(params, tokens)``: the final-normed states ``(B, T, U)`` the
+    head reads and, with ``mtp_depth`` prediction modules, their own
+    final-normed states ``(B, mtp_depth, T, U)`` beside them.
+
+    ``params`` maps :class:`LatentMoELM`'s short parameter names to jax
+    arrays (no ``l1_router_bias``: the routers choose by score alone;
+    the shared expert's three leaves are read under ``shared_expert``);
+    ``tokens`` is
+    ``(B, T)`` int.  Layer ``i`` is pre-norm, ``x + MLA(RMS(x))`` then
+    ``x + FFN(RMS(x))``.
+
+    MLA (DeepSeek-V2, arXiv:2405.04434) is :func:`latent_attention`:
+    queries, keys and values through normed low-rank latents, one rotary
+    key shared by all heads (pairing (2i, 2i + 1) under
+    ``rope_interleaved``), scores over ``nope_dim + rope_dim`` dimensions
+    and values of ``v_dim``.
+
+    FFN: ``mlp_layer_types[i]`` "dense" is one SwiGLU; "sparse" one
+    chip's share of a routed expert layer
+    (:func:`parallel.moe.routed_experts` with ``scoring``, the held
+    selection bias ``router_bias`` and ``route_scale``) plus the shared
+    expert every token takes, which is this block's and not the routed
+    layer's: summed over the chips' shares it counts once.
+
+    Prediction module ``k`` (DeepSeek-V3, arXiv:2412.19437): ``[RMS_e(
+    Emb(t_{i+k})) ; RMS_h(h_i)] W`` through one more sparse layer of its
+    own and a final norm of its own, ``h`` being the main states after
+    their final norm for ``k = 1`` and module ``k - 1``'s un-normed
+    output after it; embedding and head are the model's.  The last ``k``
+    positions read tokens that wrap round; the loss leaves them out
+    (``gluon.loss.MultiTokenCELoss``).  Each layer and each module is a
+    ``jax.checkpoint``."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ...ops.contrib import _gated_ffn
+    from ...ops.nn import _rms_norm
+    from ...parallel.moe import routed_experts
+    from ...telemetry import phases
+
+    attention = functools.partial(
+        latent_attention, num_heads=num_heads, nope_dim=nope_dim,
+        rope_dim=rope_dim, v_dim=v_dim, rope_base=rope_base,
+        rope_interleaved=rope_interleaved, eps=eps)
+
+    def layer(x, p, kind):
+        b, t, u = x.shape
+        x = x + attention(_rms_norm(x, p["norm1_gamma"], eps=eps), p)
+        h = _rms_norm(x, p["norm2_gamma"], eps=eps)
+        if kind == DENSE:
+            return x + _gated_ffn(h, p["gate_weight"], p["up_weight"],
+                                  p["down_weight"])
+        y = routed_experts(
+            h.reshape(b * t, u), p["router_weight"],
+            (p["gate_weight"], p["up_weight"], p["down_weight"]), top_k,
+            held, norm_topk=norm_topk, scoring=scoring,
+            bias=p.get("router_bias"), scale=route_scale).reshape(b, t, u)
+        if shared_expert:
+            with jax.named_scope(phases.SHARED_EXPERT_SCOPE):
+                y = y + _gated_ffn(h, p["shared_gate_weight"],
+                                   p["shared_up_weight"],
+                                   p["shared_down_weight"])
+        return x + y
+
+    def leaves(prefix, names):
+        return {n: params[prefix + n] for n in names
+                if prefix + n in params}
+
+    def layer_leaves(prefix, kind):
+        return leaves(prefix, _LATENT_ATTN_LEAVES + _LATENT_FFN_LEAVES[kind])
+
+    def module(h, nxt, p, front):
+        e = _rms_norm(params["embed_weight"][nxt],
+                      front["embed_norm_gamma"], eps=eps)
+        hn = _rms_norm(h, front["hidden_norm_gamma"], eps=eps)
+        x = jnp.einsum("btc,uc->btu", jnp.concatenate([e, hn], -1),
+                       front["proj_weight"])
+        return layer(x, p, SPARSE)
+
+    tokens = tokens.astype(jnp.int32)
+    x = params["embed_weight"][tokens]
+    for i, kind in enumerate(mlp_layer_types):
+        x = jax.checkpoint(layer, static_argnums=2)(
+            x, layer_leaves("l%d_" % i, kind), kind)
+    states = _rms_norm(x, params["norm_gamma"], eps=eps)
+    if not mtp_depth:
+        return states
+    h, outs = states, []
+    with jax.named_scope(phases.MTP_SCOPE):
+        for k in range(mtp_depth):
+            pre = "mtp%d_" % k
+            h = jax.checkpoint(module)(
+                h, jnp.roll(tokens, -(k + 1), axis=1),
+                layer_leaves(pre, SPARSE), leaves(pre, _MTP_FRONT_LEAVES))
+            outs.append(_rms_norm(h, params[pre + "norm_gamma"], eps=eps))
+    return states, jnp.stack(outs, axis=1)
+
+
+def _export_expert_gauges(num_routed, held, top_k):
+    """The routed layer of the newest block that has one, in gauges: the
+    router's width, the experts held here, the experts a token takes."""
+    from ... import telemetry
+    experts = telemetry.gauge(
+        "mxnet_moe_experts", "experts of a layer of the newest MoELM or "
+        "LatentMoELM: the router's width (published) and the ones this "
+        "block holds (held)")
+    experts.labels(which="published").set(num_routed)
+    experts.labels(which="held").set(held)
+    telemetry.gauge("mxnet_moe_top_k", "experts a token is routed to in "
+                    "the newest MoELM or LatentMoELM").set(top_k)
+
+
+def _export_expert_rows(model, tokens, top_k, held, num_routed):
+    """Rows a step of ``tokens`` tokens sends to the held experts of one
+    layer of ``model``, in expectation under a symmetric router —
+    exported as ``mxnet_moe_expected_rows`` (what the experts' products
+    are sized against).  Beside it the two static shapes those rows lie
+    in, of which dispatch and combine fetch the held rows alone:
+    ``mxnet_moe_buffer_rows`` (the row buffer, sized for every
+    assignment being held) and ``mxnet_moe_slot_rows`` (a row a (token,
+    slot) assignment)."""
+    from ... import telemetry
+    from ...ops.pallas_kernels import GROUPED_TILE_ROWS as tm
+    slots = tokens * top_k
+    rows = slots * held[1] / num_routed
+    telemetry.gauge("mxnet_moe_expected_rows", "rows a step's tokens "
+                    "send to the held experts of one layer of the "
+                    "newest %s, in expectation under a symmetric "
+                    "router" % model).set(rows)
+    telemetry.gauge("mxnet_moe_buffer_rows", "rows of the expert "
+                    "layer's row buffer in the newest %s: whole "
+                    "tiles for every assignment being held" % model).set(
+                        (-(-slots // tm) + held[1]) * tm)
+    telemetry.gauge("mxnet_moe_slot_rows", "(token, slot) "
+                    "assignments a step of the newest %s routes, "
+                    "held or not" % model).set(slots)
+    return rows
+
+
+class LatentMoELM(HybridBlock):
+    """Decoder-only LM of latent-attention layers (MLA: queries, keys
+    and values through low-rank latents, one rotary key shared by all
+    heads, scores over ``nope_dim + rope_dim`` dimensions and values of
+    ``v_dim`` — the value head size differs from the key's) whose
+    feed-forward part is, layer by layer (``mlp_layer_types``), "dense"
+    — one SwiGLU of ``dense_width`` — or "sparse": a top-``top_k`` routed
+    layer of SwiGLU experts of ``expert_width`` of which this block HOLDS
+    ``held = (first, count)`` of the ``num_routed`` the router runs over
+    (``parallel.moe.routed_experts``; sigmoid or softmax ``scoring``, a
+    held selection bias ``router_bias`` — ``grad_req`` null: the step
+    does not update it — under ``selection_bias``, weights times
+    ``route_scale``), beside ``shared_experts`` shared ones (one SwiGLU
+    of ``shared_experts x expert_width``) that every token takes.  With
+    ``mtp_depth`` multi-token-prediction modules after the trunk, each
+    one more sparse layer between a projection of ``[embedding ;
+    state]`` and a final norm of its own, sharing embedding and head
+    (DeepSeek-V3 / JoyAI-LLM-Flash).  Pre-norm, RMSNorm, no biases,
+    untied head.
+
+    Input ``(B, T)`` token ids; output the final-normed states ``(B, T,
+    U)`` and, with prediction modules, theirs ``(B, mtp_depth, T, U)``
+    beside them (:func:`latent_moe_lm_forward`, which this block only
+    wraps).  The head is a parameter of this block (``head_weight``) but
+    its product is the loss's: ``lm_loss()`` sends every term through it
+    fused with its cross-entropy.  Expert weights are stacked leaves,
+    ``l{i}_gate_weight (count, F, U)``.  Each layer and module is
+    rematerialised in the backward pass (the block's own property, no
+    option)."""
+
+    def __init__(self, vocab_size, units=128, dense_width=256,
+                 expert_width=64, mlp_layer_types=(DENSE, SPARSE),
+                 num_heads=4, q_rank=48, kv_rank=32, nope_dim=16,
+                 rope_dim=8, v_dim=16, num_routed=8, held=None, top_k=2,
+                 shared_experts=1, scoring="sigmoid", selection_bias=True,
+                 route_scale=1.0, norm_topk=True, rope_base=10000.0,
+                 rope_interleaved=True, mtp_depth=0, epsilon=1e-6, **kwargs):
+        super().__init__(**kwargs)
+        held = (0, num_routed) if held is None else \
+            (int(held[0]), int(held[1]))
+        if rope_dim % 2:
+            raise ValueError("rope_dim (%d) must be even" % rope_dim)
+        if any(k not in (DENSE, SPARSE) for k in mlp_layer_types):
+            raise ValueError("mlp_layer_types are %r or %r, got %r"
+                             % (DENSE, SPARSE, list(mlp_layer_types)))
+        if scoring not in ("sigmoid", "softmax"):
+            raise ValueError("scoring is sigmoid or softmax, got %r"
+                             % (scoring,))
+        if held[0] < 0 or held[1] < 1 or sum(held) > num_routed \
+                or not 1 <= top_k <= num_routed:
+            raise ValueError("held experts %r and top_k %d do not fit %d "
+                             "routed experts" % (held, top_k, num_routed))
+        self._config = dict(
+            mlp_layer_types=tuple(mlp_layer_types), num_heads=num_heads,
+            nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim, top_k=top_k,
+            held=held, scoring=scoring, route_scale=float(route_scale),
+            norm_topk=bool(norm_topk), shared_expert=bool(shared_experts),
+            rope_base=float(rope_base),
+            rope_interleaved=bool(rope_interleaved),
+            mtp_depth=int(mtp_depth), eps=epsilon)
+        self._num_routed = num_routed
+        n, f, fs = held[1], expert_width, shared_experts * expert_width
+        attn = {"q_a_weight": (q_rank, units),
+                "q_a_norm_gamma": (q_rank,),
+                "q_b_weight": (num_heads * (nope_dim + rope_dim), q_rank),
+                "kv_a_weight": (kv_rank + rope_dim, units),
+                "kv_a_norm_gamma": (kv_rank,),
+                "kv_b_weight": (num_heads * (nope_dim + v_dim), kv_rank),
+                "out_weight": (units, num_heads * v_dim)}
+        ffn = {DENSE: {"gate_weight": (dense_width, units),
+                       "up_weight": (dense_width, units),
+                       "down_weight": (units, dense_width)},
+               SPARSE: {"router_weight": (num_routed, units),
+                        "router_bias": (num_routed,),
+                        "gate_weight": (n, f, units),
+                        "up_weight": (n, f, units),
+                        "down_weight": (n, units, f),
+                        "shared_gate_weight": (fs, units),
+                        "shared_up_weight": (fs, units),
+                        "shared_down_weight": (units, fs)}}
+        absent = (() if selection_bias else ("router_bias",)) + (
+            () if shared_experts else ("shared_gate_weight",
+                                       "shared_up_weight",
+                                       "shared_down_weight"))
+
+        def layer(prefix, kind):
+            return [(prefix + k, {**attn, **ffn[kind]}.get(k, (units,)))
+                    for k in _LATENT_ATTN_LEAVES + _LATENT_FFN_LEAVES[kind]
+                    if k not in absent]
+
+        shapes = [("embed_weight", (vocab_size, units))]
+        for i, kind in enumerate(mlp_layer_types):
+            shapes += layer("l%d_" % i, kind)
+        shapes.append(("norm_gamma", (units,)))
+        for k in range(mtp_depth):
+            pre = "mtp%d_" % k
+            shapes += [(pre + "embed_norm_gamma", (units,)),
+                       (pre + "hidden_norm_gamma", (units,)),
+                       (pre + "proj_weight", (units, 2 * units))]
+            shapes += layer(pre, SPARSE) + [(pre + "norm_gamma", (units,))]
+        shapes.append(("head_weight", (vocab_size, units)))
+        with self.name_scope():
+            # the initializer reads the suffix: gains 1, the bias 0; the
+            # selection bias is held, not trained
+            for name, shp in shapes:
+                held_only = {"grad_req": "null"} \
+                    if name.endswith("router_bias") else {}
+                setattr(self, name, self.params.get(name, shape=shp,
+                                                    **held_only))
+        self._export_gauges()
+
+    def _export_gauges(self):
+        from ... import telemetry
+        c = self._config
+        _export_expert_gauges(self._num_routed, c["held"][1], c["top_k"])
+        scoring = telemetry.gauge(
+            "mxnet_moe_scoring", "1 at the scoring (sigmoid / softmax) the "
+            "newest LatentMoELM's routers choose by, 0 at the other")
+        for kind in ("sigmoid", "softmax"):
+            scoring.labels(scoring=kind).set(int(kind == c["scoring"]))
+        layers = telemetry.gauge(
+            "mxnet_mlp_layers", "layers of the newest LatentMoELM's trunk "
+            "by kind of feed-forward part (dense / sparse)")
+        for kind in (DENSE, SPARSE):
+            layers.labels(kind=kind).set(c["mlp_layer_types"].count(kind))
+        telemetry.gauge("mxnet_mtp_depth", "multi-token-prediction modules "
+                        "of the newest LatentMoELM").set(c["mtp_depth"])
+
+    def hybrid_forward(self, F, tokens, **params):
+        from ...imperative import invoke_fn
+        names = [n for n in params if n != "head_weight"]
+        config = self._config
+        _export_expert_rows("LatentMoELM", tokens.shape[0] * tokens.shape[1],
+                            config["top_k"], config["held"],
+                            self._num_routed)
+
+        def forward(tokens_, *leaves):
+            return latent_moe_lm_forward(dict(zip(names, leaves)), tokens_,
+                                         **config)
+
+        out = invoke_fn(forward, [tokens] + [params[n] for n in names])
+        return tuple(out) if config["mtp_depth"] else out
+
+    def logits(self, states):
+        """The head over final-normed states: ``(B, T, V)``."""
+        from ... import ndarray as nd
+        return nd.dot(states, self.head_weight.data(), transpose_b=True)
+
+    def lm_loss(self, mtp_weight=0.3, **kwargs):
+        """The training objective over this block's outputs, sharing its
+        head: ``loss(*net(tokens), labels)`` — per sequence the mean
+        next-token cross-entropy, plus ``mtp_weight`` times the mean of
+        the prediction modules' (each over the positions it has)."""
+        from ..loss import LinearCELoss, MultiTokenCELoss
+        if not self._config["mtp_depth"]:
+            return LinearCELoss(params=self.params, **kwargs)
+        return MultiTokenCELoss(mtp_weight=mtp_weight, params=self.params,
+                                **kwargs)

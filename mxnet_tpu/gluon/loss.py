@@ -16,7 +16,7 @@ __all__ = ["Loss", "L2Loss", "L1Loss", "SigmoidBinaryCrossEntropyLoss",
            "SigmoidBCELoss", "SoftmaxCrossEntropyLoss", "SoftmaxCELoss",
            "KLDivLoss", "CTCLoss", "HuberLoss", "HingeLoss",
            "SquaredHingeLoss", "LogisticLoss", "TripletLoss",
-           "ExitWeightedCELoss", "LinearCELoss"]
+           "ExitWeightedCELoss", "LinearCELoss", "MultiTokenCELoss"]
 
 
 def _apply_weighting(F, loss, weight=None, sample_weight=None):
@@ -321,3 +321,57 @@ class LinearCELoss(Loss):
         loss = F.contrib.linear_cross_entropy(states, head_weight, label)
         loss = _apply_weighting(F, loss, self._weight, sample_weight)
         return _mean_all_but_batch(loss, self._batch_axis)
+
+
+class MultiTokenCELoss(Loss):
+    """Next-token cross-entropy plus ``mtp_weight`` times the mean
+    cross-entropy of ``D`` multi-token-prediction modules (no reference
+    analogue; DeepSeek-V3, arXiv:2412.19437, section 2.2):
+    ``loss(states, mtp_states, label)`` with ``states`` ``(B, T, U)``
+    the state the head reads for the next token, ``mtp_states`` ``(B,
+    D, T, U)`` the modules' own final-normed states, ``label`` ``(B,
+    T)`` the next token of every position.  Module ``k`` (from 1) at
+    position ``i`` predicts the token ``k`` after the next one,
+    ``label[i + k]``; its last ``k`` positions have no target and are
+    left out, and its term is the mean over the positions it has.  All
+    ``D + 1`` terms go through the ONE shared head ``head_weight`` ``(V,
+    U)`` — construct the loss with ``params=net.params``
+    (``gluon.contrib.transformer.LatentMoELM.lm_loss()``) — each fused
+    with its cross-entropy (``F.contrib.linear_cross_entropy``), so no
+    term's float32 logits are kept; a module's weight reaches its
+    gradients in float32, behind the head's products
+    (``F.contrib.scale_gradient``).  Returns ``(B,)``."""
+
+    def __init__(self, mtp_weight=0.3, weight=None, batch_axis=0, **kwargs):
+        super().__init__(weight, batch_axis, **kwargs)
+        self._mtp_weight = float(mtp_weight)
+        self.head_weight = self.params.get("head_weight")
+
+    @staticmethod
+    def _target(F, label, k):
+        """``label[i + k]`` at position ``i``; the last ``k`` wrap round
+        (the caller cuts them off)."""
+        return F.concat(F.slice_axis(label, axis=1, begin=k, end=None),
+                        F.slice_axis(label, axis=1, begin=0, end=k), dim=1)
+
+    def hybrid_forward(self, F, states, mtp_states, label, head_weight,
+                       sample_weight=None):
+        import jax
+        from ..telemetry import phases
+        t, depth = label.shape[1], mtp_states.shape[1]
+        loss = F.contrib.linear_cross_entropy(states, head_weight,
+                                              label).mean(axis=1)
+        # a module's weight multiplies its gradients where the term READS
+        # (F.contrib.scale_gradient says why), so the term's value is
+        # brought to weight x term beside it
+        w = self._mtp_weight / depth
+        weigh = lambda a: F.contrib.scale_gradient(a, scale=w)
+        with jax.named_scope(phases.MTP_SCOPE):
+            for k in range(1, depth + 1):
+                ce = F.contrib.linear_cross_entropy(
+                    weigh(mtp_states[:, k - 1]), weigh(head_weight),
+                    self._target(F, label, k))
+                term = F.slice_axis(ce, axis=1, begin=0,
+                                    end=t - k).mean(axis=1)
+                loss = loss + term - (1.0 - w) * F.BlockGrad(term)
+        return _apply_weighting(F, loss, self._weight, sample_weight)

@@ -833,7 +833,10 @@ def _fused_bn_relu(data, gamma, beta, moving_mean, moving_var, eps=1e-5,
 def _flash_attention_op(q, k, v, causal=False, scale=None, window=None,
                         **attrs):
     """Softmax attention over (B, T, H, D) tensors; K/V may carry fewer
-    heads (GQA).  Dispatches to the Pallas flash kernel on TPU (O(T)
+    heads (GQA), and V a head size of its own, (B, T, H, Dv) — the
+    result is then (B, T, H, Dv) and the default scale still ``D **
+    -0.5`` (latent attention: 192-wide keys over 128-wide values).
+    Dispatches to the Pallas flash kernel on TPU (O(T)
     memory), the einsum path elsewhere (mxnet_tpu/parallel/attention.py
     local_attention).  ``window`` (with ``causal``): a query sees its
     last ``window`` keys, itself among them.  For sequence-sharded T use
@@ -881,15 +884,18 @@ def yarn_inv_freq(dim, base, factor, original_max_position, beta_fast=32.0,
 
 
 @register("_contrib_rotary_embedding")
-def _rotary_embedding(data, base=10000.0, inv_freq=None, scale=1.0, **attrs):
+def _rotary_embedding(data, base=10000.0, inv_freq=None, scale=1.0,
+                      interleaved=False, **attrs):
     """Rotary position embedding (Su et al., arXiv:2104.09864) over
     ``(B, T, H, D)`` with HALF-SPLIT pairing: element ``i < D/2`` turns
     with element ``i + D/2`` by ``t * base**(-2i/D)`` — or by ``t *
     inv_freq[i]`` where a layer brings a frequency table of its own
     (``D/2`` numbers: :func:`yarn_inv_freq`); cos and sin are multiplied
-    by ``scale`` (YaRN's attention factor).  The angles, sines and the
-    rotation run in float32; the result is cast back to ``data``'s
-    dtype."""
+    by ``scale`` (YaRN's attention factor).  ``interleaved``: the pairing
+    is ``(2i, 2i + 1)`` instead, each pair turned where it lies (the
+    layout GPT-J and DeepSeek's latent attention store their rotary
+    dimensions in).  The angles, sines and the rotation run in float32;
+    the result is cast back to ``data``'s dtype."""
     t, d = data.shape[1], data.shape[-1]
     half = d // 2
     if inv_freq is None:
@@ -907,8 +913,15 @@ def _rotary_embedding(data, base=10000.0, inv_freq=None, scale=1.0, **attrs):
     if float(scale) != 1.0:
         cos, sin = cos * float(scale), sin * float(scale)
     xf = data.astype(jnp.float32)
-    x1, x2 = xf[..., :half], xf[..., half:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    if _boolattr(interleaved):
+        pairs = xf.reshape(xf.shape[:-1] + (half, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                        -1).reshape(xf.shape)
+    else:
+        x1, x2 = xf[..., :half], xf[..., half:]
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                              -1)
     return out.astype(data.dtype)
 
 
@@ -939,6 +952,26 @@ def _linear_cross_entropy(data, weight, label, **attrs):
         return lse - picked
 
     return ce(data, weight, label.astype(jnp.int32))
+
+
+@register("_contrib_scale_gradient")
+def _scale_gradient(data, scale=1.0, **attrs):
+    """``data`` as it is; its gradient times ``scale``.  A term's weight
+    put here, on what the term reads, multiplies the gradients AFTER the
+    products that make them.  Put on the term itself it rides the
+    cotangent INTO those products, whose operands the TPU rounds to
+    bfloat16 — and under a cross-entropy every label's entry of that
+    cotangent is the same number, ``-weight / positions``, so one
+    rounding (0.3 / 8191 goes up by 0.26 %) tilts a whole gradient."""
+    scale = float(scale)
+
+    @jax.custom_vjp
+    def scaled(x):
+        return x
+
+    scaled.defvjp(lambda x: (x, None),
+                  lambda _res, g: ((g * scale).astype(g.dtype),))
+    return scaled(data)
 
 
 @register("_contrib_exit_weighted_loss")

@@ -522,11 +522,12 @@ def _flash_tiles(kernel, bq, bk, dtype):
 _FLASH_SEMANTICS = ("parallel", "parallel", "arbitrary")
 
 
-def _flash_q_major_specs(d, bq, bk, causal, window=None):
+def _flash_q_major_specs(d, dv, bq, bk, causal, window=None):
     """Specs of the kernels on grid (BH, nQ, nK): the Q side follows
     i; the K side follows j, held at the last block row i needs under
     the causal mask — and at the first one under a window — so the
-    masked steps fetch nothing."""
+    masked steps fetch nothing.  ``(q, k, lse, v, o)``: the values and
+    the output are ``dv`` wide."""
     def kmap(b, i, j):
         if not causal:
             return (b, j, 0)
@@ -536,35 +537,42 @@ def _flash_q_major_specs(d, bq, bk, causal, window=None):
         return (b, j, 0)
     return (pl.BlockSpec((None, bq, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((None, bk, d), kmap),
-            pl.BlockSpec((None, bq, LANES), lambda b, i, j: (b, i, 0)))
+            pl.BlockSpec((None, bq, LANES), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((None, bk, dv), kmap),
+            pl.BlockSpec((None, bq, dv), lambda b, i, j: (b, i, 0)))
 
 
 def flash_fwd_plan(bh, tq, tk, d, bq, bk, causal=False,
-                   dtype=jnp.float32, window=None):
-    """Plan of the flash-attention forward kernel (q, k, v -> o, lse)."""
-    qspec, kspec, lmspec = _flash_q_major_specs(d, bq, bk, causal, window)
+                   dtype=jnp.float32, window=None, dv=None):
+    """Plan of the flash-attention forward kernel (q, k, v -> o, lse);
+    ``dv``: the head size of ``v`` and ``o`` where it is not ``d``."""
+    dv = d if dv is None else dv
+    qspec, kspec, lmspec, vspec, ospec = _flash_q_major_specs(
+        d, dv, bq, bk, causal, window)
     return {
         "grid": (bh, tq // bq, tk // bk),
-        "in_specs": [qspec, kspec, kspec],
-        "in_shapes": [(bh, tq, d), (bh, tk, d), (bh, tk, d)],
-        "out_specs": [qspec, lmspec],
-        "out_shapes": [(bh, tq, d), (bh, tq, LANES)],
-        "scratch": [(bq, d), (bq, LANES), (bq, LANES)],
+        "in_specs": [qspec, kspec, vspec],
+        "in_shapes": [(bh, tq, d), (bh, tk, d), (bh, tk, dv)],
+        "out_specs": [ospec, lmspec],
+        "out_shapes": [(bh, tq, dv), (bh, tq, LANES)],
+        "scratch": [(bq, dv), (bq, LANES), (bq, LANES)],
         "dtypes": [dtype] * 4 + [jnp.float32],
         "tiles": _flash_tiles("fwd", bq, bk, dtype),
     }
 
 
 def flash_bwd_dq_plan(bh, tq, tk, d, bq, bk, causal=False,
-                      dtype=jnp.float32, window=None):
+                      dtype=jnp.float32, window=None, dv=None):
     """Plan of the dq backward kernel
     (q, k, v, do, lse, delta -> dq)."""
-    qspec, kspec, lmspec = _flash_q_major_specs(d, bq, bk, causal, window)
+    dv = d if dv is None else dv
+    qspec, kspec, lmspec, vspec, ospec = _flash_q_major_specs(
+        d, dv, bq, bk, causal, window)
     return {
         "grid": (bh, tq // bq, tk // bk),
-        "in_specs": [qspec, kspec, kspec, qspec, lmspec, lmspec],
-        "in_shapes": [(bh, tq, d), (bh, tk, d), (bh, tk, d),
-                      (bh, tq, d), (bh, tq, LANES), (bh, tq, LANES)],
+        "in_specs": [qspec, kspec, vspec, ospec, lmspec, lmspec],
+        "in_shapes": [(bh, tq, d), (bh, tk, d), (bh, tk, dv),
+                      (bh, tq, dv), (bh, tq, LANES), (bh, tq, LANES)],
         "out_specs": [qspec],
         "out_shapes": [(bh, tq, d)],
         "scratch": [(bq, d)],
@@ -574,13 +582,14 @@ def flash_bwd_dq_plan(bh, tq, tk, d, bq, bk, causal=False,
 
 
 def flash_bwd_dkv_plan(bh, tq, tk, d, bq, bk, causal=False,
-                       dtype=jnp.float32, window=None):
+                       dtype=jnp.float32, window=None, dv=None):
     """Plan of the dk/dv backward kernel — grid (BH, nK, nQ), so the
     q-side specs transpose their two minor grid coordinates, and under
     the causal mask hold at the first Q block column j needs (under a
     window at the last one too).  ``lse`` and ``delta`` are rows here:
     (BH, nQ, 1, bq), one row a Q block."""
     nq = tq // bq
+    dv = d if dv is None else dv
 
     def qblock(j, i):
         if not causal:
@@ -591,17 +600,20 @@ def flash_bwd_dkv_plan(bh, tq, tk, d, bq, bk, causal=False,
     qspec_t = pl.BlockSpec((None, bq, d),
                            lambda b, j, i: (b, qblock(j, i), 0))
     kspec_t = pl.BlockSpec((None, bk, d), lambda b, j, i: (b, j, 0))
+    dospec_t = pl.BlockSpec((None, bq, dv),
+                            lambda b, j, i: (b, qblock(j, i), 0))
+    vspec_t = pl.BlockSpec((None, bk, dv), lambda b, j, i: (b, j, 0))
     rowspec_t = pl.BlockSpec((None, None, 1, bq),
                              lambda b, j, i: (b, qblock(j, i), 0, 0))
     return {
         "grid": (bh, tk // bk, nq),
-        "in_specs": [qspec_t, kspec_t, kspec_t, qspec_t, rowspec_t,
+        "in_specs": [qspec_t, kspec_t, vspec_t, dospec_t, rowspec_t,
                      rowspec_t],
-        "in_shapes": [(bh, tq, d), (bh, tk, d), (bh, tk, d),
-                      (bh, tq, d), (bh, nq, 1, bq), (bh, nq, 1, bq)],
-        "out_specs": [kspec_t, kspec_t],
-        "out_shapes": [(bh, tk, d), (bh, tk, d)],
-        "scratch": [(bk, d), (bk, d)],
+        "in_shapes": [(bh, tq, d), (bh, tk, d), (bh, tk, dv),
+                      (bh, tq, dv), (bh, nq, 1, bq), (bh, nq, 1, bq)],
+        "out_specs": [kspec_t, vspec_t],
+        "out_shapes": [(bh, tk, d), (bh, tk, dv)],
+        "scratch": [(bk, d), (bk, dv)],
         "dtypes": [dtype] * 4 + [jnp.float32] * 2 + [dtype] * 2,
         "tiles": _flash_tiles("dkv", bq, bk, dtype),
     }
@@ -648,7 +660,7 @@ def _block_choices(t, sub):
     return whole or [_pick_block(t, cap)]
 
 
-def _flash_blocks(tq, tk, d, dtype, kernel):
+def _flash_blocks(tq, tk, d, dtype, kernel, dv=None):
     """(bq, bk) for one flash kernel from what the call can see: the
     largest blocks, in whole tiles and dividing T, whose plan fits the
     scoped-VMEM budget graftkern holds the plans to
@@ -661,25 +673,27 @@ def _flash_blocks(tq, tk, d, dtype, kernel):
     qs, ks = _block_choices(tq, sub), _block_choices(tk, sub)
     fits = [(bq * bk, -abs(bq - bk), bq, bk) for bq in qs for bk in ks
             if _flash_vmem_bytes(_FLASH_PLANS[kernel](
-                1, tq, tk, d, bq, bk, dtype=dtype)) < budget]
+                1, tq, tk, d, bq, bk, dtype=dtype, dv=dv)) < budget]
     if not fits:
         return qs[-1], ks[-1]
     return max(fits)[2:]
 
 
-def _flash_plan(kernel, q, k, causal, block_q, block_k, window=None):
+def _flash_plan(kernel, q, k, causal, block_q, block_k, window=None,
+                dv=None):
     """(bq, bk, plan) of one kernel for this call: a caller's explicit
     blocks are honoured (halved until they divide T, as ever), a side
-    left None is picked from the shape; the choice is exported."""
+    left None is picked from the shape; the choice is exported.  ``dv``
+    is the values' head size (None: the keys')."""
     bh, tq, d = q.shape
     tk = k.shape[1]
-    bq, bk = _flash_blocks(tq, tk, d, q.dtype, kernel)
+    bq, bk = _flash_blocks(tq, tk, d, q.dtype, kernel, dv)
     if block_q is not None:
         bq = _pick_block(tq, block_q)
     if block_k is not None:
         bk = _pick_block(tk, block_k)
     plan = _FLASH_PLANS[kernel](bh, tq, tk, d, bq, bk, causal, q.dtype,
-                                window)
+                                window, dv)
     _export_flash_gauges(kernel, bq, bk, plan["grid"], window)
     return bq, bk, plan
 
@@ -725,8 +739,10 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, window=None):
     """Blockwise online-softmax attention.
 
-    q, k, v: (BH, T, D) — fold batch and heads into the leading dim.
-    Returns (BH, T, D).  O(T) memory.  The (block_q, block_k) score
+    q, k: (BH, T, D), v: (BH, T, Dv) — fold batch and heads into the
+    leading dim; the values' head size may differ from the keys' (latent
+    attention scores over 192 dimensions and sums values of 128).
+    Returns (BH, T, Dv).  O(T) memory.  The (block_q, block_k) score
     blocks are chosen per kernel from the shape — the largest whole-
     tile divisors of T that fit the scoped-VMEM budget
     (``_flash_blocks``); an explicit ``block_q`` / ``block_k`` is
@@ -746,7 +762,8 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window=None):
     _count("flash_attention_fwd")
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     window = _flash_window(window, causal, q.shape[1], k.shape[1])
-    bq, bk, plan = _flash_plan("fwd", q, k, causal, block_q, block_k, window)
+    bq, bk, plan = _flash_plan("fwd", q, k, causal, block_q, block_k, window,
+                               v.shape[-1])
     o, lse = _flash_call(
         functools.partial(_flash_fwd_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nk=k.shape[1] // bk, window=window),
@@ -766,13 +783,15 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, window, res, do):
     s = scale if scale is not None else 1.0 / math.sqrt(d)
     window = _flash_window(window, causal, tq, k.shape[1])
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    bq, bk, plan = _flash_plan("dq", q, k, causal, block_q, block_k, window)
+    bq, bk, plan = _flash_plan("dq", q, k, causal, block_q, block_k, window,
+                               v.shape[-1])
     dq, = _flash_call(
         functools.partial(_flash_bwd_dq_kernel, scale=s, causal=causal,
                           bq=bq, bk=bk, nk=k.shape[1] // bk, window=window),
         plan, q, k, v, do, lse,
         jnp.broadcast_to(delta[..., None], (bh, tq, LANES)))
-    bq, bk, plan = _flash_plan("dkv", q, k, causal, block_q, block_k, window)
+    bq, bk, plan = _flash_plan("dkv", q, k, causal, block_q, block_k, window,
+                               v.shape[-1])
     rows = (bh, tq // bq, 1, bq)        # dK/dV reads its statistics as rows
     dk, dv = _flash_call(
         functools.partial(_flash_bwd_dkv_kernel, scale=s, causal=causal,

@@ -48,7 +48,10 @@ def local_attention(q, k, v, causal=False, q_offset=0, kv_offset=0,
                     scale=None, impl="auto", kv_len=None, window=None):
     """Softmax attention on local blocks.
 
-    q: (B, Tq, H, D), k/v: (B, Tk, H, D).  Offsets give the global
+    q: (B, Tq, H, D), k: (B, Tk, H, D), v: (B, Tk, H, Dv) — the values'
+    head size may differ from the keys' (latent attention: scores over
+    192 dimensions, values of 128); the result is (B, Tq, H, Dv) and the
+    default scale is ``D ** -0.5``.  Offsets give the global
     positions of the first query/key for causal masking across shards.
     ``kv_len`` masks out keys whose global position is >= kv_len —
     the padding mask for sequences padded up to a shard multiple.
@@ -76,10 +79,10 @@ def local_attention(q, k, v, causal=False, q_offset=0, kv_offset=0,
         b, tq, h, _ = q.shape
         tk = k.shape[1]
         fold = lambda a, t: jnp.transpose(a, (0, 2, 1, 3)).reshape(
-            b * h, t, d)
+            b * h, t, a.shape[-1])
         o = flash_attention(fold(q, tq), fold(k, tk), fold(v, tk),
                             causal, scale, None, None, window)
-        return jnp.transpose(o.reshape(b, h, tq, d), (0, 2, 1, 3))
+        return jnp.transpose(o.reshape(b, h, tq, v.shape[-1]), (0, 2, 1, 3))
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     kpos = kv_offset + jnp.arange(k.shape[1])

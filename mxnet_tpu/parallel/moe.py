@@ -36,16 +36,31 @@ from ..telemetry import phases as _phases
 __all__ = ["switch_moe", "stack_experts", "routed_experts"]
 
 
-def _route_top_k(x, router_w, top_k, norm_topk=True):
+def _route_top_k(x, router_w, top_k, norm_topk=True, scoring="softmax",
+                 bias=None, scale=1.0):
     """``(weights, experts)``, both ``(T, top_k)``: the router's product
-    ``x @ router_w.T`` accumulated in float32, a float32 softmax over ALL
-    ``router_w.shape[0]`` experts, then each token's ``top_k`` largest,
-    renormalised to sum to 1 under ``norm_topk``."""
+    ``x @ router_w.T`` accumulated in float32, float32 scores over ALL
+    ``router_w.shape[0]`` experts — their softmax, or under ``scoring``
+    "sigmoid" each expert's own sigmoid — then each token's ``top_k``
+    largest, renormalised to sum to 1 under ``norm_topk`` and multiplied
+    by ``scale``.  A ``bias (E,)`` is added to the scores for the CHOICE
+    alone: the weights are the chosen experts' scores without it."""
+    if scoring not in ("softmax", "sigmoid"):
+        raise MXNetError("router scoring is softmax or sigmoid, got %r"
+                         % (scoring,))
     logits = jnp.einsum("tu,eu->te", x, router_w.astype(x.dtype),
                         preferred_element_type=jnp.float32)
-    weights, experts = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    scores = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" \
+        else jax.nn.sigmoid(logits)
+    if bias is None:
+        weights, experts = lax.top_k(scores, top_k)
+    else:
+        experts = lax.top_k(scores + bias.astype(jnp.float32), top_k)[1]
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return weights, experts
 
 
@@ -220,7 +235,8 @@ def _tile_product(x, w, tile_group, used, tm):
     return _tile_einsum(x, w, tile_group, used, tm)
 
 
-def routed_experts(x, router_w, experts, top_k, held, norm_topk=True):
+def routed_experts(x, router_w, experts, top_k, held, norm_topk=True,
+                   scoring="softmax", bias=None, scale=1.0):
     """One chip's share of a top-``top_k`` routed expert layer.
 
     ``x (T, U)`` tokens; ``router_w (E, U)`` the bias-free router over
@@ -228,8 +244,11 @@ def routed_experts(x, router_w, experts, top_k, held, norm_topk=True):
     experts' SwiGLU weights stacked, ``(count, F, U)``, ``(count, F,
     U)``, ``(count, U, F)``; ``held = (first, count)``: this chip holds
     experts ``first .. first + count - 1``.  Every token is routed over
-    all ``E`` (float32 logits and softmax, then the ``top_k`` largest,
-    renormalised under ``norm_topk``); the assignments whose expert is
+    all ``E`` (float32 logits and scores — ``scoring`` "softmax" over
+    all of them or each expert's "sigmoid" — then the ``top_k`` largest,
+    chosen by score plus ``bias (E,)`` where one is given and weighed by
+    the score without it, renormalised under ``norm_topk``, times
+    ``scale``: :func:`_route_top_k`); the assignments whose expert is
     held are sorted by expert and the three products run as grouped
     products over the sorted rows; each token gets the weighted sum of
     ITS held experts' outputs — the partial result expert parallelism
@@ -254,7 +273,8 @@ def routed_experts(x, router_w, experts, top_k, held, norm_topk=True):
             "router over %d" % (first, first + count, gate.shape[0],
                                 router_w.shape[0]))
     with jax.named_scope(_phases.MOE_SCOPE):
-        weights, chosen = _route_top_k(x, router_w, top_k, norm_topk)
+        weights, chosen = _route_top_k(x, router_w, top_k, norm_topk,
+                                       scoring, bias, float(scale))
         tm = pk.GROUPED_TILE_ROWS
         src, _, dst, is_held, tile_group, used, counts = _layout(
             chosen, (first, count), tm)

@@ -24,11 +24,12 @@ __all__ = ["FWD_SCOPE", "LOSS_SCOPE", "UPDATE_SCOPE", "CODEC_SCOPE",
            "FLATTEN_SCOPE", "UNFLATTEN_SCOPE", "SWEEP_SCOPE",
            "COLLECTIVE_PREFIX", "LOOP_SCOPE", "EXIT_SCOPE", "MOE_SCOPE",
            "MOE_EXPERTS_SCOPE", "ATTN_WINDOW_SCOPE", "ATTN_FULL_SCOPE",
-           "FWD", "BWD", "UPDATE", "COLLECTIVE", "CONTROL", "OTHER", "LOOP",
+           "ATTN_LATENT_SCOPE", "SHARED_EXPERT_SCOPE", "MTP_SCOPE", "FWD", "BWD", "UPDATE", "COLLECTIVE", "CONTROL", "OTHER", "LOOP",
            "EXIT", "ROUTE", "EXPERTS", "ATTN_WINDOW", "ATTN_FULL",
-           "phase_of", "instruction_phases", "loop_part_of",
+           "ATTN_LATENT", "SHARED_EXPERT", "phase_of", "instruction_phases", "loop_part_of",
            "instruction_loop_parts", "block_part_of",
-           "instruction_block_parts", "register_program", "program_hlo",
+           "instruction_block_parts", "latent_part_of",
+           "instruction_latent_parts", "register_program", "program_hlo",
            "program_names"]
 
 FWD_SCOPE, LOSS_SCOPE = "mx_fwd", "mx_loss"
@@ -49,6 +50,17 @@ LOOP_SCOPE, EXIT_SCOPE = "mx_loop", "mx_exit"
 # before them
 MOE_SCOPE, MOE_EXPERTS_SCOPE = "mx_moe", "mx_moe_experts"
 ATTN_WINDOW_SCOPE, ATTN_FULL_SCOPE = "mx_attn_window", "mx_attn_full"
+# inside mx_fwd, in a block of latent-attention layers with a shared
+# expert and multi-token-prediction modules
+# (gluon.contrib.transformer.LatentMoELM): what latent attention runs
+# around its flash call (the down-projections, the latents' norms, the
+# up-projections, the split and the shared rotary key's broadcast: the
+# call itself and its rotary are mx_attn_full); the expert every token
+# takes; a whole prediction module, which CROSSES the other parts (its
+# layer's attention and experts carry their scopes inside it).  The
+# maps match by substring, so none of these names holds an older one
+ATTN_LATENT_SCOPE, SHARED_EXPERT_SCOPE = "mx_attn_latent", "mx_shared_expert"
+MTP_SCOPE = "mx_mtp"
 # what jax writes into the name stack of a forward that is run again in
 # the backward pass (jax.checkpoint)
 REMAT_MARK = "rematted_computation"
@@ -57,6 +69,7 @@ FWD, BWD, UPDATE, COLLECTIVE, CONTROL, OTHER = \
 LOOP, EXIT = "loop", "exit"
 ROUTE, EXPERTS = "route", "experts"
 ATTN_WINDOW, ATTN_FULL = "attn_window", "attn_full"
+ATTN_LATENT, SHARED_EXPERT = "attn_latent", "shared_expert"
 
 
 def phase_of(op_name):
@@ -98,6 +111,19 @@ def block_part_of(op_name):
         ATTN_WINDOW if ATTN_WINDOW_SCOPE in op_name else \
         ATTN_FULL if ATTN_FULL_SCOPE in op_name else None
     return part, REMAT_MARK in op_name
+
+
+def latent_part_of(op_name):
+    """``(part, in_mtp)`` of an HLO ``op_name`` in a block of latent-
+    attention layers: ``part`` is :data:`ATTN_LATENT` (scope
+    ``mx_attn_latent``), :data:`SHARED_EXPERT` (``mx_shared_expert``) or
+    None; ``in_mtp`` whether the instruction belongs to a multi-token-
+    prediction module (``mx_mtp``), whatever its part."""
+    if not op_name:
+        return None, False
+    part = ATTN_LATENT if ATTN_LATENT_SCOPE in op_name else \
+        SHARED_EXPERT if SHARED_EXPERT_SCOPE in op_name else None
+    return part, MTP_SCOPE in op_name
 
 
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%([\w.\-]+) = (.*)$")
@@ -219,6 +245,16 @@ def instruction_block_parts(hlo_text):
     ``route``, ``experts``, ``attn_window``, ``attn_full`` or None
     (:func:`block_part_of`); the same inheritance."""
     return _classify(hlo_text, block_part_of, (None, False), (None, False))
+
+
+def instruction_latent_parts(hlo_text):
+    """``{instruction name: (part, in_mtp)}`` beside
+    :func:`instruction_block_parts`, for a program of latent-attention
+    layers with a shared expert and prediction modules: ``part`` is
+    ``attn_latent``, ``shared_expert`` or None, ``in_mtp`` whether a
+    prediction module runs it (:func:`latent_part_of`); the same
+    inheritance."""
+    return _classify(hlo_text, latent_part_of, (None, False), (None, False))
 
 
 # name -> [jitted fn, abstract args, context factory or None, HLO text]

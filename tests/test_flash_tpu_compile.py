@@ -28,33 +28,38 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-# (BH, Tq, Tk, D, dtype, causal): the two LM cells of the benchmark, a
+# (BH, Tq, Tk, D, Dv, dtype, causal): the two LM cells of the benchmark, a
 # longer sequence, float32 operands at a wide head (the blocks shrink to
-# fit VMEM), a sequence of no whole lane tile, and Tq != Tk
-@pytest.mark.parametrize("bh,tq,tk,d,dtype,causal", [
-    (64, 2048, 2048, 64, "bfloat16", True),
-    (16, 2048, 2048, 128, "bfloat16", True),
-    (2, 8192, 8192, 128, "bfloat16", True),
-    (2, 2048, 2048, 256, "float32", True),
-    (2, 200, 200, 64, "float32", True),
-    (2, 384, 128, 128, "bfloat16", False),
+# fit VMEM), a sequence of no whole lane tile, Tq != Tk, and latent
+# attention's pair — keys of 192 (a lane tile and a half: what Mosaic
+# may refuse and interpret mode cannot show) over values of 128 — at the
+# fifth cell's 8192 tokens, in bfloat16 and in float32
+@pytest.mark.parametrize("bh,tq,tk,d,dv,dtype,causal", [
+    (64, 2048, 2048, 64, 64, "bfloat16", True),
+    (16, 2048, 2048, 128, 128, "bfloat16", True),
+    (2, 8192, 8192, 128, 128, "bfloat16", True),
+    (2, 2048, 2048, 256, 256, "float32", True),
+    (2, 200, 200, 64, 64, "float32", True),
+    (2, 384, 128, 128, 128, "bfloat16", False),
+    (2, 8192, 8192, 192, 128, "bfloat16", True),
+    (2, 2048, 2048, 192, 128, "float32", True),
 ])
 def test_flash_kernels_compile_for_the_chip(one_chip, monkeypatch, bh, tq,
-                                            tk, d, dtype, causal):
+                                            tk, d, dv, dtype, causal):
     monkeypatch.setattr(pk, "_interpret", lambda: False)
 
     def loss(q, k, v):
         o = pk.flash_attention(q, k, v, causal)
         return jnp.sum(o.astype(jnp.float32) ** 2)
 
-    def aval(t):
-        return jax.ShapeDtypeStruct((bh, t, d), jnp.dtype(dtype),
+    def aval(t, width=d):
+        return jax.ShapeDtypeStruct((bh, t, width), jnp.dtype(dtype),
                                     sharding=one_chip)
     # the suite asks for float32 products (conftest.py); the chip runs
     # with the default, and Mosaic takes no bf16 operand at "highest"
     with jax.default_matmul_precision("default"):
         compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
-            aval(tq), aval(tk), aval(tk)).compile()
+            aval(tq), aval(tk), aval(tk, dv)).compile()
     text = compiled.as_text()
     for name in ("_flash_fwd_kernel", "_flash_bwd_dq_kernel",
                  "_flash_bwd_dkv_kernel"):
