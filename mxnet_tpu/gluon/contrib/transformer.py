@@ -250,6 +250,26 @@ class TransformerLM(HybridBlock):
         return {"config": config, "params": params}
 
 
+def _keep_flash():
+    """The ``policy`` of the ``jax.checkpoint`` that ``moe_lm_forward``
+    and ``latent_moe_lm_forward`` wrap each layer (each prediction
+    module) in: keep the state that enters and, of what the layer
+    computes, the flash call's output and row statistics alone
+    (``pallas_kernels.FLASH_KEPT``; 67 + 1 MB a layer at 32 x 8192 x 128
+    bfloat16) — the backward runs the layer again WITHOUT its flash
+    forward, which would re-make just those two.  A layer under it tells
+    its attention op ``kept=True``, so the statistics are held one
+    float32 a row; the einsum form (off the TPU) names nothing and is run
+    again whole.
+    ``looped_lm_forward``'s pass keeps nothing: what a pass keeps is
+    stacked over the ``scan``, and writing it there and reading it back
+    cost what the forward kernel took (PERF.md, PR 35)."""
+    import jax
+
+    from ...ops.pallas_kernels import FLASH_KEPT
+    return jax.checkpoint_policies.save_only_these_names(*FLASH_KEPT)
+
+
 # one looped layer's leaves, in construction order
 _LOOPED_LAYER_LEAVES = (
     "norm1_gamma", "q_weight", "k_weight", "v_weight", "out_weight",
@@ -270,9 +290,11 @@ def looped_lm_forward(params, tokens, *, num_layers, num_heads, num_passes,
     applied ``num_passes`` times WITH THE SAME WEIGHTS inside one
     ``lax.scan``; after every pass the state is normed (the normed state
     enters the next pass), and read by a one-output exit gate.  With
-    ``remat`` each pass of the stack is a ``jax.checkpoint``: the
+    ``remat`` each pass of the stack is a bare ``jax.checkpoint``: the
     backward pass keeps the state that enters a pass and runs the pass
-    again, so activations cost one pass, not ``num_passes``.
+    again, flash forward and all (:func:`_keep_flash` says why nothing
+    more is kept here), so activations cost one pass, not
+    ``num_passes``.
 
     Returns ``(logits, states, gates)``, batch-major: the LAST exit's
     logits ``(B, T, V)``, every exit's normed state ``(B, P, T, U)`` and
@@ -433,8 +455,11 @@ def moe_lm_forward(params, tokens, *, layer_types, num_heads, num_kv_heads,
     — and one chip's share of a top-``top_k`` routed expert layer
     (:func:`parallel.moe.routed_experts`: the router over ALL published
     experts, ``held = (first, count)`` the ones whose stacked weights
-    are here).  Each layer is a ``jax.checkpoint``: the backward pass
-    keeps the state that enters a layer and runs the layer again."""
+    are here).  Each layer is a ``jax.checkpoint``
+    (:func:`_keep_flash`): the backward pass keeps the state that
+    enters a layer and runs the layer again — except the flash call,
+    whose output and row statistics are kept (67 + 1 MB a layer at 32 x
+    8192 x 128)."""
     import jax
     import jax.numpy as jnp
 
@@ -457,7 +482,7 @@ def moe_lm_forward(params, tokens, *, layer_types, num_heads, num_kv_heads,
             o = _flash_attention_op(
                 turn(heads(wq, num_heads)), turn(heads(wk, num_kv_heads)),
                 heads(wv, num_kv_heads), causal=True,
-                window=window if kind == SLIDING else None)
+                window=window if kind == SLIDING else None, kept=True)
         x = x + jnp.einsum("bto,uo->btu", o.reshape(b, t, -1), wo)
         h = _rms_norm(x, n2, eps=eps)
         y = routed_experts(h.reshape(b * t, u), wr, (wg, wu, wd), top_k,
@@ -467,7 +492,8 @@ def moe_lm_forward(params, tokens, *, layer_types, num_heads, num_kv_heads,
     x = params["embed_weight"][tokens.astype(jnp.int32)]
     for i, kind in enumerate(layer_types):
         p = [params["l%d_%s" % (i, n)] for n in _MOE_LAYER_LEAVES]
-        x = jax.checkpoint(layer, static_argnums=2)(x, p, kind)
+        x = jax.checkpoint(layer, static_argnums=2,
+                           policy=_keep_flash())(x, p, kind)
     return _rms_norm(x, params["norm_gamma"], eps=eps)
 
 
@@ -618,7 +644,8 @@ _MTP_FRONT_LEAVES = ("embed_norm_gamma", "hidden_norm_gamma", "proj_weight")
 
 
 def latent_attention(h, p, *, num_heads, nope_dim, rope_dim, v_dim,
-                     rope_base=10000.0, rope_interleaved=True, eps=1e-6):
+                     rope_base=10000.0, rope_interleaved=True, eps=1e-6,
+                     kept=False):
     """Multi-head latent attention over normed states ``h (B, T, U)``:
     ``(B, T, U)``, the output projection included.  ``p`` holds one
     layer's ``q_a_weight (Rq, U)``, ``q_a_norm_gamma``, ``q_b_weight (H
@@ -629,7 +656,8 @@ def latent_attention(h, p, *, num_heads, nope_dim, rope_dim, v_dim,
     scores over ``nope + rope`` dimensions (scale their ``-0.5`` power)
     and sums values of ``v_dim`` — neither side is padded to the other.
     What runs around the flash call is under scope ``mx_attn_latent``,
-    the call and its rotary under ``mx_attn_full``."""
+    the call and its rotary under ``mx_attn_full``.  ``kept``: the
+    caller is a layer under :func:`_keep_flash`."""
     import jax
     import jax.numpy as jnp
 
@@ -659,7 +687,8 @@ def latent_attention(h, p, *, num_heads, nope_dim, rope_dim, v_dim,
             [kv[..., :nope_dim],
              jnp.broadcast_to(k_rope, (b, t, num_heads, rope_dim))], -1)
     with jax.named_scope(phases.ATTN_FULL_SCOPE):
-        o = _flash_attention_op(q, k, kv[..., nope_dim:], causal=True)
+        o = _flash_attention_op(q, k, kv[..., nope_dim:], causal=True,
+                                kept=kept)
     return jnp.einsum("bto,uo->btu", o.reshape(b, t, -1), p["out_weight"])
 
 
@@ -700,7 +729,10 @@ def latent_moe_lm_forward(params, tokens, *, mlp_layer_types, num_heads,
     output after it; embedding and head are the model's.  The last ``k``
     positions read tokens that wrap round; the loss leaves them out
     (``gluon.loss.MultiTokenCELoss``).  Each layer and each module is a
-    ``jax.checkpoint``."""
+    ``jax.checkpoint`` (:func:`_keep_flash`): the backward pass keeps
+    the state that enters it and runs it again — except the flash call,
+    whose output and row statistics are kept (67 + 1 MB a layer at 32 x
+    8192 x 128 values)."""
     import functools
 
     import jax
@@ -714,7 +746,7 @@ def latent_moe_lm_forward(params, tokens, *, mlp_layer_types, num_heads,
     attention = functools.partial(
         latent_attention, num_heads=num_heads, nope_dim=nope_dim,
         rope_dim=rope_dim, v_dim=v_dim, rope_base=rope_base,
-        rope_interleaved=rope_interleaved, eps=eps)
+        rope_interleaved=rope_interleaved, eps=eps, kept=True)
 
     def layer(x, p, kind):
         b, t, u = x.shape
@@ -753,7 +785,7 @@ def latent_moe_lm_forward(params, tokens, *, mlp_layer_types, num_heads,
     tokens = tokens.astype(jnp.int32)
     x = params["embed_weight"][tokens]
     for i, kind in enumerate(mlp_layer_types):
-        x = jax.checkpoint(layer, static_argnums=2)(
+        x = jax.checkpoint(layer, static_argnums=2, policy=_keep_flash())(
             x, layer_leaves("l%d_" % i, kind), kind)
     states = _rms_norm(x, params["norm_gamma"], eps=eps)
     if not mtp_depth:
@@ -762,7 +794,7 @@ def latent_moe_lm_forward(params, tokens, *, mlp_layer_types, num_heads,
     with jax.named_scope(phases.MTP_SCOPE):
         for k in range(mtp_depth):
             pre = "mtp%d_" % k
-            h = jax.checkpoint(module)(
+            h = jax.checkpoint(module, policy=_keep_flash())(
                 h, jnp.roll(tokens, -(k + 1), axis=1),
                 layer_leaves(pre, SPARSE), leaves(pre, _MTP_FRONT_LEAVES))
             outs.append(_rms_norm(h, params[pre + "norm_gamma"], eps=eps))

@@ -831,7 +831,7 @@ def _fused_bn_relu(data, gamma, beta, moving_mean, moving_var, eps=1e-5,
 # ---------------------------------------------------------------------------
 @register("_contrib_flash_attention")
 def _flash_attention_op(q, k, v, causal=False, scale=None, window=None,
-                        **attrs):
+                        kept=False, **attrs):
     """Softmax attention over (B, T, H, D) tensors; K/V may carry fewer
     heads (GQA), and V a head size of its own, (B, T, H, Dv) — the
     result is then (B, T, H, Dv) and the default scale still ``D **
@@ -841,7 +841,10 @@ def _flash_attention_op(q, k, v, causal=False, scale=None, window=None,
     local_attention).  ``window`` (with ``causal``): a query sees its
     last ``window`` keys, itself among them.  For sequence-sharded T use
     parallel.ring_attention / ulysses_attention over an 'sp' mesh axis
-    (which raise on a window)."""
+    (which raise on a window).  ``kept`` is not a user's to set: a
+    layer whose ``jax.checkpoint`` keeps the flash call's results
+    (``gluon.contrib.transformer._keep_flash``) says so with it, and the
+    kernels' op holds its row statistics compactly; no number moves."""
     from ..parallel.attention import local_attention, ring_attention
     from ..parallel.mesh import current_mesh
     if scale is not None:
@@ -855,7 +858,7 @@ def _flash_attention_op(q, k, v, causal=False, scale=None, window=None,
         return ring_attention(q, k, v, mesh=mesh, causal=_boolattr(causal),
                               scale=scale, window=window)
     return local_attention(q, k, v, causal=_boolattr(causal), scale=scale,
-                           window=window)
+                           window=window, kept=_boolattr(kept))
 
 
 # ---------------------------------------------------------------------------

@@ -76,9 +76,11 @@ from __future__ import annotations
 import functools
 import logging
 import math
+import re
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -186,6 +188,20 @@ NEG_INF = -1e30
 # index maps clamp to the last block the row (column) needs, fetch
 # nothing — consecutive steps name the same block and Pallas skips the
 # copy.
+#
+# What the backward reads of the forward is ``(q, k, v, o, lse)``.  The
+# forward kernel's two results carry names (``FLASH_KEPT``), identities
+# until a checkpoint's policy asks for them: a rematerialised layer of
+# ``gluon.contrib.transformer`` keeps them across its ``jax.checkpoint``
+# and runs everything else again, so the layer run again holds no flash
+# forward — the kernel would only re-make ``o`` and ``lse``.  Such a
+# caller says ``kept=True`` and ``lse`` is held as ONE float32 a row,
+# ``(BH, T)``, not as the kernel's lane-broadcast ``(BH, T, 128)``: at
+# 32 x 8192 that is 1 MB beside the output's 67 MB, where the broadcast
+# is 134 MB; dQ is then handed a broadcast of it, as it is of ``delta``.
+# Every other caller holds the kernel's own output, which dQ reads as it
+# is: the broadcast is a pass a layer (1 ms a step of the looped LM's 36
+# layer applications, PERF.md, PR 35) that only a kept ``lse`` pays for.
 
 # index-map arithmetic that serves traced grid indices and the plain
 # Python ints graftkern evaluates the same maps with (analysis/kern/)
@@ -475,6 +491,29 @@ def _export_flash_gauges(kernel, bq, bk, grid, window=None):
             kernel=kernel, **own).set(math.prod(grid))
 
 
+_FLASH_FWD_CALL = re.compile(
+    r' custom-call\(.*op_name="[^"]*\b_flash_fwd_kernel\b')
+
+
+def export_flash_fwd_calls(program, hlo_text):
+    """``mxnet_flash_fwd_calls{program}``: the forward kernel's custom
+    calls in a program's OPTIMIZED module (``telemetry.program_hlo``).
+    The ``flash_attention_fwd`` counter counts traces and cannot tell a
+    layer that keeps ``FLASH_KEPT`` across its checkpoint (one call)
+    from one that runs the kernel again in the backward pass (two)."""
+    from .. import telemetry
+    if not telemetry.enabled():
+        return
+    telemetry.gauge(
+        "mxnet_flash_fwd_calls",
+        "_flash_fwd_kernel custom calls in the optimized module of a "
+        "registered program: one an attention layer where the layer's "
+        "checkpoint keeps the kernel's results, two where the backward "
+        "pass runs it again").labels(program=program).set(
+            sum(1 for line in hlo_text.splitlines()
+                if _FLASH_FWD_CALL.search(line)))
+
+
 # one operand block, counted at the f32 width the kernels compute in:
 # in + out blocks double-buffered stay a small fraction of the 16 MiB
 # of scoped VMEM whatever the channel count
@@ -734,9 +773,14 @@ def _flash_window(window, causal, tq, tk):
     return None if window >= max(tq, tk) else window
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+# the forward kernel's results by the names a checkpoint policy keeps
+# them under: the output, the row statistics
+FLASH_KEPT = ("flash_out", "flash_lse")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    block_k=None, window=None):
+                    block_k=None, window=None, kept=False):
     """Blockwise online-softmax attention.
 
     q, k: (BH, T, D), v: (BH, T, Dv) — fold batch and heads into the
@@ -752,51 +796,61 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     square diagonal block slab by slab.  ``window`` (with ``causal``):
     a query sees its last ``window`` keys, itself among them; a block
     wholly below the window is neither fetched nor run either, and only
-    the blocks an edge of the band crosses build a mask.
+    the blocks an edge of the band crosses build a mask.  ``kept``: the
+    caller is a layer whose ``jax.checkpoint`` keeps ``FLASH_KEPT``, so
+    the backward's ``lse`` is held one float32 a row (the section's
+    header); no number moves.
     """
     o, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, window)
     return o
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window=None):
+    """``(o, lse)`` off the forward kernel; ``lse`` lane-broadcast,
+    ``(BH, T, LANES)`` float32, as the kernel writes it."""
     _count("flash_attention_fwd")
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     window = _flash_window(window, causal, q.shape[1], k.shape[1])
     bq, bk, plan = _flash_plan("fwd", q, k, causal, block_q, block_k, window,
                                v.shape[-1])
-    o, lse = _flash_call(
+    return _flash_call(
         functools.partial(_flash_fwd_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nk=k.shape[1] // bk, window=window),
         plan, q, k, v)
+
+
+def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, window, kept):
+    o, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k, window)
+    # named BEFORE they become residuals: whatever the backward reads of
+    # the two derives from a value a policy can keep
+    o = checkpoint_name(o, FLASH_KEPT[0])
+    lse = checkpoint_name(lse[:, :, 0] if kept else lse, FLASH_KEPT[1])
     return o, (q, k, v, o, lse)
 
 
-def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, window):
-    o, res = _flash_fwd(q, k, v, causal, scale, block_q, block_k, window)
-    return o, res
-
-
-def _flash_bwd_rule(causal, scale, block_q, block_k, window, res, do):
+def _flash_bwd_rule(causal, scale, block_q, block_k, window, kept, res, do):
     _count("flash_attention_bwd")
     q, k, v, o, lse = res
     bh, tq, d = q.shape
     s = scale if scale is not None else 1.0 / math.sqrt(d)
     window = _flash_window(window, causal, tq, k.shape[1])
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    # dQ reads a row statistic over the lanes, dK/dV as rows
+    lanes = lambda a: jnp.broadcast_to(a[..., None], (bh, tq, LANES))
+    lse_lanes, lse = (lanes(lse), lse) if kept else (lse, lse[:, :, 0])
     bq, bk, plan = _flash_plan("dq", q, k, causal, block_q, block_k, window,
                                v.shape[-1])
     dq, = _flash_call(
         functools.partial(_flash_bwd_dq_kernel, scale=s, causal=causal,
                           bq=bq, bk=bk, nk=k.shape[1] // bk, window=window),
-        plan, q, k, v, do, lse,
-        jnp.broadcast_to(delta[..., None], (bh, tq, LANES)))
+        plan, q, k, v, do, lse_lanes, lanes(delta))
     bq, bk, plan = _flash_plan("dkv", q, k, causal, block_q, block_k, window,
                                v.shape[-1])
-    rows = (bh, tq // bq, 1, bq)        # dK/dV reads its statistics as rows
+    rows = (bh, tq // bq, 1, bq)
     dk, dv = _flash_call(
         functools.partial(_flash_bwd_dkv_kernel, scale=s, causal=causal,
                           bq=bq, bk=bk, nq=tq // bq, window=window),
-        plan, q, k, v, do, lse[:, :, 0].reshape(rows), delta.reshape(rows))
+        plan, q, k, v, do, lse.reshape(rows), delta.reshape(rows))
     return dq, dk, dv
 
 

@@ -45,7 +45,8 @@ def _flash_eligible(q, k, causal, q_offset, kv_offset):
 
 
 def local_attention(q, k, v, causal=False, q_offset=0, kv_offset=0,
-                    scale=None, impl="auto", kv_len=None, window=None):
+                    scale=None, impl="auto", kv_len=None, window=None,
+                    kept=False):
     """Softmax attention on local blocks.
 
     q: (B, Tq, H, D), k: (B, Tk, H, D), v: (B, Tk, H, Dv) — the values'
@@ -62,6 +63,9 @@ def local_attention(q, k, v, causal=False, q_offset=0, kv_offset=0,
     impl: "auto" uses the Pallas flash kernel on TPU when offsets are
     aligned and T divides into blocks (O(T) memory instead of the
     materialized (T, T) logits); "einsum"/"flash" force a path.
+    ``kept`` goes to the flash kernels' op as it is
+    (``pallas_kernels.flash_attention``); the einsum form has nothing
+    to keep.
     """
     d = q.shape[-1]
     if window is not None and not causal:
@@ -81,7 +85,7 @@ def local_attention(q, k, v, causal=False, q_offset=0, kv_offset=0,
         fold = lambda a, t: jnp.transpose(a, (0, 2, 1, 3)).reshape(
             b * h, t, a.shape[-1])
         o = flash_attention(fold(q, tq), fold(k, tk), fold(v, tk),
-                            causal, scale, None, None, window)
+                            causal, scale, None, None, window, kept)
         return jnp.transpose(o.reshape(b, h, tq, v.shape[-1]), (0, 2, 1, 3))
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale

@@ -293,6 +293,8 @@ def program_hlo(name):
         with scope() if scope is not None else contextlib.nullcontext():
             ent[3] = jit_fn.lower(*args).compile().as_text()
         ent[:3] = None, None, None      # the text is all that is needed
+        from ..ops.pallas_kernels import export_flash_fwd_calls
+        export_flash_fwd_calls(name, ent[3])
     return ent[3]
 
 

@@ -47,3 +47,49 @@ def _seed_rng():
     np.random.seed(0)
     mx.random.seed(0)
     yield
+
+
+def kernel_calls(jaxpr, counts=None):
+    """``pallas_call`` equations of a jaxpr by kernel name, the bodies
+    of its ``scan`` / ``checkpoint`` / ``pjit`` equations included (each
+    body once)."""
+    import collections
+    counts = collections.Counter() if counts is None else counts
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            counts[eqn.params["name"]] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            kernel_calls(sub, counts)
+    return counts
+
+
+@pytest.fixture
+def check_flash_kept(monkeypatch):
+    """The shared check of the LM blocks whose layers keep their flash
+    results (``moe_lm``, ``latent_moe_lm``): with the flash path forced
+    (interpret mode), the gradient of ``loss(params)`` over a block of
+    ``layers`` attention layers holds ``layers`` forward kernels — the
+    backward pass runs none again — and ``layers`` of each backward
+    kernel, where a bare ``jax.checkpoint`` holds the forward twice; and
+    loss and every gradient leaf are the bare checkpoint's bit for bit:
+    the kept values are the ones the second run would make."""
+    from mxnet_tpu.gluon.contrib import transformer
+    from mxnet_tpu.parallel import attention
+    monkeypatch.setattr(attention, "_flash_eligible", lambda *a: True)
+
+    def check(loss, params, layers):
+        def run():
+            counts = kernel_calls(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+            return counts, jax.jit(jax.value_and_grad(loss))(params)
+        kept, (value, grads) = run()
+        monkeypatch.setattr(transformer, "_keep_flash", lambda: None)
+        bare, (bare_value, bare_grads) = run()
+        assert kept["_flash_fwd_kernel"] == layers, kept
+        assert bare["_flash_fwd_kernel"] == 2 * layers, bare
+        for name in ("_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel"):
+            assert kept[name] == bare[name] == layers, (name, kept, bare)
+        assert np.array_equal(np.asarray(value), np.asarray(bare_value))
+        for k in grads:
+            assert np.array_equal(np.asarray(grads[k]),
+                                  np.asarray(bare_grads[k])), k
+    return check

@@ -104,3 +104,57 @@ def test_row_movers_compile_for_the_chip(one_chip, monkeypatch, tokens,
     for name in ("_moe_rows_kernel", "_moe_slots_kernel",
                  "_moe_words_kernel"):
         assert name in text
+
+
+def test_the_gauge_counts_the_forward_kernels_of_a_registered_step(
+        one_chip, monkeypatch):
+    """``mxnet_flash_fwd_calls{program}`` off the OPTIMIZED module of a
+    two-layer ``MoELM`` step (loss, gradients, an SGD update) registered
+    through ``telemetry.register_program``: one forward kernel a layer
+    where the layers keep the kernel's results across their checkpoints,
+    two under a bare ``jax.checkpoint`` — what the trace-time counter
+    cannot tell apart — and the two backward kernels once a layer in
+    both."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.gluon.contrib import transformer
+    from mxnet_tpu.parallel import attention
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    monkeypatch.setattr(attention, "_flash_eligible", lambda *a: True)
+    net = transformer.MoELM(
+        256, units=128, expert_width=64,
+        layer_types=[transformer.SLIDING, transformer.FULL], num_heads=2,
+        num_kv_heads=1, head_dim=64, num_routed=4, held=(0, 2), top_k=2,
+        window=128)
+    config = net._config
+    short = len(net.prefix)
+    params = {name[short:]: jax.ShapeDtypeStruct(
+        p.shape, jnp.bfloat16, sharding=one_chip)
+        for name, p in net.collect_params().items()
+        if not name.endswith("head_weight")}
+    tokens = jax.ShapeDtypeStruct((2, 256), jnp.int32, sharding=one_chip)
+
+    def calls(program):
+        def step(params_, tokens_):         # a trace of its own a program
+            def loss(p):
+                states = transformer.moe_lm_forward(p, tokens_, **config)
+                return jnp.mean(states.astype(jnp.float32) ** 2)
+            grads = jax.grad(loss)(params_)
+            return jax.tree_util.tree_map(lambda w, g: w - 0.1 * g,
+                                          params_, grads)
+
+        telemetry.register_program(program, jax.jit(step), (params, tokens))
+        with jax.default_matmul_precision("default"):
+            text = telemetry.program_hlo(program)
+        for kernel in ("_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel"):
+            assert sum(1 for line in text.splitlines()
+                       if " custom-call(" in line and kernel in line) == 2
+        return telemetry.gauge("mxnet_flash_fwd_calls").labels(
+            program=program).value
+
+    telemetry.enable()
+    try:
+        assert calls("kept") == 2
+        monkeypatch.setattr(transformer, "_keep_flash", lambda: None)
+        assert calls("bare") == 4
+    finally:
+        telemetry.disable()

@@ -644,6 +644,26 @@ def test_moe_lm_step_program_is_unchanged_by_the_new_arguments(monkeypatch):
     assert spelled == plain
 
 
+def test_a_rematerialised_layer_keeps_its_flash_results(ref,
+                                                        check_flash_kept):
+    """The dense layer, one sparse layer and the prediction module's own
+    layer: three flash calls at keys of 24 over values of 16
+    (``check_flash_kept``, shared with the other block whose layers
+    keep them)."""
+    config = dict(CONFIG, num_hidden_layers=2)
+    weights = ref.init_weights(config, 2 ** 31 + 35)
+    net = _block(ref, config, weights)
+    tokens = jnp.asarray(_batch(35)[0])
+
+    def loss(params):
+        states, mtp_states = transformer.latent_moe_lm_forward(
+            params, tokens, **net._config)
+        return jnp.mean(states ** 2) + jnp.mean(mtp_states ** 2)
+
+    check_flash_kept(loss, {k: jnp.asarray(v) for k, v in weights.items()
+                            if k != "head_weight"}, 3)
+
+
 def test_the_block_says_what_it_is_in_gauges():
     from mxnet_tpu import telemetry
     telemetry.enable()

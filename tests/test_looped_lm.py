@@ -268,6 +268,33 @@ def test_gradients_with_and_without_rematerialisation_are_bit_identical(ref):
         and "mx_exit" in text
 
 
+def test_a_rematerialised_pass_runs_its_flash_forward_again(monkeypatch,
+                                                            ref):
+    """The pass's checkpoint is bare, unlike the layers' of the two
+    sparse-expert blocks (``check_flash_kept``): what a pass keeps is
+    stacked over the scan, and on the chip the stacking cost what the
+    forward kernel took (PERF.md, PR 35); nor does the pass say
+    ``kept``, so its step is the program it was.  The scan's two bodies
+    hold the stack once each: two layers, four forward kernels, two of
+    each backward kernel."""
+    from conftest import kernel_calls
+    from mxnet_tpu.parallel import attention
+    monkeypatch.setattr(attention, "_flash_eligible", lambda *a: True)
+    w = {k: jnp.asarray(v) for k, v in _weights(ref, CONFIG, 35).items()}
+    x = jnp.asarray(_batch(CONFIG, 36)[0])
+
+    def loss(params):
+        logits, states, gates = looped_lm_forward(
+            params, x, num_layers=2, num_heads=4, num_passes=4, eps=1e-6,
+            rope_base=1e6)
+        return jnp.mean(logits ** 2) + jnp.mean(states ** 2) \
+            + jnp.mean(gates ** 2)
+
+    assert kernel_calls(jax.make_jaxpr(jax.grad(loss))(w).jaxpr) == {
+        "_flash_fwd_kernel": 4, "_flash_bwd_dq_kernel": 2,
+        "_flash_bwd_dkv_kernel": 2}
+
+
 def test_three_trainer_steps_follow_the_reference(ref):
     from mxnet_tpu.parallel import ParallelTrainer, make_mesh
     cfg = dict(CONFIG, batch_size=4)

@@ -25,7 +25,7 @@ import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import autograd, nd
-from mxnet_tpu.gluon.contrib.transformer import MoELM
+from mxnet_tpu.gluon.contrib.transformer import MoELM, moe_lm_forward
 from mxnet_tpu.ops import pallas_kernels as pk
 from mxnet_tpu.ops.contrib import _rotary_embedding, yarn_inv_freq
 from mxnet_tpu.parallel.attention import (local_attention, ring_attention,
@@ -625,6 +625,23 @@ def test_flash_gauges_tell_a_windowed_instantiation_from_a_full_one():
         assert steps.labels(kernel="fwd", window=1024).value == 4 * 8 * 8
     finally:
         telemetry.disable()
+
+
+def test_a_rematerialised_layer_keeps_its_flash_results(ref,
+                                                        check_flash_kept):
+    """One window layer and one full one (``check_flash_kept``, shared
+    with the other block whose layers keep them)."""
+    config = dict(CONFIG, num_hidden_layers=2,
+                  layer_types=["sliding_attention", "full_attention"])
+    weights = ref.init_weights(config, 2 ** 31 + 35)
+    net = _block(ref, config, weights)
+    tokens = jnp.asarray(np.random.default_rng(35).integers(0, 256, (2, 96)))
+
+    def loss(params):
+        return jnp.mean(moe_lm_forward(params, tokens, **net._config) ** 2)
+
+    check_flash_kept(loss, {k: jnp.asarray(v) for k, v in weights.items()
+                            if k != "head_weight"}, 2)
 
 
 # ---------------------------------------------------------------------------

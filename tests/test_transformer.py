@@ -102,3 +102,48 @@ def test_transformer_sp_mesh_transparent():
     with parallel.mesh_scope(mesh):
         sharded = lm(toks).asnumpy()
     assert np.allclose(dense, sharded, atol=2e-4)
+
+
+def test_transformer_lm_pays_nothing_for_the_flash_names(monkeypatch):
+    """``TransformerLM`` has no checkpoint and keeps every activation:
+    the names the flash forward gives its results are identities there.
+    Its gradient holds one forward and one of each backward kernel a
+    layer, as ever, and what the backward keeps of a layer's row
+    statistics is the kernel's own lane-broadcast output, which dQ reads
+    as it is — not a one-value-a-row copy beside or in place of it (that
+    form is for a layer that says ``kept``: it costs a broadcast a
+    layer).  371,417 bytes of residuals is the reading before the names
+    (PR 34's tree, this block, these sizes)."""
+    import collections
+
+    import jax.numpy as jnp
+    from conftest import kernel_calls
+    from mxnet_tpu.ops.pallas_kernels import LANES
+    from mxnet_tpu.parallel import attention
+    from mxnet_tpu.parallel.trainer import pure_block_apply
+    monkeypatch.setattr(attention, "_flash_eligible", lambda *a: True)
+    layers, heads, batch, t = 2, 4, 2, 16
+    lm = TransformerLM(vocab_size=64, units=32, hidden_size=64,
+                       num_layers=layers, num_heads=heads, max_len=t,
+                       dropout=0.0)
+    lm.initialize(mx.init.Xavier())
+    toks = np.random.RandomState(3).randint(0, 64, (batch, t)) \
+        .astype(np.float32)
+    lm(nd.array(toks))                  # materialise the deferred shapes
+    apply = pure_block_apply(lm, list(lm.collect_params()), True)
+    params = {n: p.data()._data for n, p in lm.collect_params().items()}
+
+    def loss(p):
+        return jnp.mean(apply(p, jax.random.PRNGKey(0),
+                              jnp.asarray(toks)) ** 2)
+
+    calls = kernel_calls(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+    assert calls == {"_flash_fwd_kernel": layers,
+                     "_flash_bwd_dq_kernel": layers,
+                     "_flash_bwd_dkv_kernel": layers}
+    kept = [a for a in jax.tree_util.tree_leaves(jax.vjp(loss, params)[1])
+            if hasattr(a, "nbytes")]
+    shapes = collections.Counter(a.shape for a in kept)
+    assert shapes[(batch * heads, t, LANES)] == layers
+    assert shapes[(batch * heads, t)] == 0
+    assert sum(a.nbytes for a in kept) <= 371417
