@@ -177,6 +177,14 @@ def _compiles():
     return telemetry.scalar_totals().get("mxnet_xla_compiles_total", 0)
 
 
+def _jit_compiles():
+    """Every program jax compiled or loaded, whichever layer dispatched
+    it: ``_compiles()`` counts at the executor's dispatch alone and
+    never sees a ``ParallelTrainer``."""
+    from mxnet_tpu import telemetry
+    return telemetry.scalar_totals().get("mxnet_jit_compiles_total", 0)
+
+
 # ---------------------------------------------------------------------------
 # phase 0: the device
 # ---------------------------------------------------------------------------
@@ -659,11 +667,14 @@ def phase_four_chip(cfg, platform):
         return (jax.device_put(x.astype(np.float32), ns),
                 jax.device_put(y.astype(np.float32), ns))
 
+    compiled = []       # programs compiled so far, after each step
+
     def run(tr, x, y, steps):
         out = []
         for _ in range(steps):
             out.append(float(tr.step(nd.NDArray(x), nd.NDArray(y))
                              .asnumpy()))
+            compiled.append(_jit_compiles())
         return out
 
     mesh4 = make_mesh(dp=4)
@@ -687,8 +698,16 @@ def phase_four_chip(cfg, platform):
 
     # -- full width: global batch 1024 over 4 chips --------------------------
     x, y = batch(cfg["mc_batch"], mesh4)
+    before = _jit_compiles()
     lw = run(t4, x, y, steps=cfg["mc_steps"])
     _check(all(math.isfinite(l) for l in lw), "non-finite loss %s" % lw)
+    wide = compiled[-cfg["mc_steps"]:]
+    _check(wide[0] > before, "the new batch shape compiled nothing that "
+           "mxnet_jit_compiles_total saw (%d programs)" % before)
+    _check(wide[-1] == wide[0], "the dp4 step compiled after the first "
+           "step at its shape: %s programs after each step" % wide)
+    _log("%d program(s) at the global batch's first step, none after"
+         % (wide[0] - before))
     _log("loss dp4 global batch %d: %s"
          % (cfg["mc_batch"], ["%.4f" % l for l in lw]))
     leaves = (jax.tree_util.tree_leaves(t4._params)

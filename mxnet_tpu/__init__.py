@@ -3,6 +3,9 @@
 Structure mirrors the reference Python package (python/mxnet/__init__.py)
 while the implementation is idiomatic jax/XLA/pjit/Pallas throughout.
 """
+import time as _time
+_IMPORT_T0 = _time.perf_counter()   # first: mxnet_import_seconds, below
+
 from .libinfo import __version__  # noqa: F401
 from .base import MXNetError  # noqa: F401
 from .context import Context, cpu, gpu, tpu, current_context, num_gpus, num_tpus  # noqa: F401
@@ -84,3 +87,8 @@ if any(config.get(_k) for _k in (
 from . import fault  # noqa: F401,E402
 if config.get("MXNET_FAULT_PLAN"):
     fault.install()
+
+# last: what importing this package's own modules cost (jax, where the
+# caller had imported it already, is not in it) — a gauge once telemetry
+# is enabled; the span ring is not armed while the package imports
+telemetry.note_import_seconds(_time.perf_counter() - _IMPORT_T0)

@@ -43,7 +43,21 @@ On top of the raw wiring it adds what jax leaves out:
 - **telemetry** — ``mxnet_compile_cache_{hits,misses,evictions,
   errors}_total`` counters + a ``mxnet_compile_cache_size_bytes``
   gauge, recorded via jax's monitoring events so the numbers are the
-  cache's own truth, not a parallel guess.
+  cache's own truth, not a parallel guess;
+- **every program's compilation** — the same listeners see each jitted
+  program's trace, lowering and backend stage (a compile or a cache
+  load) with its name, whichever layer dispatched it: the executor's
+  ``fbu``, ``ParallelTrainer``'s ``step``, eager one-op programs.  With
+  telemetry on each stage lands on the span ring as ``xla.trace`` /
+  ``xla.lower`` / ``xla.compile`` (tags ``program``; ``cache`` =
+  ``hit`` / ``miss`` / ``off`` and ``load_s`` on the last), and each
+  backend stage counts in ``mxnet_jit_compiles_total``.  The spans are
+  the record of the seconds; the hit / miss split is the span's tag and
+  ``mxnet_compile_cache_hits_total``.  A function traced inside another
+  stage (another's trace, a lowering) is that stage's time: only the
+  outermost trace leaves a span.  jax reports only on a COMPILING
+  dispatch: a cached dispatch runs none of this.  A fault in the
+  recording is logged once and never reaches the compiling call.
 
 Multi-process sharing is safe by construction: jax commits entries by
 write-to-temp + rename, readers of a just-evicted entry degrade to a
@@ -85,7 +99,6 @@ _STATE = {                      # guarded-by: _LOCK
     "max_bytes": 0,
     "entries": 0,               # as of the last sweep()/stats(refresh=True)
     "size_bytes": 0,            # as of the last sweep()/stats(refresh=True)
-    "listener": False,          # jax monitoring listener installed
     "hooks": False,             # error-accounting wrappers installed
 }
 _COUNTS = {"requests": 0, "hits": 0, "misses": 0, "errors": 0,
@@ -110,17 +123,39 @@ _HELP = {
 }
 
 
+# the three stages jax reports of every program it compiles
+# (jax/_src/dispatch.py), each as a duration and as a (start, end) span:
+# event -> span name
+_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "xla.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "xla.lower",
+    "/jax/core/compile/backend_compile_duration": "xla.compile",
+}
+_JIT_COMPILES = (
+    "mxnet_jit_compiles_total",
+    "backend stages of jitted programs as jax reports them, any layer's "
+    "(executor, trainer, eager ops): XLA compiles and persistent-cache "
+    "loads together (mxnet_compile_cache_hits_total counts the loads)")
+_RECORD_FAILED = [False]        # _on_jax_time_span logged its fault
+# per thread (jax fires its events on the thread that compiles): what
+# the cache said inside the backend stage now running (``cache``,
+# ``load_s``) and how many stages are open (``open``)
+_tls = threading.local()
+
+
 def _declare_counters():
     """Create every mxnet_compile_cache_*_total family up front so the
     exposition shows an explicit 0 from the moment the cache is
     configured — a scraper must be able to tell "zero misses" (warm
-    restart) from "cache off" (family absent)."""
+    restart) from "cache off" (family absent) — and
+    ``mxnet_jit_compiles_total`` with them."""
     from . import telemetry
     if not telemetry.enabled():
         return
     for kind in _COUNTS:
         telemetry.counter("mxnet_compile_cache_%s_total" % kind,
                           _HELP[kind])
+    telemetry.counter(*_JIT_COMPILES)
 
 
 def _set_size_gauge(total):
@@ -149,17 +184,90 @@ def _on_jax_event(event, **kwargs):
         _bump("requests")
     elif event == "/jax/compilation_cache/cache_hits":
         _bump("hits")
+        _note(cache="hit")
     elif event == "/jax/compilation_cache/cache_misses":
+        # fired as the compiled program is WRITTEN: one the thresholds
+        # keep out of the cache compiles every time and is no miss
         _bump("misses")
+        _note(cache="miss")
 
 
-def _install_listener():
-    with _LOCK:
-        if _STATE["listener"]:
-            return
-        _STATE["listener"] = True
+def _on_jax_duration(event, duration_secs, **kwargs):
+    if event == "/jax/compilation_cache/cache_retrieval_time_sec":
+        _note(load_s=duration_secs)
+
+
+def _note(**said):
+    """Keep what the cache said for the ``xla.compile`` span around it."""
+    from . import telemetry
+    if telemetry.enabled():
+        _tls.__dict__.update(said)
+
+
+def _on_jax_scalar(event, value, **kwargs):
+    # jax reports a stage's start as a scalar.  A jitted function traced
+    # while another stage is open on the thread — inside another's trace
+    # (a symbol's step holds one per operator), inside a lowering (the
+    # random bits' rule traces its arithmetic) — is reported on its own
+    # AND inside the outer one, thousands a program: the depth, kept
+    # telemetry on or off so that it stays balanced, tells them apart
+    if event in _STAGES:
+        _tls.open = getattr(_tls, "open", 0) + 1
+
+
+def _on_jax_time_span(event, start_time, end_time, fun_name=None, **kwargs):
+    """One stage of one program's compilation, as jax reports it at the
+    stage's end: a span on the ring, and the counter for a backend stage.
+    A trace inside another stage is that stage's time and leaves none (a
+    program lowered and compiled inside one — an eager operation on a
+    constant while another traces — keeps those two)."""
+    span = _STAGES.get(event)
+    if span is None:
+        return
+    said = _tls.__dict__
+    said["open"] = depth = max(said.get("open", 1) - 1, 0)
+    if depth and span == "xla.trace":
+        return
+    from . import telemetry
+    if not telemetry.enabled():
+        return
+    try:
+        _record_stage(telemetry, span, start_time, end_time, fun_name, said)
+    except Exception:       # jax calls this inside the user's jit call
+        if not _RECORD_FAILED[0]:
+            _RECORD_FAILED[0] = True
+            logging.exception(
+                "compile observer: recording %s of %r failed; the "
+                "compilation goes on (logged once)", span, fun_name)
+
+
+def _record_stage(telemetry, span, start_time, end_time, fun_name, said):
+    # jax takes both ends from time.time(), which can step back
+    seconds = max(0.0, end_time - start_time)
+    # jax names the trace after the function and the later stages after
+    # the module it made of it ("jit(step)"): one name for the three
+    program = str(fun_name)
+    if program.startswith("jit(") and program.endswith(")"):
+        program = program[4:-1]
+    tags = {"program": program}
+    if span == "xla.compile":
+        tags["cache"] = said.pop("cache", "off")
+        if "load_s" in said:
+            tags["load_s"] = said.pop("load_s")
+        telemetry.counter(*_JIT_COMPILES).inc()
+    tracing = telemetry.tracing
+    tracing.add_span(span, tracing.process_root(), start_time,
+                     1e3 * seconds, **tags)
+
+
+def _install_listeners():
+    """Called once, at import and not at configuration: the stages are
+    reported of every program, cache on or off, executor bound or not."""
     from jax._src import monitoring
     monitoring.register_event_listener(_on_jax_event)
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    monitoring.register_event_time_span_listener(_on_jax_time_span)
+    monitoring.register_scalar_listener(_on_jax_scalar)
 
 
 def _install_error_hooks():
@@ -295,7 +403,6 @@ def configure(directory, min_compile_secs=None, min_entry_bytes=None,
     if not external:
         _reset_jax_cache()
     _declare_counters()
-    _install_listener()
     _install_error_hooks()
     with _LOCK:
         _STATE["enabled"] = True
@@ -424,3 +531,6 @@ def reset():
         _STATE["size_bytes"] = 0
         for k in _COUNTS:
             _COUNTS[k] = 0
+
+
+_install_listeners()

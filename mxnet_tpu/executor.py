@@ -690,11 +690,12 @@ class Executor:
         A compile is detected EXACTLY: jax's jit cache growing across
         the call (``_cache_size``), so a program compiled before
         telemetry was enabled is never miscounted as a recompile when a
-        measurement window opens mid-run.  The call's wall time is the
-        compile cost (dispatch itself is async and returns in
-        microseconds).  Disabled telemetry pays one boolean check and
-        an extra frame.  Fallback for jit objects without a cache-size
-        probe: a per-executor (tag, shapes) signature set.
+        measurement window opens mid-run.  Disabled telemetry pays one
+        boolean check and an extra frame.  Fallback for jit objects
+        without a cache-size probe: a per-executor (tag, shapes)
+        signature set.  What the compile cost, by stage and for every
+        program of the process, is ``compile_cache``'s to observe (the
+        ``xla.trace`` / ``xla.lower`` / ``xla.compile`` spans).
 
         The graftsan recompile sanitizer shares this exact detection:
         when armed, every observed compile is forwarded with its shape
@@ -705,18 +706,15 @@ class Executor:
         san_on = _san_hooks.RECOMPILE[0]
         if not telemetry.enabled() and not san_on:
             return fn(*call_args)
-        import time as _time
         sig = None
         size_fn = getattr(fn, "_cache_size", None)
         if size_fn is not None:
             before = size_fn()
-            t0 = _time.perf_counter()
             out = fn(*call_args)
             compiled = size_fn() > before
         else:
             sig = (tag, tuple(tuple(a.shape) for a in sig_arrays))
             compiled = sig not in self._compile_seen
-            t0 = _time.perf_counter()
             out = fn(*call_args)
         if compiled:
             # the signature tuple is O(arg count) to build — only pay
@@ -731,12 +729,6 @@ class Executor:
                     "mxnet_xla_compiles_total",
                     "XLA program compilations observed at dispatch "
                     "(jit-cache growth; cache-miss == recompile)").inc()
-                telemetry.histogram(
-                    "mxnet_xla_compile_seconds",
-                    "wall time of compiling dispatches (trace + XLA "
-                    "compile)",
-                    buckets=telemetry.exponential_buckets(0.001, 4.0, 12)
-                ).observe(_time.perf_counter() - t0)
             if san_on:
                 _san_hooks.on_compile(tag, sig[1], prior)
         return out

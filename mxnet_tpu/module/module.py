@@ -177,7 +177,12 @@ class Module(BaseModule):
                           "init_params call ignored.", stacklevel=2)
             return
         assert self.binded, "call bind before initializing the parameters"
+        with _tracing.span("module.init_params"):
+            self._init_params(initializer, arg_params, aux_params,
+                              allow_missing, allow_extra)
 
+    def _init_params(self, initializer, arg_params, aux_params,
+                     allow_missing, allow_extra):
         attrs = self._symbol.attr_dict()
         for own, given in ((self._arg_params, arg_params),
                            (self._aux_params, aux_params)):
@@ -211,6 +216,12 @@ class Module(BaseModule):
     def set_params(self, arg_params, aux_params, allow_missing=False,
                    force_init=True, allow_extra=False):
         """Directly assign params (reference: module.py set_params)."""
+        with _tracing.span("module.set_params"):
+            self._set_params(arg_params, aux_params, allow_missing,
+                             force_init, allow_extra)
+
+    def _set_params(self, arg_params, aux_params, allow_missing, force_init,
+                    allow_extra):
         if not allow_missing:
             self.init_params(initializer=None, arg_params=arg_params,
                              aux_params=aux_params, allow_missing=allow_missing,
@@ -218,7 +229,7 @@ class Module(BaseModule):
             return
         if self.params_initialized and not force_init:
             warnings.warn("Parameters already initialized and force_init=False. "
-                          "set_params call ignored.", stacklevel=2)
+                          "set_params call ignored.", stacklevel=3)
             return
         self._exec_group.set_params(arg_params, aux_params,
                                     allow_extra=allow_extra)
@@ -235,6 +246,12 @@ class Module(BaseModule):
             self.logger.warning("Already binded, ignoring bind()")
             return
 
+        with _tracing.span("module.bind"):
+            self._bind(data_shapes, label_shapes, for_training,
+                       inputs_need_grad, shared_module, grad_req)
+
+    def _bind(self, data_shapes, label_shapes, for_training,
+              inputs_need_grad, shared_module, grad_req):
         self.for_training = for_training
         self.inputs_need_grad = inputs_need_grad
         self._grad_req = grad_req
@@ -304,6 +321,10 @@ class Module(BaseModule):
         if self.optimizer_initialized and not force_init:
             self.logger.warning("optimizer already initialized, ignoring...")
             return
+        with _tracing.span("module.init_optimizer"):
+            self._init_optimizer(kvstore, optimizer, optimizer_params)
+
+    def _init_optimizer(self, kvstore, optimizer, optimizer_params):
         if self._params_dirty:
             self._sync_params_from_devices()
 
@@ -333,7 +354,7 @@ class Module(BaseModule):
                     "Optimizer created manually outside Module but rescale_grad "
                     "is not normalized to 1.0/batch_size/num_workers (%s vs. %s). "
                     "Is this intended?" % (optimizer.rescale_grad, rescale_grad),
-                    stacklevel=2)
+                    stacklevel=3)
             if not optimizer.idx2name:
                 # faithful reference quirk (module.py:528): the map is
                 # assigned without refreshing lr/wd mults, so a manually

@@ -258,6 +258,21 @@ class ParallelTrainer:
         self._param_objs = [params[k] for k in self._param_names]
         self._trainable = [p.grad_req != "null" for p in self._param_objs]
 
+        with _trace.span("trainer.place") as place:
+            self._place(bucket_bytes, first_bucket_bytes)
+            place.tag(param_bytes=sum(
+                int(v.nbytes) for v in self._params.values()))
+        self._comm = self._comm_model()
+        self._jit_step = None
+        self._registered_step = None    # the jit telemetry last saw
+        self._jit_eval = None
+        self._export_state_gauges()
+
+    def _place(self, bucket_bytes, first_bucket_bytes):
+        """Everything the trainer puts on the devices before its first
+        step: a copy of every parameter under its sharding, the bucket
+        plan over them, the optimizer's slots and the codec's
+        residuals."""
         # device placement: params laid out by their sharding spec
         self._pspecs = {}
         param_values = {}
@@ -295,11 +310,6 @@ class ParallelTrainer:
 
         self._opt_state = self._init_opt_state()
         self._resids = self._init_residuals()
-        self._comm = self._comm_model()
-        self._jit_step = None
-        self._registered_step = None    # the jit telemetry last saw
-        self._jit_eval = None
-        self._export_state_gauges()
 
     # -- state layout --------------------------------------------------------
     def _init_opt_state(self):
@@ -468,6 +478,10 @@ class ParallelTrainer:
 
     # -- step program --------------------------------------------------------
     def _build(self, n_inputs):
+        with _trace.span("trainer.build"):
+            self._build_programs(n_inputs)
+
+    def _build_programs(self, n_inputs):
         mesh = self._mesh
         batch_sharding = NamedSharding(mesh, P(("dp", "fsdp")))
         param_shardings = {k: NamedSharding(mesh, s)

@@ -42,11 +42,13 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricFamily",
            "prometheus_text", "write_prometheus", "validate_exposition",
            "exponential_buckets", "enabled", "enable", "disable",
            "reset", "scalar_totals", "publish_to_profiler",
-           "chrome_counter_events", "tracing", "flight", "phases",
+           "chrome_counter_events", "note_import_seconds", "tracing",
+           "flight", "phases",
            "register_program", "program_hlo"]
 
 _REGISTRY = MetricsRegistry()
 _ENABLED = [False]
+_IMPORT_SECONDS = [None]    # note_import_seconds()
 
 
 def get_registry():
@@ -100,8 +102,27 @@ def enable(on=True):
     _ENABLED[0] = bool(on)
     if on:
         tracing.arm_ring()
+        _export_import_seconds()
     else:
         tracing.disarm_ring()
+
+
+def note_import_seconds(seconds):
+    """``mxnet_tpu/__init__.py``'s last line: what importing the package
+    took.  Kept until telemetry is on (now, under ``MXNET_TELEMETRY``),
+    then exported as gauge ``mxnet_import_seconds``."""
+    _IMPORT_SECONDS[0] = float(seconds)
+    if _ENABLED[0]:
+        _export_import_seconds()
+
+
+def _export_import_seconds():
+    if _IMPORT_SECONDS[0] is not None:
+        gauge("mxnet_import_seconds",
+              "seconds spent importing the mxnet_tpu package's own "
+              "modules, first line to last of its __init__ (whatever "
+              "was imported before it, jax for one, is not in it)"
+              ).set(_IMPORT_SECONDS[0])
 
 
 def disable():

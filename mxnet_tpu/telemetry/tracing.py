@@ -50,8 +50,9 @@ __all__ = ["ACTIVE", "TraceContext", "Span", "enable", "disable",
            "enabled", "mint", "current", "use", "span", "start_span",
            "add_span", "mark", "complete", "inject", "extract", "keep",
            "flush", "arm_ring", "disarm_ring",
-           "export_jsonl", "chrome_events", "snapshot", "anomalous",
-           "retained_traces", "reset", "shard_path"]
+           "export_jsonl", "chrome_events", "snapshot", "evicted",
+           "process_root", "anomalous", "retained_traces", "reset",
+           "shard_path"]
 
 # one-boolean fast path (the fault/hooks.py idiom): hot call sites guard
 # on ACTIVE[0]; span()/mark()/inject() re-check it themselves so cold
@@ -73,11 +74,13 @@ _STATE = {
     "ring_cap": 4096,
     "exported": 0,         # guarded-by: _lock — spans written to shard
     "dropped": 0,          # guarded-by: _lock — sampled-out spans
+    "evicted": 0,          # guarded-by: _lock — spans the full ring lost
 }
 _RING = deque(maxlen=4096)        # guarded-by: _lock — finished spans
 _ANOMALOUS = OrderedDict()        # guarded-by: _lock — trace_id -> reason
 _ROOTS_DONE = OrderedDict()       # guarded-by: _lock — trace_id -> True
 _P99 = {}     # guarded-by: _lock — name -> [deque(durs), threshold, n]
+_PROCESS_ROOT = [None]            # process_root()'s context
 _ATEXIT = [False]
 # True while ACTIVE was set by telemetry.enable() (arm_ring) and not by
 # MXNET_TRACE / enable(): telemetry.disable() takes back only that
@@ -148,6 +151,30 @@ def _ambient():
             # as always export-eligible
             _done_locked(ctx.trace_id)
     return ctx
+
+
+def process_root():
+    """The process's own root context, for spans that belong to no
+    request and to no thread's activity: the compilations jax reports
+    (``compile_cache``), whichever thread dispatched them.  One stable
+    trace per process, export-eligible like a thread's ambient one —
+    marked so at every call (a compile is rare), since :func:`reset`
+    and the cap on finished roots both forget the mark."""
+    ctx = _PROCESS_ROOT[0]
+    if ctx is None:
+        ctx = _PROCESS_ROOT[0] = TraceContext(
+            "bg-%d-process" % os.getpid())
+    with _lock:
+        _done_locked(ctx.trace_id)
+    return ctx
+
+
+def _push_locked(rec):
+    """Land one finished span in the ring, counting the one a full ring
+    drops for it (:func:`evicted`)."""
+    if len(_RING) == _RING.maxlen:
+        _STATE["evicted"] += 1
+    _RING.append(rec)
 
 
 class use:
@@ -283,7 +310,7 @@ class Span:
         if self.tags:
             rec["tags"] = {k: _jsonable(v) for k, v in self.tags.items()}
         with _lock:
-            _RING.append(rec)
+            _push_locked(rec)
             if self.status != "ok":
                 _mark_locked(self.trace_id, self.status)
             if self.parent_id is None \
@@ -358,7 +385,7 @@ def add_span(name, ctx, ts, dur_ms, status="ok", **tags):
     if tags:
         rec["tags"] = {k: _jsonable(v) for k, v in tags.items()}
     with _lock:
-        _RING.append(rec)
+        _push_locked(rec)
         if status != "ok":
             _mark_locked(ctx.trace_id, status)
 
@@ -494,7 +521,7 @@ def export_jsonl(path=None, drain=True):
             # re-park the in-flight spans (bounded: the deque cap still
             # applies, oldest spill first)
             for rec in stay:
-                _RING.append(rec)
+                _push_locked(rec)
             _STATE["dropped"] += drop
             _STATE["exported"] += len(out)
     if out and path:
@@ -543,6 +570,18 @@ def snapshot():
         return [dict(r) for r in _RING]
 
 
+def evicted():
+    """How many spans the full ring has dropped since the process began
+    (or :func:`reset`).  Above 0, :func:`snapshot` is no longer the
+    whole record: a reader that sums "everything since the start"
+    reports nothing, never a partial sum.  An export that drains
+    (:func:`export_jsonl` under ``MXNET_TRACE_DIR``) takes spans out of
+    the ring too, to the shard: :func:`stats` counts those as
+    ``exported`` and ``dropped``, and the same reader checks all three."""
+    with _lock:
+        return _STATE["evicted"]
+
+
 def retained_traces():
     """``{trace_id: [spans]}`` of the ANOMALOUS traces still in the
     ring — the flight recorder attaches exactly these to an incident
@@ -559,6 +598,7 @@ def retained_traces():
 def stats():
     with _lock:
         return {"ring": len(_RING), "anomalous": len(_ANOMALOUS),
+                "evicted": _STATE["evicted"],
                 "exported": _STATE["exported"],
                 "dropped": _STATE["dropped"],
                 "sample": _STATE["sample"], "dir": _STATE["dir"]}
@@ -587,7 +627,9 @@ def enable(sample=None, seed=None, ring=None, trace_dir=None,
         cap = int(_config.get("MXNET_TRACE_RING") if ring is None
                   else ring)
         if cap != _RING.maxlen:
-            _RING = deque(_RING, maxlen=max(16, cap))
+            cap = max(16, cap)
+            _STATE["evicted"] += max(0, len(_RING) - cap)
+            _RING = deque(_RING, maxlen=cap)
         _STATE["ring_cap"] = _RING.maxlen
         d = (_config.get("MXNET_TRACE_DIR") if trace_dir is None
              else trace_dir)
@@ -638,3 +680,4 @@ def reset():
         _P99.clear()
         _STATE["exported"] = 0
         _STATE["dropped"] = 0
+        _STATE["evicted"] = 0
