@@ -250,24 +250,40 @@ class TransformerLM(HybridBlock):
         return {"config": config, "params": params}
 
 
-def _keep_flash():
+def _layer_keeps():
     """The ``policy`` of the ``jax.checkpoint`` that ``moe_lm_forward``
     and ``latent_moe_lm_forward`` wrap each layer (each prediction
     module) in: keep the state that enters and, of what the layer
-    computes, the flash call's output and row statistics alone
-    (``pallas_kernels.FLASH_KEPT``; 67 + 1 MB a layer at 32 x 8192 x 128
-    bfloat16) — the backward runs the layer again WITHOUT its flash
-    forward, which would re-make just those two.  A layer under it tells
-    its attention op ``kept=True``, so the statistics are held one
-    float32 a row; the einsum form (off the TPU) names nothing and is run
-    again whole.
+    computes, two things alone, each worth its bytes on the chip.
+
+    * The flash call's output and row statistics
+      (``pallas_kernels.FLASH_KEPT``; 67 + 1 MB a layer at 32 x 8192 x
+      128 bfloat16, for the 6.7 ms a layer the forward kernel takes) —
+      the backward runs the layer again WITHOUT its flash forward, which
+      would re-make just those two.  A layer under it tells its
+      attention op ``kept=True``, so the statistics are held one float32
+      a row; the einsum form (off the TPU) names nothing and is run
+      again whole.
+    * The routed expert layer's choice and row tables
+      (``parallel.moe.ROUTE_KEPT``: the chosen experts and ``_layout``'s
+      ``src``, ``dst``, ``is_held``, ``tile_group``, ``used``,
+      ``counts``; int32 and bool, about 1 MB a layer at 8192 tokens x 8
+      slots, for the 1.5 ms a layer the top-k, the two sorts and the
+      tables' gathers take, PERF.md, PR 37) — the layer run again holds
+      none of them: only the router's product, the scores and the
+      weights read off them at the kept choice, which the router's
+      gradient needs.  Forward and backward then agree on the choice
+      whatever XLA rounds the recomputed scores to.
+
     ``looped_lm_forward``'s pass keeps nothing: what a pass keeps is
     stacked over the ``scan``, and writing it there and reading it back
     cost what the forward kernel took (PERF.md, PR 35)."""
     import jax
 
     from ...ops.pallas_kernels import FLASH_KEPT
-    return jax.checkpoint_policies.save_only_these_names(*FLASH_KEPT)
+    from ...parallel.moe import ROUTE_KEPT
+    return jax.checkpoint_policies.save_only_these_names(*FLASH_KEPT,
+                                                         ROUTE_KEPT)
 
 
 # one looped layer's leaves, in construction order
@@ -292,7 +308,7 @@ def looped_lm_forward(params, tokens, *, num_layers, num_heads, num_passes,
     enters the next pass), and read by a one-output exit gate.  With
     ``remat`` each pass of the stack is a bare ``jax.checkpoint``: the
     backward pass keeps the state that enters a pass and runs the pass
-    again, flash forward and all (:func:`_keep_flash` says why nothing
+    again, flash forward and all (:func:`_layer_keeps` says why nothing
     more is kept here), so activations cost one pass, not
     ``num_passes``.
 
@@ -456,10 +472,11 @@ def moe_lm_forward(params, tokens, *, layer_types, num_heads, num_kv_heads,
     (:func:`parallel.moe.routed_experts`: the router over ALL published
     experts, ``held = (first, count)`` the ones whose stacked weights
     are here).  Each layer is a ``jax.checkpoint``
-    (:func:`_keep_flash`): the backward pass keeps the state that
+    (:func:`_layer_keeps`): the backward pass keeps the state that
     enters a layer and runs the layer again — except the flash call,
     whose output and row statistics are kept (67 + 1 MB a layer at 32 x
-    8192 x 128)."""
+    8192 x 128), and the expert layer's routing, whose choice and row
+    tables are (about 1 MB)."""
     import jax
     import jax.numpy as jnp
 
@@ -493,7 +510,7 @@ def moe_lm_forward(params, tokens, *, layer_types, num_heads, num_kv_heads,
     for i, kind in enumerate(layer_types):
         p = [params["l%d_%s" % (i, n)] for n in _MOE_LAYER_LEAVES]
         x = jax.checkpoint(layer, static_argnums=2,
-                           policy=_keep_flash())(x, p, kind)
+                           policy=_layer_keeps())(x, p, kind)
     return _rms_norm(x, params["norm_gamma"], eps=eps)
 
 
@@ -657,7 +674,7 @@ def latent_attention(h, p, *, num_heads, nope_dim, rope_dim, v_dim,
     and sums values of ``v_dim`` — neither side is padded to the other.
     What runs around the flash call is under scope ``mx_attn_latent``,
     the call and its rotary under ``mx_attn_full``.  ``kept``: the
-    caller is a layer under :func:`_keep_flash`."""
+    caller is a layer under :func:`_layer_keeps`."""
     import jax
     import jax.numpy as jnp
 
@@ -729,10 +746,11 @@ def latent_moe_lm_forward(params, tokens, *, mlp_layer_types, num_heads,
     output after it; embedding and head are the model's.  The last ``k``
     positions read tokens that wrap round; the loss leaves them out
     (``gluon.loss.MultiTokenCELoss``).  Each layer and each module is a
-    ``jax.checkpoint`` (:func:`_keep_flash`): the backward pass keeps
+    ``jax.checkpoint`` (:func:`_layer_keeps`): the backward pass keeps
     the state that enters it and runs it again — except the flash call,
     whose output and row statistics are kept (67 + 1 MB a layer at 32 x
-    8192 x 128 values)."""
+    8192 x 128 values), and a sparse layer's routing, whose choice and
+    row tables are (about 1 MB)."""
     import functools
 
     import jax
@@ -785,7 +803,7 @@ def latent_moe_lm_forward(params, tokens, *, mlp_layer_types, num_heads,
     tokens = tokens.astype(jnp.int32)
     x = params["embed_weight"][tokens]
     for i, kind in enumerate(mlp_layer_types):
-        x = jax.checkpoint(layer, static_argnums=2, policy=_keep_flash())(
+        x = jax.checkpoint(layer, static_argnums=2, policy=_layer_keeps())(
             x, layer_leaves("l%d_" % i, kind), kind)
     states = _rms_norm(x, params["norm_gamma"], eps=eps)
     if not mtp_depth:
@@ -794,7 +812,7 @@ def latent_moe_lm_forward(params, tokens, *, mlp_layer_types, num_heads,
     with jax.named_scope(phases.MTP_SCOPE):
         for k in range(mtp_depth):
             pre = "mtp%d_" % k
-            h = jax.checkpoint(module, policy=_keep_flash())(
+            h = jax.checkpoint(module, policy=_layer_keeps())(
                 h, jnp.roll(tokens, -(k + 1), axis=1),
                 layer_leaves(pre, SPARSE), leaves(pre, _MTP_FRONT_LEAVES))
             outs.append(_rms_norm(h, params[pre + "norm_gamma"], eps=eps))

@@ -843,7 +843,7 @@ def _flash_attention_op(q, k, v, causal=False, scale=None, window=None,
     parallel.ring_attention / ulysses_attention over an 'sp' mesh axis
     (which raise on a window).  ``kept`` is not a user's to set: a
     layer whose ``jax.checkpoint`` keeps the flash call's results
-    (``gluon.contrib.transformer._keep_flash``) says so with it, and the
+    (``gluon.contrib.transformer._layer_keeps``) says so with it, and the
     kernels' op holds its row statistics compactly; no number moves."""
     from ..parallel.attention import local_attention, ring_attention
     from ..parallel.mesh import current_mesh

@@ -23,9 +23,12 @@ Routing math (per source device, capacity C):
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..base import MXNetError
@@ -34,6 +37,54 @@ from .pipeline import stack_stages as stack_experts  # same stacking helper
 from ..telemetry import phases as _phases
 
 __all__ = ["switch_moe", "stack_experts", "routed_experts"]
+
+# The name routing's integer results carry (:func:`routed_experts`): the
+# chosen experts and the row tables made from them.  An identity until a
+# checkpoint's policy asks for it: a rematerialised layer of
+# ``gluon.contrib.transformer`` keeps them across its ``jax.checkpoint``
+# (about 1 MB a layer at 8192 tokens x 8 slots), and the layer run again
+# in the backward pass then holds no top-k, no sort and no table work.
+ROUTE_KEPT = "moe_route"
+
+# a routing pass in an optimized module: its ``lax.top_k`` under the
+# expert layer's scope (``.../mx_moe/top_k``, or ``.../jvp(mx_moe)/top_k``
+# where the scope is the outermost under a transform) — a ``sort`` on
+# the TPU, a ``TopK`` call on the CPU, a ``topk`` where the backend has
+# the instruction
+_ROUTE_PASS = re.compile(
+    r' (?:(?:sort|topk)\(|custom-call\(.*custom_call_target="TopK")'
+    r'.*op_name="[^"]*\b%s\)*/top_k"' % _phases.MOE_SCOPE)
+
+
+def _kept(a):
+    """``a`` under the name ``ROUTE_KEPT``, held FLAT behind a barrier,
+    so that what a checkpoint keeps is ``a``'s own bytes.  A name alone
+    is an identity to the compiler, which then keeps what suits its
+    fusions — the whole ``(T, E)`` index array the top-k's sort wrote,
+    sliced to ``(T, k)`` where it is read — and a ``(T, k)`` array in
+    the TPU's tiles is padded from ``k`` to 128 lanes."""
+    flat = lax.optimization_barrier(a.reshape(-1))
+    return checkpoint_name(flat, ROUTE_KEPT).reshape(a.shape)
+
+
+def export_route_passes(program, hlo_text):
+    """``mxnet_moe_route_passes{program}``: the routing passes — top-k,
+    the two sorts, the tables — in a program's OPTIMIZED module
+    (``telemetry.program_hlo``), counted by their top-k.  One a routed
+    layer where the layer's checkpoint keeps ``ROUTE_KEPT``, two where
+    the backward pass routes the tokens again."""
+    from .. import telemetry
+    if not telemetry.enabled():
+        return
+    telemetry.gauge(
+        "mxnet_moe_route_passes",
+        "top-k instructions under the expert layer's scope in the "
+        "optimized module of a registered program: one a routed layer "
+        "where the layer's checkpoint keeps the choice and the row "
+        "tables, two where the backward pass routes again").labels(
+            program=program).set(
+                sum(1 for line in hlo_text.splitlines()
+                    if _ROUTE_PASS.search(line)))
 
 
 def _route_top_k(x, router_w, top_k, norm_topk=True, scoring="softmax",
@@ -44,7 +95,17 @@ def _route_top_k(x, router_w, top_k, norm_topk=True, scoring="softmax",
     "sigmoid" each expert's own sigmoid — then each token's ``top_k``
     largest, renormalised to sum to 1 under ``norm_topk`` and multiplied
     by ``scale``.  A ``bias (E,)`` is added to the scores for the CHOICE
-    alone: the weights are the chosen experts' scores without it."""
+    alone: the weights are the chosen experts' scores without it.
+
+    The weights are always the scores AT the choice, and the choice
+    carries the name ``ROUTE_KEPT``: the same numbers as ``lax.top_k``'s
+    values bit for bit and the same gradient, so a layer whose
+    checkpoint keeps the name runs no top-k again for the sake of its
+    values.  They are taken by a masked sum over the experts, not by
+    ``take_along_axis``: one fused pass of ``T x top_k x E`` selects
+    (0.2 ms at 8192 x 8 x 256 on a v5e) where XLA's gather of 65,536
+    elements takes 0.67 ms, and its transpose is the same pass where
+    the gather's is a scatter."""
     if scoring not in ("softmax", "sigmoid"):
         raise MXNetError("router scoring is softmax or sigmoid, got %r"
                          % (scoring,))
@@ -52,11 +113,12 @@ def _route_top_k(x, router_w, top_k, norm_topk=True, scoring="softmax",
                         preferred_element_type=jnp.float32)
     scores = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" \
         else jax.nn.sigmoid(logits)
-    if bias is None:
-        weights, experts = lax.top_k(scores, top_k)
-    else:
-        experts = lax.top_k(scores + bias.astype(jnp.float32), top_k)[1]
-        weights = jnp.take_along_axis(scores, experts, axis=-1)
+    experts = _kept(lax.top_k(
+        scores if bias is None else scores + bias.astype(jnp.float32),
+        top_k)[1])
+    chosen = experts[..., None] == jnp.arange(scores.shape[-1],
+                                              dtype=experts.dtype)
+    weights = jnp.sum(jnp.where(chosen, scores[:, None, :], 0), axis=-1)
     if norm_topk:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     if scale != 1.0:
@@ -262,7 +324,15 @@ def routed_experts(x, router_w, experts, top_k, held, norm_topk=True,
     ``moe_slots``) fetch those rows alone, forward and backward, where a
     gather copies the whole static shape.  They leave the unused tiles
     UNWRITTEN: whatever reads the buffer skips them by ``used``.
-    ``held = (0, E)`` is the whole layer.  Returns ``(T, U)``."""
+    ``held = (0, E)`` is the whole layer.  Returns ``(T, U)``.
+
+    Routing's integer results — the choice and, of :func:`_layout`'s
+    tables, the ones used here — carry the name ``ROUTE_KEPT``, and the
+    weights are the scores at the named choice (:func:`_route_top_k`):
+    under a ``jax.checkpoint`` whose policy
+    keeps the name, the layer run again in the backward pass reads them
+    and runs no top-k, no sort and no table work; under any other
+    caller the names are identities."""
     from ..ops import pallas_kernels as pk
     gate, up, down = experts
     first, count = int(held[0]), int(held[1])
@@ -276,15 +346,20 @@ def routed_experts(x, router_w, experts, top_k, held, norm_topk=True,
         weights, chosen = _route_top_k(x, router_w, top_k, norm_topk,
                                        scoring, bias, float(scale))
         tm = pk.GROUPED_TILE_ROWS
-        src, _, dst, is_held, tile_group, used, counts = _layout(
-            chosen, (first, count), tm)
-        rows, rows_again = _dispatch(x, src // top_k, counts, used, dst,
-                                     is_held)
+        src, _, *tables = _layout(chosen, (first, count), tm)
+        src, dst, is_held, tile_group, used, counts = (
+            _kept(a) for a in (src, *tables))
+        # lax's own operations on the kept values, not jnp's jitted
+        # wrappers: a checkpoint that keeps a jitted function's operand
+        # keeps its result beside it
+        rows, rows_again = _dispatch(x, lax.div(src, jnp.int32(top_k)),
+                                     counts, used, dst, is_held)
         with jax.named_scope(_phases.MOE_EXPERTS_SCOPE):
             product = lambda a, w: _tile_product(a, w, tile_group, used, tm)
             h = jax.nn.silu(product(rows, gate)) * product(rows_again, up)
             y = product(h, down)
-        w = jnp.where(is_held, weights, 0).astype(jnp.float32)
+        w = lax.select(is_held, weights,
+                       jnp.zeros_like(weights)).astype(jnp.float32)
         return _combine(y, w, src, counts, used, dst, is_held)
 
 

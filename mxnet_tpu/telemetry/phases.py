@@ -294,7 +294,9 @@ def program_hlo(name):
             ent[3] = jit_fn.lower(*args).compile().as_text()
         ent[:3] = None, None, None      # the text is all that is needed
         from ..ops.pallas_kernels import export_flash_fwd_calls
+        from ..parallel.moe import export_route_passes
         export_flash_fwd_calls(name, ent[3])
+        export_route_passes(name, ent[3])
     return ent[3]
 
 

@@ -49,18 +49,28 @@ def _seed_rng():
     yield
 
 
-def kernel_calls(jaxpr, counts=None):
-    """``pallas_call`` equations of a jaxpr by kernel name, the bodies
-    of its ``scan`` / ``checkpoint`` / ``pjit`` equations included (each
-    body once)."""
-    import collections
-    counts = collections.Counter() if counts is None else counts
+def _equations(jaxpr):
+    """Every equation of a jaxpr, the bodies of its ``scan`` /
+    ``checkpoint`` / ``pjit`` equations included (each body once)."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            counts[eqn.params["name"]] += 1
+        yield eqn
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            kernel_calls(sub, counts)
-    return counts
+            yield from _equations(sub)
+
+
+def kernel_calls(jaxpr):
+    """``pallas_call`` equations of a jaxpr by kernel name."""
+    import collections
+    return collections.Counter(
+        eqn.params["name"] for eqn in _equations(jaxpr)
+        if eqn.primitive.name == "pallas_call")
+
+
+def primitive_calls(jaxpr):
+    """Equations of a jaxpr by primitive name."""
+    import collections
+    return collections.Counter(
+        eqn.primitive.name for eqn in _equations(jaxpr))
 
 
 @pytest.fixture
@@ -82,12 +92,55 @@ def check_flash_kept(monkeypatch):
             counts = kernel_calls(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
             return counts, jax.jit(jax.value_and_grad(loss))(params)
         kept, (value, grads) = run()
-        monkeypatch.setattr(transformer, "_keep_flash", lambda: None)
+        monkeypatch.setattr(transformer, "_layer_keeps", lambda: None)
         bare, (bare_value, bare_grads) = run()
         assert kept["_flash_fwd_kernel"] == layers, kept
         assert bare["_flash_fwd_kernel"] == 2 * layers, bare
         for name in ("_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel"):
             assert kept[name] == bare[name] == layers, (name, kept, bare)
+        assert np.array_equal(np.asarray(value), np.asarray(bare_value))
+        for k in grads:
+            assert np.array_equal(np.asarray(grads[k]),
+                                  np.asarray(bare_grads[k])), k
+    return check
+
+
+@pytest.fixture
+def check_route_kept(monkeypatch):
+    """The shared check of the LM blocks whose layers keep their expert
+    layer's routing (``moe_lm``, ``latent_moe_lm``): the gradient of
+    ``loss(params)`` over a block of ``layers`` routed layers, each of
+    ``tokens`` tokens taking ``top_k`` of the ``held`` experts' slots,
+    holds ``layers`` top-k's and ``layers`` pairs of sorts — the
+    backward pass routes nothing again — where a bare
+    ``jax.checkpoint`` holds twice that; what the backward pass is
+    handed grows by the tables' bytes (``parallel.moe.ROUTE_KEPT``: the
+    choice, ``src``, ``dst``, ``is_held``, ``tile_group``, ``used``,
+    ``counts``) and nothing else, less the ``bias`` floats of a
+    selection bias, which only the choice read; and loss and every
+    gradient leaf are the bare checkpoint's bit for bit: the kept
+    choice is the one the second run would make."""
+    from mxnet_tpu.gluon.contrib import transformer
+    from mxnet_tpu.ops.pallas_kernels import GROUPED_TILE_ROWS as tm
+
+    def check(loss, params, layers, tokens, top_k, held, bias=0):
+        def run():
+            calls = primitive_calls(
+                jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+            handed = jax.tree_util.tree_leaves(jax.eval_shape(
+                lambda p: jax.vjp(loss, p)[1], params))
+            return (calls, sum(a.size * a.dtype.itemsize for a in handed),
+                    jax.jit(jax.value_and_grad(loss))(params))
+        kept, kept_bytes, (value, grads) = run()
+        monkeypatch.setattr(transformer, "_layer_keeps", lambda: None)
+        bare, bare_bytes, (bare_value, bare_grads) = run()
+        assert (kept["top_k"], kept["sort"]) == (layers, 2 * layers), kept
+        assert (bare["top_k"], bare["sort"]) == (2 * layers, 4 * layers), bare
+        slots = tokens * top_k
+        tiles = -(-slots // tm) + held
+        tables = (4 * slots + 4 * tiles * tm + 4 * slots + slots
+                  + 4 * tiles + 4 + 4 * tiles)
+        assert kept_bytes - bare_bytes == layers * (tables - 4 * bias)
         assert np.array_equal(np.asarray(value), np.asarray(bare_value))
         for k in grads:
             assert np.array_equal(np.asarray(grads[k]),

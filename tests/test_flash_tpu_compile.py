@@ -9,6 +9,8 @@ process may hold the TPU's library, and every xdist worker imports this
 file (``on-chip-measurement`` guide, section 2).  Keep such tests in
 this one file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -106,20 +108,17 @@ def test_row_movers_compile_for_the_chip(one_chip, monkeypatch, tokens,
         assert name in text
 
 
-def test_the_gauge_counts_the_forward_kernels_of_a_registered_step(
-        one_chip, monkeypatch):
-    """``mxnet_flash_fwd_calls{program}`` off the OPTIMIZED module of a
-    two-layer ``MoELM`` step (loss, gradients, an SGD update) registered
-    through ``telemetry.register_program``: one forward kernel a layer
-    where the layers keep the kernel's results across their checkpoints,
-    two under a bare ``jax.checkpoint`` — what the trace-time counter
-    cannot tell apart — and the two backward kernels once a layer in
-    both."""
+@pytest.fixture(scope="module")
+def moe_steps(one_chip):
+    """A two-layer ``MoELM`` step (loss, gradients, an SGD update; the
+    flash path forced) registered through ``telemetry.register_program``
+    twice — as ``kept``, its layers under their policy, and as ``bare``,
+    under a bare ``jax.checkpoint`` — and compiled for the described
+    chip by ``telemetry.program_hlo``: ``{program: (optimized text,
+    gauge -> value)}``."""
     from mxnet_tpu import telemetry
     from mxnet_tpu.gluon.contrib import transformer
     from mxnet_tpu.parallel import attention
-    monkeypatch.setattr(pk, "_interpret", lambda: False)
-    monkeypatch.setattr(attention, "_flash_eligible", lambda *a: True)
     net = transformer.MoELM(
         256, units=128, expert_width=64,
         layer_types=[transformer.SLIDING, transformer.FULL], num_heads=2,
@@ -133,7 +132,7 @@ def test_the_gauge_counts_the_forward_kernels_of_a_registered_step(
         if not name.endswith("head_weight")}
     tokens = jax.ShapeDtypeStruct((2, 256), jnp.int32, sharding=one_chip)
 
-    def calls(program):
+    def compiled(program):
         def step(params_, tokens_):         # a trace of its own a program
             def loss(p):
                 states = transformer.moe_lm_forward(p, tokens_, **config)
@@ -145,16 +144,48 @@ def test_the_gauge_counts_the_forward_kernels_of_a_registered_step(
         telemetry.register_program(program, jax.jit(step), (params, tokens))
         with jax.default_matmul_precision("default"):
             text = telemetry.program_hlo(program)
+        return text, {name: telemetry.gauge(name).labels(
+            program=program).value
+            for name in ("mxnet_flash_fwd_calls", "mxnet_moe_route_passes")}
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(pk, "_interpret", lambda: False)
+        patch.setattr(attention, "_flash_eligible", lambda *a: True)
+        telemetry.enable()
+        try:
+            steps = {"kept": compiled("kept")}
+            patch.setattr(transformer, "_layer_keeps", lambda: None)
+            steps["bare"] = compiled("bare")
+        finally:
+            telemetry.disable()
+    return steps
+
+
+def test_the_gauge_counts_the_forward_kernels_of_a_registered_step(
+        moe_steps):
+    """``mxnet_flash_fwd_calls{program}`` off the OPTIMIZED module: one
+    forward kernel a layer where the layers keep the kernel's results
+    across their checkpoints, two under a bare ``jax.checkpoint`` — what
+    the trace-time counter cannot tell apart — and the two backward
+    kernels once a layer in both."""
+    for program, forward in (("kept", 2), ("bare", 4)):
+        text, gauges = moe_steps[program]
         for kernel in ("_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel"):
             assert sum(1 for line in text.splitlines()
                        if " custom-call(" in line and kernel in line) == 2
-        return telemetry.gauge("mxnet_flash_fwd_calls").labels(
-            program=program).value
+        assert gauges["mxnet_flash_fwd_calls"] == forward
 
-    telemetry.enable()
-    try:
-        assert calls("kept") == 2
-        monkeypatch.setattr(transformer, "_keep_flash", lambda: None)
-        assert calls("bare") == 4
-    finally:
-        telemetry.disable()
+
+def test_the_gauge_counts_the_routing_passes_of_a_registered_step(
+        moe_steps):
+    """``mxnet_moe_route_passes{program}`` off the OPTIMIZED module: one
+    top-k a routed layer where the layers keep their routing across
+    their checkpoints, two under a bare ``jax.checkpoint`` — and the
+    comparison sorts (two a pass; the chip's compiler writes the top-k
+    as a third) fall with it."""
+    routed = re.compile(r"\bmx_moe\)*/")     # not mx_moe_experts
+    for program, passes in (("kept", 2), ("bare", 4)):
+        text, gauges = moe_steps[program]
+        assert gauges["mxnet_moe_route_passes"] == passes
+        assert sum(1 for line in text.splitlines()
+                   if " sort(" in line and routed.search(line)) == 3 * passes

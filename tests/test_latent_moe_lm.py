@@ -431,6 +431,43 @@ def test_the_bias_changes_the_choice_and_not_the_weight():
         moe._route_top_k(x, router, 2, scoring="tanh")
 
 
+@pytest.mark.parametrize("norm_topk", [True, False])
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+def test_the_weights_are_top_ks_values_read_at_the_choice(scoring, norm_topk):
+    """Without a bias the router took ``lax.top_k``'s values; now it
+    reads the scores at ``lax.top_k``'s indices, as it always did under
+    a bias (so a layer that keeps the choice across its checkpoint
+    needs no second top-k for the values) — by a masked sum, where it
+    gathered: the same weights bit for bit, and the same gradient to the
+    router and to the tokens."""
+    from jax import lax
+    rng = np.random.default_rng(41)
+    x = jnp.asarray(rng.normal(size=(64, 32)), jnp.float32)
+    router = jnp.asarray(rng.normal(size=(16, 32)) * 0.3, jnp.float32)
+    mix = jnp.asarray(rng.normal(size=(64, 4)), jnp.float32)
+
+    def by_values(x, router):
+        logits = jnp.einsum("tu,eu->te", x, router,
+                            preferred_element_type=jnp.float32)
+        scores = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" \
+            else jax.nn.sigmoid(logits)
+        weights, experts = lax.top_k(scores, 4)
+        if norm_topk:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        return weights * 2.5, experts
+
+    def at_the_choice(x, router):
+        return moe._route_top_k(x, router, 4, norm_topk, scoring, None, 2.5)
+
+    for a, b in zip(at_the_choice(x, router), by_values(x, router)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    grads = [jax.grad(lambda x, r: jnp.sum(route(x, r)[0] * mix), (0, 1))(
+        x, router) for route in (at_the_choice, by_values)]
+    for a, b in zip(*grads):
+        assert np.any(np.asarray(a)) and np.array_equal(np.asarray(a),
+                                                        np.asarray(b))
+
+
 def _sparse_part(ref, rng, routed=16, tokens=64):
     cfg = dict(CONFIG, n_routed_experts=routed,
                deployment={"experts_held": [0, routed]},
@@ -662,6 +699,44 @@ def test_a_rematerialised_layer_keeps_its_flash_results(ref,
 
     check_flash_kept(loss, {k: jnp.asarray(v) for k, v in weights.items()
                             if k != "head_weight"}, 3)
+
+
+@pytest.mark.parametrize("block", ["MoELM", "LatentMoELM"])
+def test_a_rematerialised_layer_keeps_its_routing(block, check_route_kept):
+    """``MoELM`` (softmax scores, no bias; two layers, both routed) and
+    ``LatentMoELM`` (sigmoid scores, a selection bias; a dense layer, a
+    routed one and the prediction module's own): two routed layers each,
+    192 tokens taking 2 of 8 experts, 4 of them held
+    (``check_route_kept``)."""
+    if block == "MoELM":
+        net = MoELM(256, units=128, expert_width=64, num_heads=4,
+                    num_kv_heads=2, num_routed=8, held=(2, 4), top_k=2)
+        forward = transformer.moe_lm_forward
+    else:
+        net = LatentMoELM(256, units=128, dense_width=192, expert_width=64,
+                          num_routed=8, held=(2, 4), top_k=2,
+                          route_scale=2.5, mtp_depth=1)
+        forward = transformer.latent_moe_lm_forward
+    net.initialize(mx.init.Normal(0.05), ctx=mx.cpu())
+    rng = np.random.default_rng(37)
+    tokens = jnp.asarray(rng.integers(0, 256, (2, 96)))
+    net(nd.array(np.asarray(tokens), dtype="int32"))    # deferred shapes
+    short = len(net.prefix)
+    params = {name[short:]: p.data()._data
+              for name, p in net.collect_params().items()
+              if not name.endswith("head_weight")}
+    for name in params:
+        if name.endswith("router_bias"):    # one that changes the choice
+            params[name] = jnp.asarray(rng.normal(size=8) * 0.05,
+                                       jnp.float32)
+
+    def loss(p):
+        return sum(jnp.mean(states ** 2) for states in
+                   jax.tree_util.tree_leaves(forward(p, tokens,
+                                                     **net._config)))
+
+    check_route_kept(loss, params, layers=2, tokens=192, top_k=2, held=4,
+                     bias=8 * (block == "LatentMoELM"))
 
 
 def test_the_block_says_what_it_is_in_gauges():
