@@ -200,11 +200,13 @@ def sweep_reports(n=None):
 # -- flash attention -------------------------------------------------------
 
 def flash_reports(bh=8, tq=512, tk=512, d=64, bq=128, bk=128,
-                  causal=False, dtype="float32", dv=None):
+                  causal=False, dtype="float32", dv=None,
+                  block_diffusion=None):
     """The three flash kernels at one shape.  ``bq`` / ``bk`` None:
     the blocks ``_flash_blocks`` picks for each kernel from the shape,
     as a call without explicit blocks runs them.  ``dv``: the values'
-    head size where it is not the keys'."""
+    head size where it is not the keys'.  ``block_diffusion``: the block
+    length of that mask, under which a grid's minor axis counts visits."""
     from mxnet_tpu.ops import pallas_kernels as pk
     structural = [
         {"name": "scale", "detail": "architecture constant (1/sqrt(d) "
@@ -232,11 +234,13 @@ def flash_reports(bh=8, tq=512, tk=512, d=64, bq=128, bk=128,
              ("q", "k", "v", "do", "lse", "delta"), ("dq",), "nk"),
             ("_flash_bwd_dkv_kernel", "dkv",
              ("q", "k", "v", "do", "lse", "delta"), ("dk", "dv"), "nq")):
-        pq, pk_ = pk._flash_blocks(tq, tk, d, dtype, kernel, dv)
+        pq, pk_ = pk._flash_blocks(tq, tk, d, dtype, kernel, dv,
+                                   block_diffusion)
         reports.append(_report(
             name, family,
             pk._FLASH_PLANS[kernel](bh, tq, tk, d, bq or pq, bk or pk_,
-                                    causal, dtype, None, dv),
+                                    causal, dtype, None, dv,
+                                    block_diffusion),
             ins, outs,
             python_constants=structural + [
                 {"name": extent, "detail": "grid extent"}],
@@ -249,13 +253,16 @@ def flash_cell_reports():
     bf16 with the blocks picked from the shape — OPT-1.3B's 2 x 32 heads
     of 64 and Ouro-2.6B's 1 x 16 heads of 128 at T 2048, JoyAI-LLM-
     Flash's 32 heads of latent attention at T 8192, keys of 192 over
-    values of 128."""
+    values of 128 — and SDAR's 32 heads of 128 over the 8192 rows
+    ``[noised ; clean]`` under the block-diffusion mask, blocks of 4."""
     return (flash_reports(64, 2048, 2048, 64, None, None, True,
                           "bfloat16")
             + flash_reports(16, 2048, 2048, 128, None, None, True,
                             "bfloat16")
             + flash_reports(32, 8192, 8192, 192, None, None, True,
-                            "bfloat16", dv=128))
+                            "bfloat16", dv=128)
+            + flash_reports(32, 8192, 8192, 128, None, None, False,
+                            "bfloat16", block_diffusion=4))
 
 
 # -- grouped matrix product (sparse experts) --------------------------------

@@ -448,16 +448,25 @@ class LoopedLM(HybridBlock):
         return ExitWeightedCELoss(beta=beta, params=self.params, **kwargs)
 
 
-# one expert layer's leaves, in construction order
+# one expert layer's leaves, in construction order; the two gains of a
+# QK-normed layer come after the projections they follow
 _MOE_LAYER_LEAVES = (
     "norm1_gamma", "q_weight", "k_weight", "v_weight", "out_weight",
     "norm2_gamma", "router_weight", "gate_weight", "up_weight",
     "down_weight")
+_QK_NORM_LEAVES = ("q_norm_gamma", "k_norm_gamma")
 SLIDING, FULL = "sliding_attention", "full_attention"
 
 
+def _moe_layer_leaves(qk_norm):
+    if not qk_norm:
+        return _MOE_LAYER_LEAVES
+    return _MOE_LAYER_LEAVES[:4] + _QK_NORM_LEAVES + _MOE_LAYER_LEAVES[4:]
+
+
 def moe_lm_forward(params, tokens, *, layer_types, num_heads, num_kv_heads,
-                   top_k, held, window, rope, norm_topk=True, eps=1e-6):
+                   top_k, held, window, rope, norm_topk=True, eps=1e-6,
+                   qk_norm=False, block_length=None):
     """A sparse-expert LM's trunk as ONE pure function of ``(params,
     tokens)``: the final-normed states ``(B, T, U)`` the head reads.
 
@@ -471,12 +480,30 @@ def moe_lm_forward(params, tokens, *, layer_types, num_heads, num_kv_heads,
     — and one chip's share of a top-``top_k`` routed expert layer
     (:func:`parallel.moe.routed_experts`: the router over ALL published
     experts, ``held = (first, count)`` the ones whose stacked weights
-    are here).  Each layer is a ``jax.checkpoint``
+    are here).  With ``qk_norm`` every query and key head is RMS-normed
+    over its own dimensions (gains ``l{i}_q_norm_gamma``,
+    ``l{i}_k_norm_gamma``) BEFORE the rotary, as Qwen3's attention does.
+
+    With ``block_length`` the trunk is trained by diffusion over blocks
+    (Arriola et al., arXiv:2503.09573; SDAR, arXiv:2510.06303):
+    ``tokens`` is ``(B, 2 L)``, a noised copy of every sequence and then
+    its clean copy, both at positions ``0 .. L - 1``; EVERY layer's
+    attention runs under the three-part block mask
+    (``F.contrib.flash_attention``'s ``block_diffusion``; the layer kind
+    still picks the rotary table, a window is not applied) under scope
+    ``mx_attn_blockdiff``, the embedding's gather of the ``2 L`` rows
+    under ``mx_noise``; and only the noised half's states are normed and
+    returned, ``(B, L, U)`` — the objective reads no other
+    (``gluon.loss.BlockDiffusionCELoss``).
+
+    Each layer is a ``jax.checkpoint``
     (:func:`_layer_keeps`): the backward pass keeps the state that
     enters a layer and runs the layer again — except the flash call,
     whose output and row statistics are kept (67 + 1 MB a layer at 32 x
     8192 x 128), and the expert layer's routing, whose choice and row
     tables are (about 1 MB)."""
+    import contextlib
+
     import jax
     import jax.numpy as jnp
 
@@ -485,32 +512,53 @@ def moe_lm_forward(params, tokens, *, layer_types, num_heads, num_kv_heads,
     from ...parallel.moe import routed_experts
     from ...telemetry import phases
 
+    leaves = _moe_layer_leaves(qk_norm)
+    half = tokens.shape[1] // 2
+    positions = None if block_length is None else \
+        jnp.tile(jnp.arange(half, dtype=jnp.int32), 2)
+
     def layer(x, p, kind):
-        n1, wq, wk, wv, wo, n2, wr, wg, wu, wd = p
+        p = dict(zip(leaves, p))
         b, t, u = x.shape
         base, inv_freq, scale = rope[kind]
-        h = _rms_norm(x, n1, eps=eps)
+        h = _rms_norm(x, p["norm1_gamma"], eps=eps)
         heads = lambda w, n: jnp.einsum("btu,ou->bto", h, w).reshape(
             b, t, n, w.shape[0] // n)
-        with jax.named_scope(phases.ATTN_WINDOW_SCOPE if kind == SLIDING
+        mask = {"block_diffusion": block_length} \
+            if block_length is not None else \
+            {"causal": True, "window": window if kind == SLIDING else None}
+        with jax.named_scope(phases.ATTN_BLOCKDIFF_SCOPE
+                             if block_length is not None else
+                             phases.ATTN_WINDOW_SCOPE if kind == SLIDING
                              else phases.ATTN_FULL_SCOPE):
-            turn = lambda a: _rotary_embedding(a, base=base,
-                                               inv_freq=inv_freq, scale=scale)
+            def turned(which, n):
+                a = heads(p[which + "_weight"], n)
+                if qk_norm:
+                    a = _rms_norm(a, p[which + "_norm_gamma"], eps=eps)
+                return _rotary_embedding(a, positions, base=base,
+                                         inv_freq=inv_freq, scale=scale)
+
             o = _flash_attention_op(
-                turn(heads(wq, num_heads)), turn(heads(wk, num_kv_heads)),
-                heads(wv, num_kv_heads), causal=True,
-                window=window if kind == SLIDING else None, kept=True)
-        x = x + jnp.einsum("bto,uo->btu", o.reshape(b, t, -1), wo)
-        h = _rms_norm(x, n2, eps=eps)
-        y = routed_experts(h.reshape(b * t, u), wr, (wg, wu, wd), top_k,
-                           held, norm_topk=norm_topk)
+                turned("q", num_heads), turned("k", num_kv_heads),
+                heads(p["v_weight"], num_kv_heads), kept=True, **mask)
+        x = x + jnp.einsum("bto,uo->btu", o.reshape(b, t, -1),
+                           p["out_weight"])
+        h = _rms_norm(x, p["norm2_gamma"], eps=eps)
+        y = routed_experts(
+            h.reshape(b * t, u), p["router_weight"],
+            (p["gate_weight"], p["up_weight"], p["down_weight"]), top_k,
+            held, norm_topk=norm_topk)
         return x + y.reshape(b, t, u)
 
-    x = params["embed_weight"][tokens.astype(jnp.int32)]
+    with jax.named_scope(phases.NOISE_SCOPE) if block_length is not None \
+            else contextlib.nullcontext():
+        x = params["embed_weight"][tokens.astype(jnp.int32)]
     for i, kind in enumerate(layer_types):
-        p = [params["l%d_%s" % (i, n)] for n in _MOE_LAYER_LEAVES]
+        p = [params["l%d_%s" % (i, n)] for n in leaves]
         x = jax.checkpoint(layer, static_argnums=2,
                            policy=_layer_keeps())(x, p, kind)
+    if block_length is not None:
+        x = x[:, :half]
     return _rms_norm(x, params["norm_gamma"], eps=eps)
 
 
@@ -536,13 +584,33 @@ class MoELM(HybridBlock):
     logits are never kept, and ``logits(states)`` gives them where they
     are wanted.  The expert weights are stacked leaves, ``l{i}_gate_weight
     (count, F, U)``.  Each layer is rematerialised in the backward pass
-    (the block's own property, no option)."""
+    (the block's own property, no option).
+
+    ``qk_norm``: every query and key head is RMS-normed over its own
+    dimensions before the rotary (two ``(head_dim,)`` gains a layer), as
+    Qwen3's and SDAR's attention is.
+
+    ``block_length`` makes the block a BLOCK-DIFFUSION model in training
+    (Arriola et al., arXiv:2503.09573; SDAR, arXiv:2510.06303) — its
+    objective is then ``diffusion_loss()``, not ``lm_loss()``.  A forward
+    noises its ``(B, L)`` clean ids (``F.contrib.block_diffusion_noise``:
+    one mask rate a block of ``block_length`` positions, ``noise_eps``
+    its floor, ``mask_token_id`` in a masked position's place; the draws
+    come from the step's key), runs the ``2 L`` rows ``[noised ; clean]``
+    through every layer at positions ``[0 .. L - 1 ; 0 .. L - 1]`` under
+    the three-part block mask, and returns the NOISED half's
+    final-normed states ``(B, L, U)`` with the positions' weights ``m /
+    p`` ``(B, L)`` beside them.  A caller that must reproduce a step
+    hands the draws in with the batch: an int32 input ``(B, 3, L)`` is
+    ``[ids ; the positions' draws ; the blocks' draws]``, integers in
+    ``[0, 2**24)``, a block reading the entry at its first position."""
 
     def __init__(self, vocab_size, units=128, expert_width=64,
                  layer_types=(SLIDING, FULL), num_heads=4, num_kv_heads=2,
                  head_dim=None, num_routed=8, held=None, top_k=2,
                  window=32, rope=None, norm_topk=True, epsilon=1e-6,
-                 **kwargs):
+                 qk_norm=False, block_length=None, mask_token_id=None,
+                 noise_eps=1e-3, **kwargs):
         super().__init__(**kwargs)
         from ...ops.contrib import yarn_inv_freq
         head_dim = head_dim or units // num_heads
@@ -559,6 +627,13 @@ class MoELM(HybridBlock):
                 or not 1 <= top_k <= num_routed:
             raise ValueError("held experts %r and top_k %d do not fit %d "
                              "routed experts" % (held, top_k, num_routed))
+        if block_length is not None and (
+                int(block_length) < 1 or mask_token_id is None
+                or not 0 <= int(mask_token_id) < vocab_size):
+            raise ValueError(
+                "a block-diffusion model needs a block length of at least "
+                "1 and a mask_token_id among its %d vocabulary rows, got "
+                "%r and %r" % (vocab_size, block_length, mask_token_id))
         tables = {}
         for kind in set(layer_types):
             r = dict((rope or {}).get(kind) or {})
@@ -576,10 +651,15 @@ class MoELM(HybridBlock):
             layer_types=tuple(layer_types), num_heads=num_heads,
             num_kv_heads=num_kv_heads, top_k=top_k, held=held,
             window=int(window), rope=tables, norm_topk=bool(norm_topk),
-            eps=epsilon)
+            eps=epsilon, qk_norm=bool(qk_norm),
+            block_length=None if block_length is None else int(block_length))
+        self._noise = None if block_length is None else dict(
+            block_length=int(block_length), mask_id=int(mask_token_id),
+            eps=float(noise_eps))
         self._num_routed = num_routed
         n, f = held[1], expert_width
         shape = {"q_weight": (num_heads * head_dim, units),
+                 "q_norm_gamma": (head_dim,), "k_norm_gamma": (head_dim,),
                  "k_weight": (num_kv_heads * head_dim, units),
                  "v_weight": (num_kv_heads * head_dim, units),
                  "out_weight": (units, num_heads * head_dim),
@@ -589,7 +669,7 @@ class MoELM(HybridBlock):
         shapes = [("embed_weight", (vocab_size, units))]
         for i in range(len(layer_types)):
             shapes += [("l%d_%s" % (i, k), shape.get(k, (units,)))
-                       for k in _MOE_LAYER_LEAVES]
+                       for k in _moe_layer_leaves(qk_norm)]
         shapes += [("norm_gamma", (units,)),
                    ("head_weight", (vocab_size, units))]
         with self.name_scope():
@@ -610,6 +690,10 @@ class MoELM(HybridBlock):
         telemetry.gauge("mxnet_attn_window", "keys a query of a "
                         "sliding_attention layer of the newest MoELM "
                         "sees").set(c["window"])
+        telemetry.gauge(
+            "mxnet_diffusion_block_length", "positions of a block of the "
+            "newest MoELM trained by diffusion over blocks (0: a causal "
+            "model)").set(c["block_length"] or 0)
 
     def expected_rows(self, tokens):
         """Rows a step of ``tokens`` tokens sends to this block's held
@@ -619,17 +703,46 @@ class MoELM(HybridBlock):
         return _export_expert_rows("MoELM", tokens, c["top_k"], c["held"],
                                    self._num_routed)
 
+    def _noised_rows(self, F, tokens):
+        """``(rows (B, 2 L), weight (B, L))`` of a block-diffusion
+        forward: the noised copy of every sequence, then the clean one."""
+        import jax
+
+        from ... import telemetry
+        from ...imperative import invoke_fn
+        from ...telemetry import phases
+        length = self._noise["block_length"]
+        with jax.named_scope(phases.NOISE_SCOPE):
+            draws = []
+            if tokens.ndim == 3:
+                tokens, *draws = invoke_fn(
+                    lambda t: (t[:, 0], t[:, 1], t[:, 2, ::length]),
+                    [tokens])
+            noised, weight = F.contrib.block_diffusion_noise(
+                tokens, *draws, **self._noise)
+            rows = F.concat(noised, tokens.astype("int32"), dim=1)
+        telemetry.gauge(
+            "mxnet_diffusion_stack_rows", "rows a step of the newest "
+            "MoELM trained by diffusion over blocks sends through every "
+            "layer: a noised and a clean copy of every position").set(
+                rows.shape[0] * rows.shape[1])
+        return rows, weight
+
     def hybrid_forward(self, F, tokens, **params):
         from ...imperative import invoke_fn
         names = [n for n in params if n != "head_weight"]
         config = self._config
+        weight = None
+        if self._noise is not None:
+            tokens, weight = self._noised_rows(F, tokens)
         self.expected_rows(tokens.shape[0] * tokens.shape[1])
 
         def forward(tokens_, *leaves):
             return moe_lm_forward(dict(zip(names, leaves)), tokens_,
                                   **config)
 
-        return invoke_fn(forward, [tokens] + [params[n] for n in names])
+        states = invoke_fn(forward, [tokens] + [params[n] for n in names])
+        return states if weight is None else (states, weight)
 
     def logits(self, states):
         """The head over final-normed states: ``(B, T, V)``."""
@@ -642,6 +755,16 @@ class MoELM(HybridBlock):
         over positions of the next token's cross-entropy."""
         from ..loss import LinearCELoss
         return LinearCELoss(params=self.params, **kwargs)
+
+    def diffusion_loss(self, **kwargs):
+        """The training objective of a block-diffusion model
+        (``block_length``) over this block's outputs, sharing its head:
+        ``loss(*net(tokens), tokens)`` — per sequence, the mean over
+        positions of ``weight_i`` times the cross-entropy of the CLEAN
+        token at the noised copy's position ``i``, no shift
+        (``gluon.loss.BlockDiffusionCELoss``)."""
+        from ..loss import BlockDiffusionCELoss
+        return BlockDiffusionCELoss(params=self.params, **kwargs)
 
 
 # a latent-attention layer's leaves, in construction order: attention and

@@ -16,7 +16,8 @@ __all__ = ["Loss", "L2Loss", "L1Loss", "SigmoidBinaryCrossEntropyLoss",
            "SigmoidBCELoss", "SoftmaxCrossEntropyLoss", "SoftmaxCELoss",
            "KLDivLoss", "CTCLoss", "HuberLoss", "HingeLoss",
            "SquaredHingeLoss", "LogisticLoss", "TripletLoss",
-           "ExitWeightedCELoss", "LinearCELoss", "MultiTokenCELoss"]
+           "ExitWeightedCELoss", "LinearCELoss", "BlockDiffusionCELoss",
+           "MultiTokenCELoss"]
 
 
 def _apply_weighting(F, loss, weight=None, sample_weight=None):
@@ -319,6 +320,44 @@ class LinearCELoss(Loss):
     def hybrid_forward(self, F, states, label, head_weight,
                        sample_weight=None):
         loss = F.contrib.linear_cross_entropy(states, head_weight, label)
+        loss = _apply_weighting(F, loss, self._weight, sample_weight)
+        return _mean_all_but_batch(loss, self._batch_axis)
+
+
+class BlockDiffusionCELoss(Loss):
+    """The objective of a masked block-diffusion language model (no
+    reference analogue; Arriola et al., "Block Diffusion",
+    arXiv:2503.09573, equation 8 under the linear schedule, with SDAR's
+    clipped rate, arXiv:2510.06303): ``loss(states, weight, label)`` with
+    ``states`` ``(B, L, U)`` the final-normed states at the NOISED copy's
+    positions, ``weight`` ``(B, L)`` the positions' ``m_i / p_b`` —
+    ``m_i`` whether position ``i`` was masked, ``p_b`` its block's mask
+    rate (``F.contrib.block_diffusion_noise``) — and ``label`` ``(B, L)``
+    the CLEAN ids.  Per sequence::
+
+        loss = (1 / L) sum_i weight_i * -log softmax(W_head states_i)[label_i]
+
+    no shift: position ``i`` predicts its own clean token; an unmasked
+    position weighs nothing.  The head is the SHARED parameter
+    ``head_weight`` ``(V, U)`` — construct the loss with
+    ``params=net.params``
+    (``gluon.contrib.transformer.MoELM.diffusion_loss()``) — fused with
+    its cross-entropy (``F.contrib.linear_cross_entropy``), so the ``(L,
+    V)`` float32 logits are never kept.  The operator takes the
+    positions' weights itself (``position_weight``): ``m / p`` can put
+    most of a step's gradient on one position, and multiplied onto the
+    terms it would be rounded to bfloat16 with the cotangent, ONE
+    rounding that tilts every leaf's gradient; the operator applies it
+    in float32 behind the head's products.  Returns ``(B,)``."""
+
+    def __init__(self, weight=None, batch_axis=0, **kwargs):
+        super().__init__(weight, batch_axis, **kwargs)
+        self.head_weight = self.params.get("head_weight")
+
+    def hybrid_forward(self, F, states, weight, label, head_weight,
+                       sample_weight=None):
+        loss = F.contrib.linear_cross_entropy(states, head_weight, label,
+                                              weight)
         loss = _apply_weighting(F, loss, self._weight, sample_weight)
         return _mean_all_but_batch(loss, self._batch_axis)
 

@@ -831,7 +831,7 @@ def _fused_bn_relu(data, gamma, beta, moving_mean, moving_var, eps=1e-5,
 # ---------------------------------------------------------------------------
 @register("_contrib_flash_attention")
 def _flash_attention_op(q, k, v, causal=False, scale=None, window=None,
-                        kept=False, **attrs):
+                        kept=False, block_diffusion=None, **attrs):
     """Softmax attention over (B, T, H, D) tensors; K/V may carry fewer
     heads (GQA), and V a head size of its own, (B, T, H, Dv) — the
     result is then (B, T, H, Dv) and the default scale still ``D **
@@ -844,21 +844,47 @@ def _flash_attention_op(q, k, v, causal=False, scale=None, window=None,
     (which raise on a window).  ``kept`` is not a user's to set: a
     layer whose ``jax.checkpoint`` keeps the flash call's results
     (``gluon.contrib.transformer._layer_keeps``) says so with it, and the
-    kernels' op holds its row statistics compactly; no number moves."""
+    kernels' op holds its row statistics compactly; no number moves.
+
+    ``block_diffusion`` (a block length ``B``, in place of ``causal`` and
+    ``window``) is the mask a block-diffusion language model trains under
+    (Arriola et al., "Block Diffusion", arXiv:2503.09573, section 4; SDAR,
+    arXiv:2510.06303).  The ``T = 2 L`` rows are a noised copy of a
+    sequence followed by its clean copy; with ``half(r) = [r >= L]`` and
+    ``blk(r) = (r mod L) // B``, query row r sees key row c iff
+
+    - ``half r = half c`` and ``blk r = blk c`` (a block sees itself,
+      both ways), or
+    - ``half r = 0``, ``half c = 1`` and ``blk r > blk c`` (a noised block
+      sees the CLEAN blocks before it), or
+    - ``half r = half c = 1`` and ``blk r >= blk c`` (clean rows are
+      block-causal);
+
+    a clean row never sees a noised one, and ``L^2 + L B`` of the ``4
+    L^2`` pairs are visible.  On the TPU the flash kernels neither fetch
+    nor run a tile without a visible pair
+    (``ops.pallas_kernels.flash_attention``); elsewhere the einsum form
+    builds the mask from this definition
+    (``parallel.attention.block_diffusion_mask``).  Across the shards of
+    an 'sp' mesh axis it raises, as a window does."""
     from ..parallel.attention import local_attention, ring_attention
     from ..parallel.mesh import current_mesh
     if scale is not None:
         scale = float(scale)
     if window is not None:
         window = int(window)
+    if block_diffusion is not None:
+        block_diffusion = int(block_diffusion)
     mesh = current_mesh()
     if mesh is not None and mesh.shape.get("sp", 1) > 1:
         # an active sp mesh makes the SAME model sequence-parallel:
         # the time axis shards over the ring, K/V blocks rotate on ICI
         return ring_attention(q, k, v, mesh=mesh, causal=_boolattr(causal),
-                              scale=scale, window=window)
+                              scale=scale, window=window,
+                              block_diffusion=block_diffusion)
     return local_attention(q, k, v, causal=_boolattr(causal), scale=scale,
-                           window=window, kept=_boolattr(kept))
+                           window=window, kept=_boolattr(kept),
+                           block_diffusion=block_diffusion)
 
 
 # ---------------------------------------------------------------------------
@@ -887,8 +913,8 @@ def yarn_inv_freq(dim, base, factor, original_max_position, beta_fast=32.0,
 
 
 @register("_contrib_rotary_embedding")
-def _rotary_embedding(data, base=10000.0, inv_freq=None, scale=1.0,
-                      interleaved=False, **attrs):
+def _rotary_embedding(data, positions=None, base=10000.0, inv_freq=None,
+                      scale=1.0, interleaved=False, **attrs):
     """Rotary position embedding (Su et al., arXiv:2104.09864) over
     ``(B, T, H, D)`` with HALF-SPLIT pairing: element ``i < D/2`` turns
     with element ``i + D/2`` by ``t * base**(-2i/D)`` — or by ``t *
@@ -897,8 +923,11 @@ def _rotary_embedding(data, base=10000.0, inv_freq=None, scale=1.0,
     by ``scale`` (YaRN's attention factor).  ``interleaved``: the pairing
     is ``(2i, 2i + 1)`` instead, each pair turned where it lies (the
     layout GPT-J and DeepSeek's latent attention store their rotary
-    dimensions in).  The angles, sines and the rotation run in float32;
-    the result is cast back to ``data``'s dtype."""
+    dimensions in).  Row ``t`` turns by its index, or by ``positions[t]``
+    where the rows are not positions ``0 .. T - 1`` (``(T,)``, one for
+    every row: block diffusion's rows are two copies of a sequence, both
+    at positions ``0 .. L - 1``).  The angles, sines and the rotation run
+    in float32; the result is cast back to ``data``'s dtype."""
     t, d = data.shape[1], data.shape[-1]
     half = d // 2
     if inv_freq is None:
@@ -909,7 +938,13 @@ def _rotary_embedding(data, base=10000.0, inv_freq=None, scale=1.0,
         if inv_freq.shape != (half,):
             raise ValueError("inv_freq holds %d frequencies, the heads "
                              "need %d" % (inv_freq.shape[0], half))
-    pos = jnp.arange(t, dtype=jnp.float32)
+    if positions is None:
+        pos = jnp.arange(t, dtype=jnp.float32)
+    else:
+        if positions.shape != (t,):
+            raise ValueError("positions hold %s entries, the data has %d "
+                             "rows" % (positions.shape, t))
+        pos = positions.astype(jnp.float32)
     ang = pos[:, None] * inv_freq[None, :]
     cos = jnp.cos(ang)[None, :, None, :]
     sin = jnp.sin(ang)[None, :, None, :]
@@ -939,22 +974,127 @@ def _gated_ffn(data, gate_weight, up_weight, down_weight, **attrs):
 
 
 @register("_contrib_linear_cross_entropy")
-def _linear_cross_entropy(data, weight, label, **attrs):
+def _linear_cross_entropy(data, weight, label, position_weight=None,
+                          **attrs):
     """Projection fused with its softmax cross-entropy: per position
     ``-log softmax(data @ weight.T)[label]`` in float32, WITHOUT keeping
     the ``(..., V)`` logits for the backward pass — they are recomputed
     there (``jax.checkpoint``), so only one projection's float32 logits
     live at a time however many heads a loss reads.  The product runs in
-    ``weight``'s dtype with float32 accumulation."""
-    @jax.checkpoint
-    def ce(x, w, y):
-        logits = jnp.einsum("...u,vu->...v", x.astype(w.dtype), w,
-                            preferred_element_type=jnp.float32)
+    ``weight``'s dtype with float32 accumulation.
+
+    ``position_weight`` (the label's shape, float32): the terms come
+    back times their position's weight, ``w_i CE_i``, and the weight
+    reaches the gradients BEHIND the head's two backward products, in
+    float32.  Multiplied onto the terms instead it rides the cotangent
+    INTO those products, whose operands the TPU rounds to bfloat16, and
+    the label's entry of a position's cotangent is ``-w_i (1 - p_i)``:
+    ONE rounding of up to ``2**-8`` tilts that position's whole gradient
+    (``F.contrib.scale_gradient`` tells the same of a term's weight).
+    Where the weights are a diffusion objective's ``m / p`` a single
+    position can carry most of a step's gradient, and its rounding then
+    tilts every leaf's.  Here ``d = softmax - onehot`` goes into the
+    products unweighted, ``dx_i = w_i (d_i W)`` and ``dW = d^T (w x)``:
+    what is rounded has as many independent roundings as it has
+    entries."""
+    def logits_of(x, w):
+        return jnp.einsum("...u,vu->...v", x.astype(w.dtype), w,
+                          preferred_element_type=jnp.float32)
+
+    def terms(logits, y):
         lse = jax.nn.logsumexp(logits, axis=-1)
         picked = jnp.take_along_axis(logits, y[..., None], axis=-1)[..., 0]
         return lse - picked
 
-    return ce(data, weight, label.astype(jnp.int32))
+    label = label.astype(jnp.int32)
+    if position_weight is None:
+        return jax.checkpoint(lambda x, w, y: terms(logits_of(x, w), y))(
+            data, weight, label)
+
+    @jax.custom_vjp
+    def weighted(x, w, y, pw):
+        return pw * terms(logits_of(x, w), y)
+
+    def backward(res, g):
+        x, w, y, pw = res
+        logits = logits_of(x, w)
+        d = (jax.nn.softmax(logits, axis=-1)
+             - jax.nn.one_hot(y, logits.shape[-1], dtype=jnp.float32)
+             ).astype(w.dtype)
+        by = (g * pw)[..., None]
+        dx = jnp.einsum("...v,vu->...u", d, w,
+                        preferred_element_type=jnp.float32) * by
+        dw = jnp.einsum("...v,...u->vu", d,
+                        (x.astype(jnp.float32) * by).astype(w.dtype),
+                        preferred_element_type=jnp.float32)
+        return (dx.astype(x.dtype), dw.astype(w.dtype), None,
+                g * terms(logits, y))
+
+    weighted.defvjp(lambda *args: (weighted(*args), args), backward)
+    return weighted(data, weight, label,
+                    position_weight.astype(jnp.float32))
+
+
+DRAW_BITS = 24      # a draw is k / 2**24, k an integer in [0, 2**24)
+
+
+@register("_contrib_block_diffusion_noise", num_outputs=2, needs_rng=True)
+def _block_diffusion_noise(data, position_draws=None, block_draws=None,
+                           block_length=4, mask_id=0, eps=1e-3,
+                           __rng__=None, **attrs):
+    """The forward (noising) process of a masked block-diffusion language
+    model (Arriola et al., "Block Diffusion", arXiv:2503.09573, equation
+    8 under the linear schedule; SDAR, arXiv:2510.06303, clips the rate):
+    ``(noised, weight)`` of clean ids ``data (B, L)``, ``L`` a whole
+    number of blocks of ``block_length`` positions.  With one draw ``t_b
+    in [0, 1)`` a block and one ``u_i in [0, 1)`` a position::
+
+        p_b      = (1 - eps) t_b + eps          the block's mask rate
+        m_i      = [u_i < p_b]                  b = i // block_length
+        noised_i = mask_id if m_i else data_i
+        weight_i = m_i / p_b                    float32
+
+    so that ``mean_i weight_i CE_i`` over the masked positions is the
+    block-diffusion bound (its weight ``1 / t`` with the clipped rate in
+    ``t``'s place; ``gluon.loss.BlockDiffusionCELoss``).
+
+    The draws are INTEGERS ``k`` in ``[0, 2**24)`` standing for ``k /
+    2**24`` — ``position_draws (B, L)`` and ``block_draws (B, L //
+    block_length)`` — and everything up to the weight's one division is
+    integer arithmetic, so the same draws give the same mask on any
+    backend: ``m_i = [k_i < P_b]`` with ``P_b = k_b + (2**24 - k_b) //
+    round(1 / eps)``, and ``p_b = P_b / 2**24`` (``eps`` is held as one
+    over a whole number; the rate so lies within ``2**-24`` of the
+    formula).  Given no draws the operator draws both from the step's
+    key, which is what a training step wants; a caller that must
+    reproduce a step hands them in with the batch."""
+    ids = data.astype(jnp.int32)
+    b, l = ids.shape
+    length = int(block_length)
+    if l % length:
+        raise ValueError("%d positions are no whole number of blocks of %d"
+                         % (l, length))
+    one = 1 << DRAW_BITS
+    if position_draws is None or block_draws is None:
+        if position_draws is not None or block_draws is not None:
+            raise ValueError("hand in both draws or neither")
+        ku, kt = jax.random.split(__rng__)
+        position_draws = jax.random.randint(ku, (b, l), 0, one, jnp.int32)
+        block_draws = jax.random.randint(kt, (b, l // length), 0, one,
+                                         jnp.int32)
+    if position_draws.shape != (b, l) \
+            or block_draws.shape != (b, l // length):
+        raise ValueError("draws of shapes %s and %s do not fit %d x %d "
+                         "positions in blocks of %d"
+                         % (position_draws.shape, block_draws.shape, b, l,
+                            length))
+    kt = block_draws.astype(jnp.int32)
+    rate = kt + (one - kt) // int(round(1.0 / float(eps)))
+    rate = jnp.repeat(rate, length, axis=1)                 # (B, L)
+    masked = position_draws.astype(jnp.int32) < rate
+    weight = jnp.where(masked, jnp.float32(one) / rate.astype(jnp.float32),
+                       jnp.float32(0.0))
+    return jnp.where(masked, jnp.int32(int(mask_id)), ids), weight
 
 
 @register("_contrib_scale_gradient")

@@ -222,6 +222,11 @@ def _isel(cond, a, b):
         else jnp.where(cond, a, b)
 
 
+def _iand(a, b):
+    return (a and b) if isinstance(a, bool) and isinstance(b, bool) \
+        else jnp.logical_and(a, b)
+
+
 def _causal_last_k(i, bq, bk):
     """Last K block a causal Q block ``i`` has a visible key in."""
     return _idiv(i * bq + bq - 1, bk)
@@ -245,6 +250,116 @@ def _window_last_q(j, bq, bk, window):
 
 
 _DIAG = "diag"        # mask tag: the slab's own square lies on the diagonal
+
+
+# The block-diffusion mask (Arriola et al., arXiv:2503.09573, section 4):
+# the T = 2 L rows are a noised copy of a sequence and then its clean
+# copy, both cut into blocks of ``length`` positions.  A noised row sees
+# its own noised block (both ways) and the clean blocks BEFORE its own; a
+# clean row sees the clean blocks up to and with its own; no clean row
+# sees a noised one.  With square tiles of ``b`` rows that divide L and
+# hold whole blocks, ``n = L // b`` tiles a half, a Q tile's visible keys
+# are NOT one band of K tiles: noised Q tile i has its own tile (a
+# block diagonal: _BD_SELF) and the clean tiles n .. n + i, the last of
+# them cut by the block diagonal (_BD_DIAG, strictly below it); clean Q
+# tile n + i has the clean tiles n .. n + i (_BD_DIAG, the diagonal's
+# blocks with it).  So the grid's minor axis counts VISITS, not tiles:
+# ``_bd_q_visit`` / ``_bd_k_visit`` name the tile a step visits and what
+# lies in it, and hold at the last visited tile once a row (column) has
+# none left — no body, no fetch.  n (n + 2) of the (2 n)^2 tiles run.
+_BD_NONE, _BD_FULL, _BD_SELF, _BD_DIAG = 0, 1, 2, 3
+
+
+class _BlockMask:
+    """Mask tag of a square that lies on the block-diffusion mask's
+    diagonal: query row r sees key c iff ``r // length - c // length``
+    is 0 (``lo`` None: a block sees itself, both ways) or at least
+    ``lo`` (0: the blocks up to its own; 1: the blocks before it)."""
+
+    def __init__(self, length, lo):
+        self.length, self.lo = length, lo
+
+    def blocks(self, a):
+        length = self.length
+        if length & (length - 1) == 0:
+            return jax.lax.shift_right_logical(a, length.bit_length() - 1)
+        return jax.lax.div(a, length)
+
+
+def _bd_q_visit(i, s, n):
+    """``(K tile, case, lo)`` of visit ``s`` (of ``n + 1``) of Q tile
+    ``i`` under the block-diffusion mask, ``n`` tiles a half.  A noised
+    tile visits its own tile first — every row has a key there, so the
+    forward's running maximum is finite from the first visit on."""
+    noised = i < n
+    p = _isel(noised, i, i - n)
+    c = _isel(noised, s - 1, s)         # the clean tile this visit is for
+    own = _iand(noised, s == 0)
+    tile = _isel(own, i, n + _imin(_imax(c, 0), p))
+    case = _isel(own, _BD_SELF,
+                 _isel(c < p, _BD_FULL, _isel(c == p, _BD_DIAG, _BD_NONE)))
+    return tile, case, _isel(noised, 1, 0)
+
+
+def _bd_k_visit(j, s, n):
+    """``(Q tile, case, lo)`` of visit ``s`` (of ``2 n``) of K tile
+    ``j``: a noised tile is seen by its own Q tile alone; clean tile
+    ``n + p`` by the noised Q tiles ``p .. n - 1`` and then by the clean
+    ones ``n + p .. 2 n - 1``, the first of each cut by the diagonal."""
+    p = j - n
+    m = n - p                           # Q tiles of each half that see it
+    first = s < m
+    tile = _isel(j < n, j,
+                 _isel(first, p + s, _imin(n + p + s - m, 2 * n - 1)))
+    case = _isel(
+        j < n, _isel(s == 0, _BD_SELF, _BD_NONE),
+        _isel(first, _isel(s == 0, _BD_DIAG, _BD_FULL),
+              _isel(s < 2 * m, _isel(s == m, _BD_DIAG, _BD_FULL),
+                    _BD_NONE)))
+    return tile, case, _isel(first, 1, 0)
+
+
+def _bd_tiles(n, length, b):
+    """``(tiles run, tiles that hold a visible pair)`` of one (batch,
+    head) under the block-diffusion mask — the same for the three
+    kernels.  They differ only where a tile is one block: the tile
+    strictly below the diagonal is then visited and empty."""
+    run = n * (n + 2)
+    return run, run - (n if b <= length else 0)
+
+
+def _flash_block_diffusion_cases(case, lo, b, length, update, k_major=False,
+                                 slabs=True):
+    """Drive ``update(q_rows, k_rows, mask)`` over one visited tile of
+    the block-diffusion mask (``b`` rows square, whole blocks of
+    ``length``).  _BD_FULL: the whole tile, no mask.  _BD_SELF (noised
+    rows against their own noised tile): only the blocks on the diagonal
+    are visible, so the tile is worked in squares of one lane tile along
+    it — an eighth of a 1024-row tile's products — each under a block
+    mask.  _BD_DIAG (the clean tile at a row's own position): the slabs
+    of :func:`_flash_block_cases`' diagonal, the slab's own square under
+    the block mask ``lo``."""
+    full = slice(None)
+    pl.when(case == _BD_FULL)(lambda: update(full, full, None))
+
+    @pl.when(case == _BD_SELF)
+    def _self():
+        r = LANES if b % LANES == 0 and LANES % length == 0 else b
+        for i in range(b // r):
+            own = slice(i * r, (i + 1) * r)
+            update(own, own, _BlockMask(length, None))
+
+    @pl.when(case == _BD_DIAG)
+    def _diagonal():
+        n = _flash_slabs(b) if slabs else 1
+        n = n if (b // n) % length == 0 else 1
+        r = b // n
+        for i in range(n):
+            own = slice(i * r, (i + 1) * r)
+            if k_major:
+                update(slice(i * r, b), own, _BlockMask(length, lo))
+            else:
+                update(own, slice(0, (i + 1) * r), _BlockMask(length, lo))
 
 
 def _flash_slabs(b):
@@ -318,7 +433,8 @@ def _flash_scores(a, b, scale, mask, rows_are_q):
     pair ``(lo, hi)`` — a window's band, query row r sees key c iff
     ``lo <= r - c <= hi`` — or _DIAG: the square at the end of a Q
     slab's keys (at the start of a K slab's queries) is the one on the
-    diagonal."""
+    diagonal; a :class:`_BlockMask` is that square under the
+    block-diffusion mask's rule."""
     s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if mask is None:
@@ -330,11 +446,18 @@ def _flash_scores(a, b, scale, mask, rows_are_q):
         return jnp.where(jnp.logical_and(d >= mask[0], d <= mask[1]),
                          s, NEG_INF)
     n = s.shape[0]
-    sq = s if mask is not _DIAG else s[:, -n:] if rows_are_q else s[:, :n]
+    blocks = isinstance(mask, _BlockMask)
+    sq = s if not (blocks or mask is _DIAG) \
+        else s[:, -n:] if rows_are_q else s[:, :n]
     r = jax.lax.broadcasted_iota(jnp.int32, sq.shape, 0)
     c = jax.lax.broadcasted_iota(jnp.int32, sq.shape, 1)
-    sq = jnp.where((r - c if rows_are_q else c - r)
-                   >= (0 if mask is _DIAG else mask), sq, NEG_INF)
+    if blocks:
+        # the square's origin holds whole blocks on both sides
+        r, c = mask.blocks(r), mask.blocks(c)
+    d = r - c if rows_are_q else c - r
+    sq = jnp.where(d == 0 if blocks and mask.lo is None else
+                   d >= (mask.lo if blocks else 0 if mask is _DIAG else mask),
+                   sq, NEG_INF)
     if sq.shape == s.shape:
         return sq
     return jnp.concatenate([s[:, :-n], sq] if rows_are_q
@@ -342,10 +465,13 @@ def _flash_scores(a, b, scale, mask, rows_are_q):
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
-                      l_ref, *, scale, causal, bq, bk, nk, window=None):
+                      l_ref, *, scale, causal, bq, bk, nk, window=None,
+                      blocks=None):
     """Grid (BH, nQ, nK); accumulate across the sequential nK dimension in
     VMEM scratch, finalize on the last K step (the canonical online-
-    softmax schedule)."""
+    softmax schedule).  ``blocks``: ``(length, tiles a half)`` of the
+    block-diffusion mask, under which the minor axis counts a Q tile's
+    visits (``nk`` of them)."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -369,8 +495,13 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
         m_ref[qs] = jnp.broadcast_to(m_new, (m_new.shape[0], LANES))
         l_ref[qs] = jnp.broadcast_to(l_new, (l_new.shape[0], LANES))
 
-    _flash_block_cases(causal, qi, ki, bq, bk, _update, slabs=False,
-                       window=window)
+    if blocks is not None:
+        _tile, case, lo = _bd_q_visit(qi, ki, blocks[1])
+        _flash_block_diffusion_cases(case, lo, bq, blocks[0], _update,
+                                     slabs=False)
+    else:
+        _flash_block_cases(causal, qi, ki, bq, bk, _update, slabs=False,
+                           window=window)
 
     @pl.when(ki == nk - 1)
     def _final():
@@ -381,7 +512,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, acc_ref, *, scale, causal, bq, bk, nk,
-                         window=None):
+                         window=None, blocks=None):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -401,7 +532,11 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _flash_block_cases(causal, qi, ki, bq, bk, _update, window=window)
+    if blocks is not None:
+        _tile, case, lo = _bd_q_visit(qi, ki, blocks[1])
+        _flash_block_diffusion_cases(case, lo, bq, blocks[0], _update)
+    else:
+        _flash_block_cases(causal, qi, ki, bq, bk, _update, window=window)
 
     @pl.when(ki == nk - 1)
     def _final():
@@ -410,8 +545,10 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                          bq, bk, nq, window=None):
-    """Grid (BH, nK, nQ).  The block is held transposed, (bk, bq):
+                          bq, bk, nq, window=None, blocks=None):
+    """Grid (BH, nK, nQ) — under the block-diffusion mask ``blocks`` the
+    minor axis counts a K tile's ``nq`` visits.  The block is held
+    transposed, (bk, bq):
     ``lse`` and ``delta`` arrive as rows (1, bq), so the scores, ``p``
     and ``ds`` come out of plain products in the orientation the dV and
     dK products consume — no transpose of a score tile."""
@@ -438,8 +575,13 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _flash_block_cases(causal, qi, ki, bq, bk, _update, k_major=True,
-                       window=window)
+    if blocks is not None:
+        _tile, case, lo = _bd_k_visit(ki, qi, blocks[1])
+        _flash_block_diffusion_cases(case, lo, bq, blocks[0], _update,
+                                     k_major=True)
+    else:
+        _flash_block_cases(causal, qi, ki, bq, bk, _update, k_major=True,
+                           window=window)
 
     @pl.when(qi == nq - 1)
     def _final():
@@ -469,14 +611,30 @@ def flash_seq_ok(t, dtype, pref=128):
     return b == t or b % _sublane(dtype) == 0
 
 
-def _export_flash_gauges(kernel, bq, bk, grid, window=None):
+def _export_flash_gauges(kernel, bq, bk, grid, window=None,
+                         block_diffusion=None):
     """What the newest instantiation of ``kernel`` was tiled into (trace
     time, beside ``_count``); a windowed instantiation is kept apart
-    from a full one by a ``window`` label of its own."""
+    from a full one by a ``window`` label of its own, one under the
+    block-diffusion mask by ``mask`` — and that one says how many tiles a
+    (batch, head) runs and how many of them hold a visible pair."""
     from .. import telemetry
     if not telemetry.enabled():
         return
     own = {} if window is None else {"window": int(window)}
+    if block_diffusion is not None:
+        own = {"mask": "block_diffusion"}
+        run, seen = _bd_tiles(grid[1] // 2, block_diffusion, bq)
+        tiles = telemetry.gauge(
+            "mxnet_flash_mask_tiles",
+            "score tiles of one (batch, head) of the newest flash-"
+            "attention kernel instantiation under a mask that names its "
+            "tiles, by kernel: the whole square (all), the tiles its grid "
+            "runs (run) and those of them that hold a visible pair "
+            "(visible)")
+        for which, count in (("all", grid[1] ** 2), ("run", run),
+                             ("visible", seen)):
+            tiles.labels(kernel=kernel, which=which, **own).set(count)
     rows = telemetry.gauge(
         "mxnet_flash_block_rows",
         "rows of the score block the newest flash-attention kernel "
@@ -561,13 +719,17 @@ def _flash_tiles(kernel, bq, bk, dtype):
 _FLASH_SEMANTICS = ("parallel", "parallel", "arbitrary")
 
 
-def _flash_q_major_specs(d, dv, bq, bk, causal, window=None):
+def _flash_q_major_specs(d, dv, bq, bk, causal, window=None, half=None):
     """Specs of the kernels on grid (BH, nQ, nK): the Q side follows
     i; the K side follows j, held at the last block row i needs under
     the causal mask — and at the first one under a window — so the
-    masked steps fetch nothing.  ``(q, k, lse, v, o)``: the values and
+    masked steps fetch nothing.  Under the block-diffusion mask (``half``
+    tiles a half) j counts row i's visits and the K side is the tile
+    ``_bd_q_visit`` names.  ``(q, k, lse, v, o)``: the values and
     the output are ``dv`` wide."""
     def kmap(b, i, j):
+        if half is not None:
+            return (b, _bd_q_visit(i, j, half)[0], 0)
         if not causal:
             return (b, j, 0)
         j = _imin(j, _causal_last_k(i, bq, bk))
@@ -581,15 +743,38 @@ def _flash_q_major_specs(d, dv, bq, bk, causal, window=None):
             pl.BlockSpec((None, bq, dv), lambda b, i, j: (b, i, 0)))
 
 
+def _bd_half(tq, tk, bq, bk, causal, window, length):
+    """Tiles a half of a plan under the block-diffusion mask of
+    ``length``-position blocks (None without one): the rows are two
+    halves of whole square tiles of whole blocks, and the mask stands
+    in no other's stead."""
+    if length is None:
+        return None
+    if causal or window is not None:
+        raise ValueError("the block-diffusion mask is a mask of its own: "
+                         "neither causal nor a window goes with it")
+    if tq != tk or bq != bk or tq % (2 * bq) or bq % length:
+        raise ValueError(
+            "the block-diffusion mask needs rows [noised ; clean] of two "
+            "equal halves in square tiles of whole blocks: got %d x %d "
+            "rows in %d x %d tiles, blocks of %d"
+            % (tq, tk, bq, bk, length))
+    return tq // (2 * bq)
+
+
 def flash_fwd_plan(bh, tq, tk, d, bq, bk, causal=False,
-                   dtype=jnp.float32, window=None, dv=None):
+                   dtype=jnp.float32, window=None, dv=None,
+                   block_diffusion=None):
     """Plan of the flash-attention forward kernel (q, k, v -> o, lse);
-    ``dv``: the head size of ``v`` and ``o`` where it is not ``d``."""
+    ``dv``: the head size of ``v`` and ``o`` where it is not ``d``;
+    ``block_diffusion``: the block length of that mask, under which the
+    grid's minor axis is a Q tile's visits."""
     dv = d if dv is None else dv
+    half = _bd_half(tq, tk, bq, bk, causal, window, block_diffusion)
     qspec, kspec, lmspec, vspec, ospec = _flash_q_major_specs(
-        d, dv, bq, bk, causal, window)
+        d, dv, bq, bk, causal, window, half)
     return {
-        "grid": (bh, tq // bq, tk // bk),
+        "grid": (bh, tq // bq, tk // bk if half is None else half + 1),
         "in_specs": [qspec, kspec, vspec],
         "in_shapes": [(bh, tq, d), (bh, tk, d), (bh, tk, dv)],
         "out_specs": [ospec, lmspec],
@@ -601,14 +786,16 @@ def flash_fwd_plan(bh, tq, tk, d, bq, bk, causal=False,
 
 
 def flash_bwd_dq_plan(bh, tq, tk, d, bq, bk, causal=False,
-                      dtype=jnp.float32, window=None, dv=None):
+                      dtype=jnp.float32, window=None, dv=None,
+                      block_diffusion=None):
     """Plan of the dq backward kernel
-    (q, k, v, do, lse, delta -> dq)."""
+    (q, k, v, do, lse, delta -> dq); the forward's grid."""
     dv = d if dv is None else dv
+    half = _bd_half(tq, tk, bq, bk, causal, window, block_diffusion)
     qspec, kspec, lmspec, vspec, ospec = _flash_q_major_specs(
-        d, dv, bq, bk, causal, window)
+        d, dv, bq, bk, causal, window, half)
     return {
-        "grid": (bh, tq // bq, tk // bk),
+        "grid": (bh, tq // bq, tk // bk if half is None else half + 1),
         "in_specs": [qspec, kspec, vspec, ospec, lmspec, lmspec],
         "in_shapes": [(bh, tq, d), (bh, tk, d), (bh, tk, dv),
                       (bh, tq, dv), (bh, tq, LANES), (bh, tq, LANES)],
@@ -621,16 +808,21 @@ def flash_bwd_dq_plan(bh, tq, tk, d, bq, bk, causal=False,
 
 
 def flash_bwd_dkv_plan(bh, tq, tk, d, bq, bk, causal=False,
-                       dtype=jnp.float32, window=None, dv=None):
+                       dtype=jnp.float32, window=None, dv=None,
+                       block_diffusion=None):
     """Plan of the dk/dv backward kernel — grid (BH, nK, nQ), so the
     q-side specs transpose their two minor grid coordinates, and under
     the causal mask hold at the first Q block column j needs (under a
-    window at the last one too).  ``lse`` and ``delta`` are rows here:
-    (BH, nQ, 1, bq), one row a Q block."""
+    window at the last one too); under the block-diffusion mask the
+    minor axis is a K tile's visits (``_bd_k_visit``).  ``lse`` and
+    ``delta`` are rows here: (BH, nQ, 1, bq), one row a Q block."""
     nq = tq // bq
     dv = d if dv is None else dv
+    half = _bd_half(tq, tk, bq, bk, causal, window, block_diffusion)
 
     def qblock(j, i):
+        if half is not None:
+            return _bd_k_visit(j, i, half)[0]
         if not causal:
             return i
         last = nq - 1 if window is None else \
@@ -645,7 +837,7 @@ def flash_bwd_dkv_plan(bh, tq, tk, d, bq, bk, causal=False,
     rowspec_t = pl.BlockSpec((None, None, 1, bq),
                              lambda b, j, i: (b, qblock(j, i), 0, 0))
     return {
-        "grid": (bh, tk // bk, nq),
+        "grid": (bh, tk // bk, nq),     # a clean K tile's visits: up to nq
         "in_specs": [qspec_t, kspec_t, vspec_t, dospec_t, rowspec_t,
                      rowspec_t],
         "in_shapes": [(bh, tq, d), (bh, tk, d), (bh, tk, dv),
@@ -699,42 +891,78 @@ def _block_choices(t, sub):
     return whole or [_pick_block(t, cap)]
 
 
-def _flash_blocks(tq, tk, d, dtype, kernel, dv=None):
+def _flash_blocks(tq, tk, d, dtype, kernel, dv=None, block_diffusion=None):
     """(bq, bk) for one flash kernel from what the call can see: the
     largest blocks, in whole tiles and dividing T, whose plan fits the
     scoped-VMEM budget graftkern holds the plans to
     (``MXNET_KERN_VMEM_BYTES``).  Among equal areas the squarer pair
     wins (a square block on the diagonal is worked in slabs), then the
-    wider Q side."""
+    wider Q side.  Under the block-diffusion mask the tiles are square,
+    divide a HALF of the rows and hold whole blocks
+    (:func:`flash_block_diffusion_ok` says whether such a tile exists)."""
     from .. import config as _config
     budget = int(_config.get("MXNET_KERN_VMEM_BYTES"))
     sub = _sublane(dtype)
-    qs, ks = _block_choices(tq, sub), _block_choices(tk, sub)
-    fits = [(bq * bk, -abs(bq - bk), bq, bk) for bq in qs for bk in ks
+    if block_diffusion is not None:
+        pairs = [(b, b) for b in _block_choices(tq // 2, sub)
+                 if b % block_diffusion == 0]
+        if not pairs:
+            raise ValueError(
+                "no row block divides a half of %d rows in whole blocks of "
+                "%d positions" % (tq, block_diffusion))
+    else:
+        pairs = [(bq, bk) for bq in _block_choices(tq, sub)
+                 for bk in _block_choices(tk, sub)]
+    fits = [(bq * bk, -abs(bq - bk), bq, bk) for bq, bk in pairs
             if _flash_vmem_bytes(_FLASH_PLANS[kernel](
-                1, tq, tk, d, bq, bk, dtype=dtype, dv=dv)) < budget]
-    if not fits:
-        return qs[-1], ks[-1]
-    return max(fits)[2:]
+                1, tq, tk, d, bq, bk, dtype=dtype, dv=dv,
+                block_diffusion=block_diffusion)) < budget]
+    # nothing fits: the smallest blocks of both sides
+    return max(fits)[2:] if fits else pairs[-1]
+
+
+def flash_block_diffusion_ok(t, length, dtype):
+    """Whether the kernels can tile ``t`` rows ``[noised ; clean]``
+    under the block-diffusion mask of ``length``-position blocks: two
+    halves of whole blocks, and a legal row block that divides a half
+    and holds whole blocks."""
+    return t % (2 * length) == 0 and any(
+        b % length == 0 for b in _block_choices(t // 2, _sublane(dtype)))
 
 
 def _flash_plan(kernel, q, k, causal, block_q, block_k, window=None,
-                dv=None):
+                dv=None, block_diffusion=None):
     """(bq, bk, plan) of one kernel for this call: a caller's explicit
     blocks are honoured (halved until they divide T, as ever), a side
     left None is picked from the shape; the choice is exported.  ``dv``
-    is the values' head size (None: the keys')."""
+    is the values' head size (None: the keys').  Under the
+    block-diffusion mask the tile is square: the smaller explicit side,
+    halved until it divides a half of the rows."""
     bh, tq, d = q.shape
     tk = k.shape[1]
-    bq, bk = _flash_blocks(tq, tk, d, q.dtype, kernel, dv)
-    if block_q is not None:
-        bq = _pick_block(tq, block_q)
-    if block_k is not None:
-        bk = _pick_block(tk, block_k)
+    bq, bk = _flash_blocks(tq, tk, d, q.dtype, kernel, dv, block_diffusion)
+    if block_diffusion is not None:
+        given = [b for b in (block_q, block_k) if b is not None]
+        if given:
+            bq = bk = _pick_block(tq // 2, min(given))
+    else:
+        if block_q is not None:
+            bq = _pick_block(tq, block_q)
+        if block_k is not None:
+            bk = _pick_block(tk, block_k)
     plan = _FLASH_PLANS[kernel](bh, tq, tk, d, bq, bk, causal, q.dtype,
-                                window, dv)
-    _export_flash_gauges(kernel, bq, bk, plan["grid"], window)
+                                window, dv, block_diffusion)
+    _export_flash_gauges(kernel, bq, bk, plan["grid"], window,
+                         block_diffusion)
     return bq, bk, plan
+
+
+def _flash_mask_kwargs(plan, window, block_diffusion):
+    """The mask as a kernel takes it: the window, or the block length
+    with the tiles a half the plan's grid was laid out for."""
+    if block_diffusion is None:
+        return {"window": window}
+    return {"blocks": (int(block_diffusion), plan["grid"][1] // 2)}
 
 
 def _flash_call(kernel, plan, *operands):
@@ -778,9 +1006,10 @@ def _flash_window(window, causal, tq, tk):
 FLASH_KEPT = ("flash_out", "flash_lse")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    block_k=None, window=None, kept=False):
+                    block_k=None, window=None, kept=False,
+                    block_diffusion=None):
     """Blockwise online-softmax attention.
 
     q, k: (BH, T, D), v: (BH, T, Dv) — fold batch and heads into the
@@ -800,27 +1029,48 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     caller is a layer whose ``jax.checkpoint`` keeps ``FLASH_KEPT``, so
     the backward's ``lse`` is held one float32 a row (the section's
     header); no number moves.
+
+    ``block_diffusion`` (an int ``B``; neither ``causal`` nor ``window``
+    goes with it): the mask block diffusion trains under (Arriola et
+    al., arXiv:2503.09573, section 4).  The ``T = 2 L`` rows are a
+    noised copy of a sequence followed by its clean copy, both in blocks
+    of ``B`` positions; with ``half(r) = [r >= L]`` and ``blk(r) = (r mod
+    L) // B`` query row r sees key row c iff ``half r = half c and blk r
+    = blk c`` (a block sees itself, both ways), or ``half r = 0, half c
+    = 1 and blk r > blk c`` (a noised block sees the clean blocks before
+    it), or ``half r = half c = 1 and blk r >= blk c`` (clean rows are
+    block-causal): ``L^2 + L B`` of the ``4 L^2`` pairs.  The tiles are
+    square, divide L and hold whole blocks; a tile with no visible pair
+    is neither fetched nor run in any of the three grids, whose minor
+    axis counts a row's (column's) visits — ``n (n + 2)`` of ``(2
+    n)^2`` tiles at ``n`` tiles a half, 24 of 64 at L = 4096.
     """
-    o, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, window)
+    o, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, window,
+                      block_diffusion)
     return o
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window=None):
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window=None,
+               block_diffusion=None):
     """``(o, lse)`` off the forward kernel; ``lse`` lane-broadcast,
     ``(BH, T, LANES)`` float32, as the kernel writes it."""
     _count("flash_attention_fwd")
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     window = _flash_window(window, causal, q.shape[1], k.shape[1])
     bq, bk, plan = _flash_plan("fwd", q, k, causal, block_q, block_k, window,
-                               v.shape[-1])
+                               v.shape[-1], block_diffusion)
     return _flash_call(
         functools.partial(_flash_fwd_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, nk=k.shape[1] // bk, window=window),
+                          bq=bq, bk=bk, nk=plan["grid"][2],
+                          **_flash_mask_kwargs(plan, window,
+                                               block_diffusion)),
         plan, q, k, v)
 
 
-def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, window, kept):
-    o, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k, window)
+def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, window, kept,
+                    block_diffusion):
+    o, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k, window,
+                        block_diffusion)
     # named BEFORE they become residuals: whatever the backward reads of
     # the two derives from a value a policy can keep
     o = checkpoint_name(o, FLASH_KEPT[0])
@@ -828,7 +1078,8 @@ def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, window, kept):
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd_rule(causal, scale, block_q, block_k, window, kept, res, do):
+def _flash_bwd_rule(causal, scale, block_q, block_k, window, kept,
+                    block_diffusion, res, do):
     _count("flash_attention_bwd")
     q, k, v, o, lse = res
     bh, tq, d = q.shape
@@ -839,17 +1090,21 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, window, kept, res, do):
     lanes = lambda a: jnp.broadcast_to(a[..., None], (bh, tq, LANES))
     lse_lanes, lse = (lanes(lse), lse) if kept else (lse, lse[:, :, 0])
     bq, bk, plan = _flash_plan("dq", q, k, causal, block_q, block_k, window,
-                               v.shape[-1])
+                               v.shape[-1], block_diffusion)
     dq, = _flash_call(
         functools.partial(_flash_bwd_dq_kernel, scale=s, causal=causal,
-                          bq=bq, bk=bk, nk=k.shape[1] // bk, window=window),
+                          bq=bq, bk=bk, nk=plan["grid"][2],
+                          **_flash_mask_kwargs(plan, window,
+                                               block_diffusion)),
         plan, q, k, v, do, lse_lanes, lanes(delta))
     bq, bk, plan = _flash_plan("dkv", q, k, causal, block_q, block_k, window,
-                               v.shape[-1])
+                               v.shape[-1], block_diffusion)
     rows = (bh, tq // bq, 1, bq)
     dk, dv = _flash_call(
         functools.partial(_flash_bwd_dkv_kernel, scale=s, causal=causal,
-                          bq=bq, bk=bk, nq=tq // bq, window=window),
+                          bq=bq, bk=bk, nq=plan["grid"][2],
+                          **_flash_mask_kwargs(plan, window,
+                                               block_diffusion)),
         plan, q, k, v, do, lse.reshape(rows), delta.reshape(rows))
     return dq, dk, dv
 

@@ -41,7 +41,8 @@ _OP_REGISTRY: dict[str, "OpDef"] = {}
 # variables to auto-create.
 OPTIONAL_ARRAY_INPUTS = frozenset({
     "bias", "gamma", "state_cell", "sequence_length",
-    "data_lengths", "label_lengths", "trans"})
+    "data_lengths", "label_lengths", "trans", "positions",
+    "position_draws", "block_draws", "position_weight"})
 
 # Framework metadata attrs that ride along with any op call and are not
 # op parameters (reference: node attrs like `name` live on the NNVM node,

@@ -28,7 +28,8 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-__all__ = ["ring_attention", "ulysses_attention", "local_attention"]
+__all__ = ["ring_attention", "ulysses_attention", "local_attention",
+           "block_diffusion_mask"]
 
 
 def _flash_eligible(q, k, causal, q_offset, kv_offset):
@@ -44,9 +45,30 @@ def _flash_eligible(q, k, causal, q_offset, kv_offset):
         and flash_seq_ok(k.shape[1], k.dtype)
 
 
+def block_diffusion_mask(t, length):
+    """The ``(t, t)`` boolean mask block diffusion trains under
+    (Arriola et al., arXiv:2503.09573, section 4), from its three-part
+    definition: the ``t = 2 L`` rows are a noised copy of a sequence and
+    then its clean copy, both in blocks of ``length`` positions; True
+    where query row r sees key row c."""
+    if t % (2 * length):
+        raise ValueError(
+            "the block-diffusion mask needs rows [noised ; clean] of two "
+            "equal halves of whole blocks: got %d rows, blocks of %d"
+            % (t, length))
+    row = jnp.arange(t)
+    clean = row >= t // 2
+    blk = (row % (t // 2)) // length
+    rq, rk, bq, bk = clean[:, None], clean[None, :], blk[:, None], \
+        blk[None, :]
+    return ((rq == rk) & (bq == bk)             # a block sees itself
+            | ~rq & rk & (bq > bk)              # noised: clean blocks before
+            | rq & rk & (bq >= bk))             # clean: block-causal
+
+
 def local_attention(q, k, v, causal=False, q_offset=0, kv_offset=0,
                     scale=None, impl="auto", kv_len=None, window=None,
-                    kept=False):
+                    kept=False, block_diffusion=None):
     """Softmax attention on local blocks.
 
     q: (B, Tq, H, D), k: (B, Tk, H, D), v: (B, Tk, H, Dv) — the values'
@@ -60,6 +82,12 @@ def local_attention(q, k, v, causal=False, q_offset=0, kv_offset=0,
     itself among them — in the flash kernels and in the einsum form
     alike.
 
+    ``block_diffusion`` (a block length; neither ``causal`` nor
+    ``window``, offsets 0, no ``kv_len``): the rows are ``[noised ;
+    clean]`` and a query sees what :func:`block_diffusion_mask` says — in
+    the flash kernels, which skip the tiles with no visible pair, and in
+    the einsum form alike.
+
     impl: "auto" uses the Pallas flash kernel on TPU when offsets are
     aligned and T divides into blocks (O(T) memory instead of the
     materialized (T, T) logits); "einsum"/"flash" force a path.
@@ -71,6 +99,14 @@ def local_attention(q, k, v, causal=False, q_offset=0, kv_offset=0,
     if window is not None and not causal:
         raise ValueError("an attention window needs causal=True: a query "
                          "sees its last `window` keys, itself among them")
+    if block_diffusion is not None:
+        block_diffusion = int(block_diffusion)
+        if causal or window is not None or q_offset or kv_offset \
+                or kv_len is not None or q.shape[1] != k.shape[1]:
+            raise ValueError(
+                "the block-diffusion mask is a mask of its own over one "
+                "whole sequence of rows [noised ; clean]: no causal, "
+                "window, offset or kv_len goes with it")
     k, v = _expand_kv_heads(q, k, v)
     if kv_len is not None and kv_len >= kv_offset + k.shape[1]:
         kv_len = None  # no padded keys in this block
@@ -78,6 +114,10 @@ def local_attention(q, k, v, causal=False, q_offset=0, kv_offset=0,
                  (impl == "flash" or
                   (impl == "auto" and _flash_eligible(q, k, causal,
                                                       q_offset, kv_offset))))
+    if use_flash and block_diffusion is not None and impl != "flash":
+        from ..ops.pallas_kernels import flash_block_diffusion_ok
+        use_flash = flash_block_diffusion_ok(q.shape[1], block_diffusion,
+                                             q.dtype)
     if use_flash:
         from ..ops.pallas_kernels import flash_attention
         b, tq, h, _ = q.shape
@@ -85,7 +125,8 @@ def local_attention(q, k, v, causal=False, q_offset=0, kv_offset=0,
         fold = lambda a, t: jnp.transpose(a, (0, 2, 1, 3)).reshape(
             b * h, t, a.shape[-1])
         o = flash_attention(fold(q, tq), fold(k, tk), fold(v, tk),
-                            causal, scale, None, None, window, kept)
+                            causal, scale, None, None, window, kept,
+                            block_diffusion)
         return jnp.transpose(o.reshape(b, h, tq, v.shape[-1]), (0, 2, 1, 3))
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
@@ -96,6 +137,8 @@ def local_attention(q, k, v, causal=False, q_offset=0, kv_offset=0,
         mask = qpos[:, None] >= kpos[None, :]
         if window is not None:
             mask = mask & (qpos[:, None] - kpos[None, :] < int(window))
+    if block_diffusion is not None:
+        mask = block_diffusion_mask(q.shape[1], block_diffusion)
     if kv_len is not None:
         valid = (kpos < kv_len)[None, :]
         mask = valid if mask is None else mask & valid
@@ -247,29 +290,36 @@ def _ring_attention_local(q, k, v, axis_name, causal, scale, kv_len=None):
     return jnp.transpose(out, (0, 2, 1, 3))  # (b, t_local, h, d)
 
 
-def _no_window_across_shards(window, what):
+def _no_window_across_shards(window, what, block_diffusion=None):
     if window is not None:
         raise NotImplementedError(
             "%s has no attention window: its shards mask by the causal "
             "rule alone, and ignoring window=%r would be another model"
             % (what, window))
+    if block_diffusion is not None:
+        raise NotImplementedError(
+            "%s has no block-diffusion mask: its shards mask by the causal "
+            "rule alone, a noised row's keys lie in BOTH halves of the "
+            "rows, and ignoring block_diffusion=%r would be another model"
+            % (what, block_diffusion))
 
 
 def ring_attention(q, k, v, mesh=None, axis_name="sp", causal=False,
-                   scale=None, window=None):
+                   scale=None, window=None, block_diffusion=None):
     """Ring attention over a sequence-sharded axis.
 
     Inputs (B, T, H, D) with T sharded over ``axis_name``; output has the
     same sharding.  Used directly or as the attention core of
-    sequence-parallel transformer layers.  A ``window`` is served only
-    where the axis has one member (``local_attention``); across shards
-    it raises."""
+    sequence-parallel transformer layers.  A ``window`` or the
+    ``block_diffusion`` mask is served only where the axis has one
+    member (``local_attention``); across shards either raises."""
     from .mesh import current_mesh
     mesh = mesh or current_mesh()
     if mesh is None or mesh.shape.get(axis_name, 1) == 1:
         return local_attention(q, k, v, causal=causal, scale=scale,
-                               window=window)
-    _no_window_across_shards(window, "ring_attention")
+                               window=window,
+                               block_diffusion=block_diffusion)
+    _no_window_across_shards(window, "ring_attention", block_diffusion)
     sp = mesh.shape[axis_name]
     t_real = q.shape[1]
     home = _single_device_of(q)
@@ -305,15 +355,17 @@ def _ulysses_local(q, k, v, axis_name, causal, scale, kv_len=None):
 
 
 def ulysses_attention(q, k, v, mesh=None, axis_name="sp", causal=False,
-                      scale=None, window=None):
+                      scale=None, window=None, block_diffusion=None):
     """DeepSpeed-Ulysses style sequence parallelism; requires
-    num_heads % sp == 0.  A ``window`` as in :func:`ring_attention`."""
+    num_heads % sp == 0.  A ``window`` or ``block_diffusion`` as in
+    :func:`ring_attention`."""
     from .mesh import current_mesh
     mesh = mesh or current_mesh()
     if mesh is None or mesh.shape.get(axis_name, 1) == 1:
         return local_attention(q, k, v, causal=causal, scale=scale,
-                               window=window)
-    _no_window_across_shards(window, "ulysses_attention")
+                               window=window,
+                               block_diffusion=block_diffusion)
+    _no_window_across_shards(window, "ulysses_attention", block_diffusion)
     sp = mesh.shape[axis_name]
     if q.shape[2] % sp:
         raise ValueError(

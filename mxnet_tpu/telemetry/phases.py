@@ -24,12 +24,16 @@ __all__ = ["FWD_SCOPE", "LOSS_SCOPE", "UPDATE_SCOPE", "CODEC_SCOPE",
            "FLATTEN_SCOPE", "UNFLATTEN_SCOPE", "SWEEP_SCOPE",
            "COLLECTIVE_PREFIX", "LOOP_SCOPE", "EXIT_SCOPE", "MOE_SCOPE",
            "MOE_EXPERTS_SCOPE", "ATTN_WINDOW_SCOPE", "ATTN_FULL_SCOPE",
-           "ATTN_LATENT_SCOPE", "SHARED_EXPERT_SCOPE", "MTP_SCOPE", "FWD", "BWD", "UPDATE", "COLLECTIVE", "CONTROL", "OTHER", "LOOP",
+           "ATTN_LATENT_SCOPE", "SHARED_EXPERT_SCOPE", "MTP_SCOPE",
+           "ATTN_BLOCKDIFF_SCOPE", "NOISE_SCOPE", "FWD", "BWD", "UPDATE",
+           "COLLECTIVE", "CONTROL", "OTHER", "LOOP",
            "EXIT", "ROUTE", "EXPERTS", "ATTN_WINDOW", "ATTN_FULL",
-           "ATTN_LATENT", "SHARED_EXPERT", "phase_of", "instruction_phases", "loop_part_of",
+           "ATTN_LATENT", "SHARED_EXPERT", "ATTN_BLOCKDIFF", "NOISE",
+           "phase_of", "instruction_phases", "loop_part_of",
            "instruction_loop_parts", "block_part_of",
            "instruction_block_parts", "latent_part_of",
-           "instruction_latent_parts", "register_program", "program_hlo",
+           "instruction_latent_parts", "diffusion_part_of",
+           "instruction_diffusion_parts", "register_program", "program_hlo",
            "program_names"]
 
 FWD_SCOPE, LOSS_SCOPE = "mx_fwd", "mx_loss"
@@ -61,6 +65,12 @@ ATTN_WINDOW_SCOPE, ATTN_FULL_SCOPE = "mx_attn_window", "mx_attn_full"
 # maps match by substring, so none of these names holds an older one
 ATTN_LATENT_SCOPE, SHARED_EXPERT_SCOPE = "mx_attn_latent", "mx_shared_expert"
 MTP_SCOPE = "mx_mtp"
+# inside mx_fwd, in a block trained by diffusion over blocks
+# (gluon.contrib.transformer.MoELM with block_length): a layer's attention
+# over the rows [noised ; clean] under the three-part block mask — QK-norm,
+# rotary at the rows' positions, the K/V repeat, the kernels — and the
+# noising with the gather of the 2 L rows' embeddings
+ATTN_BLOCKDIFF_SCOPE, NOISE_SCOPE = "mx_attn_blockdiff", "mx_noise"
 # what jax writes into the name stack of a forward that is run again in
 # the backward pass (jax.checkpoint)
 REMAT_MARK = "rematted_computation"
@@ -70,6 +80,7 @@ LOOP, EXIT = "loop", "exit"
 ROUTE, EXPERTS = "route", "experts"
 ATTN_WINDOW, ATTN_FULL = "attn_window", "attn_full"
 ATTN_LATENT, SHARED_EXPERT = "attn_latent", "shared_expert"
+ATTN_BLOCKDIFF, NOISE = "attn_blockdiff", "noise"
 
 
 def phase_of(op_name):
@@ -124,6 +135,18 @@ def latent_part_of(op_name):
     part = ATTN_LATENT if ATTN_LATENT_SCOPE in op_name else \
         SHARED_EXPERT if SHARED_EXPERT_SCOPE in op_name else None
     return part, MTP_SCOPE in op_name
+
+
+def diffusion_part_of(op_name):
+    """``(part, recomputed)`` of an HLO ``op_name`` in a block trained by
+    diffusion over blocks: ``part`` is :data:`ATTN_BLOCKDIFF` (scope
+    ``mx_attn_blockdiff``), :data:`NOISE` (``mx_noise``) or None;
+    ``recomputed`` as in :func:`loop_part_of`."""
+    if not op_name:
+        return None, False
+    part = ATTN_BLOCKDIFF if ATTN_BLOCKDIFF_SCOPE in op_name else \
+        NOISE if NOISE_SCOPE in op_name else None
+    return part, REMAT_MARK in op_name
 
 
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%([\w.\-]+) = (.*)$")
@@ -255,6 +278,15 @@ def instruction_latent_parts(hlo_text):
     prediction module runs it (:func:`latent_part_of`); the same
     inheritance."""
     return _classify(hlo_text, latent_part_of, (None, False), (None, False))
+
+
+def instruction_diffusion_parts(hlo_text):
+    """``{instruction name: (part, recomputed)}`` beside
+    :func:`instruction_block_parts`, for a program trained by diffusion
+    over blocks: ``part`` is ``attn_blockdiff``, ``noise`` or None
+    (:func:`diffusion_part_of`); the same inheritance."""
+    return _classify(hlo_text, diffusion_part_of, (None, False),
+                     (None, False))
 
 
 # name -> [jitted fn, abstract args, context factory or None, HLO text]
