@@ -1675,6 +1675,25 @@ def moe_slots(ys, dst, w, is_held, used, tm):
     return _moe_slots(tuple(ys), dst, w, is_held, used, tm)
 
 
+def _slot_fetch(rows, bt, k):
+    """``(held (t / bt,), fetch (t / bt, bt k))`` of the slots' rows
+    ``rows (t, k)`` (-1 where a slot is not held), a tile of ``bt``
+    tokens a row: how many of a tile's slots are held, and those slots
+    first, in their (token, slot) order, each its row of the buffer and
+    the place its row lands in (``row << bits | place``).  One stable
+    sort of each tile's codes keyed by "not held", no gather; what
+    follows a tile's held slots is never read."""
+    t = rows.shape[0]
+    bits = (bt * k - 1).bit_length()
+    flat = rows.reshape(t // bt, bt * k)
+    q = jnp.arange(bt * k, dtype=jnp.int32)
+    place = (q % k) * bt + q // k
+    absent = (flat < 0).astype(jnp.int32)
+    fetch = jax.lax.sort((absent, (flat << bits) | place), dimension=1,
+                         num_keys=1)[1]
+    return jnp.sum(1 - absent, axis=1, dtype=jnp.int32), fetch
+
+
 @functools.partial(jax.jit, static_argnums=(5,))
 def _moe_slots(ys, dst, w, is_held, used, tm):
     (p, units), (t, k), dtype = ys[0].shape, dst.shape, ys[0].dtype
@@ -1684,16 +1703,7 @@ def _moe_slots(ys, dst, w, is_held, used, tm):
         raise ValueError("a buffer of %d rows is too long for tiles of %d "
                          "tokens with %d slots each" % (p, bt, k))
     rows = jnp.where(is_held, dst, -1).astype(jnp.int32)
-    # a tile's held assignments first, in their (token, slot) order: the
-    # q-th of them is the first whose running count passes q
-    flat = rows.reshape(t // bt, bt * k)
-    seen = jnp.cumsum(flat >= 0, axis=1, dtype=jnp.int32)
-    order = jnp.minimum(jnp.sum(
-        seen[:, None, :] <= jnp.arange(bt * k, dtype=jnp.int32)[:, None],
-        axis=-1, dtype=jnp.int32), bt * k - 1)
-    place = (order % k) * bt + order // k
-    fetch = (jnp.take_along_axis(flat, order, axis=1) << bits) | place
-    held = seen[:, -1]
+    held, fetch = _slot_fetch(rows, bt, k)
     return _mover_call(
         functools.partial(_moe_slots_kernel, bt=bt, k=k, dtype=dtype),
         moe_slots_plan(t, k, p, units, bt, dtype),

@@ -42,7 +42,7 @@ __all__ = ["switch_moe", "stack_experts", "routed_experts"]
 # chosen experts and the row tables made from them.  An identity until a
 # checkpoint's policy asks for it: a rematerialised layer of
 # ``gluon.contrib.transformer`` keeps them across its ``jax.checkpoint``
-# (about 1 MB a layer at 8192 tokens x 8 slots), and the layer run again
+# (about 1.4 MB a layer at 8192 tokens x 8 slots), and the layer run again
 # in the backward pass then holds no top-k, no sort and no table work.
 ROUTE_KEPT = "moe_route"
 
@@ -67,15 +67,24 @@ def _kept(a):
     return checkpoint_name(flat, ROUTE_KEPT).reshape(a.shape)
 
 
+# an XLA gather under the expert layer's scope, its experts' included
+_ROUTE_GATHER = re.compile(r' gather\(.*op_name="[^"]*\b%s\)*/'
+                           % _phases.MOE_SCOPE)
+
+
 def export_route_passes(program, hlo_text):
     """``mxnet_moe_route_passes{program}``: the routing passes — top-k,
     the two sorts, the tables — in a program's OPTIMIZED module
     (``telemetry.program_hlo``), counted by their top-k.  One a routed
     layer where the layer's checkpoint keeps ``ROUTE_KEPT``, two where
-    the backward pass routes the tokens again."""
+    the backward pass routes the tokens again.  Beside it, from the same
+    text, ``mxnet_moe_route_gathers{program}``: the XLA ``gather``
+    instructions under the expert layer's scope — none, where every
+    table, weight and row is moved by a sort, a shift or a select."""
     from .. import telemetry
     if not telemetry.enabled():
         return
+    lines = hlo_text.splitlines()
     telemetry.gauge(
         "mxnet_moe_route_passes",
         "top-k instructions under the expert layer's scope in the "
@@ -83,8 +92,14 @@ def export_route_passes(program, hlo_text):
         "where the layer's checkpoint keeps the choice and the row "
         "tables, two where the backward pass routes again").labels(
             program=program).set(
-                sum(1 for line in hlo_text.splitlines()
-                    if _ROUTE_PASS.search(line)))
+                sum(1 for line in lines if _ROUTE_PASS.search(line)))
+    telemetry.gauge(
+        "mxnet_moe_route_gathers",
+        "gather instructions under the expert layer's scope in the "
+        "optimized module of a registered program: zero where its "
+        "tables, weights and rows move by sort, shift and "
+        "select").labels(program=program).set(
+            sum(1 for line in lines if _ROUTE_GATHER.search(line)))
 
 
 def _route_top_k(x, router_w, top_k, norm_topk=True, scoring="softmax",
@@ -126,6 +141,61 @@ def _route_top_k(x, router_w, top_k, norm_topk=True, scoring="softmax",
     return weights, experts
 
 
+def _select(e_of, table):
+    """``table[e_of]`` for a short ``table`` — one value an expert — by
+    compare and select, one fused elementwise pass (an XLA gather of
+    65,536 elements takes 0.67 ms on a v5e); zero where ``e_of`` names
+    no entry."""
+    out = jnp.zeros(e_of.shape, table.dtype)
+    for e in range(table.shape[0]):
+        out = jnp.where(e_of == e, table[e], out)
+    return out
+
+
+def _to_buffer(v, runs, rows):
+    """``v (A,)`` in sorted order, laid out as the buffer's ``rows``:
+    expert ``e``'s run ``v[starts[e] : starts[e] + sizes[e]]`` from row
+    ``row0[e]`` on, zero on the padding rows.  A run is ``v`` shifted
+    right by ``row0[e] - starts[e]`` (less than ``rows - A``): one
+    ``dynamic_slice`` an expert of ``v`` padded on both sides, selected
+    where the row lies in the run — no gather."""
+    starts, row0, sizes = runs
+    pad = jnp.zeros((rows - v.shape[0],), v.dtype)
+    wide = jnp.concatenate([pad, v, pad])
+    p = jnp.arange(rows, dtype=jnp.int32)
+    out = jnp.zeros((rows,), v.dtype)
+    for e in range(starts.shape[0]):
+        run = lax.dynamic_slice(wide, (pad.shape[0] - row0[e] + starts[e],),
+                                (rows,))
+        out = jnp.where((p >= row0[e]) & (p < row0[e] + sizes[e]), run, out)
+    return out
+
+
+def _from_buffer(u, runs, a):
+    """:func:`_to_buffer` undone: the buffer's rows ``u`` back in sorted
+    order, ``(a,)``, each run shifted left by ``row0[e] - starts[e]``;
+    zero past the held assignments.  ``u`` is held flat behind a
+    barrier: the row mover writes it as a ``(P, 1)`` column, and XLA
+    would otherwise slice the column, each ``(a, 1)`` slice padded to
+    128 lanes on the TPU (33.5 MB at 65,536 rows)."""
+    starts, row0, sizes = runs
+    u = lax.optimization_barrier(u)
+    q = jnp.arange(a, dtype=jnp.int32)
+    out = jnp.zeros((a,), u.dtype)
+    for e in range(starts.shape[0]):
+        run = lax.dynamic_slice(u, (row0[e] - starts[e],), (a,))
+        out = jnp.where((q >= starts[e]) & (q < starts[e] + sizes[e]), run,
+                        out)
+    return out
+
+
+def _to_places(at, v):
+    """``out[at[j]] = v[j]`` for a permutation ``at``: one sort of ``v``
+    keyed by ``at`` (about 0.09 ms at 65,536 keys on a v5e), where an XLA
+    gather by the inverse permutation takes 0.67."""
+    return lax.sort((at, v), num_keys=1)[1]
+
+
 def _layout(experts, held, tm):
     """Where every (token, slot) assignment goes.  The assignments whose
     expert is held, sorted by expert, fill a row buffer in which every
@@ -135,10 +205,17 @@ def _layout(experts, held, tm):
     tiles after the last expert's are unused.  The buffer is sized for
     the worst case, every assignment held: ``ceil(T k / tm) + count``
     tiles.  Returns int32 arrays: ``src (P,)`` the flat assignment a
-    row holds, ``valid (P,)``, ``dst (T, k)`` an assignment's row (0
-    where it is not held), ``is_held (T, k)``, ``tile_group (tiles,)``,
-    ``used (1,)`` and ``counts (tiles,)``: a tile's valid rows are its
-    first ``counts[i]`` (none past the used tiles)."""
+    row holds (0 on padding), ``valid (P,)``, ``dst (T, k)`` an
+    assignment's row (0 where it is not held), ``is_held (T, k)``,
+    ``tile_group (tiles,)``, ``used (1,)``, ``counts (tiles,)``: a
+    tile's valid rows are its first ``counts[i]`` (none past the used
+    tiles); ``order (T k,)`` the assignments in sorted order, ``rank (T
+    k,)`` its inverse, and ``runs (3, count)``: each expert's ``starts``
+    in sorted order, ``row0`` in the buffer, ``sizes``.
+
+    No table is read by a gather: the per-expert ones by compare and
+    select over the ``count`` experts (:func:`_select`), ``order`` into
+    the buffer by one shift an expert (:func:`_to_buffer`)."""
     first, count = held
     t, k = experts.shape
     a = t * k
@@ -155,21 +232,19 @@ def _layout(experts, held, tm):
     tile_ends = jnp.cumsum(group_tiles)
     row0 = (tile_ends - group_tiles) * tm             # in the buffer
     used = tile_ends[-1:]
-    tile_group = jnp.minimum(
-        jnp.searchsorted(tile_ends, jnp.arange(tiles, dtype=jnp.int32),
-                         side="right"), count - 1).astype(jnp.int32)
-    p = jnp.arange(tiles * tm, dtype=jnp.int32)
-    g = tile_group[p // tm]
-    off = p - row0[g]
-    valid = jnp.logical_and(p // tm < used[0], off < sizes[g])
-    src = order[jnp.clip(starts[g] + off, 0, a - 1)]
-    g_a = jnp.minimum(key, count - 1)
-    dst = jnp.where(is_held.reshape(a), row0[g_a] + rank - starts[g_a], 0)
+    runs = jnp.stack([starts, row0, sizes]).astype(jnp.int32)
     i = jnp.arange(tiles, dtype=jnp.int32)
+    tile_group = jnp.minimum(jnp.sum(i[:, None] >= tile_ends, axis=1,
+                                     dtype=jnp.int32), count - 1)
     counts = jnp.where(i < used[0], jnp.clip(
-        sizes[tile_group] - (i * tm - row0[tile_group]), 0, tm), 0)
+        _select(tile_group, row0 + sizes) - i * tm, 0, tm), 0)
+    src = _to_buffer(order, runs, tiles * tm)
+    valid = _to_buffer(jnp.ones((a,), jnp.int32), runs, tiles * tm) > 0
+    dst = jnp.where(is_held.reshape(a), rank + _select(key, row0 - starts),
+                    0)
     return (src, valid, dst.reshape(t, k), is_held, tile_group,
-            used.astype(jnp.int32), counts.astype(jnp.int32))
+            used.astype(jnp.int32), counts.astype(jnp.int32), order, rank,
+            runs)
 
 
 def _gather_rows(x, src_token, counts, used, tm, scale=None, y=None):
@@ -250,29 +325,32 @@ _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _combine(y, w, src, counts, used, dst, is_held):
+def _combine(y, w, src, counts, used, dst, is_held, order, rank, runs):
     """``(T, U)``: token ``t`` gets ``sum_s w[t, s] y[dst[t, s]]`` over
     its held slots, summed in float32.  Transposed by moving rows the
     other way: row ``p``'s cotangent is its assignment's weight times
     its token's cotangent, and a weight's is the dot of its row with its
-    token's cotangent (taken on the buffer's side, a number a row)."""
+    token's cotangent (taken on the buffer's side, a number a row).  The
+    weights reach the rows, and the dots the assignments, by a sort
+    (``order``, ``rank``) and a shift an expert (``runs``), no gather."""
     return _move_slots((y,), dst, w, is_held, used,
                        y.shape[0] // counts.shape[0])
 
 
-def _combine_fwd(y, w, src, counts, used, dst, is_held):
-    return (_combine(y, w, src, counts, used, dst, is_held),
-            (y, w, src, counts, used, dst, is_held))
+def _combine_fwd(y, w, src, counts, used, dst, is_held, order, rank, runs):
+    return (_combine(y, w, src, counts, used, dst, is_held, order, rank,
+                     runs),
+            (y, w, src, counts, used, dst, is_held, order, rank, runs))
 
 
 def _combine_bwd(res, g):
-    y, w, src, counts, used, dst, is_held = res
-    k = w.shape[1]
+    y, w, src, counts, used, dst, is_held, order, rank, runs = res
+    t, k = w.shape
+    scale = _to_buffer(_to_places(rank, w.reshape(-1)), runs, y.shape[0])
     gy, dots = _move_rows(g.astype(y.dtype), src // k, counts, used,
-                          y.shape[0] // counts.shape[0],
-                          scale=w.reshape(-1)[src], y=y)
-    gw = jnp.where(is_held, dots[dst], 0)
-    return gy, gw.astype(w.dtype), None, None, None, None, None
+                          y.shape[0] // counts.shape[0], scale=scale, y=y)
+    gw = _to_places(order, _from_buffer(dots, runs, t * k)).reshape(t, k)
+    return (gy, jnp.where(is_held, gw, 0).astype(w.dtype)) + (None,) * 8
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -347,7 +425,7 @@ def routed_experts(x, router_w, experts, top_k, held, norm_topk=True,
                                        scoring, bias, float(scale))
         tm = pk.GROUPED_TILE_ROWS
         src, _, *tables = _layout(chosen, (first, count), tm)
-        src, dst, is_held, tile_group, used, counts = (
+        src, dst, is_held, tile_group, used, counts, order, rank, runs = (
             _kept(a) for a in (src, *tables))
         # lax's own operations on the kept values, not jnp's jitted
         # wrappers: a checkpoint that keeps a jitted function's operand
@@ -360,7 +438,8 @@ def routed_experts(x, router_w, experts, top_k, held, norm_topk=True,
             y = product(h, down)
         w = lax.select(is_held, weights,
                        jnp.zeros_like(weights)).astype(jnp.float32)
-        return _combine(y, w, src, counts, used, dst, is_held)
+        return _combine(y, w, src, counts, used, dst, is_held, order,
+                        rank, runs)
 
 
 def switch_moe(x, gate_w, expert_params, expert_fn, mesh,

@@ -111,14 +111,15 @@ def check_route_kept(monkeypatch):
     layer's routing (``moe_lm``, ``latent_moe_lm``): the gradient of
     ``loss(params)`` over a block of ``layers`` routed layers, each of
     ``tokens`` tokens taking ``top_k`` of the ``held`` experts' slots,
-    holds ``layers`` top-k's and ``layers`` pairs of sorts — the
-    backward pass routes nothing again — where a bare
-    ``jax.checkpoint`` holds twice that; what the backward pass is
-    handed grows by the tables' bytes (``parallel.moe.ROUTE_KEPT``: the
-    choice, ``src``, ``dst``, ``is_held``, ``tile_group``, ``used``,
-    ``counts``) and nothing else, less the ``bias`` floats of a
-    selection bias, which only the choice read; and loss and every
-    gradient leaf are the bare checkpoint's bit for bit: the kept
+    holds ``layers`` top-k's and ``layers`` pairs of routing's sorts —
+    the backward pass routes nothing again — where a bare
+    ``jax.checkpoint`` holds twice that (and, in both, a pair of sorts a
+    layer in the combine's transpose); what the backward pass is handed
+    grows by the tables' bytes (``parallel.moe.ROUTE_KEPT``: the choice,
+    ``src``, ``dst``, ``is_held``, ``tile_group``, ``used``, ``counts``,
+    ``order``, ``rank``, ``runs``) and nothing else, less the ``bias``
+    floats of a selection bias, which only the choice read; and loss and
+    every gradient leaf are the bare checkpoint's bit for bit: the kept
     choice is the one the second run would make."""
     from mxnet_tpu.gluon.contrib import transformer
     from mxnet_tpu.ops.pallas_kernels import GROUPED_TILE_ROWS as tm
@@ -134,12 +135,13 @@ def check_route_kept(monkeypatch):
         kept, kept_bytes, (value, grads) = run()
         monkeypatch.setattr(transformer, "_layer_keeps", lambda: None)
         bare, bare_bytes, (bare_value, bare_grads) = run()
-        assert (kept["top_k"], kept["sort"]) == (layers, 2 * layers), kept
-        assert (bare["top_k"], bare["sort"]) == (2 * layers, 4 * layers), bare
+        # a routing pass sorts twice; the combine's transpose twice more
+        assert (kept["top_k"], kept["sort"]) == (layers, 4 * layers), kept
+        assert (bare["top_k"], bare["sort"]) == (2 * layers, 6 * layers), bare
         slots = tokens * top_k
         tiles = -(-slots // tm) + held
         tables = (4 * slots + 4 * tiles * tm + 4 * slots + slots
-                  + 4 * tiles + 4 + 4 * tiles)
+                  + 4 * tiles + 4 + 4 * tiles + 8 * slots + 12 * held)
         assert kept_bytes - bare_bytes == layers * (tables - 4 * bias)
         assert np.array_equal(np.asarray(value), np.asarray(bare_value))
         for k in grads:

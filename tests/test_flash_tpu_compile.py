@@ -87,11 +87,11 @@ def test_row_movers_compile_for_the_chip(one_chip, monkeypatch, tokens,
     tiles = -(-tokens * top_k // tm) + held
     p = tiles * tm
 
-    def loss(x, w, src, counts, used, dst, is_held):
+    def loss(x, w, src, counts, used, dst, is_held, order, rank, runs):
         rows, again = moe._dispatch(x, src // top_k, counts, used, dst,
                                     is_held)
         out = moe._combine(rows * 2 + again, w, src, counts, used, dst,
-                           is_held)
+                           is_held, order, rank, runs)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     def aval(shape, kind):
@@ -101,11 +101,14 @@ def test_row_movers_compile_for_the_chip(one_chip, monkeypatch, tokens,
         aval((tokens, units), dtype), aval((tokens, top_k), "float32"),
         aval((p,), "int32"), aval((tiles,), "int32"), aval((1,), "int32"),
         aval((tokens, top_k), "int32"), aval((tokens, top_k), "bool"),
+        aval((tokens * top_k,), "int32"), aval((tokens * top_k,), "int32"),
+        aval((3, held), "int32"),
     ).compile()
     text = compiled.as_text()
     for name in ("_moe_rows_kernel", "_moe_slots_kernel",
                  "_moe_words_kernel"):
         assert name in text
+    assert not any(" gather(" in line for line in text.splitlines())
 
 
 @pytest.fixture(scope="module")
@@ -115,12 +118,13 @@ def moe_steps(one_chip):
     twice — as ``kept``, its layers under their policy, and as ``bare``,
     under a bare ``jax.checkpoint`` — and compiled for the described
     chip by ``telemetry.program_hlo``: ``{program: (optimized text,
-    gauge -> value)}``."""
+    gauge -> value)}``.  The expert layer takes the chip's path too:
+    grouped products between the row movers (rows of 256 bfloat16)."""
     from mxnet_tpu import telemetry
     from mxnet_tpu.gluon.contrib import transformer
-    from mxnet_tpu.parallel import attention
+    from mxnet_tpu.parallel import attention, moe
     net = transformer.MoELM(
-        256, units=128, expert_width=64,
+        256, units=256, expert_width=128,
         layer_types=[transformer.SLIDING, transformer.FULL], num_heads=2,
         num_kv_heads=1, head_dim=64, num_routed=4, held=(0, 2), top_k=2,
         window=128)
@@ -146,11 +150,14 @@ def moe_steps(one_chip):
             text = telemetry.program_hlo(program)
         return text, {name: telemetry.gauge(name).labels(
             program=program).value
-            for name in ("mxnet_flash_fwd_calls", "mxnet_moe_route_passes")}
+            for name in ("mxnet_flash_fwd_calls", "mxnet_moe_route_passes",
+                         "mxnet_moe_route_gathers")}
 
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(pk, "_interpret", lambda: False)
         patch.setattr(attention, "_flash_eligible", lambda *a: True)
+        patch.setattr(moe, "_tile_product", pk.grouped_matmul)
+        patch.setattr(moe, "_movers_run", pk.row_words_ok)
         telemetry.enable()
         try:
             steps = {"kept": compiled("kept")}
@@ -182,10 +189,17 @@ def test_the_gauge_counts_the_routing_passes_of_a_registered_step(
     top-k a routed layer where the layers keep their routing across
     their checkpoints, two under a bare ``jax.checkpoint`` — and the
     comparison sorts (two a pass; the chip's compiler writes the top-k
-    as a third) fall with it."""
+    as a third) fall with it.  ``mxnet_moe_route_gathers{program}``
+    reads 0 in both: no table, weight or row index under the expert
+    layer is an XLA gather."""
     routed = re.compile(r"\bmx_moe\)*/")     # not mx_moe_experts
     for program, passes in (("kept", 2), ("bare", 4)):
         text, gauges = moe_steps[program]
         assert gauges["mxnet_moe_route_passes"] == passes
+        assert gauges["mxnet_moe_route_gathers"] == 0
+        # three a routing pass; two a layer in the combine's transpose and
+        # one in each slot mover's fetch table (the combine's, dispatch's
+        # transpose)
         assert sum(1 for line in text.splitlines()
-                   if " sort(" in line and routed.search(line)) == 3 * passes
+                   if " sort(" in line and routed.search(line)) \
+            == 3 * passes + 4 * 2

@@ -283,11 +283,114 @@ def _table(case):
             (0, 4), 4
     if case == "one_valid_row_in_the_last_tile":    # 17 rows, tiles of 16
         return np.tile([0, 3], (17, 1)), (0, 2), 4
+    if case == "three_tiles_and_three_empty":   # 40 rows for 1; 2 - 4 none
+        return np.stack([[1, 5 + t % 3] for t in range(40)]), (1, 4), 8
     raise KeyError(case)
 
 
 MOVER_CASES = ["no_row_and_every_row", "0_and_k_held_slots", "whole_layer",
-               "one_valid_row_in_the_last_tile"]
+               "one_valid_row_in_the_last_tile",
+               "three_tiles_and_three_empty"]
+
+
+def _layout_oracle(experts, held, tm):
+    """``moe._layout``'s tables by a plain numpy walk: the held
+    assignments sorted by expert (ties in assignment order), each
+    expert's run from a whole tile on and padded to whole tiles (one
+    tile where it has no row)."""
+    first, count = held
+    t, k = experts.shape
+    flat = experts.reshape(-1) - first
+    is_held = (flat >= 0) & (flat < count)
+    order = np.argsort(np.where(is_held, flat, count), kind="stable")
+    rank = np.argsort(order)
+    tiles = -(-t * k // tm) + count
+    src, valid = np.zeros(tiles * tm, int), np.zeros(tiles * tm, bool)
+    dst, tile_group = np.zeros(t * k, int), np.full(tiles, count - 1)
+    counts, starts, row0, sizes = np.zeros(tiles, int), [], [], []
+    row = start = 0
+    for e in range(count):
+        mine = [j for j in order if is_held[j] and flat[j] == e]
+        starts.append(start), row0.append(row), sizes.append(len(mine))
+        span = max(-(-len(mine) // tm), 1)
+        tile_group[row // tm:row // tm + span] = e
+        for r, j in enumerate(mine):
+            src[row + r], valid[row + r], dst[j] = j, True, row + r
+            counts[(row + r) // tm] += 1
+        start, row = start + len(mine), row + span * tm
+    return (src, valid, dst.reshape(t, k), is_held.reshape(t, k),
+            tile_group, np.array([row // tm]), counts, order, rank,
+            np.array([starts, row0, sizes]))
+
+
+@pytest.mark.parametrize("case", MOVER_CASES)
+def test_the_layout_is_the_sorted_padded_buffer(case):
+    """Every table ``_layout`` makes with no gather — shifts, selects and
+    sorts — equals the plain walk's, element for element."""
+    from mxnet_tpu.parallel import moe
+    experts, held, _ = _table(case)
+    got = moe._layout(jnp.asarray(experts, jnp.int32), held, 16)
+    for name, a, b in zip(("src", "valid", "dst", "is_held", "tile_group",
+                           "used", "counts", "order", "rank", "runs"),
+                          got, _layout_oracle(experts, held, 16)):
+        assert np.array_equal(np.asarray(a), b), name
+
+
+@pytest.mark.parametrize("case", MOVER_CASES)
+def test_the_weights_and_dots_move_as_the_gathers_did(case):
+    """The combine's transpose: each buffer row's weight (a sort keyed by
+    ``rank``, then a shift an expert) and each assignment's dot (a shift
+    back, then a sort keyed by ``order``) are the gathers ``w[src]`` and
+    ``dots[dst]`` bit for bit."""
+    from mxnet_tpu.parallel import moe
+    experts, held, _ = _table(case)
+    src, valid, dst, is_held, _, _, _, order, rank, runs = moe._layout(
+        jnp.asarray(experts, jnp.int32), held, 16)
+    rng = np.random.default_rng(23)
+    w = rng.uniform(.1, 1, experts.size).astype(np.float32)
+    dots = rng.normal(size=src.shape[0]).astype(np.float32)
+    scale = moe._to_buffer(moe._to_places(rank, jnp.asarray(w)), runs,
+                           src.shape[0])
+    gw = moe._to_places(order, moe._from_buffer(jnp.asarray(dots), runs,
+                                                experts.size))
+    held_ = np.asarray(is_held).reshape(-1)
+    assert np.array_equal(np.asarray(scale),
+                          np.where(valid, w[np.asarray(src)], 0))
+    assert np.array_equal(np.where(held_, np.asarray(gw), 0), np.where(
+        held_, dots[np.asarray(dst).reshape(-1)], 0))
+
+
+@pytest.mark.parametrize("case", MOVER_CASES + ["tiles_of_128_tokens"])
+def test_the_slot_fetch_is_the_gathered_table(case):
+    """``moe_slots``' fetch table, one stable sort a token tile, against
+    the table the gather made (``take_along_axis`` by each tile's running
+    count of held slots): the same held count a tile, and the same codes
+    in the same order wherever the kernel reads them."""
+    from mxnet_tpu.parallel import moe
+    if case == "tiles_of_128_tokens":
+        rng = np.random.default_rng(24)
+        experts, held = np.stack([rng.permutation(16)[:8]
+                                  for _ in range(512)]), (4, 4)
+    else:
+        experts, held, _ = _table(case)
+    _, _, dst, is_held, *_ = moe._layout(jnp.asarray(experts, jnp.int32),
+                                         held, 16)
+    rows = jnp.where(is_held, dst, -1).astype(jnp.int32)
+    t, k = rows.shape
+    bt = pk._pick_block(t, pk._SLOT_TILE_TOKENS)
+    bits = (bt * k - 1).bit_length()
+    flat = rows.reshape(t // bt, bt * k)
+    seen = jnp.cumsum(flat >= 0, axis=1, dtype=jnp.int32)
+    order = jnp.minimum(jnp.sum(
+        seen[:, None, :] <= jnp.arange(bt * k, dtype=jnp.int32)[:, None],
+        axis=-1, dtype=jnp.int32), bt * k - 1)
+    want = (jnp.take_along_axis(flat, order, axis=1) << bits) \
+        | ((order % k) * bt + order // k)
+    held_, fetch = pk._slot_fetch(rows, bt, k)
+    assert np.array_equal(np.asarray(held_), np.asarray(seen[:, -1]))
+    for n, a, b in zip(np.asarray(held_), np.asarray(fetch),
+                       np.asarray(want)):
+        assert np.array_equal(a[:n], b[:n])
 
 
 def _moved(case, dtype, units, movers, monkeypatch):
@@ -297,7 +400,7 @@ def _moved(case, dtype, units, movers, monkeypatch):
     experts, held, _ = _table(case)
     t, k = experts.shape
     tables = moe._layout(jnp.asarray(experts, jnp.int32), held, 16)
-    src, _, dst, is_held, _, used, counts = tables
+    src, _, dst, is_held, _, used, counts, order, rank, runs = tables
     rng = np.random.default_rng(22)
     x = jnp.asarray(rng.normal(size=(t, units)), dtype)
     w = jnp.where(is_held, jnp.asarray(rng.uniform(.1, 1, (t, k)),
@@ -311,7 +414,8 @@ def _moved(case, dtype, units, movers, monkeypatch):
                                     is_held)
         y = (jnp.tanh(rows.astype(jnp.float32)) * mix
              + again.astype(jnp.float32) / 4).astype(dtype)
-        out = moe._combine(y, w_, src, counts, used, dst, is_held)
+        out = moe._combine(y, w_, src, counts, used, dst, is_held, order,
+                           rank, runs)
         return jnp.sum(jnp.sin(out.astype(jnp.float32))), (rows, out)
 
     (_, (rows, out)), (gx, gw) = jax.value_and_grad(
@@ -411,7 +515,7 @@ def test_a_tiles_count_is_its_valid_rows():
     from mxnet_tpu.parallel import moe
     for case in MOVER_CASES:
         experts, held, _ = _table(case)
-        _, valid, _, _, tile_group, used, counts = moe._layout(
+        _, valid, _, _, tile_group, used, counts, *_ = moe._layout(
             jnp.asarray(experts, jnp.int32), held, 16)
         by_tile = np.asarray(valid).reshape(-1, 16)
         assert list(np.asarray(counts)) == list(by_tile.sum(1))
