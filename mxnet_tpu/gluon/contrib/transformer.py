@@ -26,6 +26,8 @@ as the in-tree model stock through two seams:
   ``serving/generate/model.py`` compiles its prefill/decode programs
   from.
 """
+from typing import NamedTuple
+
 from ..block import HybridBlock
 from ..nn import Dense, Dropout, Embedding, HybridSequential, LayerNorm
 from ..parameter import DeferredInitializationError
@@ -455,18 +457,125 @@ _MOE_LAYER_LEAVES = (
     "norm2_gamma", "router_weight", "gate_weight", "up_weight",
     "down_weight")
 _QK_NORM_LEAVES = ("q_norm_gamma", "k_norm_gamma")
+# compressed convolutional attention's own leaves after its projections:
+# the convolution over time, the one across a head's channels, the
+# temperature of each key/value head
+_CCA_LEAVES = ("cca_time_weight", "cca_mix_weight", "cca_temperature_gamma")
 SLIDING, FULL = "sliding_attention", "full_attention"
 
 
-def _moe_layer_leaves(qk_norm):
-    if not qk_norm:
-        return _MOE_LAYER_LEAVES
-    return _MOE_LAYER_LEAVES[:4] + _QK_NORM_LEAVES + _MOE_LAYER_LEAVES[4:]
+def _moe_layer_leaves(qk_norm, cca=False, router_layers=0):
+    leaves = _MOE_LAYER_LEAVES
+    if qk_norm:
+        leaves = leaves[:4] + _QK_NORM_LEAVES + leaves[4:]
+    if cca:
+        leaves = leaves[:4] + _CCA_LEAVES + leaves[4:]
+    if router_layers:
+        at = leaves.index("router_weight") + 1
+        leaves = leaves[:at] + tuple("router%d_weight" % j for j in range(
+            router_layers)) + leaves[at:]
+    return leaves
+
+
+def compressed_conv_attention(h, p, *, num_heads, num_kv_heads, rope,
+                              rotary_dim=None, kept=False):
+    """Compressed convolutional attention (CCA; Figliolia et al.,
+    arXiv:2510.04476, as ZAYA1 runs it) over normed states ``h (B, T,
+    U)``: ``(B, T, U)``, the output projection included.  ``p`` holds one
+    layer's ``q_weight (H D, U)``, ``k_weight (Hkv D, U)``, ``v_weight
+    (Hkv D, U)``, ``cca_time_weight (K0, (H + Hkv) D)``,
+    ``cca_mix_weight (H + Hkv, K1, D, D)``, ``cca_temperature_gamma
+    (Hkv,)`` and ``out_weight (U, H D)``; query head ``n`` reads
+    key/value head ``n // (H / Hkv)``.  With ``q~ = h Wq'``, ``k~ = h
+    Wk'``, ``z = [q~ ; k~]``::
+
+        a_t = sum_j time[j] * z_{t-j}             causal, channel by channel
+        b_t = sum_j a_{t-j}[head] @ mix[head, j]  causal, across a head's D
+        m_g = (mean of group g's query heads of q~ + k~_g) / 2
+        q, k = b's query heads + m_g, b's key heads + m_g
+        v_t  = [first Hkv/2 heads of h_t Wv' ; the others of h_{t-1} Wv']
+        q, k = temperature_g * q / |q|, k / |k|   each head, float32
+        q, k = rope(q), rope(k)                   the first rotary_dim
+        o    = causal softmax(q . k) v            no further scale
+
+    (rows before the first are zero).  The projections are the layer's;
+    what mixes between them and the flash call is under scope ``mx_cca``,
+    the call under ``mx_attn_full``.  ``rope`` is ``(base, inv_freq or
+    None, scale)`` (:func:`ops.contrib._rotary_embedding`); ``kept``: the
+    caller is a layer under :func:`_layer_keeps`."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from ...ops.contrib import (_causal_conv, _flash_attention_op,
+                                _rotary_embedding)
+    from ...telemetry import phases
+
+    b, t, _u = h.shape
+    hkv, group = num_kv_heads, num_heads // num_kv_heads
+    d = p["q_weight"].shape[0] // num_heads
+    base, inv_freq, scale = rope
+    proj = lambda w: jnp.einsum("btu,ou->bto", h, w)
+    q0, k0, v0 = proj(p["q_weight"]), proj(p["k_weight"]), proj(p["v_weight"])
+    with jax.named_scope(phases.CCA_SCOPE):
+        z = _causal_conv(_causal_conv(jnp.concatenate([q0, k0], -1),
+                                      p["cca_time_weight"]),
+                         p["cca_mix_weight"]).astype(jnp.float32)
+        mean = 0.5 * (jnp.mean(q0.reshape(b, t, hkv, group, d).astype(
+            jnp.float32), axis=3) + k0.reshape(b, t, hkv, d).astype(
+                jnp.float32))
+        q = z[..., :num_heads * d].reshape(b, t, hkv, group, d) \
+            + mean[:, :, :, None]
+        k = z[..., num_heads * d:].reshape(b, t, hkv, d) + mean
+
+        def unit(a):
+            return a * lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+
+        temp = p["cca_temperature_gamma"].astype(jnp.float32)
+        q = (unit(q) * temp[:, None, None]).reshape(b, t, num_heads, d)
+        turn = lambda a: _rotary_embedding(
+            a, base=base, inv_freq=inv_freq, scale=scale,
+            rotary_dim=rotary_dim).astype(h.dtype)
+        q, k = turn(q), turn(unit(k))
+        shifted = hkv // 2 * d
+        v = jnp.concatenate(
+            [v0[..., :shifted],
+             jnp.pad(v0[..., shifted:], ((0, 0), (1, 0), (0, 0)))[:, :t]],
+            -1).reshape(b, t, hkv, d)
+    with jax.named_scope(phases.ATTN_FULL_SCOPE):
+        o = _flash_attention_op(q, k, v, causal=True, scale=1.0, kept=kept)
+    return jnp.einsum("bto,uo->btu", o.reshape(b, t, -1), p["out_weight"])
+
+
+class _RouterMLP(NamedTuple):
+    """A router given as an MLP over ``weights`` — a bias-free
+    down-projection, then layers with an exact GELU before each but the
+    first; every product in the weights' dtype with float32
+    accumulation.  Called on ``x (T, U)`` it gives the ``(T, E)`` float32
+    logits; its ``shape`` is ``(E, U)``, a linear router's over the same
+    experts (``parallel.moe.routed_experts`` reads it)."""
+    weights: tuple
+
+    @property
+    def shape(self):
+        return (self.weights[-1].shape[0], self.weights[0].shape[1])
+
+    def __call__(self, x):
+        import jax
+        import jax.numpy as jnp
+        r = x
+        for j, w in enumerate(self.weights):
+            if j > 1:
+                r = jax.nn.gelu(r, approximate=False)
+            r = jnp.einsum("ti,oi->to", r.astype(w.dtype), w,
+                           preferred_element_type=jnp.float32)
+        return r
 
 
 def moe_lm_forward(params, tokens, *, layer_types, num_heads, num_kv_heads,
                    top_k, held, window, rope, norm_topk=True, eps=1e-6,
-                   qk_norm=False, block_length=None):
+                   qk_norm=False, block_length=None, cca=False,
+                   rotary_dim=None, router_layers=0):
     """A sparse-expert LM's trunk as ONE pure function of ``(params,
     tokens)``: the final-normed states ``(B, T, U)`` the head reads.
 
@@ -496,6 +605,14 @@ def moe_lm_forward(params, tokens, *, layer_types, num_heads, num_kv_heads,
     returned, ``(B, L, U)`` — the objective reads no other
     (``gluon.loss.BlockDiffusionCELoss``).
 
+    With ``cca`` a layer's attention is compressed convolutional
+    attention (:func:`compressed_conv_attention`: latent queries and
+    keys mixed by two causal convolutions, shifted values, L2-normed
+    heads under a temperature).  ``rotary_dim`` turns only a head's first
+    dimensions.  With ``router_layers`` the router is an MLP
+    (:class:`_RouterMLP`): ``l{i}_router_weight`` projects down, then
+    ``l{i}_router0_weight ..`` give the logits.
+
     Each layer is a ``jax.checkpoint``
     (:func:`_layer_keeps`): the backward pass keeps the state that
     enters a layer and runs the layer again — except the flash call,
@@ -512,16 +629,14 @@ def moe_lm_forward(params, tokens, *, layer_types, num_heads, num_kv_heads,
     from ...parallel.moe import routed_experts
     from ...telemetry import phases
 
-    leaves = _moe_layer_leaves(qk_norm)
+    leaves = _moe_layer_leaves(qk_norm, cca, router_layers)
     half = tokens.shape[1] // 2
     positions = None if block_length is None else \
         jnp.tile(jnp.arange(half, dtype=jnp.int32), 2)
 
-    def layer(x, p, kind):
-        p = dict(zip(leaves, p))
-        b, t, u = x.shape
+    def attention(h, p, kind):
+        b, t, _u = h.shape
         base, inv_freq, scale = rope[kind]
-        h = _rms_norm(x, p["norm1_gamma"], eps=eps)
         heads = lambda w, n: jnp.einsum("btu,ou->bto", h, w).reshape(
             b, t, n, w.shape[0] // n)
         mask = {"block_diffusion": block_length} \
@@ -536,16 +651,32 @@ def moe_lm_forward(params, tokens, *, layer_types, num_heads, num_kv_heads,
                 if qk_norm:
                     a = _rms_norm(a, p[which + "_norm_gamma"], eps=eps)
                 return _rotary_embedding(a, positions, base=base,
-                                         inv_freq=inv_freq, scale=scale)
+                                         inv_freq=inv_freq, scale=scale,
+                                         rotary_dim=rotary_dim)
 
             o = _flash_attention_op(
                 turned("q", num_heads), turned("k", num_kv_heads),
                 heads(p["v_weight"], num_kv_heads), kept=True, **mask)
-        x = x + jnp.einsum("bto,uo->btu", o.reshape(b, t, -1),
-                           p["out_weight"])
+        return jnp.einsum("bto,uo->btu", o.reshape(b, t, -1),
+                          p["out_weight"])
+
+    def layer(x, p, kind):
+        p = dict(zip(leaves, p))
+        b, t, u = x.shape
+        h = _rms_norm(x, p["norm1_gamma"], eps=eps)
+        if cca:
+            x = x + compressed_conv_attention(
+                h, p, num_heads=num_heads, num_kv_heads=num_kv_heads,
+                rope=rope[kind], rotary_dim=rotary_dim, kept=True)
+        else:
+            x = x + attention(h, p, kind)
         h = _rms_norm(x, p["norm2_gamma"], eps=eps)
+        router = p["router_weight"]
+        if router_layers:
+            router = _RouterMLP((router,) + tuple(
+                p["router%d_weight" % j] for j in range(router_layers)))
         y = routed_experts(
-            h.reshape(b * t, u), p["router_weight"],
+            h.reshape(b * t, u), router,
             (p["gate_weight"], p["up_weight"], p["down_weight"]), top_k,
             held, norm_topk=norm_topk)
         return x + y.reshape(b, t, u)
@@ -603,14 +734,31 @@ class MoELM(HybridBlock):
     p`` ``(B, L)`` beside them.  A caller that must reproduce a step
     hands the draws in with the batch: an int32 input ``(B, 3, L)`` is
     ``[ids ; the positions' draws ; the blocks' draws]``, integers in
-    ``[0, 2**24)``, a block reading the entry at its first position."""
+    ``[0, 2**24)``, a block reading the entry at its first position.
+
+    ``cca = (K0, K1)`` makes every layer's attention COMPRESSED
+    CONVOLUTIONAL ATTENTION (:func:`compressed_conv_attention`: the
+    latent queries and keys mixed by a causal convolution of ``K0`` taps
+    over time and one of ``K1`` taps across a head's channels, the q-k
+    mean added, half the value heads reading the token before, every
+    head L2-normed under a temperature of its key/value head; ZAYA1).
+    ``rotary_dim``: only a head's first ``rotary_dim`` dimensions turn.
+    ``router_hidden`` with ``router_layers``: the router is an MLP — a
+    bias-free projection to ``router_hidden``, then ``router_layers``
+    layers (GELU between them) to the ``num_routed`` logits
+    (``l{i}_router_weight``, ``l{i}_router0_weight`` ...).
+    ``tie_embeddings``: the head IS the embedding table (no
+    ``head_weight``): the loss reads ``embed_weight``, and its gradient
+    is the sum of the look-up's and the head's."""
 
     def __init__(self, vocab_size, units=128, expert_width=64,
                  layer_types=(SLIDING, FULL), num_heads=4, num_kv_heads=2,
                  head_dim=None, num_routed=8, held=None, top_k=2,
                  window=32, rope=None, norm_topk=True, epsilon=1e-6,
                  qk_norm=False, block_length=None, mask_token_id=None,
-                 noise_eps=1e-3, **kwargs):
+                 noise_eps=1e-3, cca=None, rotary_dim=None,
+                 router_hidden=None, router_layers=0, tie_embeddings=False,
+                 **kwargs):
         super().__init__(**kwargs)
         from ...ops.contrib import yarn_inv_freq
         head_dim = head_dim or units // num_heads
@@ -620,6 +768,23 @@ class MoELM(HybridBlock):
             raise ValueError("num_heads (%d) must be a multiple of "
                              "num_kv_heads (%d), heads of even size (%d)"
                              % (num_heads, num_kv_heads, head_dim))
+        rotary = head_dim if rotary_dim is None else int(rotary_dim)
+        if rotary % 2 or not 0 < rotary <= head_dim:
+            raise ValueError("rotary_dim (%d) must be even and at most the "
+                             "head size (%d)" % (rotary, head_dim))
+        if cca is not None and (len(cca) != 2 or min(cca) < 1 or qk_norm
+                                or block_length is not None
+                                or num_kv_heads % 2 or SLIDING in layer_types):
+            raise ValueError(
+                "compressed convolutional attention takes two kernel "
+                "lengths of at least 1 and an even number of key/value "
+                "heads, full attention layers only, and no QK-norm or block "
+                "diffusion beside it; got cca=%r, %d key/value heads"
+                % (cca, num_kv_heads))
+        if (router_hidden is None) != (not router_layers):
+            raise ValueError("a router MLP takes router_hidden and at least "
+                             "one layer; got %r and %r"
+                             % (router_hidden, router_layers))
         if any(k not in (SLIDING, FULL) for k in layer_types):
             raise ValueError("layer_types are %r or %r, got %r"
                              % (SLIDING, FULL, list(layer_types)))
@@ -640,7 +805,7 @@ class MoELM(HybridBlock):
             base = float(r.get("rope_theta", 10000.0))
             if r.get("rope_type", "default") == "yarn":
                 tables[kind] = (base, tuple(yarn_inv_freq(
-                    head_dim, base, float(r["factor"]),
+                    rotary, base, float(r["factor"]),
                     float(r["original_max_position_embeddings"]),
                     float(r.get("beta_fast", 32.0)),
                     float(r.get("beta_slow", 1.0)))),
@@ -652,7 +817,13 @@ class MoELM(HybridBlock):
             num_kv_heads=num_kv_heads, top_k=top_k, held=held,
             window=int(window), rope=tables, norm_topk=bool(norm_topk),
             eps=epsilon, qk_norm=bool(qk_norm),
-            block_length=None if block_length is None else int(block_length))
+            block_length=None if block_length is None else int(block_length),
+            cca=cca is not None,
+            rotary_dim=None if rotary == head_dim else rotary,
+            router_layers=int(router_layers))
+        self._cca = (0, 0) if cca is None else (int(cca[0]), int(cca[1]))
+        self._rotary = rotary
+        self._tied = bool(tie_embeddings)
         self._noise = None if block_length is None else dict(
             block_length=int(block_length), mask_id=int(mask_token_id),
             eps=float(noise_eps))
@@ -663,15 +834,27 @@ class MoELM(HybridBlock):
                  "k_weight": (num_kv_heads * head_dim, units),
                  "v_weight": (num_kv_heads * head_dim, units),
                  "out_weight": (units, num_heads * head_dim),
-                 "router_weight": (num_routed, units),
+                 "router_weight": (router_hidden or num_routed, units),
                  "gate_weight": (n, f, units), "up_weight": (n, f, units),
                  "down_weight": (n, units, f)}
+        if cca is not None:
+            heads = num_heads + num_kv_heads
+            shape.update(
+                cca_time_weight=(self._cca[0], heads * head_dim),
+                cca_mix_weight=(heads, self._cca[1], head_dim, head_dim),
+                cca_temperature_gamma=(num_kv_heads,))
+        for j in range(router_layers):
+            shape["router%d_weight" % j] = (
+                num_routed if j == router_layers - 1 else router_hidden,
+                router_hidden)
         shapes = [("embed_weight", (vocab_size, units))]
         for i in range(len(layer_types)):
             shapes += [("l%d_%s" % (i, k), shape.get(k, (units,)))
-                       for k in _moe_layer_leaves(qk_norm)]
-        shapes += [("norm_gamma", (units,)),
-                   ("head_weight", (vocab_size, units))]
+                       for k in _moe_layer_leaves(qk_norm, cca is not None,
+                                                  router_layers)]
+        shapes.append(("norm_gamma", (units,)))
+        if not self._tied:
+            shapes.append(("head_weight", (vocab_size, units)))
         with self.name_scope():
             # the initializer reads the suffix: gains 1
             for name, shp in shapes:
@@ -694,6 +877,16 @@ class MoELM(HybridBlock):
             "mxnet_diffusion_block_length", "positions of a block of the "
             "newest MoELM trained by diffusion over blocks (0: a causal "
             "model)").set(c["block_length"] or 0)
+        taps = telemetry.gauge(
+            "mxnet_cca_kernel", "taps of the causal convolutions of the "
+            "newest MoELM's compressed convolutional attention (conv=time: "
+            "over time, channel by channel; conv=mix: across a head's "
+            "channels); 0 without it")
+        for conv, k in zip(("time", "mix"), self._cca):
+            taps.labels(conv=conv).set(k)
+        telemetry.gauge("mxnet_rotary_dims", "dimensions of a head the "
+                        "newest MoELM turns by rotary position").set(
+                            self._rotary)
 
     def expected_rows(self, tokens):
         """Rows a step of ``tokens`` tokens sends to this block's held
@@ -744,17 +937,23 @@ class MoELM(HybridBlock):
         states = invoke_fn(forward, [tokens] + [params[n] for n in names])
         return states if weight is None else (states, weight)
 
+    def _head(self):
+        return self.embed_weight if self._tied else self.head_weight
+
     def logits(self, states):
         """The head over final-normed states: ``(B, T, V)``."""
         from ... import ndarray as nd
-        return nd.dot(states, self.head_weight.data(), transpose_b=True)
+        return nd.dot(states, self._head().data(), transpose_b=True)
 
     def lm_loss(self, **kwargs):
         """The training objective over this block's output, sharing its
         head: ``loss(net(tokens), labels)`` — per sequence, the mean
-        over positions of the next token's cross-entropy."""
+        over positions of the next token's cross-entropy (the head the
+        embedding table where the two are tied)."""
         from ..loss import LinearCELoss
-        return LinearCELoss(params=self.params, **kwargs)
+        return LinearCELoss(params=self.params,
+                            head="embed_weight" if self._tied
+                            else "head_weight", **kwargs)
 
     def diffusion_loss(self, **kwargs):
         """The training objective of a block-diffusion model

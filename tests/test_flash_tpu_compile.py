@@ -35,7 +35,9 @@ def one_chip():
 # fit VMEM), a sequence of no whole lane tile, Tq != Tk, and latent
 # attention's pair — keys of 192 (a lane tile and a half: what Mosaic
 # may refuse and interpret mode cannot show) over values of 128 — at the
-# fifth cell's 8192 tokens, in bfloat16 and in float32
+# fifth cell's 8192 tokens, in bfloat16 and in float32; and compressed
+# convolutional attention's 8 query heads over 16384 tokens (16 key tiles
+# a query tile of 1024)
 @pytest.mark.parametrize("bh,tq,tk,d,dv,dtype,causal", [
     (64, 2048, 2048, 64, 64, "bfloat16", True),
     (16, 2048, 2048, 128, 128, "bfloat16", True),
@@ -45,6 +47,7 @@ def one_chip():
     (2, 384, 128, 128, 128, "bfloat16", False),
     (2, 8192, 8192, 192, 128, "bfloat16", True),
     (2, 2048, 2048, 192, 128, "float32", True),
+    (8, 16384, 16384, 128, 128, "bfloat16", True),
 ])
 def test_flash_kernels_compile_for_the_chip(one_chip, monkeypatch, bh, tq,
                                             tk, d, dv, dtype, causal):
@@ -109,6 +112,36 @@ def test_row_movers_compile_for_the_chip(one_chip, monkeypatch, tokens,
                  "_moe_words_kernel"):
         assert name in text
     assert not any(" gather(" in line for line in text.splitlines())
+
+
+# (units, expert width, held experts, row tiles): the top-1 cell's experts
+# of 2048 over rows of 2048 (blocks of 384 x 1024 x 1024 in the products
+# and both gradients), and Mellum2's of 896 over 2304
+@pytest.mark.parametrize("units,width,held,tiles", [
+    (2048, 2048, 8, 32),
+    (2304, 896, 16, 40),
+])
+def test_grouped_products_compile_for_the_chip(one_chip, monkeypatch, units,
+                                               width, held, tiles):
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    tm = pk.GROUPED_TILE_ROWS
+
+    def loss(x, gate, down, tile_group, used):
+        h = pk.grouped_matmul(x, gate, tile_group, used, tm)
+        y = pk.grouped_matmul(h, down, tile_group, used, tm)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    def aval(shape, kind="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(kind),
+                                    sharding=one_chip)
+    with jax.default_matmul_precision("default"):   # as the chip runs
+        compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+            aval((tiles * tm, units)), aval((held, width, units)),
+            aval((held, units, width)), aval((tiles,), "int32"),
+            aval((1,), "int32")).compile()
+    text = compiled.as_text()
+    for name in ("_grouped_matmul_kernel", "_grouped_matmul_dw_kernel"):
+        assert name in text
 
 
 @pytest.fixture(scope="module")
