@@ -39,8 +39,8 @@ import itertools
 
 __all__ = ["kernel_reports", "sweep_reports", "flash_reports",
            "flash_cell_reports", "grouped_matmul_reports",
-           "moe_mover_reports", "scale_bias_relu_reports", "layernorm_reports",
-           "softmax_reports", "ORIGIN"]
+           "moe_mover_reports", "head_ce_reports", "scale_bias_relu_reports",
+           "layernorm_reports", "softmax_reports", "ORIGIN"]
 
 ORIGIN = "mxnet_tpu/ops/pallas_kernels.py"
 
@@ -381,6 +381,43 @@ def moe_mover_reports(tokens=1024, top_k=8, units=2304, groups=4, tm=None,
     return reports
 
 
+# -- fused head (a projection with its softmax cross-entropy) --------------
+
+def head_ce_reports(n=16384, v=32784, u=2048, dtype="bfloat16"):
+    """The fused head's two kernels as ZAYA1's cell runs them, at the
+    blocks ``_head_blocks`` picks: 16384 rows of 2048 over a vocabulary
+    of 32784 — no whole number of blocks, so the last vocabulary block is
+    ragged and its columns past V are masked in the kernels."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+    dt = jnp.dtype(dtype)
+    family = "linear_cross_entropy(on a TPU)"
+    structural = [{"name": "tv", "detail": "block size"},
+                  {"name": "v", "detail": "the vocabulary's end, which "
+                                          "masks a ragged last block"},
+                  {"name": "nv", "detail": "grid extent"}]
+    reports = []
+    for kernel, name, ins, outs in (
+            ("fwd", "_head_ce_fwd_kernel", ("x", "w", "y"),
+             ("lse", "picked")),
+            ("bwd", "_head_ce_bwd_kernel", ("x", "w", "y", "lse", "g"),
+             ("dx", "d"))):
+        tm, tv = pk._head_blocks(n, v, u, dt, kernel)
+        plan = pk._HEAD_PLANS[kernel](n, v, u, tm, tv, dt)
+        padded = plan["grid"][1] * tv
+        reports.append(_report(
+            name, family, plan, ins, outs, python_constants=structural,
+            tail={"logical_elems": n * v, "padded_elems": n * padded,
+                  "masked": True,
+                  "how": "no padding in HBM: Pallas clips the last "
+                         "vocabulary block at the array's edge; in VMEM "
+                         "its columns past V are NEG_INF in the logits "
+                         "and its rows of w past V zero in the "
+                         "backward's product"}))
+    return reports
+
+
 # -- inference BatchNorm+ReLU epilogue -------------------------------------
 
 def scale_bias_relu_reports(n=16 * 7 * 7, c=2048, block=1024):
@@ -463,5 +500,5 @@ def kernel_reports():
     ``tools/lint.py --kern`` / ``--all`` judge."""
     return (sweep_reports() + flash_reports() + flash_cell_reports()
             + grouped_matmul_reports() + moe_mover_reports()
-            + scale_bias_relu_reports() + layernorm_reports()
-            + softmax_reports())
+            + head_ce_reports() + scale_bias_relu_reports()
+            + layernorm_reports() + softmax_reports())

@@ -1021,15 +1021,41 @@ def _causal_conv(data, weight, **attrs):
     return out.astype(data.dtype)
 
 
+def _head_kernel_eligible(rows, units, dtype):
+    """The fused head's kernel path: a TPU, and rows and units the
+    kernels tile whole (``pallas_kernels.head_ce_ok``)."""
+    from . import pallas_kernels as pk
+    return pk._on_tpu() and pk.head_ce_ok(rows, units, dtype)
+
+
+def _count_head(path):
+    """Advance ``mxnet_linear_ce_calls_total{path}`` (trace time, as the
+    Pallas wrappers count)."""
+    from .. import telemetry
+    if telemetry.enabled():
+        telemetry.counter(
+            "mxnet_linear_ce_calls_total",
+            "fused head (F.contrib.linear_cross_entropy) instantiations by "
+            "path: kernel (the Pallas pair, no float32 logits in HBM) or "
+            "xla (the XLA rules); trace time").labels(path=path).inc()
+
+
 @register("_contrib_linear_cross_entropy")
 def _linear_cross_entropy(data, weight, label, position_weight=None,
                           **attrs):
     """Projection fused with its softmax cross-entropy: per position
     ``-log softmax(data @ weight.T)[label]`` in float32, WITHOUT keeping
-    the ``(..., V)`` logits for the backward pass — they are recomputed
-    there (``jax.checkpoint``), so only one projection's float32 logits
-    live at a time however many heads a loss reads.  The product runs in
+    the ``(..., V)`` logits for the backward pass.  The product runs in
     ``weight``'s dtype with float32 accumulation.
+
+    Unweighted, on a TPU and at rows and units the kernels tile whole,
+    the Pallas pair ``pallas_kernels.head_cross_entropy`` takes it: the
+    forward keeps ``lse`` one float32 a row, the backward makes each
+    block of the logits again in VMEM, and no float32 ``(N, V)`` array
+    reaches HBM in either direction.  Elsewhere the logits are
+    recomputed in the backward pass (``jax.checkpoint``), so only one
+    projection's float32 logits live at a time however many heads a loss
+    reads.  ``mxnet_linear_ce_calls_total{path}`` counts the two.
 
     ``position_weight`` (the label's shape, float32): the terms come
     back times their position's weight, ``w_i CE_i``, and the weight
@@ -1055,6 +1081,16 @@ def _linear_cross_entropy(data, weight, label, position_weight=None,
         return lse - picked
 
     label = label.astype(jnp.int32)
+    units = data.shape[-1]
+    rows = math.prod(label.shape)
+    if position_weight is None \
+            and _head_kernel_eligible(rows, units, weight.dtype):
+        from . import pallas_kernels as pk
+        _count_head("kernel")
+        return pk.head_cross_entropy(
+            data.reshape(rows, units).astype(weight.dtype), weight,
+            label.reshape(rows)).reshape(label.shape)
+    _count_head("xla")
     if position_weight is None:
         return jax.checkpoint(lambda x, w, y: terms(logits_of(x, w), y))(
             data, weight, label)

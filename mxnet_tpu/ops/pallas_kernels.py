@@ -35,6 +35,14 @@ when-does-it-fuse table and the ``MXNET_PALLAS_*`` knobs):
   second buffer (the combine's transpose).  ``_moe_words_kernel`` lays a
   source out as 32-bit words a row first, since Mosaic slices a DMA
   along whole tiles only.
+- ``head_cross_entropy`` — the fused head, a projection with its
+  softmax cross-entropy: a (row block, vocabulary block) of the logits
+  lives in VMEM, never in HBM.  The forward keeps an online row max and
+  sum of exponentials and picks the label's logit; the backward makes
+  the block again from ``lse``, sums the softmax's gradient times the
+  vocabulary block into the rows' gradient and writes that gradient
+  once, in the weights' dtype, for the weights' product.  Taken by
+  ``F.contrib.linear_cross_entropy`` unweighted on the TPU.
 - ``fused_scale_bias_relu`` — the inference BatchNorm + ReLU epilogue as
   one VMEM-resident pass (reference: the BN+Activation fusion MKL-DNN
   does on CPU, nn/mkldnn/mkldnn_base-inl.h).  Call sites: the
@@ -1315,6 +1323,249 @@ def _grouped_matmul_bwd_rule(tm, res, dy):
 
 
 grouped_matmul.defvjp(_grouped_matmul_fwd_rule, _grouped_matmul_bwd_rule)
+
+
+# ---------------------------------------------------------------------------
+# Fused head (a projection with its softmax cross-entropy)
+# ---------------------------------------------------------------------------
+# ``CE_r = lse_r - z_r[y_r]`` of the logits ``z = x @ w.T`` (N rows of U
+# over a vocabulary of V) with no (N, V) float32 array in HBM: a grid step
+# owns a (tm, tv) block of the logits in VMEM, row block i by vocabulary
+# block j, j the reduction axis; the rows of ``x`` stay resident over j.
+# The forward keeps an online row max and sum of exponentials in float32
+# and picks the label's logit by comparing a column's index with the
+# label.  The backward makes the block again from ``lse``, forms ``d = g p
+# - g [col = y]`` (the softmax's gradient times the term's cotangent, ``g``
+# inside the rounding), rounds it to the weights' dtype, sums ``d w_j``
+# into the rows' gradient in a float32 scratch and writes ``d`` once, for
+# the weights' gradient ``dW = d^T x`` (one XLA product).  A vocabulary of
+# no whole number of blocks (32784, 16160, 18992 in the benchmark) leaves
+# the last block ragged: what Pallas reads past an edge is undefined, so
+# its columns past V are NEG_INF in the logits and its rows of ``w`` past V
+# zero in the backward's product.  Row statistics (the label, ``lse``,
+# ``g``) are lane-broadcast ``(N, 128)``, as the flash kernels hold them.
+
+_HEAD_MAX_ROWS = 1024
+_HEAD_COLS = (512, 256, 128)
+_HEAD_SEMANTICS = ("parallel", "arbitrary")
+
+
+def head_ce_ok(n, u, dtype):
+    """Whether the head kernels tile ``n`` rows of ``u`` in ``dtype``:
+    whole sublane tiles of rows, whole lane tiles of units."""
+    return n % _sublane(dtype) == 0 and u % LANES == 0
+
+
+def _head_ragged(v, tv, values, cols, fill):
+    """``values`` with the entries past the vocabulary's end (``cols``:
+    their vocabulary index) at ``fill``; as they are where ``tv`` divides
+    ``v`` (no block is ragged)."""
+    if v % tv == 0:
+        return values
+    return jnp.where(cols < v, values, fill)
+
+
+def _head_logits(x_ref, w_ref, tv, v):
+    """float32 logits of row block i by vocabulary block j, the columns
+    past V at NEG_INF, and the columns' vocabulary indices."""
+    z = jax.lax.dot_general(x_ref[:], w_ref[:], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    cols = pl.program_id(1) * tv \
+        + jax.lax.broadcasted_iota(jnp.int32, z.shape, 1)
+    return _head_ragged(v, tv, z, cols, NEG_INF), cols
+
+
+def _head_ce_fwd_kernel(x_ref, w_ref, y_ref, lse_ref, picked_ref, m_ref,
+                        l_ref, *, tv, v, nv):
+    """Grid (row blocks, vocabulary blocks): ``lse`` and the label's
+    logit of a row block, accumulated over the sequential j."""
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        picked_ref[:] = jnp.zeros_like(picked_ref)
+
+    z, cols = _head_logits(x_ref, w_ref, tv, v)
+    m_prev = m_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(z, axis=1, keepdims=True))
+    l_new = l_ref[:, :1] * jnp.exp(m_prev - m_new) \
+        + jnp.sum(jnp.exp(z - m_new), axis=1, keepdims=True)
+    hit = jnp.sum(jnp.where(cols == y_ref[:, :1], z, 0.0), axis=1,
+                  keepdims=True)
+    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+    picked_ref[:] += jnp.broadcast_to(hit, picked_ref.shape)
+
+    @pl.when(j == nv - 1)
+    def _final():
+        lse_ref[:] = m_ref[:] + jnp.log(l_ref[:])
+
+
+def _head_ce_bwd_kernel(x_ref, w_ref, y_ref, lse_ref, g_ref, dx_ref, d_ref,
+                        acc_ref, *, tv, v, nv):
+    """Grid (row blocks, vocabulary blocks): the block's ``d`` written
+    in the weights' dtype, ``d w_j`` summed into the rows' gradient."""
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    z, cols = _head_logits(x_ref, w_ref, tv, v)
+    g = g_ref[:, :1]
+    # today's XLA rule's order: softmax times g, less g at the label
+    d = (g * jnp.exp(z - lse_ref[:, :1])
+         - jnp.where(cols == y_ref[:, :1], g, 0.0)).astype(d_ref.dtype)
+    d_ref[:] = d
+
+    def past_end(w):
+        rows = j * tv + jax.lax.broadcasted_iota(jnp.int32, w.shape, 0)
+        return _head_ragged(v, tv, w, rows, jnp.zeros((), w.dtype))
+    w = w_ref[:]
+    if v % tv:
+        # only the last block reaches past V: the select is a pass over
+        # the whole (tv, U) block, not paid on the others
+        w = jax.lax.cond(j == nv - 1, past_end, lambda w: w, w)
+    acc_ref[:] += jax.lax.dot_general(d, w, (((1,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+
+    @pl.when(j == nv - 1)
+    def _final():
+        dx_ref[:] = acc_ref[:].astype(dx_ref.dtype)
+
+
+def _head_specs(u, tm, tv):
+    """``(x, w, row statistic)`` blocks on grid (i, j)."""
+    return (pl.BlockSpec((tm, u), lambda i, j: (i, 0)),
+            pl.BlockSpec((tv, u), lambda i, j: (j, 0)),
+            pl.BlockSpec((tm, LANES), lambda i, j: (i, 0)))
+
+
+def head_ce_fwd_plan(n, v, u, tm, tv, dtype=jnp.bfloat16):
+    """Plan of the fused head's forward kernel (x, w, y -> lse, the
+    label's logit)."""
+    xspec, wspec, rowspec = _head_specs(u, tm, tv)
+    return {
+        "grid": (n // tm, pl.cdiv(v, tv)),
+        "in_specs": [xspec, wspec, rowspec],
+        "in_shapes": [(n, u), (v, u), (n, LANES)],
+        "out_specs": [rowspec, rowspec],
+        "out_shapes": [(n, LANES), (n, LANES)],
+        "scratch": [(tm, LANES), (tm, LANES)],
+        "dtypes": [dtype, dtype, jnp.int32, jnp.float32, jnp.float32],
+        # the logits and the exponential's argument
+        "tiles": [((tm, tv), "float32")] * 2,
+    }
+
+
+def head_ce_bwd_plan(n, v, u, tm, tv, dtype=jnp.bfloat16):
+    """Plan of the fused head's backward kernel (x, w, y, lse, g -> dx,
+    d); ``d`` (N, V) in the weights' dtype, one block a grid step."""
+    xspec, wspec, rowspec = _head_specs(u, tm, tv)
+    return {
+        "grid": (n // tm, pl.cdiv(v, tv)),
+        "in_specs": [xspec, wspec, rowspec, rowspec, rowspec],
+        "in_shapes": [(n, u), (v, u)] + [(n, LANES)] * 3,
+        "out_specs": [xspec, pl.BlockSpec((tm, tv), lambda i, j: (i, j))],
+        "out_shapes": [(n, u), (n, v)],
+        "scratch": [(tm, u)],
+        "dtypes": [dtype, dtype, jnp.int32, jnp.float32, jnp.float32,
+                   dtype, dtype],
+        # the logits, the exponential, ``d`` in the operand dtype
+        "tiles": [((tm, tv), "float32")] * 2 + [((tm, tv), dtype)],
+    }
+
+
+_HEAD_PLANS = {"fwd": head_ce_fwd_plan, "bwd": head_ce_bwd_plan}
+
+
+def _head_blocks(n, v, u, dtype, kernel):
+    """(tm, tv) of one head kernel from the shape: the largest block of
+    the logits whose plan fits the scoped-VMEM budget, the more rows the
+    better among equal areas (a vocabulary block is fetched once a row
+    block: rows are what a fetched byte of ``w`` is multiplied by).  Rows
+    divide N in whole sublane tiles, up to 1024; columns are whole lane
+    tiles, or the vocabulary whole where it is narrower."""
+    from .. import config as _config
+    budget = int(_config.get("MXNET_KERN_VMEM_BYTES"))
+    sub = _sublane(dtype)
+    rows = [b for b in range(min(n, _HEAD_MAX_ROWS) // sub * sub, 0, -sub)
+            if n % b == 0] or [n]
+    cols = [c for c in _HEAD_COLS if c <= v] or [v]
+    fits = [(tm * tv, tm, tv) for tm in rows for tv in cols
+            if _flash_vmem_bytes(_HEAD_PLANS[kernel](
+                n, v, u, tm, tv, dtype)) < budget]
+    return max(fits)[1:] if fits else (rows[-1], cols[-1])
+
+
+def _head_call(kernel, n, v, u, dtype, *operands):
+    """One head ``pallas_call`` at the blocks chosen for it, named after
+    its kernel."""
+    tm, tv = _head_blocks(n, v, u, dtype, kernel)
+    plan = _HEAD_PLANS[kernel](n, v, u, tm, tv, dtype)
+    body = _head_ce_fwd_kernel if kernel == "fwd" else _head_ce_bwd_kernel
+    n_in = len(plan["in_specs"])
+    return pl.pallas_call(
+        functools.partial(body, tv=tv, v=v, nv=plan["grid"][1]),
+        grid=plan["grid"],
+        in_specs=plan["in_specs"],
+        out_specs=plan["out_specs"],
+        out_shape=[jax.ShapeDtypeStruct(s, t) for s, t in zip(
+            plan["out_shapes"], plan["dtypes"][n_in:])],
+        scratch_shapes=[pltpu.VMEM(s, jnp.float32) for s in plan["scratch"]],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_HEAD_SEMANTICS),
+        name=body.__name__,
+        interpret=_interpret(),
+    )(*operands)
+
+
+def _lanes(a):
+    return jnp.broadcast_to(a[:, None], (a.shape[0], LANES))
+
+
+def _head_ce_fwd(x, w, y):
+    """``(terms, lse)``, one float32 each a row."""
+    _count("head_ce_fwd")
+    (n, u), v = x.shape, w.shape[0]
+    lse, picked = _head_call("fwd", n, v, u, w.dtype, x, w, _lanes(y))
+    return lse[:, 0] - picked[:, 0], lse[:, 0]
+
+
+@jax.custom_vjp
+def head_cross_entropy(x, w, y):
+    """``-log softmax(x @ w.T)[y]`` a row, float32 ``(N,)``, of ``x (N,
+    U)`` and ``w (V, U)`` in one dtype, ``y (N,)`` int32 in ``[0, V)``;
+    the products in that dtype with float32 accumulation.  Neither
+    direction writes the float32 ``(N, V)`` logits: the backward keeps
+    ``(x, w, y, lse)`` and makes each block of them again in VMEM.  The
+    rows' gradient comes out in the weights' dtype, as the gradient of an
+    XLA product in that dtype does."""
+    return _head_ce_fwd(x, w, y)[0]
+
+
+def _head_ce_fwd_rule(x, w, y):
+    terms, lse = _head_ce_fwd(x, w, y)
+    return terms, (x, w, y, lse)
+
+
+def _head_ce_bwd_rule(res, g):
+    _count("head_ce_bwd")
+    x, w, y, lse = res
+    (n, u), v = x.shape, w.shape[0]
+    dx, d = _head_call("bwd", n, v, u, w.dtype, x, w, _lanes(y), _lanes(lse),
+                       _lanes(g.astype(jnp.float32)))
+    dw = jnp.einsum("nv,nu->vu", d, x, preferred_element_type=jnp.float32)
+    # the rows' gradient leaves with the weights': a loss of several heads
+    # (Ouro's four exits) would otherwise run every head's kernel first
+    # and hold all their ``d`` at once (201 MB each there)
+    dx, dw = jax.lax.optimization_barrier((dx, dw.astype(w.dtype)))
+    return dx, dw, None
+
+
+head_cross_entropy.defvjp(_head_ce_fwd_rule, _head_ce_bwd_rule)
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,6 @@
-"""The flash kernels, and the expert layer's row movers, compiled by the
-TPU's own compiler for a DESCRIBED v5e (no chip attached), the flash
-kernels at the blocks ``_flash_blocks`` picks: what
+"""The flash kernels, the expert layer's row movers and the fused head's
+kernels, compiled by the TPU's own compiler for a DESCRIBED v5e (no chip
+attached), the flash kernels at the blocks ``_flash_blocks`` picks: what
 interpret mode cannot show — a tile the compiler refuses, a block that
 overruns scoped VMEM.  Nothing runs and no time comes out of it.
 
@@ -9,6 +9,7 @@ process may hold the TPU's library, and every xdist worker imports this
 file (``on-chip-measurement`` guide, section 2).  Keep such tests in
 this one file.
 """
+import math
 import re
 
 import jax
@@ -236,3 +237,60 @@ def test_the_gauge_counts_the_routing_passes_of_a_registered_step(
         assert sum(1 for line in text.splitlines()
                    if " sort(" in line and routed.search(line)) \
             == 3 * passes + 4 * 2
+
+
+def _f32_arrays_of(text, elements, but):
+    """Lines of an optimized module holding a float32 array of exactly
+    ``elements`` elements, whatever its shape (the chip's compiler may
+    fold an (N, V) array into four dimensions), other than of the shape
+    ``but``."""
+    shapes = re.compile(r"f32\[([0-9,]+)\]")
+    return [line for line in text.splitlines()
+            if any(math.prod(dims) == elements and dims != but
+                   for dims in (tuple(int(d) for d in found.split(","))
+                                for found in shapes.findall(line)))]
+
+
+# (rows, vocabulary, units, weighted): the fused head as the cells take it
+# — ZAYA1's 16384 x 32784 x 2048, Mellum2's 8192 x 24576 x 2304, JoyAI's
+# 8192 x 16160 x 2048 (ZAYA1's and JoyAI's vocabularies no whole number
+# of 512-column blocks) and an Ouro exit's 2048 x 49152 x 2048 — and
+# SDAR's weighted rule at 4096 x 18992 x 2048, which keeps its own module
+@pytest.mark.parametrize("n,v,u,weighted", [
+    (16384, 32784, 2048, False),
+    (8192, 24576, 2304, False),
+    (8192, 16160, 2048, False),
+    (2048, 49152, 2048, False),
+    (4096, 18992, 2048, True),
+])
+def test_the_head_kernels_compile_for_the_chip(one_chip, monkeypatch, n, v,
+                                               u, weighted):
+    """The unweighted head compiles to the two kernels and one XLA
+    product, and its optimized module holds no float32 array of N x V
+    elements: the logits stay in VMEM both ways.  The weighted rule takes
+    no kernel and keeps its float32 logits."""
+    from mxnet_tpu.ops import contrib
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+
+    def loss(x, w, y, g):
+        ce = contrib._linear_cross_entropy(x, w, y, g if weighted else None)
+        return jnp.sum(ce * g)
+
+    def aval(shape, kind="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(kind),
+                                    sharding=one_chip)
+    with jax.default_matmul_precision("default"):   # as the chip runs
+        compiled = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+            aval((n, u)), aval((v, u)), aval((n,), "int32"),
+            aval((n,), "float32")).compile()
+    text = compiled.as_text()
+    kernels = [name for name in ("_head_ce_fwd_kernel", "_head_ce_bwd_kernel")
+               if name in text]
+    # the weights' gradient is float32 (V, U) inside its product's fusion,
+    # of N x V elements where U is N (an Ouro exit)
+    logits = _f32_arrays_of(text, n * v, but=(v, u))
+    if weighted:
+        assert kernels == [] and logits
+    else:
+        assert len(kernels) == 2
+        assert logits == [], logits[:3]

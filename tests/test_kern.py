@@ -396,3 +396,27 @@ def test_a_run_of_revisits_may_not_come_back_to_a_block():
     assert any("revisited" in p for p in coverage_problems(back, [5]))
     gap = dict(op, index=[[0, 0, 0]] * 3 + [[2, 0, 0]] * 2)
     assert any("never written" in p for p in coverage_problems(gap, [5]))
+
+
+def test_the_head_kernels_are_in_the_catalog_with_a_ragged_vocabulary(
+        monkeypatch):
+    """The fused head's pair at ZAYA1's shape, with jit poisoned: every
+    row block's statistics and rows' gradient written once over its run
+    of vocabulary blocks, ``d`` once a grid step, the last vocabulary
+    block ragged (32784 columns in blocks of 512) under a masking
+    contract, an instance under the VMEM budget."""
+    from mxnet_tpu.analysis.kern import head_ce_reports, kernel_reports
+    _poison_jit(monkeypatch)
+    reports = head_ce_reports()
+    assert [r["name"] for r in reports] == ["_head_ce_fwd_kernel",
+                                            "_head_ce_bwd_kernel"]
+    assert {r["name"] for r in kernel_reports()} >= {
+        "_head_ce_fwd_kernel", "_head_ce_bwd_kernel"}
+    assert run_kern_checkers(reports) == []
+    for r in reports:
+        assert r["grid"][1] == -(-32784 // 512)
+        assert r["tail"]["padded_elems"] > r["tail"]["logical_elems"]
+        assert r["vmem"]["bytes_per_instance"] <= r["vmem"]["budget"]
+    d = reports[1]["operands"][-1]
+    assert d["name"] == "d" and d["block"] == [256, 512]
+    assert d["index"][:2] == [[0, 0], [0, 1]]
