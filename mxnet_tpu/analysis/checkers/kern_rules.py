@@ -12,15 +12,15 @@ acceptance path for a deliberate finding); findings anchor to
 
 | rule | catches |
 |---|---|
-| ``kern-grid-coverage``  | output blocks the index maps never write, write unevenly (overlap), or write out of range — plus a padded tail with no masking contract (injectivity + surjectivity of grid -> output blocks, modulo declared sequential revisits) |
+| ``kern-grid-coverage``  | output blocks the index maps never write, write unevenly (overlap), or write out of range — plus a padded tail with no masking contract (injectivity + surjectivity of grid -> output blocks, modulo declared sequential revisits) — and an output block declared the sum over a group of heads whose steps leave a member of the group unread, or read a head outside it |
 | ``kern-vmem-budget``    | per-program-instance VMEM residency (block shapes x dtypes + scratch) over ``MXNET_KERN_VMEM_BYTES`` |
 | ``kern-retrace-hazard`` | schedule-varying hyperparameters (lr/momentum/betas/wd/clip) baked into the kernel as Python-level constants instead of riding the scalar-prefetch operand — the lr-schedule retrace class made structural |
 | ``kern-shard-safety``   | a shard_map-candidate kernel whose index maps are NOT provably block-local along the sharded axis (cross-block reads/writes on that dim) — the verdict ``ops/pallas_kernels.py mesh_sweep_safe`` consumes |
 
 The helpers here (:func:`shard_safety`, :func:`vmem_bytes`,
-:func:`coverage_problems`) are pure functions of a report dict, shared
-with the catalog (``analysis/kern/catalog.py``) and with
-``mesh_sweep_safe``'s cached verdict — one implementation of every
+:func:`coverage_problems`, :func:`group_problems`) are pure functions
+of a report dict, shared with the catalog (``analysis/kern/catalog.py``)
+and with ``mesh_sweep_safe``'s cached verdict — one implementation of every
 judgement.
 """
 from __future__ import annotations
@@ -33,7 +33,7 @@ __all__ = ["KernGridCoverageChecker", "KernVmemBudgetChecker",
            "KernRetraceHazardChecker", "KernShardSafetyChecker",
            "kern_checkers", "run_kern_checkers", "KERN_RULES",
            "shard_safety", "vmem_bytes", "coverage_problems",
-           "SCHEDULE_HYPERPARAMS"]
+           "group_problems", "SCHEDULE_HYPERPARAMS"]
 
 KERN_RULES = frozenset((
     "kern-grid-coverage", "kern-vmem-budget", "kern-retrace-hazard",
@@ -193,6 +193,39 @@ def coverage_problems(op, grid):
     return problems
 
 
+def group_problems(report, op):
+    """Problems of an output operand that declares ``sums: {"of":
+    operand, "heads": g}`` — each of its blocks is the sum over the
+    ``g`` heads of a group (grouped-query attention's dK / dV: the query
+    heads that share a key/value head).  The grid steps that write block
+    ``t`` must read operand ``of`` at every head ``t[0] g .. t[0] g + g
+    - 1`` (its first index) and at no other: a plan that drops a member
+    of the group writes every block as often as one that keeps it, which
+    the coverage verdict cannot tell."""
+    sums = op.get("sums")
+    if not sums:
+        return []
+    heads = int(sums["heads"])
+    source = next((o for o in report.get("operands", ())
+                   if o["name"] == sums["of"]), None)
+    if source is None or not source.get("index"):
+        return ["sums over the heads of %r, which the report does not "
+                "index" % (sums["of"],)]
+    read = {}
+    for t, src in zip(op["index"], source["index"]):
+        read.setdefault(tuple(t), set()).add(int(src[0]))
+    for t in sorted(read):
+        group = set(range(t[0] * heads, (t[0] + 1) * heads))
+        if read[t] != group:
+            missing, stray = sorted(group - read[t]), sorted(read[t] - group)
+            return ["block %s sums the %d heads %d..%d of %s, but its "
+                    "steps %s" % (
+                        t, heads, min(group), max(group), sums["of"],
+                        "read head %s of another group" % stray[0] if stray
+                        else "never read head %s" % missing[0])]
+    return []
+
+
 def shard_safety(report):
     """The ``kern-shard-safety`` verdict as pure data.
 
@@ -281,7 +314,8 @@ class KernGridCoverageChecker(_KernChecker):
         for op in report.get("operands", ()):
             if op.get("role") != "out":
                 continue
-            for problem in coverage_problems(op, grid):
+            for problem in coverage_problems(op, grid) \
+                    + group_problems(report, op):
                 out.append(self._finding(
                     report,
                     "output %s: %s — the grid must write every output "

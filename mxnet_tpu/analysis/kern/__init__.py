@@ -20,16 +20,17 @@ per-array ``tree_map`` path.  Run it with ``tools/lint.py --kern``
 """
 from __future__ import annotations
 
-from .catalog import (flash_cell_reports, flash_reports,
-                      grouped_matmul_reports, head_ce_reports, kernel_reports,
-                      layernorm_reports, moe_mover_reports,
+from .catalog import (flash_cell_reports, flash_group_reports,
+                      flash_reports, grouped_matmul_reports, head_ce_reports,
+                      kernel_reports, layernorm_reports, moe_mover_reports,
                       scale_bias_relu_reports,
                       softmax_reports, sweep_reports)
 
 __all__ = ["kernel_reports", "sweep_reports", "flash_reports",
-           "flash_cell_reports", "grouped_matmul_reports",
-           "moe_mover_reports", "head_ce_reports", "scale_bias_relu_reports",
-           "layernorm_reports", "softmax_reports", "sweep_shard_verdict"]
+           "flash_cell_reports", "flash_group_reports",
+           "grouped_matmul_reports", "moe_mover_reports", "head_ce_reports",
+           "scale_bias_relu_reports", "layernorm_reports", "softmax_reports",
+           "sweep_shard_verdict"]
 
 
 def sweep_shard_verdict():

@@ -38,7 +38,8 @@ from __future__ import annotations
 import itertools
 
 __all__ = ["kernel_reports", "sweep_reports", "flash_reports",
-           "flash_cell_reports", "grouped_matmul_reports",
+           "flash_cell_reports", "flash_group_reports",
+           "grouped_matmul_reports",
            "moe_mover_reports", "head_ce_reports", "scale_bias_relu_reports",
            "layernorm_reports", "softmax_reports", "ORIGIN"]
 
@@ -81,7 +82,7 @@ def _operand(name, role, spec, shape, grid, n_prefetch,
 
 def _report(name, family, plan, in_names, out_names, *, hyper=None,
             python_constants=(), shard=None, tail=None, prefetch=None,
-            revisit=None):
+            revisit=None, sums=None):
     from mxnet_tpu import config as _config
 
     from ..checkers.kern_rules import shard_safety, vmem_bytes
@@ -111,6 +112,10 @@ def _report(name, family, plan, in_names, out_names, *, hyper=None,
             # not "once per unused grid step" (kern_rules
             # coverage_problems)
             operands[-1]["revisit"] = revisit
+        if sums:
+            # each block the sum over a group of heads of another
+            # operand (kern_rules group_problems)
+            operands[-1]["sums"] = dict(sums)
     report = {
         "name": name, "family": family, "origin": ORIGIN,
         "grid": grid,
@@ -201,12 +206,18 @@ def sweep_reports(n=None):
 
 def flash_reports(bh=8, tq=512, tk=512, d=64, bq=128, bk=128,
                   causal=False, dtype="float32", dv=None,
-                  block_diffusion=None):
+                  block_diffusion=None, window=None, group=1):
     """The three flash kernels at one shape.  ``bq`` / ``bk`` None:
     the blocks ``_flash_blocks`` picks for each kernel from the shape,
     as a call without explicit blocks runs them.  ``dv``: the values'
     head size where it is not the keys'.  ``block_diffusion``: the block
-    length of that mask, under which a grid's minor axis counts visits."""
+    length of that mask, under which a grid's minor axis counts visits.
+    ``window`` (causal): a query's last keys.  ``group``: query heads a
+    key/value head (``bh`` counts the query heads); dK / dV's blocks
+    are then each the sum over the query heads of their group, visited
+    along the minor axis — revisited ``group`` x the Q visits, which the
+    coverage verdict's uniform revisit count admits, and declared as
+    sums so that a plan that leaves a member unread is refused."""
     from mxnet_tpu.ops import pallas_kernels as pk
     structural = [
         {"name": "scale", "detail": "architecture constant (1/sqrt(d) "
@@ -218,6 +229,7 @@ def flash_reports(bh=8, tq=512, tk=512, d=64, bq=128, bk=128,
         {"name": "bk", "detail": "block size"},
     ]
     elems = bh * tq * d
+    sums = {"of": "q", "heads": group}
     tail = {"logical_elems": elems, "padded_elems": elems,
             "masked": True,
             "how": "no padding: the picked (or halved explicit) "
@@ -239,12 +251,12 @@ def flash_reports(bh=8, tq=512, tk=512, d=64, bq=128, bk=128,
         reports.append(_report(
             name, family,
             pk._FLASH_PLANS[kernel](bh, tq, tk, d, bq or pq, bk or pk_,
-                                    causal, dtype, None, dv,
-                                    block_diffusion),
+                                    causal, dtype, window, dv,
+                                    block_diffusion, group),
             ins, outs,
             python_constants=structural + [
                 {"name": extent, "detail": "grid extent"}],
-            tail=tail))
+            tail=tail, sums=sums if kernel == "dkv" else None))
     return reports
 
 
@@ -253,8 +265,10 @@ def flash_cell_reports():
     bf16 with the blocks picked from the shape — OPT-1.3B's 2 x 32 heads
     of 64 and Ouro-2.6B's 1 x 16 heads of 128 at T 2048, JoyAI-LLM-
     Flash's 32 heads of latent attention at T 8192, keys of 192 over
-    values of 128 — and SDAR's 32 heads of 128 over the 8192 rows
-    ``[noised ; clean]`` under the block-diffusion mask, blocks of 4."""
+    values of 128 — and the grouped ones: SDAR's 32 query heads of 128
+    over 4 key/value heads and the 8192 rows ``[noised ; clean]`` under
+    the block-diffusion mask, blocks of 4; Mellum2's 32 over 4 at T 8192,
+    causal and under a 1024-key window; ZAYA1's 8 over 2 at T 16384."""
     return (flash_reports(64, 2048, 2048, 64, None, None, True,
                           "bfloat16")
             + flash_reports(16, 2048, 2048, 128, None, None, True,
@@ -262,7 +276,25 @@ def flash_cell_reports():
             + flash_reports(32, 8192, 8192, 192, None, None, True,
                             "bfloat16", dv=128)
             + flash_reports(32, 8192, 8192, 128, None, None, False,
-                            "bfloat16", block_diffusion=4))
+                            "bfloat16", block_diffusion=4, group=8)
+            + flash_reports(32, 8192, 8192, 128, None, None, True,
+                            "bfloat16", group=8)
+            + flash_reports(32, 8192, 8192, 128, None, None, True,
+                            "bfloat16", window=1024, group=8)
+            + flash_reports(8, 16384, 16384, 128, None, None, True,
+                            "bfloat16", group=4))
+
+
+def flash_group_reports():
+    """The flash kernels with 4 and 8 query heads a key/value head
+    under each mask — causal, a window, block diffusion — at a small
+    shape: two key/value heads, 512 rows in blocks of 128, so dK/dV's
+    minor axis visits 4 Q blocks of each member of a group."""
+    masks = (dict(causal=True), dict(causal=True, window=200),
+             dict(block_diffusion=4))
+    return [r for group in (4, 8) for mask in masks
+            for r in flash_reports(2 * group, 512, 512, 64, 128, 128,
+                                   group=group, **mask)]
 
 
 # -- grouped matrix product (sparse experts) --------------------------------
@@ -499,6 +531,7 @@ def kernel_reports():
     """Every in-tree kernel family's reports — the catalog
     ``tools/lint.py --kern`` / ``--all`` judge."""
     return (sweep_reports() + flash_reports() + flash_cell_reports()
+            + flash_group_reports()
             + grouped_matmul_reports() + moe_mover_reports()
             + head_ce_reports() + scale_bias_relu_reports()
             + layernorm_reports() + softmax_reports())

@@ -553,17 +553,21 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                          bq, bk, nq, window=None, blocks=None):
+                          bq, bk, nq, window=None, blocks=None, group=1):
     """Grid (BH, nK, nQ) — under the block-diffusion mask ``blocks`` the
-    minor axis counts a K tile's ``nq`` visits.  The block is held
-    transposed, (bk, bq):
+    minor axis counts a K tile's ``nq`` visits; with ``group`` query
+    heads a key/value head, grid (BH / group, nK, group nQ): the minor
+    axis visits each query head of the group in turn, ``nq`` visits a
+    head, all summed into the one float32 accumulator.  The block is
+    held transposed, (bk, bq):
     ``lse`` and ``delta`` arrive as rows (1, bq), so the scores, ``p``
     and ``ds`` come out of plain products in the orientation the dV and
     dK products consume — no transpose of a score tile."""
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    visit = pl.program_id(2)
+    qi = visit if group == 1 else jax.lax.rem(visit, nq)
 
-    @pl.when(qi == 0)
+    @pl.when(visit == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -591,7 +595,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         _flash_block_cases(causal, qi, ki, bq, bk, _update, k_major=True,
                            window=window)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(visit == group * nq - 1)
     def _final():
         dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
@@ -620,9 +624,10 @@ def flash_seq_ok(t, dtype, pref=128):
 
 
 def _export_flash_gauges(kernel, bq, bk, grid, window=None,
-                         block_diffusion=None):
+                         block_diffusion=None, group=1):
     """What the newest instantiation of ``kernel`` was tiled into (trace
-    time, beside ``_count``); a windowed instantiation is kept apart
+    time, beside ``_count``), and how many query heads it gave each
+    key/value head (``group``); a windowed instantiation is kept apart
     from a full one by a ``window`` label of its own, one under the
     block-diffusion mask by ``mask`` — and that one says how many tiles a
     (batch, head) runs and how many of them hold a visible pair."""
@@ -650,6 +655,12 @@ def _export_flash_gauges(kernel, bq, bk, grid, window=None,
         "(q / k)")
     rows.labels(kernel=kernel, side="q", **own).set(bq)
     rows.labels(kernel=kernel, side="k", **own).set(bk)
+    telemetry.gauge(
+        "mxnet_flash_kv_group",
+        "query heads that share one key/value head in the newest "
+        "flash-attention kernel instantiation, by kernel: 1 where every "
+        "query head has its own (MHA); the kernels index the shared head, "
+        "nothing is repeated").labels(kernel=kernel, **own).set(group)
     telemetry.gauge(
         "mxnet_flash_grid_steps",
         "grid steps of the newest flash-attention kernel instantiation "
@@ -727,23 +738,28 @@ def _flash_tiles(kernel, bq, bk, dtype):
 _FLASH_SEMANTICS = ("parallel", "parallel", "arbitrary")
 
 
-def _flash_q_major_specs(d, dv, bq, bk, causal, window=None, half=None):
+def _flash_q_major_specs(d, dv, bq, bk, causal, window=None, half=None,
+                         group=1):
     """Specs of the kernels on grid (BH, nQ, nK): the Q side follows
     i; the K side follows j, held at the last block row i needs under
     the causal mask — and at the first one under a window — so the
     masked steps fetch nothing.  Under the block-diffusion mask (``half``
     tiles a half) j counts row i's visits and the K side is the tile
-    ``_bd_q_visit`` names.  ``(q, k, lse, v, o)``: the values and
-    the output are ``dv`` wide."""
+    ``_bd_q_visit`` names.  The K side names the key/value head of
+    query head b: the heads fold batch-major, ``B x Hq`` rows over ``B x
+    Hkv`` with ``Hq = group Hkv``, so row b reads row ``b // group``.
+    ``(q, k, lse, v, o)``: the values and the output are ``dv`` wide."""
+    head = (lambda b: b) if group == 1 else (lambda b: _idiv(b, group))
+
     def kmap(b, i, j):
         if half is not None:
-            return (b, _bd_q_visit(i, j, half)[0], 0)
+            return (head(b), _bd_q_visit(i, j, half)[0], 0)
         if not causal:
-            return (b, j, 0)
+            return (head(b), j, 0)
         j = _imin(j, _causal_last_k(i, bq, bk))
         if window is not None:
             j = _imax(j, _window_first_k(i, bq, bk, window))
-        return (b, j, 0)
+        return (head(b), j, 0)
     return (pl.BlockSpec((None, bq, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((None, bk, d), kmap),
             pl.BlockSpec((None, bq, LANES), lambda b, i, j: (b, i, 0)),
@@ -772,19 +788,21 @@ def _bd_half(tq, tk, bq, bk, causal, window, length):
 
 def flash_fwd_plan(bh, tq, tk, d, bq, bk, causal=False,
                    dtype=jnp.float32, window=None, dv=None,
-                   block_diffusion=None):
+                   block_diffusion=None, group=1):
     """Plan of the flash-attention forward kernel (q, k, v -> o, lse);
     ``dv``: the head size of ``v`` and ``o`` where it is not ``d``;
     ``block_diffusion``: the block length of that mask, under which the
-    grid's minor axis is a Q tile's visits."""
+    grid's minor axis is a Q tile's visits; ``group``: query heads a
+    key/value head (``bh`` counts the query heads)."""
     dv = d if dv is None else dv
+    bkv = bh // group
     half = _bd_half(tq, tk, bq, bk, causal, window, block_diffusion)
     qspec, kspec, lmspec, vspec, ospec = _flash_q_major_specs(
-        d, dv, bq, bk, causal, window, half)
+        d, dv, bq, bk, causal, window, half, group)
     return {
         "grid": (bh, tq // bq, tk // bk if half is None else half + 1),
         "in_specs": [qspec, kspec, vspec],
-        "in_shapes": [(bh, tq, d), (bh, tk, d), (bh, tk, dv)],
+        "in_shapes": [(bh, tq, d), (bkv, tk, d), (bkv, tk, dv)],
         "out_specs": [ospec, lmspec],
         "out_shapes": [(bh, tq, dv), (bh, tq, LANES)],
         "scratch": [(bq, dv), (bq, LANES), (bq, LANES)],
@@ -795,17 +813,18 @@ def flash_fwd_plan(bh, tq, tk, d, bq, bk, causal=False,
 
 def flash_bwd_dq_plan(bh, tq, tk, d, bq, bk, causal=False,
                       dtype=jnp.float32, window=None, dv=None,
-                      block_diffusion=None):
+                      block_diffusion=None, group=1):
     """Plan of the dq backward kernel
     (q, k, v, do, lse, delta -> dq); the forward's grid."""
     dv = d if dv is None else dv
+    bkv = bh // group
     half = _bd_half(tq, tk, bq, bk, causal, window, block_diffusion)
     qspec, kspec, lmspec, vspec, ospec = _flash_q_major_specs(
-        d, dv, bq, bk, causal, window, half)
+        d, dv, bq, bk, causal, window, half, group)
     return {
         "grid": (bh, tq // bq, tk // bk if half is None else half + 1),
         "in_specs": [qspec, kspec, vspec, ospec, lmspec, lmspec],
-        "in_shapes": [(bh, tq, d), (bh, tk, d), (bh, tk, dv),
+        "in_shapes": [(bh, tq, d), (bkv, tk, d), (bkv, tk, dv),
                       (bh, tq, dv), (bh, tq, LANES), (bh, tq, LANES)],
         "out_specs": [qspec],
         "out_shapes": [(bh, tq, d)],
@@ -817,15 +836,22 @@ def flash_bwd_dq_plan(bh, tq, tk, d, bq, bk, causal=False,
 
 def flash_bwd_dkv_plan(bh, tq, tk, d, bq, bk, causal=False,
                        dtype=jnp.float32, window=None, dv=None,
-                       block_diffusion=None):
+                       block_diffusion=None, group=1):
     """Plan of the dk/dv backward kernel — grid (BH, nK, nQ), so the
     q-side specs transpose their two minor grid coordinates, and under
     the causal mask hold at the first Q block column j needs (under a
     window at the last one too); under the block-diffusion mask the
     minor axis is a K tile's visits (``_bd_k_visit``).  ``lse`` and
-    ``delta`` are rows here: (BH, nQ, 1, bq), one row a Q block."""
+    ``delta`` are rows here: (BH, nQ, 1, bq), one row a Q block.
+
+    With ``group`` query heads a key/value head the grid is (BH / group,
+    nK, group nQ): visit ``s`` of key/value head b reads query head ``b
+    group + s // nQ`` at the Q block that visit ``s mod nQ`` names
+    with one head a group, and the K / V blocks, whose index does not move
+    along the minor axis, stay in VMEM across the whole group."""
     nq = tq // bq
     dv = d if dv is None else dv
+    bkv = bh // group
     half = _bd_half(tq, tk, bq, bk, causal, window, block_diffusion)
 
     def qblock(j, i):
@@ -836,22 +862,30 @@ def flash_bwd_dkv_plan(bh, tq, tk, d, bq, bk, causal=False,
         last = nq - 1 if window is None else \
             _imin(_window_last_q(j, bq, bk, window), nq - 1)
         return _imin(_imax(i, _causal_first_q(j, bq, bk)), last)
+
+    def qside(b, j, s):
+        """(query head, Q block) of visit ``s`` of K block j."""
+        if group == 1:
+            return b, qblock(j, s)
+        m = _idiv(s, nq)
+        return b * group + m, qblock(j, s - m * nq)
     qspec_t = pl.BlockSpec((None, bq, d),
-                           lambda b, j, i: (b, qblock(j, i), 0))
-    kspec_t = pl.BlockSpec((None, bk, d), lambda b, j, i: (b, j, 0))
+                           lambda b, j, s: (*qside(b, j, s), 0))
+    kspec_t = pl.BlockSpec((None, bk, d), lambda b, j, s: (b, j, 0))
     dospec_t = pl.BlockSpec((None, bq, dv),
-                            lambda b, j, i: (b, qblock(j, i), 0))
-    vspec_t = pl.BlockSpec((None, bk, dv), lambda b, j, i: (b, j, 0))
+                            lambda b, j, s: (*qside(b, j, s), 0))
+    vspec_t = pl.BlockSpec((None, bk, dv), lambda b, j, s: (b, j, 0))
     rowspec_t = pl.BlockSpec((None, None, 1, bq),
-                             lambda b, j, i: (b, qblock(j, i), 0, 0))
+                             lambda b, j, s: (*qside(b, j, s), 0, 0))
     return {
-        "grid": (bh, tk // bk, nq),     # a clean K tile's visits: up to nq
+        # a clean K tile's visits: up to nq a query head of the group
+        "grid": (bkv, tk // bk, group * nq),
         "in_specs": [qspec_t, kspec_t, vspec_t, dospec_t, rowspec_t,
                      rowspec_t],
-        "in_shapes": [(bh, tq, d), (bh, tk, d), (bh, tk, dv),
+        "in_shapes": [(bh, tq, d), (bkv, tk, d), (bkv, tk, dv),
                       (bh, tq, dv), (bh, nq, 1, bq), (bh, nq, 1, bq)],
         "out_specs": [kspec_t, vspec_t],
-        "out_shapes": [(bh, tk, d), (bh, tk, dv)],
+        "out_shapes": [(bkv, tk, d), (bkv, tk, dv)],
         "scratch": [(bk, d), (bk, dv)],
         "dtypes": [dtype] * 4 + [jnp.float32] * 2 + [dtype] * 2,
         "tiles": _flash_tiles("dkv", bq, bk, dtype),
@@ -948,6 +982,7 @@ def _flash_plan(kernel, q, k, causal, block_q, block_k, window=None,
     halved until it divides a half of the rows."""
     bh, tq, d = q.shape
     tk = k.shape[1]
+    group = _flash_group(q, k)
     bq, bk = _flash_blocks(tq, tk, d, q.dtype, kernel, dv, block_diffusion)
     if block_diffusion is not None:
         given = [b for b in (block_q, block_k) if b is not None]
@@ -959,10 +994,21 @@ def _flash_plan(kernel, q, k, causal, block_q, block_k, window=None,
         if block_k is not None:
             bk = _pick_block(tk, block_k)
     plan = _FLASH_PLANS[kernel](bh, tq, tk, d, bq, bk, causal, q.dtype,
-                                window, dv, block_diffusion)
+                                window, dv, block_diffusion, group)
     _export_flash_gauges(kernel, bq, bk, plan["grid"], window,
-                         block_diffusion)
+                         block_diffusion, group)
     return bq, bk, plan
+
+
+def _flash_group(q, k):
+    """Query heads a key/value head, from the folded operands' leading
+    sides: ``B x Hq`` over ``B x Hkv``."""
+    if q.shape[0] % k.shape[0]:
+        raise ValueError(
+            "grouped-query attention needs the query heads a whole "
+            "multiple of the key/value heads: %d folded query heads over "
+            "%d" % (q.shape[0], k.shape[0]))
+    return q.shape[0] // k.shape[0]
 
 
 def _flash_mask_kwargs(plan, window, block_diffusion):
@@ -1020,9 +1066,14 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_diffusion=None):
     """Blockwise online-softmax attention.
 
-    q, k: (BH, T, D), v: (BH, T, Dv) — fold batch and heads into the
-    leading dim; the values' head size may differ from the keys' (latent
-    attention scores over 192 dimensions and sums values of 128).
+    q: (BH, T, D), k: (BKV, T, D), v: (BKV, T, Dv) — fold batch and
+    heads into the leading dim, batch-major; the values' head size may
+    differ from the keys' (latent attention scores over 192 dimensions
+    and sums values of 128).  Grouped-query attention: ``g = BH / BKV``
+    query heads share a key/value head (folded query head ``n`` reads
+    key/value head ``n // g``); the kernels index the shared head — K
+    and V are never repeated — and dK / dV come out at BKV heads, the
+    group's sum taken in the dK/dV kernel's float32 accumulator.
     Returns (BH, T, Dv).  O(T) memory.  The (block_q, block_k) score
     blocks are chosen per kernel from the shape — the largest whole-
     tile divisors of T that fit the scoped-VMEM budget
@@ -1110,7 +1161,8 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, window, kept,
     rows = (bh, tq // bq, 1, bq)
     dk, dv = _flash_call(
         functools.partial(_flash_bwd_dkv_kernel, scale=s, causal=causal,
-                          bq=bq, bk=bk, nq=plan["grid"][2],
+                          bq=bq, bk=bk, nq=tq // bq,
+                          group=_flash_group(q, k),
                           **_flash_mask_kwargs(plan, window,
                                                block_diffusion)),
         plan, q, k, v, do, lse.reshape(rows), delta.reshape(rows))

@@ -71,10 +71,13 @@ def local_attention(q, k, v, causal=False, q_offset=0, kv_offset=0,
                     kept=False, block_diffusion=None):
     """Softmax attention on local blocks.
 
-    q: (B, Tq, H, D), k: (B, Tk, H, D), v: (B, Tk, H, Dv) — the values'
-    head size may differ from the keys' (latent attention: scores over
-    192 dimensions, values of 128); the result is (B, Tq, H, Dv) and the
-    default scale is ``D ** -0.5``.  Offsets give the global
+    q: (B, Tq, H, D), k: (B, Tk, Hkv, D), v: (B, Tk, Hkv, Dv) — the
+    values' head size may differ from the keys' (latent attention:
+    scores over 192 dimensions, values of 128); the result is (B, Tq, H,
+    Dv) and the default scale is ``D ** -0.5``.  ``Hkv`` divides ``H``
+    (grouped-query attention: ``H / Hkv`` query heads share a key/value
+    head): the flash kernels index the shared head, the einsum form
+    repeats it.  Offsets give the global
     positions of the first query/key for causal masking across shards.
     ``kv_len`` masks out keys whose global position is >= kv_len —
     the padding mask for sequences padded up to a shard multiple.
@@ -107,7 +110,6 @@ def local_attention(q, k, v, causal=False, q_offset=0, kv_offset=0,
                 "the block-diffusion mask is a mask of its own over one "
                 "whole sequence of rows [noised ; clean]: no causal, "
                 "window, offset or kv_len goes with it")
-    k, v = _expand_kv_heads(q, k, v)
     if kv_len is not None and kv_len >= kv_offset + k.shape[1]:
         kv_len = None  # no padded keys in this block
     use_flash = (kv_len is None and
@@ -121,13 +123,13 @@ def local_attention(q, k, v, causal=False, q_offset=0, kv_offset=0,
     if use_flash:
         from ..ops.pallas_kernels import flash_attention
         b, tq, h, _ = q.shape
-        tk = k.shape[1]
-        fold = lambda a, t: jnp.transpose(a, (0, 2, 1, 3)).reshape(
-            b * h, t, a.shape[-1])
-        o = flash_attention(fold(q, tq), fold(k, tk), fold(v, tk),
-                            causal, scale, None, None, window, kept,
-                            block_diffusion)
+        # batch-major: query head b H + i reads key/value head b Hkv + i // g
+        fold = lambda a: jnp.transpose(a, (0, 2, 1, 3)).reshape(
+            b * a.shape[2], a.shape[1], a.shape[-1])
+        o = flash_attention(fold(q), fold(k), fold(v), causal, scale, None,
+                            None, window, kept, block_diffusion)
         return jnp.transpose(o.reshape(b, h, tq, v.shape[-1]), (0, 2, 1, 3))
+    k, v = _expand_kv_heads(q, k, v)
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     kpos = kv_offset + jnp.arange(k.shape[1])
@@ -218,7 +220,9 @@ def _pad_to_shards(q, k, v, sp):
 
 def _expand_kv_heads(q, k, v):
     """GQA/MQA: replicate K/V heads up to the query head count when
-    num_kv_heads divides num_q_heads (grouped-query attention)."""
+    num_kv_heads divides num_q_heads (grouped-query attention) — for the
+    einsum form and the sequence-parallel bodies; the flash kernels
+    index the shared head instead."""
     hq, hkv = q.shape[2], k.shape[2]
     if hq == hkv:
         return k, v

@@ -72,6 +72,108 @@ def test_flash_kernels_compile_for_the_chip(one_chip, monkeypatch, bh, tq,
         assert name in text
 
 
+def _defined_shapes(text):
+    """``%name -> (dtype, dims)`` of every instruction of an optimized
+    module that makes one array."""
+    found = re.finditer(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\w+)\[([0-9,]*)\]",
+                        text, re.M)
+    return {m.group(1): (m.group(2), tuple(int(d) for d in m.group(3).split(
+        ",") if d)) for m in found}
+
+
+def _attention_module(one_chip, monkeypatch, t, hq, hkv, d, dv, mask,
+                      attend=None):
+    """The optimized module of one attention sub-layer's loss and
+    gradient at batch 1 — ``attend(q, k, v)`` or ``local_attention`` on
+    the flash path as the cells' layers call it (``kept``)."""
+    from mxnet_tpu.parallel import attention
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    monkeypatch.setattr(attention, "_flash_eligible", lambda *a: True)
+    attend = attend or (lambda q, k, v: attention.local_attention(
+        q, k, v, kept=True, **mask))
+
+    def loss(q, k, v):
+        return jnp.sum(attend(q, k, v).astype(jnp.float32) ** 2)
+
+    def aval(h, width):
+        return jax.ShapeDtypeStruct((1, t, h, width), jnp.bfloat16,
+                                    sharding=one_chip)
+    with jax.default_matmul_precision("default"):
+        return jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+            aval(hq, d), aval(hkv, d), aval(hkv, dv)).compile().as_text()
+
+
+# (T, query heads, key/value heads, mask): the grouped cells' attention —
+# Mellum2's full and window layers (32 over 4), SDAR's block-diffusion
+# layers (32 over 4 over the 8192 rows [noised ; clean]) and ZAYA1's
+# compressed convolutional attention (8 over 2 at scale 1)
+@pytest.mark.parametrize("t,hq,hkv,mask", [
+    (8192, 32, 4, dict(causal=True)),
+    (8192, 32, 4, dict(causal=True, window=1024)),
+    (8192, 32, 4, dict(block_diffusion=4)),
+    (16384, 8, 2, dict(causal=True, scale=1.0)),
+], ids=["mellum2-full", "mellum2-window", "sdar-blockdiff", "zaya1-cca"])
+def test_grouped_attention_holds_no_repeat(one_chip, monkeypatch, t, hq,
+                                           hkv, mask):
+    """No bf16 ``broadcast`` of K or V up to the query heads and no
+    ``reduce`` of their gradients over the group: the kernels take K and
+    V at the key/value heads and give dK and dV back at them."""
+    d = 128
+    text = _attention_module(one_chip, monkeypatch, t, hq, hkv, d, d, mask)
+    shapes = _defined_shapes(text)
+    for line in text.splitlines():
+        if " broadcast(" in line or " reduce(" in line:
+            name = re.match(r"\s*(?:ROOT )?(%[\w.\-]+)", line).group(1)
+            dtype, dims = shapes[name]
+            assert not (" broadcast(" in line and dtype == "bf16"
+                        and math.prod(dims) == t * hq * d), line[:120]
+            assert not (" reduce(" in line
+                        and math.prod(dims) == t * hkv * d), line[:120]
+    calls = [line for line in text.splitlines() if " custom-call(" in line]
+    for kernel, operands in (("_flash_fwd_kernel", (1, 2)),
+                             ("_flash_bwd_dq_kernel", (1, 2)),
+                             ("_flash_bwd_dkv_kernel", (1, 2))):
+        line, = [c for c in calls if kernel in c]
+        args = re.search(r" custom-call\(([^)]*)\)", line).group(1)
+        args = [a.strip().split(" ")[-1] for a in args.split(",")]
+        for i in operands:
+            assert shapes[args[i]][1][0] == hkv, (kernel, i)
+    dkv, = [c for c in calls if "_flash_bwd_dkv_kernel" in c]
+    assert re.search(r"= \(bf16\[%d,%d,%d\]" % (hkv, t, d), dkv)
+
+
+def _instructions(text):
+    """An optimized module's instructions as (opcode, result type),
+    sorted: what it computes, whatever its names and source lines."""
+    return sorted(re.findall(r"= ((?:\(.*?\))|\S+) ([a-z][\w\-]*)\(",
+                             re.sub(r"\{[^{}]*\}", "", text)))
+
+
+# (T, heads, D, Dv): OPT's 32 heads of 64 and Ouro's 16 of 128 at T 2048,
+# JoyAI's latent attention, 32 heads of 192 over values of 128 at T 8192
+@pytest.mark.parametrize("t,h,d,dv", [
+    (2048, 32, 64, 64), (2048, 16, 128, 128), (8192, 32, 192, 128),
+], ids=["opt1p3b", "ouro2p6b", "joyai-flash"])
+def test_one_head_a_group_compiles_to_the_fold_it_was(one_chip,
+                                                      monkeypatch, t, h, d,
+                                                      dv):
+    """Where every query head has its own key/value head, the sub-layer
+    is the program it was before the kernels took grouped heads: the
+    heads folded into the leading side, the three kernels, unfolded —
+    instruction for instruction."""
+    def fold_and_call(q, k, v):
+        fold = lambda a: jnp.transpose(a, (0, 2, 1, 3)).reshape(
+            h, t, a.shape[-1])
+        o = pk.flash_attention(fold(q), fold(k), fold(v), True, None, None,
+                               None, None, True)
+        return jnp.transpose(o.reshape(1, h, t, dv), (0, 2, 1, 3))
+    mask = dict(causal=True)
+    assert _instructions(_attention_module(
+        one_chip, monkeypatch, t, h, h, d, dv, mask)) == _instructions(
+            _attention_module(one_chip, monkeypatch, t, h, h, d, dv, mask,
+                              fold_and_call))
+
+
 # (tokens, top_k, units, held experts, dtype): the benchmark's expert
 # layer (8192 tokens, 8 slots, rows of 2304 bfloat16, 16 held experts: a
 # buffer of 187 tiles of 384 rows), and a float32 layer of one lane tile

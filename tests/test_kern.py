@@ -420,3 +420,58 @@ def test_the_head_kernels_are_in_the_catalog_with_a_ragged_vocabulary(
     d = reports[1]["operands"][-1]
     assert d["name"] == "d" and d["block"] == [256, 512]
     assert d["index"][:2] == [[0, 0], [0, 1]]
+
+
+@pytest.mark.parametrize("mask", ["causal", "window", "block_diffusion"])
+@pytest.mark.parametrize("group", [4, 8])
+def test_grouped_flash_plans_are_in_the_catalog(group, mask, monkeypatch):
+    """The flash kernels with ``group`` query heads a key/value head,
+    with jit poisoned: clean under every mask, dK/dV's blocks each
+    revisited over the group's visits (the coverage verdict's uniform
+    revisit count) and declared the sum over the group's query heads.
+    A plan that drops the group's last member — its dK/dV still writes
+    every block equally often — or that reads a head of another group
+    is refused."""
+    from mxnet_tpu.analysis.kern import flash_group_reports
+    _poison_jit(monkeypatch)
+    which = ["causal", "window", "block_diffusion"].index(mask)
+    reports = flash_group_reports()[(3 * [4, 8].index(group) + which) * 3:][:3]
+    assert [r["name"] for r in reports] == [
+        "_flash_fwd_kernel", "_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel"]
+    assert run_kern_checkers(reports) == []
+    fwd, _dq, dkv = reports
+    ops = {o["name"]: o for o in dkv["operands"]}
+    nq = 4
+    assert dkv["grid"] == [2, 4, group * nq]
+    assert ops["k"]["shape"] == ops["dk"]["shape"] == [2, 512, 64]
+    assert ops["q"]["shape"] == [2 * group, 512, 64]
+    assert ops["dk"]["sums"] == {"of": "q", "heads": group}
+    assert {tuple(i) for i in ops["dk"]["index"][:group * nq]} == {(0, 0, 0)}
+    assert [o["shape"][0] for o in fwd["operands"]] == [2 * group, 2, 2,
+                                                        2 * group, 2 * group]
+
+    def cut(report, keep):
+        """The report as a plan that visits only the grid points where
+        ``keep(point)`` holds along a shortened minor axis."""
+        grid = report["grid"]
+        points = [p for p in np.ndindex(*grid)]
+        kept = [n for n, p in enumerate(points) if keep(p)]
+        out = dict(report, grid=grid[:2] + [grid[2] - nq])
+        out["operands"] = [dict(o, index=[o["index"][n] for n in kept])
+                           if o.get("index") else o
+                           for o in report["operands"]]
+        return out
+
+    dropped = cut(dkv, lambda p: p[2] < (group - 1) * nq)
+    out = next(o for o in dropped["operands"] if o["name"] == "dk")
+    assert coverage_problems(out, dropped["grid"]) == []
+    findings = run_kern_checkers([dropped])
+    assert {f.rule for f in findings} == {"kern-grid-coverage"}
+    assert any("never read head" in f.message for f in findings)
+    stray = json.loads(json.dumps(dkv))
+    for o in stray["operands"]:
+        if o["name"] in ("q", "do", "lse", "delta"):
+            o["index"] = [[(i[0] + group) % (2 * group)] + i[1:]
+                          for i in o["index"]]
+    assert any("another group" in f.message
+               for f in run_kern_checkers([stray]))
