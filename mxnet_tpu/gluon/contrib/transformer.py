@@ -253,10 +253,11 @@ class TransformerLM(HybridBlock):
 
 
 def _layer_keeps():
-    """The ``policy`` of the ``jax.checkpoint`` that ``moe_lm_forward``
-    and ``latent_moe_lm_forward`` wrap each layer (each prediction
-    module) in: keep the state that enters and, of what the layer
-    computes, two things alone, each worth its bytes on the chip.
+    """The ``policy`` of the ``jax.checkpoint`` that the expert LMs'
+    trunk (:func:`_trunk`) wraps each layer in, and
+    ``latent_moe_lm_forward`` each prediction module: keep the state
+    that enters and, of what the layer computes, two things alone, each
+    worth its bytes on the chip.
 
     * The flash call's output and row statistics
       (``pallas_kernels.FLASH_KEPT``; 67 + 1 MB a layer at 32 x 8192 x
@@ -450,31 +451,129 @@ class LoopedLM(HybridBlock):
         return ExitWeightedCELoss(beta=beta, params=self.params, **kwargs)
 
 
-# one expert layer's leaves, in construction order; the two gains of a
-# QK-normed layer come after the projections they follow
-_MOE_LAYER_LEAVES = (
-    "norm1_gamma", "q_weight", "k_weight", "v_weight", "out_weight",
-    "norm2_gamma", "router_weight", "gate_weight", "up_weight",
-    "down_weight")
-_QK_NORM_LEAVES = ("q_norm_gamma", "k_norm_gamma")
-# compressed convolutional attention's own leaves after its projections:
-# the convolution over time, the one across a head's channels, the
-# temperature of each key/value head
-_CCA_LEAVES = ("cca_time_weight", "cca_mix_weight", "cca_temperature_gamma")
 SLIDING, FULL = "sliding_attention", "full_attention"
+DENSE, SPARSE = "dense", "sparse"
 
 
-def _moe_layer_leaves(qk_norm, cca=False, router_layers=0):
-    leaves = _MOE_LAYER_LEAVES
+# The leaves of the expert LMs' layers, each with its shape in the sizes
+# the block names (``_ExpertLM._make_params``).  A layer's leaves are its
+# first gain, its attention form's, its second gain and its feed-forward
+# form's, in this order (:func:`_layer_leaves`).
+def _gqa_leaves(qk_norm=False, cca=False):
+    """Grouped-query attention's leaves (:func:`grouped_query_attention`;
+    with ``cca``, :func:`compressed_conv_attention`'s): the projections,
+    then the two QK-norm gains or the convolutions and the temperature,
+    then the output projection."""
+    own = ()
     if qk_norm:
-        leaves = leaves[:4] + _QK_NORM_LEAVES + leaves[4:]
+        own = (("q_norm_gamma", ("head",)), ("k_norm_gamma", ("head",)))
     if cca:
-        leaves = leaves[:4] + _CCA_LEAVES + leaves[4:]
-    if router_layers:
-        at = leaves.index("router_weight") + 1
-        leaves = leaves[:at] + tuple("router%d_weight" % j for j in range(
-            router_layers)) + leaves[at:]
-    return leaves
+        own = (("cca_time_weight", ("cca_time", "qk")),
+               ("cca_mix_weight", ("qk_heads", "cca_mix", "head", "head")),
+               ("cca_temperature_gamma", ("kv_heads",)))
+    return ((("q_weight", ("queries", "units")),
+             ("k_weight", ("keys", "units")),
+             ("v_weight", ("keys", "units"))) + own
+            + (("out_weight", ("units", "queries")),))
+
+
+# latent attention's leaves (:func:`latent_attention`)
+_LATENT_LEAVES = (
+    ("q_a_weight", ("q_rank", "units")), ("q_a_norm_gamma", ("q_rank",)),
+    ("q_b_weight", ("q_b", "q_rank")), ("kv_a_weight", ("kv_a", "units")),
+    ("kv_a_norm_gamma", ("kv_rank",)), ("kv_b_weight", ("kv_b", "kv_rank")),
+    ("out_weight", ("units", "values")))
+# the dense feed-forward form's leaves: one SwiGLU
+_DENSE_LEAVES = (("gate_weight", ("dense", "units")),
+                 ("up_weight", ("dense", "units")),
+                 ("down_weight", ("units", "dense")))
+
+
+def _routed_leaves(router_layers=0, bias=False, shared=False):
+    """The routed feed-forward form's leaves (:func:`routed_ffn`): the
+    router (an MLP's first projection where ``router_layers``), the MLP's
+    layers, the held selection bias, the held experts' stacked weights,
+    the shared expert's."""
+    return ((("router_weight", ("router", "units")),)
+            + tuple(("router%d_weight" % j,
+                     ("routed" if j == router_layers - 1 else "router",
+                      "router")) for j in range(router_layers))
+            + ((("router_bias", ("routed",)),) if bias else ())
+            + (("gate_weight", ("held", "expert", "units")),
+               ("up_weight", ("held", "expert", "units")),
+               ("down_weight", ("held", "units", "expert")))
+            + ((("shared_gate_weight", ("shared", "units")),
+                ("shared_up_weight", ("shared", "units")),
+                ("shared_down_weight", ("units", "shared"))) if shared
+               else ()))
+
+
+def _layer_leaves(attention, ffn):
+    """A pre-norm layer's leaves in order: ``norm1_gamma``, the attention
+    form's, ``norm2_gamma``, the feed-forward form's."""
+    gain = lambda name: ((name, ("units",)),)
+    return gain("norm1_gamma") + attention + gain("norm2_gamma") + ffn
+
+
+# a multi-token-prediction module's own leaves around its layer
+_MTP_FRONT_LEAVES = (("embed_norm_gamma", ("units",)),
+                     ("hidden_norm_gamma", ("units",)),
+                     ("proj_weight", ("units", "joined")))
+
+
+def grouped_query_attention(h, p, *, num_heads, num_kv_heads, rope,
+                            window=None, qk_norm=False, block_length=None,
+                            positions=None, rotary_dim=None, eps=1e-6,
+                            kept=False):
+    """Grouped-query causal attention with rotary q and k over normed
+    states ``h (B, T, U)``: ``(B, T, U)``, the output projection
+    included.  ``p`` holds one layer's ``q_weight (H D, U)``, ``k_weight
+    (Hkv D, U)``, ``v_weight (Hkv D, U)``, ``out_weight (U, H D)`` and,
+    under ``qk_norm``, ``q_norm_gamma`` and ``k_norm_gamma (D,)``: every
+    query and key head RMS-normed over its own dimensions BEFORE the
+    rotary, as Qwen3's attention does.  ``rope`` is ``(base, inv_freq or
+    None, scale)`` (:func:`ops.contrib._rotary_embedding`; only the first
+    ``rotary_dim`` dimensions turn where one is given).
+
+    With ``window`` a query sees its last ``window`` keys (scope
+    ``mx_attn_window``), without it every key before it
+    (``mx_attn_full``); with ``block_length`` the three-part block mask
+    of block diffusion (``F.contrib.flash_attention``'s
+    ``block_diffusion``, no window; scope ``mx_attn_blockdiff``) over rows
+    at ``positions``.  The projections and the flash call are under the
+    scope, the output projection is not.  ``kept``: the caller is a layer
+    under :func:`_layer_keeps`."""
+    import jax
+    import jax.numpy as jnp
+
+    from ...ops.contrib import _flash_attention_op, _rotary_embedding
+    from ...ops.nn import _rms_norm
+    from ...telemetry import phases
+
+    b, t, _u = h.shape
+    base, inv_freq, scale = rope
+    heads = lambda w, n: jnp.einsum("btu,ou->bto", h, w).reshape(
+        b, t, n, w.shape[0] // n)
+    if block_length is not None:
+        mask, scope = {"block_diffusion": block_length}, \
+            phases.ATTN_BLOCKDIFF_SCOPE
+    else:
+        mask = {"causal": True, "window": window}
+        scope = phases.ATTN_FULL_SCOPE if window is None else \
+            phases.ATTN_WINDOW_SCOPE
+    with jax.named_scope(scope):
+        def turned(which, n):
+            a = heads(p[which + "_weight"], n)
+            if qk_norm:
+                a = _rms_norm(a, p[which + "_norm_gamma"], eps=eps)
+            return _rotary_embedding(a, positions, base=base,
+                                     inv_freq=inv_freq, scale=scale,
+                                     rotary_dim=rotary_dim)
+
+        o = _flash_attention_op(
+            turned("q", num_heads), turned("k", num_kv_heads),
+            heads(p["v_weight"], num_kv_heads), kept=kept, **mask)
+    return jnp.einsum("bto,uo->btu", o.reshape(b, t, -1), p["out_weight"])
 
 
 def compressed_conv_attention(h, p, *, num_heads, num_kv_heads, rope,
@@ -572,128 +671,401 @@ class _RouterMLP(NamedTuple):
         return r
 
 
+def routed_ffn(h, p, *, top_k, held, router_layers=0, shared_expert=False,
+               **route):
+    """The routed feed-forward form over normed states ``h (B, T, U)``:
+    one chip's share of a top-``top_k`` routed expert layer
+    (:func:`parallel.moe.routed_experts`: the router over ALL published
+    experts, ``held = (first, count)`` the ones whose stacked weights
+    ``gate_weight``, ``up_weight``, ``down_weight`` are here; ``route``
+    its keywords, ``norm_topk``, ``scoring``, ``scale``; the held
+    selection bias ``router_bias`` where ``p`` has one).  With
+    ``router_layers`` the router is an MLP (:class:`_RouterMLP`):
+    ``router_weight`` projects down, then ``router0_weight ..`` give the
+    logits.  With ``shared_expert`` the
+    SwiGLU every token takes (``shared_*_weight``, scope
+    ``mx_shared_expert``) is added: it is this block's and not the routed
+    layer's, so summed over the chips' shares it counts once."""
+    import jax
+
+    from ...ops.contrib import _gated_ffn
+    from ...parallel.moe import routed_experts
+    from ...telemetry import phases
+
+    b, t, u = h.shape
+    router = p["router_weight"]
+    if router_layers:
+        router = _RouterMLP((router,) + tuple(
+            p["router%d_weight" % j] for j in range(router_layers)))
+    if "router_bias" in p:
+        route = dict(route, bias=p["router_bias"])
+    y = routed_experts(
+        h.reshape(b * t, u), router,
+        (p["gate_weight"], p["up_weight"], p["down_weight"]), top_k, held,
+        **route).reshape(b, t, u)
+    if shared_expert:
+        with jax.named_scope(phases.SHARED_EXPERT_SCOPE):
+            y = y + _gated_ffn(h, p["shared_gate_weight"],
+                               p["shared_up_weight"], p["shared_down_weight"])
+    return y
+
+
+def dense_ffn(h, p):
+    """The dense feed-forward form: one SwiGLU over ``h (B, T, U)``."""
+    from ...ops.contrib import _gated_ffn
+    return _gated_ffn(h, p["gate_weight"], p["up_weight"], p["down_weight"])
+
+
+def _layer(x, p, attention, ffn, eps):
+    """One pre-norm layer, ``x + Attn(RMS(x))`` then ``x + FFN(RMS(x))``:
+    ``attention`` and ``ffn`` are forms, ``(h, p) -> (B, T, U)``."""
+    from ...ops.nn import _rms_norm
+    x = x + attention(_rms_norm(x, p["norm1_gamma"], eps=eps), p)
+    return x + ffn(_rms_norm(x, p["norm2_gamma"], eps=eps), p)
+
+
+def _trunk(params, tokens, layers, *, eps, block_diffusion=False):
+    """The expert LMs' one trunk: the embedding's gather of ``tokens (B,
+    T)`` (under ``mx_noise`` in a ``block_diffusion`` forward), the
+    ``layers`` in turn, the final norm ``norm_gamma``.  Each layer is
+    ``(prefix, names, attention, ffn)``: its leaves are ``params[prefix +
+    name]`` in the order ``names`` gives, the order they are handed to
+    its ``jax.checkpoint`` in (:func:`_layer_keeps`: the backward pass
+    keeps the state that enters a layer and runs the layer again —
+    except the flash call, whose output and row statistics are kept, 67
+    + 1 MB a layer at 32 x 8192 x 128, and the expert layer's routing,
+    whose choice and row tables are, about 1 MB).  A block-diffusion
+    forward runs ``[noised ; clean]`` and returns the noised half alone,
+    ``(B, T / 2, U)``.
+
+    A new architecture costs one form — a function ``(h, p, **static) ->
+    (B, T, U)`` of normed states and the layer's leaves, like
+    :func:`grouped_query_attention`, :func:`compressed_conv_attention`,
+    :func:`latent_attention`, :func:`routed_ffn` or :func:`dense_ffn` —
+    and its leaf entry (``_gqa_leaves``, ``_LATENT_LEAVES``,
+    ``_routed_leaves``, ``_DENSE_LEAVES``): the block that takes it
+    chooses the form from its arguments and names the sizes its shapes
+    read; no new forward or block."""
+    import collections
+    import contextlib
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ...ops.nn import _rms_norm
+    from ...telemetry import phases
+
+    with jax.named_scope(phases.NOISE_SCOPE) if block_diffusion \
+            else contextlib.nullcontext():
+        x = params["embed_weight"][tokens.astype(jnp.int32)]
+    for prefix, names, attention, ffn in layers:
+        p = collections.OrderedDict((n, params[prefix + n]) for n in names)
+        x = jax.checkpoint(functools.partial(
+            _layer, attention=attention, ffn=ffn, eps=eps),
+            policy=_layer_keeps())(x, p)
+    if block_diffusion:
+        x = x[:, :tokens.shape[1] // 2]
+    return _rms_norm(x, params["norm_gamma"], eps=eps)
+
+
 def moe_lm_forward(params, tokens, *, layer_types, num_heads, num_kv_heads,
                    top_k, held, window, rope, norm_topk=True, eps=1e-6,
                    qk_norm=False, block_length=None, cca=False,
                    rotary_dim=None, router_layers=0):
     """A sparse-expert LM's trunk as ONE pure function of ``(params,
     tokens)``: the final-normed states ``(B, T, U)`` the head reads.
-
     ``params`` maps :class:`MoELM`'s short parameter names to jax
-    arrays; ``tokens`` is ``(B, T)`` int.  Layer ``i`` is pre-norm,
-    ``x + Attn(RMS(x))`` then ``x + Experts(RMS(x))``: grouped-query
-    causal attention with rotary q and k — on a ``sliding_attention``
-    layer a query sees its last ``window`` keys, on a ``full_attention``
-    layer every key before it; ``rope`` maps each kind to ``(base,
-    inv_freq or None, scale)`` (:func:`ops.contrib._rotary_embedding`)
-    — and one chip's share of a top-``top_k`` routed expert layer
-    (:func:`parallel.moe.routed_experts`: the router over ALL published
-    experts, ``held = (first, count)`` the ones whose stacked weights
-    are here).  With ``qk_norm`` every query and key head is RMS-normed
-    over its own dimensions (gains ``l{i}_q_norm_gamma``,
-    ``l{i}_k_norm_gamma``) BEFORE the rotary, as Qwen3's attention does.
+    arrays, ``tokens`` is ``(B, T)`` int, the keywords are the block's
+    ``_config``.  Layer ``i`` (:func:`_trunk`) is
+    :func:`grouped_query_attention` — ``window`` keys on a
+    ``sliding_attention`` layer, ``rope[kind]`` its rotary table — or,
+    with ``cca``, :func:`compressed_conv_attention`; then
+    :func:`routed_ffn`, its router an MLP of ``router_layers`` layers
+    where that is given.
 
     With ``block_length`` the trunk is trained by diffusion over blocks
     (Arriola et al., arXiv:2503.09573; SDAR, arXiv:2510.06303):
     ``tokens`` is ``(B, 2 L)``, a noised copy of every sequence and then
     its clean copy, both at positions ``0 .. L - 1``; EVERY layer's
-    attention runs under the three-part block mask
-    (``F.contrib.flash_attention``'s ``block_diffusion``; the layer kind
-    still picks the rotary table, a window is not applied) under scope
-    ``mx_attn_blockdiff``, the embedding's gather of the ``2 L`` rows
-    under ``mx_noise``; and only the noised half's states are normed and
-    returned, ``(B, L, U)`` — the objective reads no other
-    (``gluon.loss.BlockDiffusionCELoss``).
+    attention runs under the three-part block mask (the layer kind still
+    picks the rotary table, a window is not applied), and only the
+    noised half's states are returned, ``(B, L, U)`` — the objective
+    reads no other (``gluon.loss.BlockDiffusionCELoss``)."""
+    import functools
 
-    With ``cca`` a layer's attention is compressed convolutional
-    attention (:func:`compressed_conv_attention`: latent queries and
-    keys mixed by two causal convolutions, shifted values, L2-normed
-    heads under a temperature).  ``rotary_dim`` turns only a head's first
-    dimensions.  With ``router_layers`` the router is an MLP
-    (:class:`_RouterMLP`): ``l{i}_router_weight`` projects down, then
-    ``l{i}_router0_weight ..`` give the logits.
+    import jax.numpy as jnp
 
-    Each layer is a ``jax.checkpoint``
-    (:func:`_layer_keeps`): the backward pass keeps the state that
-    enters a layer and runs the layer again — except the flash call,
-    whose output and row statistics are kept (67 + 1 MB a layer at 32 x
-    8192 x 128), and the expert layer's routing, whose choice and row
-    tables are (about 1 MB)."""
-    import contextlib
+    names = [n for n, _ in _layer_leaves(_gqa_leaves(qk_norm, cca),
+                                         _routed_leaves(router_layers))]
+    positions = None if block_length is None else \
+        jnp.tile(jnp.arange(tokens.shape[1] // 2, dtype=jnp.int32), 2)
+    ffn = functools.partial(routed_ffn, top_k=top_k, held=held,
+                            norm_topk=norm_topk, router_layers=router_layers)
 
+    heads = dict(num_heads=num_heads, num_kv_heads=num_kv_heads,
+                 rotary_dim=rotary_dim, kept=True)
+
+    def attention(kind):
+        if cca:
+            return functools.partial(compressed_conv_attention,
+                                     rope=rope[kind], **heads)
+        return functools.partial(
+            grouped_query_attention, rope=rope[kind],
+            window=window if kind == SLIDING else None, qk_norm=qk_norm,
+            block_length=block_length, positions=positions, eps=eps, **heads)
+
+    return _trunk(params, tokens,
+                  [("l%d_" % i, names, attention(kind), ffn)
+                   for i, kind in enumerate(layer_types)],
+                  eps=eps, block_diffusion=block_length is not None)
+
+
+def latent_attention(h, p, *, num_heads, nope_dim, rope_dim, v_dim,
+                     rope_base=10000.0, rope_interleaved=True, eps=1e-6,
+                     kept=False):
+    """Multi-head latent attention over normed states ``h (B, T, U)``:
+    ``(B, T, U)``, the output projection included.  ``p`` holds one
+    layer's ``q_a_weight (Rq, U)``, ``q_a_norm_gamma``, ``q_b_weight (H
+    (nope + rope), Rq)``, ``kv_a_weight (Rkv + rope, U)``,
+    ``kv_a_norm_gamma``, ``kv_b_weight (H (nope + v), Rkv)`` and
+    ``out_weight (U, H v)``.  Every head's key is ``[k_nope | k_rope]``
+    with the ONE rotary key broadcast over the heads; the flash call
+    scores over ``nope + rope`` dimensions (scale their ``-0.5`` power)
+    and sums values of ``v_dim`` — neither side is padded to the other.
+    What runs around the flash call is under scope ``mx_attn_latent``,
+    the call and its rotary under ``mx_attn_full``.  ``kept``: the
+    caller is a layer under :func:`_layer_keeps`."""
     import jax
     import jax.numpy as jnp
 
     from ...ops.contrib import _flash_attention_op, _rotary_embedding
     from ...ops.nn import _rms_norm
-    from ...parallel.moe import routed_experts
     from ...telemetry import phases
 
-    leaves = _moe_layer_leaves(qk_norm, cca, router_layers)
-    half = tokens.shape[1] // 2
-    positions = None if block_length is None else \
-        jnp.tile(jnp.arange(half, dtype=jnp.int32), 2)
-
-    def attention(h, p, kind):
-        b, t, _u = h.shape
-        base, inv_freq, scale = rope[kind]
-        heads = lambda w, n: jnp.einsum("btu,ou->bto", h, w).reshape(
-            b, t, n, w.shape[0] // n)
-        mask = {"block_diffusion": block_length} \
-            if block_length is not None else \
-            {"causal": True, "window": window if kind == SLIDING else None}
-        with jax.named_scope(phases.ATTN_BLOCKDIFF_SCOPE
-                             if block_length is not None else
-                             phases.ATTN_WINDOW_SCOPE if kind == SLIDING
-                             else phases.ATTN_FULL_SCOPE):
-            def turned(which, n):
-                a = heads(p[which + "_weight"], n)
-                if qk_norm:
-                    a = _rms_norm(a, p[which + "_norm_gamma"], eps=eps)
-                return _rotary_embedding(a, positions, base=base,
-                                         inv_freq=inv_freq, scale=scale,
-                                         rotary_dim=rotary_dim)
-
-            o = _flash_attention_op(
-                turned("q", num_heads), turned("k", num_kv_heads),
-                heads(p["v_weight"], num_kv_heads), kept=True, **mask)
-        return jnp.einsum("bto,uo->btu", o.reshape(b, t, -1),
-                          p["out_weight"])
-
-    def layer(x, p, kind):
-        p = dict(zip(leaves, p))
-        b, t, u = x.shape
-        h = _rms_norm(x, p["norm1_gamma"], eps=eps)
-        if cca:
-            x = x + compressed_conv_attention(
-                h, p, num_heads=num_heads, num_kv_heads=num_kv_heads,
-                rope=rope[kind], rotary_dim=rotary_dim, kept=True)
-        else:
-            x = x + attention(h, p, kind)
-        h = _rms_norm(x, p["norm2_gamma"], eps=eps)
-        router = p["router_weight"]
-        if router_layers:
-            router = _RouterMLP((router,) + tuple(
-                p["router%d_weight" % j] for j in range(router_layers)))
-        y = routed_experts(
-            h.reshape(b * t, u), router,
-            (p["gate_weight"], p["up_weight"], p["down_weight"]), top_k,
-            held, norm_topk=norm_topk)
-        return x + y.reshape(b, t, u)
-
-    with jax.named_scope(phases.NOISE_SCOPE) if block_length is not None \
-            else contextlib.nullcontext():
-        x = params["embed_weight"][tokens.astype(jnp.int32)]
-    for i, kind in enumerate(layer_types):
-        p = [params["l%d_%s" % (i, n)] for n in leaves]
-        x = jax.checkpoint(layer, static_argnums=2,
-                           policy=_layer_keeps())(x, p, kind)
-    if block_length is not None:
-        x = x[:, :half]
-    return _rms_norm(x, params["norm_gamma"], eps=eps)
+    b, t, _u = h.shape
+    turn = lambda a: _rotary_embedding(a, base=rope_base,
+                                       interleaved=rope_interleaved)
+    with jax.named_scope(phases.ATTN_LATENT_SCOPE):
+        cq = _rms_norm(jnp.einsum("btu,ru->btr", h, p["q_a_weight"]),
+                       p["q_a_norm_gamma"], eps=eps)
+        q = jnp.einsum("btr,or->bto", cq, p["q_b_weight"]).reshape(
+            b, t, num_heads, nope_dim + rope_dim)
+        ckv = jnp.einsum("btu,ru->btr", h, p["kv_a_weight"])
+        kv = jnp.einsum(
+            "btr,or->bto",
+            _rms_norm(ckv[..., :-rope_dim], p["kv_a_norm_gamma"], eps=eps),
+            p["kv_b_weight"]).reshape(b, t, num_heads, nope_dim + v_dim)
+    with jax.named_scope(phases.ATTN_FULL_SCOPE):
+        q_rope = turn(q[..., nope_dim:])
+        k_rope = turn(ckv[:, :, None, -rope_dim:])
+    with jax.named_scope(phases.ATTN_LATENT_SCOPE):
+        q = jnp.concatenate([q[..., :nope_dim], q_rope], -1)
+        k = jnp.concatenate(
+            [kv[..., :nope_dim],
+             jnp.broadcast_to(k_rope, (b, t, num_heads, rope_dim))], -1)
+    with jax.named_scope(phases.ATTN_FULL_SCOPE):
+        o = _flash_attention_op(q, k, kv[..., nope_dim:], causal=True,
+                                kept=kept)
+    return jnp.einsum("bto,uo->btu", o.reshape(b, t, -1), p["out_weight"])
 
 
-class MoELM(HybridBlock):
+def latent_moe_lm_forward(params, tokens, *, mlp_layer_types, num_heads,
+                          nope_dim, rope_dim, v_dim, top_k, held,
+                          scoring="sigmoid", route_scale=1.0, norm_topk=True,
+                          shared_expert=True, rope_base=10000.0,
+                          rope_interleaved=True, mtp_depth=0, eps=1e-6):
+    """A latent-attention sparse-expert LM's trunk as ONE pure function
+    of ``(params, tokens)``: the final-normed states ``(B, T, U)`` the
+    head reads and, with ``mtp_depth`` prediction modules, their own
+    final-normed states ``(B, mtp_depth, T, U)`` beside them.
+    ``params`` maps :class:`LatentMoELM`'s short parameter names to jax
+    arrays (a leaf the block did not make, as ``l1_router_bias`` without
+    a selection bias, is left out), ``tokens`` is ``(B, T)`` int, the
+    keywords are the block's ``_config``.  Layer ``i`` (:func:`_trunk`)
+    is :func:`latent_attention` (MLA, DeepSeek-V2, arXiv:2405.04434),
+    then by ``mlp_layer_types[i]`` :func:`dense_ffn` or
+    :func:`routed_ffn` with ``scoring``, ``route_scale`` and, under
+    ``shared_expert``, the shared expert.
+
+    Prediction module ``k`` (DeepSeek-V3, arXiv:2412.19437): ``[RMS_e(
+    Emb(t_{i+k})) ; RMS_h(h_i)] W`` through one more sparse layer of its
+    own and a final norm of its own, ``h`` being the main states after
+    their final norm for ``k = 1`` and module ``k - 1``'s un-normed
+    output after it; embedding and head are the model's.  The last ``k``
+    positions read tokens that wrap round; the loss leaves them out
+    (``gluon.loss.MultiTokenCELoss``).  A module is checkpointed as a
+    layer is (:func:`_layer_keeps`)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ...ops.nn import _rms_norm
+    from ...telemetry import phases
+
+    attention = functools.partial(
+        latent_attention, num_heads=num_heads, nope_dim=nope_dim,
+        rope_dim=rope_dim, v_dim=v_dim, rope_base=rope_base,
+        rope_interleaved=rope_interleaved, eps=eps, kept=True)
+    ffn = {DENSE: dense_ffn,
+           SPARSE: functools.partial(
+               routed_ffn, top_k=top_k, held=held, norm_topk=norm_topk,
+               scoring=scoring, scale=route_scale,
+               shared_expert=shared_expert)}
+    leaves = {DENSE: _layer_leaves(_LATENT_LEAVES, _DENSE_LEAVES),
+              SPARSE: _layer_leaves(_LATENT_LEAVES, _routed_leaves(
+                  bias=True, shared=True))}
+
+    def names(prefix, kind):
+        # the leaves the block made, by name: the order in which this
+        # model's layers hand them to their checkpoint (the operands of
+        # its barrier in the compiled step)
+        return sorted(n for n, _ in leaves[kind] if prefix + n in params)
+
+    def module(h, nxt, p, front):
+        e = _rms_norm(params["embed_weight"][nxt],
+                      front["embed_norm_gamma"], eps=eps)
+        hn = _rms_norm(h, front["hidden_norm_gamma"], eps=eps)
+        x = jnp.einsum("btc,uc->btu", jnp.concatenate([e, hn], -1),
+                       front["proj_weight"])
+        return _layer(x, p, attention, ffn[SPARSE], eps)
+
+    tokens = tokens.astype(jnp.int32)
+    states = _trunk(params, tokens,
+                    [("l%d_" % i, names("l%d_" % i, kind), attention,
+                      ffn[kind]) for i, kind in enumerate(mlp_layer_types)],
+                    eps=eps)
+    if not mtp_depth:
+        return states
+    h, outs = states, []
+    with jax.named_scope(phases.MTP_SCOPE):
+        for k in range(mtp_depth):
+            pre = "mtp%d_" % k
+            h = jax.checkpoint(module, policy=_layer_keeps())(
+                h, jnp.roll(tokens, -(k + 1), axis=1),
+                {n: params[pre + n] for n in names(pre, SPARSE)},
+                {n: params[pre + n] for n, _ in _MTP_FRONT_LEAVES})
+            outs.append(_rms_norm(h, params[pre + "norm_gamma"], eps=eps))
+    return states, jnp.stack(outs, axis=1)
+
+
+def _export_expert_rows(model, tokens, top_k, held, num_routed):
+    """Rows a step of ``tokens`` tokens sends to the held experts of one
+    layer of ``model``, in expectation under a symmetric router —
+    exported as ``mxnet_moe_expected_rows`` (what the experts' products
+    are sized against).  Beside it the two static shapes those rows lie
+    in, of which dispatch and combine fetch the held rows alone:
+    ``mxnet_moe_buffer_rows`` (the row buffer, sized for every
+    assignment being held) and ``mxnet_moe_slot_rows`` (a row a (token,
+    slot) assignment)."""
+    from ... import telemetry
+    from ...ops.pallas_kernels import GROUPED_TILE_ROWS as tm
+    slots = tokens * top_k
+    rows = slots * held[1] / num_routed
+    telemetry.gauge("mxnet_moe_expected_rows", "rows a step's tokens "
+                    "send to the held experts of one layer of the "
+                    "newest %s, in expectation under a symmetric "
+                    "router" % model).set(rows)
+    telemetry.gauge("mxnet_moe_buffer_rows", "rows of the expert "
+                    "layer's row buffer in the newest %s: whole "
+                    "tiles for every assignment being held" % model).set(
+                        (-(-slots // tm) + held[1]) * tm)
+    telemetry.gauge("mxnet_moe_slot_rows", "(token, slot) "
+                    "assignments a step of the newest %s routes, "
+                    "held or not" % model).set(slots)
+    return rows
+
+
+class _ExpertLM(HybridBlock):
+    """What the sparse-expert LMs share around the one trunk
+    (:func:`_trunk`): the held experts' check, the parameters made in
+    order, the expert layer's gauges, the forward through ``invoke_fn``
+    and the head.  A subclass turns its arguments into ``_config``, the
+    leaves and the sizes their shapes read, and calls its pure forward
+    in ``_forward`` (looked up when the block runs, not when it is
+    made)."""
+
+    _tied = False
+
+    @staticmethod
+    def _held_experts(num_routed, held, top_k):
+        held = (0, num_routed) if held is None else \
+            (int(held[0]), int(held[1]))
+        if held[0] < 0 or held[1] < 1 or sum(held) > num_routed \
+                or not 1 <= top_k <= num_routed:
+            raise ValueError("held experts %r and top_k %d do not fit %d "
+                             "routed experts" % (held, top_k, num_routed))
+        return held
+
+    def _make_params(self, shapes, sizes):
+        """Parameters in the order of ``shapes``, ``(name, shape)`` pairs
+        whose entries are numbers or names of ``sizes``; then the
+        gauges."""
+        with self.name_scope():
+            # the initializer reads the suffix: gains 1, the bias 0; the
+            # selection bias is held, not trained
+            for name, shape in shapes:
+                held_only = {"grad_req": "null"} \
+                    if name.endswith("router_bias") else {}
+                setattr(self, name, self.params.get(
+                    name, shape=tuple(sizes.get(d, d) for d in shape),
+                    **held_only))
+        self._export_gauges()
+
+    def _export_gauges(self):
+        """The routed layer, in gauges: the router's width, the experts
+        held here, the experts a token takes."""
+        from ... import telemetry
+        c = self._config
+        experts = telemetry.gauge(
+            "mxnet_moe_experts", "experts of a layer of the newest MoELM or "
+            "LatentMoELM: the router's width (published) and the ones this "
+            "block holds (held)")
+        experts.labels(which="published").set(self._num_routed)
+        experts.labels(which="held").set(c["held"][1])
+        telemetry.gauge("mxnet_moe_top_k", "experts a token is routed to in "
+                        "the newest MoELM or LatentMoELM").set(c["top_k"])
+
+    def expected_rows(self, tokens):
+        """Rows a step of ``tokens`` tokens sends to this block's held
+        experts, a layer, in expectation (:func:`_export_expert_rows`:
+        exported with the two static shapes those rows lie in)."""
+        c = self._config
+        return _export_expert_rows(type(self).__name__, tokens, c["top_k"],
+                                   c["held"], self._num_routed)
+
+    def hybrid_forward(self, F, tokens, **params):
+        from ...imperative import invoke_fn
+        names = [n for n in params if n != "head_weight"]
+        self.expected_rows(tokens.shape[0] * tokens.shape[1])
+
+        def forward(tokens_, *leaves):
+            return self._forward(dict(zip(names, leaves)), tokens_)
+
+        out = invoke_fn(forward, [tokens] + [params[n] for n in names])
+        return tuple(out) if isinstance(out, list) else out
+
+    def _head(self):
+        return self.embed_weight if self._tied else self.head_weight
+
+    def logits(self, states):
+        """The head over final-normed states: ``(B, T, V)``."""
+        from ... import ndarray as nd
+        return nd.dot(states, self._head().data(), transpose_b=True)
+
+    def lm_loss(self, **kwargs):
+        """The training objective over this block's output, sharing its
+        head: ``loss(net(tokens), labels)`` — per sequence, the mean
+        over positions of the next token's cross-entropy (the head the
+        embedding table where the two are tied)."""
+        from ..loss import LinearCELoss
+        return LinearCELoss(params=self.params,
+                            head="embed_weight" if self._tied
+                            else "head_weight", **kwargs)
+
+
+class MoELM(_ExpertLM):
     """Decoder-only LM of sparse-expert layers under attention of two
     kinds: ``layer_types`` names each layer ``sliding_attention`` (a
     query sees its last ``window`` keys) or ``full_attention``; every
@@ -762,8 +1134,6 @@ class MoELM(HybridBlock):
         super().__init__(**kwargs)
         from ...ops.contrib import yarn_inv_freq
         head_dim = head_dim or units // num_heads
-        held = (0, num_routed) if held is None else \
-            (int(held[0]), int(held[1]))
         if num_heads % num_kv_heads or head_dim % 2:
             raise ValueError("num_heads (%d) must be a multiple of "
                              "num_kv_heads (%d), heads of even size (%d)"
@@ -788,10 +1158,7 @@ class MoELM(HybridBlock):
         if any(k not in (SLIDING, FULL) for k in layer_types):
             raise ValueError("layer_types are %r or %r, got %r"
                              % (SLIDING, FULL, list(layer_types)))
-        if held[0] < 0 or held[1] < 1 or sum(held) > num_routed \
-                or not 1 <= top_k <= num_routed:
-            raise ValueError("held experts %r and top_k %d do not fit %d "
-                             "routed experts" % (held, top_k, num_routed))
+        held = self._held_experts(num_routed, held, top_k)
         if block_length is not None and (
                 int(block_length) < 1 or mask_token_id is None
                 or not 0 <= int(mask_token_id) < vocab_size):
@@ -828,43 +1195,31 @@ class MoELM(HybridBlock):
             block_length=int(block_length), mask_id=int(mask_token_id),
             eps=float(noise_eps))
         self._num_routed = num_routed
-        n, f = held[1], expert_width
-        shape = {"q_weight": (num_heads * head_dim, units),
-                 "q_norm_gamma": (head_dim,), "k_norm_gamma": (head_dim,),
-                 "k_weight": (num_kv_heads * head_dim, units),
-                 "v_weight": (num_kv_heads * head_dim, units),
-                 "out_weight": (units, num_heads * head_dim),
-                 "router_weight": (router_hidden or num_routed, units),
-                 "gate_weight": (n, f, units), "up_weight": (n, f, units),
-                 "down_weight": (n, units, f)}
-        if cca is not None:
-            heads = num_heads + num_kv_heads
-            shape.update(
-                cca_time_weight=(self._cca[0], heads * head_dim),
-                cca_mix_weight=(heads, self._cca[1], head_dim, head_dim),
-                cca_temperature_gamma=(num_kv_heads,))
-        for j in range(router_layers):
-            shape["router%d_weight" % j] = (
-                num_routed if j == router_layers - 1 else router_hidden,
-                router_hidden)
+        sizes = dict(
+            units=units, head=head_dim, queries=num_heads * head_dim,
+            keys=num_kv_heads * head_dim, kv_heads=num_kv_heads,
+            qk_heads=num_heads + num_kv_heads,
+            qk=(num_heads + num_kv_heads) * head_dim,
+            cca_time=self._cca[0], cca_mix=self._cca[1],
+            router=router_hidden or num_routed, routed=num_routed,
+            held=held[1], expert=expert_width)
+        layer = _layer_leaves(_gqa_leaves(qk_norm, cca is not None),
+                              _routed_leaves(router_layers))
         shapes = [("embed_weight", (vocab_size, units))]
         for i in range(len(layer_types)):
-            shapes += [("l%d_%s" % (i, k), shape.get(k, (units,)))
-                       for k in _moe_layer_leaves(qk_norm, cca is not None,
-                                                  router_layers)]
+            shapes += [("l%d_%s" % (i, n), shape) for n, shape in layer]
         shapes.append(("norm_gamma", (units,)))
         if not self._tied:
             shapes.append(("head_weight", (vocab_size, units)))
-        with self.name_scope():
-            # the initializer reads the suffix: gains 1
-            for name, shp in shapes:
-                setattr(self, name, self.params.get(name, shape=shp))
-        self._export_gauges()
+        self._make_params(shapes, sizes)
+
+    def _forward(self, params, tokens):
+        return moe_lm_forward(params, tokens, **self._config)
 
     def _export_gauges(self):
         from ... import telemetry
+        super()._export_gauges()
         c = self._config
-        _export_expert_gauges(self._num_routed, c["held"][1], c["top_k"])
         layers = telemetry.gauge(
             "mxnet_attn_layers", "layers of the newest MoELM by kind of "
             "attention (sliding_attention / full_attention)")
@@ -887,14 +1242,6 @@ class MoELM(HybridBlock):
         telemetry.gauge("mxnet_rotary_dims", "dimensions of a head the "
                         "newest MoELM turns by rotary position").set(
                             self._rotary)
-
-    def expected_rows(self, tokens):
-        """Rows a step of ``tokens`` tokens sends to this block's held
-        experts, a layer, in expectation (:func:`_export_expert_rows`:
-        exported with the two static shapes those rows lie in)."""
-        c = self._config
-        return _export_expert_rows("MoELM", tokens, c["top_k"], c["held"],
-                                   self._num_routed)
 
     def _noised_rows(self, F, tokens):
         """``(rows (B, 2 L), weight (B, L))`` of a block-diffusion
@@ -922,38 +1269,10 @@ class MoELM(HybridBlock):
         return rows, weight
 
     def hybrid_forward(self, F, tokens, **params):
-        from ...imperative import invoke_fn
-        names = [n for n in params if n != "head_weight"]
-        config = self._config
-        weight = None
-        if self._noise is not None:
-            tokens, weight = self._noised_rows(F, tokens)
-        self.expected_rows(tokens.shape[0] * tokens.shape[1])
-
-        def forward(tokens_, *leaves):
-            return moe_lm_forward(dict(zip(names, leaves)), tokens_,
-                                  **config)
-
-        states = invoke_fn(forward, [tokens] + [params[n] for n in names])
-        return states if weight is None else (states, weight)
-
-    def _head(self):
-        return self.embed_weight if self._tied else self.head_weight
-
-    def logits(self, states):
-        """The head over final-normed states: ``(B, T, V)``."""
-        from ... import ndarray as nd
-        return nd.dot(states, self._head().data(), transpose_b=True)
-
-    def lm_loss(self, **kwargs):
-        """The training objective over this block's output, sharing its
-        head: ``loss(net(tokens), labels)`` — per sequence, the mean
-        over positions of the next token's cross-entropy (the head the
-        embedding table where the two are tied)."""
-        from ..loss import LinearCELoss
-        return LinearCELoss(params=self.params,
-                            head="embed_weight" if self._tied
-                            else "head_weight", **kwargs)
+        if self._noise is None:
+            return super().hybrid_forward(F, tokens, **params)
+        rows, weight = self._noised_rows(F, tokens)
+        return super().hybrid_forward(F, rows, **params), weight
 
     def diffusion_loss(self, **kwargs):
         """The training objective of a block-diffusion model
@@ -966,223 +1285,7 @@ class MoELM(HybridBlock):
         return BlockDiffusionCELoss(params=self.params, **kwargs)
 
 
-# a latent-attention layer's leaves, in construction order: attention and
-# both gains, then the feed-forward part of its kind
-_LATENT_ATTN_LEAVES = (
-    "norm1_gamma", "q_a_weight", "q_a_norm_gamma", "q_b_weight",
-    "kv_a_weight", "kv_a_norm_gamma", "kv_b_weight", "out_weight",
-    "norm2_gamma")
-_LATENT_FFN_LEAVES = {
-    "dense": ("gate_weight", "up_weight", "down_weight"),
-    "sparse": ("router_weight", "router_bias", "gate_weight", "up_weight",
-               "down_weight", "shared_gate_weight", "shared_up_weight",
-               "shared_down_weight")}
-DENSE, SPARSE = "dense", "sparse"
-# a multi-token-prediction module's own leaves around its layer
-_MTP_FRONT_LEAVES = ("embed_norm_gamma", "hidden_norm_gamma", "proj_weight")
-
-
-def latent_attention(h, p, *, num_heads, nope_dim, rope_dim, v_dim,
-                     rope_base=10000.0, rope_interleaved=True, eps=1e-6,
-                     kept=False):
-    """Multi-head latent attention over normed states ``h (B, T, U)``:
-    ``(B, T, U)``, the output projection included.  ``p`` holds one
-    layer's ``q_a_weight (Rq, U)``, ``q_a_norm_gamma``, ``q_b_weight (H
-    (nope + rope), Rq)``, ``kv_a_weight (Rkv + rope, U)``,
-    ``kv_a_norm_gamma``, ``kv_b_weight (H (nope + v), Rkv)`` and
-    ``out_weight (U, H v)``.  Every head's key is ``[k_nope | k_rope]``
-    with the ONE rotary key broadcast over the heads; the flash call
-    scores over ``nope + rope`` dimensions (scale their ``-0.5`` power)
-    and sums values of ``v_dim`` — neither side is padded to the other.
-    What runs around the flash call is under scope ``mx_attn_latent``,
-    the call and its rotary under ``mx_attn_full``.  ``kept``: the
-    caller is a layer under :func:`_layer_keeps`."""
-    import jax
-    import jax.numpy as jnp
-
-    from ...ops.contrib import _flash_attention_op, _rotary_embedding
-    from ...ops.nn import _rms_norm
-    from ...telemetry import phases
-
-    b, t, _u = h.shape
-    turn = lambda a: _rotary_embedding(a, base=rope_base,
-                                       interleaved=rope_interleaved)
-    with jax.named_scope(phases.ATTN_LATENT_SCOPE):
-        cq = _rms_norm(jnp.einsum("btu,ru->btr", h, p["q_a_weight"]),
-                       p["q_a_norm_gamma"], eps=eps)
-        q = jnp.einsum("btr,or->bto", cq, p["q_b_weight"]).reshape(
-            b, t, num_heads, nope_dim + rope_dim)
-        ckv = jnp.einsum("btu,ru->btr", h, p["kv_a_weight"])
-        kv = jnp.einsum(
-            "btr,or->bto",
-            _rms_norm(ckv[..., :-rope_dim], p["kv_a_norm_gamma"], eps=eps),
-            p["kv_b_weight"]).reshape(b, t, num_heads, nope_dim + v_dim)
-    with jax.named_scope(phases.ATTN_FULL_SCOPE):
-        q_rope = turn(q[..., nope_dim:])
-        k_rope = turn(ckv[:, :, None, -rope_dim:])
-    with jax.named_scope(phases.ATTN_LATENT_SCOPE):
-        q = jnp.concatenate([q[..., :nope_dim], q_rope], -1)
-        k = jnp.concatenate(
-            [kv[..., :nope_dim],
-             jnp.broadcast_to(k_rope, (b, t, num_heads, rope_dim))], -1)
-    with jax.named_scope(phases.ATTN_FULL_SCOPE):
-        o = _flash_attention_op(q, k, kv[..., nope_dim:], causal=True,
-                                kept=kept)
-    return jnp.einsum("bto,uo->btu", o.reshape(b, t, -1), p["out_weight"])
-
-
-def latent_moe_lm_forward(params, tokens, *, mlp_layer_types, num_heads,
-                          nope_dim, rope_dim, v_dim, top_k, held,
-                          scoring="sigmoid", route_scale=1.0, norm_topk=True,
-                          shared_expert=True, rope_base=10000.0,
-                          rope_interleaved=True, mtp_depth=0, eps=1e-6):
-    """A latent-attention sparse-expert LM's trunk as ONE pure function
-    of ``(params, tokens)``: the final-normed states ``(B, T, U)`` the
-    head reads and, with ``mtp_depth`` prediction modules, their own
-    final-normed states ``(B, mtp_depth, T, U)`` beside them.
-
-    ``params`` maps :class:`LatentMoELM`'s short parameter names to jax
-    arrays (no ``l1_router_bias``: the routers choose by score alone;
-    the shared expert's three leaves are read under ``shared_expert``);
-    ``tokens`` is
-    ``(B, T)`` int.  Layer ``i`` is pre-norm, ``x + MLA(RMS(x))`` then
-    ``x + FFN(RMS(x))``.
-
-    MLA (DeepSeek-V2, arXiv:2405.04434) is :func:`latent_attention`:
-    queries, keys and values through normed low-rank latents, one rotary
-    key shared by all heads (pairing (2i, 2i + 1) under
-    ``rope_interleaved``), scores over ``nope_dim + rope_dim`` dimensions
-    and values of ``v_dim``.
-
-    FFN: ``mlp_layer_types[i]`` "dense" is one SwiGLU; "sparse" one
-    chip's share of a routed expert layer
-    (:func:`parallel.moe.routed_experts` with ``scoring``, the held
-    selection bias ``router_bias`` and ``route_scale``) plus the shared
-    expert every token takes, which is this block's and not the routed
-    layer's: summed over the chips' shares it counts once.
-
-    Prediction module ``k`` (DeepSeek-V3, arXiv:2412.19437): ``[RMS_e(
-    Emb(t_{i+k})) ; RMS_h(h_i)] W`` through one more sparse layer of its
-    own and a final norm of its own, ``h`` being the main states after
-    their final norm for ``k = 1`` and module ``k - 1``'s un-normed
-    output after it; embedding and head are the model's.  The last ``k``
-    positions read tokens that wrap round; the loss leaves them out
-    (``gluon.loss.MultiTokenCELoss``).  Each layer and each module is a
-    ``jax.checkpoint`` (:func:`_layer_keeps`): the backward pass keeps
-    the state that enters it and runs it again — except the flash call,
-    whose output and row statistics are kept (67 + 1 MB a layer at 32 x
-    8192 x 128 values), and a sparse layer's routing, whose choice and
-    row tables are (about 1 MB)."""
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-
-    from ...ops.contrib import _gated_ffn
-    from ...ops.nn import _rms_norm
-    from ...parallel.moe import routed_experts
-    from ...telemetry import phases
-
-    attention = functools.partial(
-        latent_attention, num_heads=num_heads, nope_dim=nope_dim,
-        rope_dim=rope_dim, v_dim=v_dim, rope_base=rope_base,
-        rope_interleaved=rope_interleaved, eps=eps, kept=True)
-
-    def layer(x, p, kind):
-        b, t, u = x.shape
-        x = x + attention(_rms_norm(x, p["norm1_gamma"], eps=eps), p)
-        h = _rms_norm(x, p["norm2_gamma"], eps=eps)
-        if kind == DENSE:
-            return x + _gated_ffn(h, p["gate_weight"], p["up_weight"],
-                                  p["down_weight"])
-        y = routed_experts(
-            h.reshape(b * t, u), p["router_weight"],
-            (p["gate_weight"], p["up_weight"], p["down_weight"]), top_k,
-            held, norm_topk=norm_topk, scoring=scoring,
-            bias=p.get("router_bias"), scale=route_scale).reshape(b, t, u)
-        if shared_expert:
-            with jax.named_scope(phases.SHARED_EXPERT_SCOPE):
-                y = y + _gated_ffn(h, p["shared_gate_weight"],
-                                   p["shared_up_weight"],
-                                   p["shared_down_weight"])
-        return x + y
-
-    def leaves(prefix, names):
-        return {n: params[prefix + n] for n in names
-                if prefix + n in params}
-
-    def layer_leaves(prefix, kind):
-        return leaves(prefix, _LATENT_ATTN_LEAVES + _LATENT_FFN_LEAVES[kind])
-
-    def module(h, nxt, p, front):
-        e = _rms_norm(params["embed_weight"][nxt],
-                      front["embed_norm_gamma"], eps=eps)
-        hn = _rms_norm(h, front["hidden_norm_gamma"], eps=eps)
-        x = jnp.einsum("btc,uc->btu", jnp.concatenate([e, hn], -1),
-                       front["proj_weight"])
-        return layer(x, p, SPARSE)
-
-    tokens = tokens.astype(jnp.int32)
-    x = params["embed_weight"][tokens]
-    for i, kind in enumerate(mlp_layer_types):
-        x = jax.checkpoint(layer, static_argnums=2, policy=_layer_keeps())(
-            x, layer_leaves("l%d_" % i, kind), kind)
-    states = _rms_norm(x, params["norm_gamma"], eps=eps)
-    if not mtp_depth:
-        return states
-    h, outs = states, []
-    with jax.named_scope(phases.MTP_SCOPE):
-        for k in range(mtp_depth):
-            pre = "mtp%d_" % k
-            h = jax.checkpoint(module, policy=_layer_keeps())(
-                h, jnp.roll(tokens, -(k + 1), axis=1),
-                layer_leaves(pre, SPARSE), leaves(pre, _MTP_FRONT_LEAVES))
-            outs.append(_rms_norm(h, params[pre + "norm_gamma"], eps=eps))
-    return states, jnp.stack(outs, axis=1)
-
-
-def _export_expert_gauges(num_routed, held, top_k):
-    """The routed layer of the newest block that has one, in gauges: the
-    router's width, the experts held here, the experts a token takes."""
-    from ... import telemetry
-    experts = telemetry.gauge(
-        "mxnet_moe_experts", "experts of a layer of the newest MoELM or "
-        "LatentMoELM: the router's width (published) and the ones this "
-        "block holds (held)")
-    experts.labels(which="published").set(num_routed)
-    experts.labels(which="held").set(held)
-    telemetry.gauge("mxnet_moe_top_k", "experts a token is routed to in "
-                    "the newest MoELM or LatentMoELM").set(top_k)
-
-
-def _export_expert_rows(model, tokens, top_k, held, num_routed):
-    """Rows a step of ``tokens`` tokens sends to the held experts of one
-    layer of ``model``, in expectation under a symmetric router —
-    exported as ``mxnet_moe_expected_rows`` (what the experts' products
-    are sized against).  Beside it the two static shapes those rows lie
-    in, of which dispatch and combine fetch the held rows alone:
-    ``mxnet_moe_buffer_rows`` (the row buffer, sized for every
-    assignment being held) and ``mxnet_moe_slot_rows`` (a row a (token,
-    slot) assignment)."""
-    from ... import telemetry
-    from ...ops.pallas_kernels import GROUPED_TILE_ROWS as tm
-    slots = tokens * top_k
-    rows = slots * held[1] / num_routed
-    telemetry.gauge("mxnet_moe_expected_rows", "rows a step's tokens "
-                    "send to the held experts of one layer of the "
-                    "newest %s, in expectation under a symmetric "
-                    "router" % model).set(rows)
-    telemetry.gauge("mxnet_moe_buffer_rows", "rows of the expert "
-                    "layer's row buffer in the newest %s: whole "
-                    "tiles for every assignment being held" % model).set(
-                        (-(-slots // tm) + held[1]) * tm)
-    telemetry.gauge("mxnet_moe_slot_rows", "(token, slot) "
-                    "assignments a step of the newest %s routes, "
-                    "held or not" % model).set(slots)
-    return rows
-
-
-class LatentMoELM(HybridBlock):
+class LatentMoELM(_ExpertLM):
     """Decoder-only LM of latent-attention layers (MLA: queries, keys
     and values through low-rank latents, one rotary key shared by all
     heads, scores over ``nope_dim + rope_dim`` dimensions and values of
@@ -1220,8 +1323,6 @@ class LatentMoELM(HybridBlock):
                  route_scale=1.0, norm_topk=True, rope_base=10000.0,
                  rope_interleaved=True, mtp_depth=0, epsilon=1e-6, **kwargs):
         super().__init__(**kwargs)
-        held = (0, num_routed) if held is None else \
-            (int(held[0]), int(held[1]))
         if rope_dim % 2:
             raise ValueError("rope_dim (%d) must be even" % rope_dim)
         if any(k not in (DENSE, SPARSE) for k in mlp_layer_types):
@@ -1230,10 +1331,7 @@ class LatentMoELM(HybridBlock):
         if scoring not in ("sigmoid", "softmax"):
             raise ValueError("scoring is sigmoid or softmax, got %r"
                              % (scoring,))
-        if held[0] < 0 or held[1] < 1 or sum(held) > num_routed \
-                or not 1 <= top_k <= num_routed:
-            raise ValueError("held experts %r and top_k %d do not fit %d "
-                             "routed experts" % (held, top_k, num_routed))
+        held = self._held_experts(num_routed, held, top_k)
         self._config = dict(
             mlp_layer_types=tuple(mlp_layer_types), num_heads=num_heads,
             nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim, top_k=top_k,
@@ -1243,60 +1341,34 @@ class LatentMoELM(HybridBlock):
             rope_interleaved=bool(rope_interleaved),
             mtp_depth=int(mtp_depth), eps=epsilon)
         self._num_routed = num_routed
-        n, f, fs = held[1], expert_width, shared_experts * expert_width
-        attn = {"q_a_weight": (q_rank, units),
-                "q_a_norm_gamma": (q_rank,),
-                "q_b_weight": (num_heads * (nope_dim + rope_dim), q_rank),
-                "kv_a_weight": (kv_rank + rope_dim, units),
-                "kv_a_norm_gamma": (kv_rank,),
-                "kv_b_weight": (num_heads * (nope_dim + v_dim), kv_rank),
-                "out_weight": (units, num_heads * v_dim)}
-        ffn = {DENSE: {"gate_weight": (dense_width, units),
-                       "up_weight": (dense_width, units),
-                       "down_weight": (units, dense_width)},
-               SPARSE: {"router_weight": (num_routed, units),
-                        "router_bias": (num_routed,),
-                        "gate_weight": (n, f, units),
-                        "up_weight": (n, f, units),
-                        "down_weight": (n, units, f),
-                        "shared_gate_weight": (fs, units),
-                        "shared_up_weight": (fs, units),
-                        "shared_down_weight": (units, fs)}}
-        absent = (() if selection_bias else ("router_bias",)) + (
-            () if shared_experts else ("shared_gate_weight",
-                                       "shared_up_weight",
-                                       "shared_down_weight"))
-
-        def layer(prefix, kind):
-            return [(prefix + k, {**attn, **ffn[kind]}.get(k, (units,)))
-                    for k in _LATENT_ATTN_LEAVES + _LATENT_FFN_LEAVES[kind]
-                    if k not in absent]
-
+        sizes = dict(
+            units=units, q_rank=q_rank, q_b=num_heads * (nope_dim + rope_dim),
+            kv_rank=kv_rank, kv_a=kv_rank + rope_dim,
+            kv_b=num_heads * (nope_dim + v_dim), values=num_heads * v_dim,
+            dense=dense_width, router=num_routed, routed=num_routed,
+            held=held[1], expert=expert_width,
+            shared=shared_experts * expert_width, joined=2 * units)
+        layer = {DENSE: _layer_leaves(_LATENT_LEAVES, _DENSE_LEAVES),
+                 SPARSE: _layer_leaves(_LATENT_LEAVES, _routed_leaves(
+                     bias=selection_bias, shared=shared_experts))}
+        prefixed = lambda pre, leaves: [(pre + n, s) for n, s in leaves]
         shapes = [("embed_weight", (vocab_size, units))]
         for i, kind in enumerate(mlp_layer_types):
-            shapes += layer("l%d_" % i, kind)
+            shapes += prefixed("l%d_" % i, layer[kind])
         shapes.append(("norm_gamma", (units,)))
         for k in range(mtp_depth):
-            pre = "mtp%d_" % k
-            shapes += [(pre + "embed_norm_gamma", (units,)),
-                       (pre + "hidden_norm_gamma", (units,)),
-                       (pre + "proj_weight", (units, 2 * units))]
-            shapes += layer(pre, SPARSE) + [(pre + "norm_gamma", (units,))]
+            shapes += prefixed("mtp%d_" % k, _MTP_FRONT_LEAVES + layer[SPARSE]
+                               + (("norm_gamma", ("units",)),))
         shapes.append(("head_weight", (vocab_size, units)))
-        with self.name_scope():
-            # the initializer reads the suffix: gains 1, the bias 0; the
-            # selection bias is held, not trained
-            for name, shp in shapes:
-                held_only = {"grad_req": "null"} \
-                    if name.endswith("router_bias") else {}
-                setattr(self, name, self.params.get(name, shape=shp,
-                                                    **held_only))
-        self._export_gauges()
+        self._make_params(shapes, sizes)
+
+    def _forward(self, params, tokens):
+        return latent_moe_lm_forward(params, tokens, **self._config)
 
     def _export_gauges(self):
         from ... import telemetry
+        super()._export_gauges()
         c = self._config
-        _export_expert_gauges(self._num_routed, c["held"][1], c["top_k"])
         scoring = telemetry.gauge(
             "mxnet_moe_scoring", "1 at the scoring (sigmoid / softmax) the "
             "newest LatentMoELM's routers choose by, 0 at the other")
@@ -1310,33 +1382,13 @@ class LatentMoELM(HybridBlock):
         telemetry.gauge("mxnet_mtp_depth", "multi-token-prediction modules "
                         "of the newest LatentMoELM").set(c["mtp_depth"])
 
-    def hybrid_forward(self, F, tokens, **params):
-        from ...imperative import invoke_fn
-        names = [n for n in params if n != "head_weight"]
-        config = self._config
-        _export_expert_rows("LatentMoELM", tokens.shape[0] * tokens.shape[1],
-                            config["top_k"], config["held"],
-                            self._num_routed)
-
-        def forward(tokens_, *leaves):
-            return latent_moe_lm_forward(dict(zip(names, leaves)), tokens_,
-                                         **config)
-
-        out = invoke_fn(forward, [tokens] + [params[n] for n in names])
-        return tuple(out) if config["mtp_depth"] else out
-
-    def logits(self, states):
-        """The head over final-normed states: ``(B, T, V)``."""
-        from ... import ndarray as nd
-        return nd.dot(states, self.head_weight.data(), transpose_b=True)
-
     def lm_loss(self, mtp_weight=0.3, **kwargs):
         """The training objective over this block's outputs, sharing its
         head: ``loss(*net(tokens), labels)`` — per sequence the mean
         next-token cross-entropy, plus ``mtp_weight`` times the mean of
         the prediction modules' (each over the positions it has)."""
-        from ..loss import LinearCELoss, MultiTokenCELoss
+        from ..loss import MultiTokenCELoss
         if not self._config["mtp_depth"]:
-            return LinearCELoss(params=self.params, **kwargs)
+            return super().lm_loss(**kwargs)
         return MultiTokenCELoss(mtp_weight=mtp_weight, params=self.params,
                                 **kwargs)

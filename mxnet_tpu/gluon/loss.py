@@ -308,10 +308,11 @@ class LinearCELoss(Loss):
     head reads (no reference analogue): ``loss(states, label)`` with
     ``states`` ``(B, T, U)`` and the head the SHARED parameter
     ``head_weight`` ``(V, U)`` — construct the loss with
-    ``params=net.params`` (``gluon.contrib.transformer.MoELM.lm_loss()``).
-    ``head`` names that parameter: ``"embed_weight"`` where the head is
-    TIED to the embedding table, whose gradient is then the sum of the
-    look-up's and the head's.  The projection is fused with its
+    ``params=net.params`` (``lm_loss()`` of the expert LMs,
+    ``gluon.contrib.transformer.MoELM`` and ``LatentMoELM``, without
+    prediction modules).  ``head`` names that parameter:
+    ``"embed_weight"`` where the head is TIED to the embedding table,
+    whose gradient is then the sum of the look-up's and the head's.  The projection is fused with its
     cross-entropy (``F.contrib.linear_cross_entropy``), so the float32
     logits are never kept for the backward pass.  Returns ``(B,)``."""
 

@@ -127,7 +127,7 @@ def _route_top_k(x, router_w, top_k, norm_topk=True, scoring="softmax",
                          % (scoring,))
     # a router given as a function is a Python callable, never a traced
     # array: the test is of its type, static at trace time
-    if callable(router_w):  # graftlint: disable=recompile-hazard — audit: unreachable-in-audit (the audit's workload routes no tokens to experts)
+    if callable(router_w):
         logits = router_w(x)
     else:
         logits = jnp.einsum("tu,eu->te", x, router_w.astype(x.dtype),

@@ -50,18 +50,21 @@ LOOP_SCOPE, EXIT_SCOPE = "mx_loop", "mx_exit"
 # two kinds (gluon.contrib.transformer.MoELM): a layer's expert part —
 # router, top-k, dispatch, the experts' products, combine — with the
 # grouped products and their activation in an inner scope of their own
-# (parallel/moe.py routed_experts); rotary and attention of a layer whose
-# queries see a window of keys, and of one whose queries see every key
-# before them
+# (transformer.routed_ffn -> parallel/moe.py routed_experts); rotary and
+# attention of a layer whose queries see a window of keys, and of one
+# whose queries see every key before them
+# (transformer.grouped_query_attention)
 MOE_SCOPE, MOE_EXPERTS_SCOPE = "mx_moe", "mx_moe_experts"
 ATTN_WINDOW_SCOPE, ATTN_FULL_SCOPE = "mx_attn_window", "mx_attn_full"
 # inside mx_fwd, in a block of latent-attention layers with a shared
 # expert and multi-token-prediction modules
 # (gluon.contrib.transformer.LatentMoELM): what latent attention runs
-# around its flash call (the down-projections, the latents' norms, the
-# up-projections, the split and the shared rotary key's broadcast: the
-# call itself and its rotary are mx_attn_full); the expert every token
-# takes; a whole prediction module, which CROSSES the other parts (its
+# around its flash call (transformer.latent_attention: the
+# down-projections, the latents' norms, the up-projections, the split and
+# the shared rotary key's broadcast: the call itself and its rotary are
+# mx_attn_full); the expert every token takes (transformer.routed_ffn);
+# a whole prediction module (transformer.latent_moe_lm_forward), which
+# CROSSES the other parts (its
 # layer's attention and experts carry their scopes inside it).  The
 # maps match by substring, so none of these names holds an older one
 ATTN_LATENT_SCOPE, SHARED_EXPERT_SCOPE = "mx_attn_latent", "mx_shared_expert"
@@ -69,11 +72,13 @@ MTP_SCOPE = "mx_mtp"
 # inside mx_fwd, in a block trained by diffusion over blocks
 # (gluon.contrib.transformer.MoELM with block_length): a layer's attention
 # over the rows [noised ; clean] under the three-part block mask — QK-norm,
-# rotary at the rows' positions, the K/V repeat, the kernels — and the
-# noising with the gather of the 2 L rows' embeddings
+# rotary at the rows' positions, the kernels
+# (transformer.grouped_query_attention) — and the noising with the gather
+# of the 2 L rows' embeddings (MoELM._noised_rows, transformer._trunk)
 ATTN_BLOCKDIFF_SCOPE, NOISE_SCOPE = "mx_attn_blockdiff", "mx_noise"
 # inside mx_fwd, in a block of compressed convolutional attention
-# (gluon.contrib.transformer.MoELM with cca): what mixes the latent
+# (gluon.contrib.transformer.MoELM with cca;
+# transformer.compressed_conv_attention): what mixes the latent
 # queries and keys before the flash call — the causal convolution over
 # time, the one over channels by head, the q-k mean, the values' shift,
 # the QK norm with its temperature and the partial rotary.  The

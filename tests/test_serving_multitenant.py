@@ -289,17 +289,20 @@ def test_doomed_requests_shed_before_dispatch():
     at low load the (whole-batch-median) estimate is NOT applied —
     a small request would ride a cheaper dispatch."""
     srv = _two_model_server(queue_depth=8)
+    # margins no scheduler delay can cross: the doomed request's 1 s
+    # deadline is far over any start-up delay and far under the 10 s
+    # estimate, which is itself under every other request's deadline
     with srv._mlock:
-        srv._exec_ms["A"] = [50.0] * 10   # measured: ~50 ms per batch
-        srv._exec_est["A"] = 50.0
-    # low load: no brownout, so this meetable-in-practice request is
-    # NOT doomed-shed even though 5 ms < the 50 ms batch median
+        srv._exec_ms["A"] = [10000.0] * 10   # measured: ~10 s per batch
+        srv._exec_est["A"] = 10000.0
+    # low load: no brownout, so this request is NOT doomed-shed
     lone = srv.infer_async("A", _x(), timeout_ms=120000.0)
     # now fill to the high watermark with class-1 work (not sheddable
-    # by class) — brownout enters, the doomed test arms
+    # by class; the 30 s default deadline) — brownout enters, the
+    # doomed test arms
     futs = [srv.infer_async("A", _x(), priority=1) for _ in range(5)]
     assert srv.stats()["brownout"]["active"]
-    doomed = srv.infer_async("A", _x(), timeout_ms=5.0, priority=1)
+    doomed = srv.infer_async("A", _x(), timeout_ms=1000.0, priority=1)
     time.sleep(0.002)
     srv.start()
     with pytest.raises(mx.serving.DeadlineExceeded, match="shed"):
