@@ -35,7 +35,9 @@ from ..parameter import DeferredInitializationError
 __all__ = ["MultiHeadAttention", "TransformerEncoderCell", "TransformerLM",
            "LoopedLM", "looped_lm_forward", "MoELM", "moe_lm_forward",
            "LatentMoELM", "latent_moe_lm_forward", "latent_attention",
-           "causal_attention", "cached_attention_step"]
+           "SambaYLM", "mamba_mixer", "gated_memory",
+           "differential_attention", "causal_attention",
+           "cached_attention_step"]
 
 
 def causal_attention(q, k, v):
@@ -278,15 +280,20 @@ def _layer_keeps():
       gradient needs.  Forward and backward then agree on the choice
       whatever XLA rounds the recomputed scores to.
 
+    * The selective scan's output and the states entering its chunks
+      (``pallas_kernels.SSM_KEPT``; 84 + 21 MB a Mamba layer at 8192 x
+      5120 bfloat16 with a state of 16) — the backward kernel reads the
+      states, so the layer run again holds no forward scan.
+
     ``looped_lm_forward``'s pass keeps nothing: what a pass keeps is
     stacked over the ``scan``, and writing it there and reading it back
     cost what the forward kernel took (PERF.md, PR 35)."""
     import jax
 
-    from ...ops.pallas_kernels import FLASH_KEPT
+    from ...ops.pallas_kernels import FLASH_KEPT, SSM_KEPT
     from ...parallel.moe import ROUTE_KEPT
-    return jax.checkpoint_policies.save_only_these_names(*FLASH_KEPT,
-                                                         ROUTE_KEPT)
+    return jax.checkpoint_policies.save_only_these_names(
+        *FLASH_KEPT, ROUTE_KEPT, *SSM_KEPT)
 
 
 # one looped layer's leaves, in construction order
@@ -456,7 +463,7 @@ DENSE, SPARSE = "dense", "sparse"
 
 
 # The leaves of the expert LMs' layers, each with its shape in the sizes
-# the block names (``_ExpertLM._make_params``).  A layer's leaves are its
+# the block names (``_TrunkLM._make_params``).  A layer's leaves are its
 # first gain, its attention form's, its second gain and its feed-forward
 # form's, in this order (:func:`_layer_leaves`).
 def _gqa_leaves(qk_norm=False, cca=False):
@@ -508,11 +515,39 @@ def _routed_leaves(router_layers=0, bias=False, shared=False):
                else ()))
 
 
-def _layer_leaves(attention, ffn):
-    """A pre-norm layer's leaves in order: ``norm1_gamma``, the attention
-    form's, ``norm2_gamma``, the feed-forward form's."""
-    gain = lambda name: ((name, ("units",)),)
-    return gain("norm1_gamma") + attention + gain("norm2_gamma") + ffn
+def _layer_leaves(attention, ffn, layer_norm=False):
+    """A pre-norm layer's leaves in order: ``norm1_gamma`` (and
+    ``norm1_beta`` under ``layer_norm``), the attention form's,
+    ``norm2_gamma`` (``norm2_beta``), the feed-forward form's."""
+    gain = lambda name: ((name + "_gamma", ("units",)),) + (
+        ((name + "_beta", ("units",)),) if layer_norm else ())
+    return gain("norm1") + attention + gain("norm2") + ffn
+
+
+# a Mamba mixer's leaves (:func:`mamba_mixer`)
+_MAMBA_LEAVES = (
+    ("in_weight", ("inner2", "units")), ("conv_weight", ("conv", "inner")),
+    ("conv_bias", ("inner",)), ("x_weight", ("dbc", "inner")),
+    ("dt_weight", ("inner", "dt_rank")), ("dt_bias", ("inner",)),
+    ("a_log_weight", ("inner", "state")), ("d_weight", ("inner",)),
+    ("out_weight", ("units", "inner")))
+# a gated memory unit's leaves (:func:`gated_memory`)
+_GMU_LEAVES = (("gmu_in_weight", ("inner", "units")),
+               ("out_weight", ("units", "inner")))
+
+
+def _differential_leaves(cross=False):
+    """Differential attention's leaves (:func:`differential_attention`):
+    the projection of queries, keys and values (of the queries alone in a
+    cross-attention layer) with its bias, the four vectors ``lambda`` is
+    made of, the heads' norm, the output projection with its bias."""
+    proj = (("q_weight", ("queries", "units")), ("q_bias", ("queries",))) \
+        if cross else (("qkv_weight", ("qkv", "units")),
+                       ("qkv_bias", ("qkv",)))
+    return proj + tuple(("lambda_%s_weight" % n, ("head",))
+                        for n in ("q1", "k1", "q2", "k2")) \
+        + (("subln_gamma", ("pair",)), ("out_weight", ("units", "queries")),
+           ("out_bias", ("units",)))
 
 
 # a multi-token-prediction module's own leaves around its layer
@@ -716,20 +751,166 @@ def dense_ffn(h, p):
     return _gated_ffn(h, p["gate_weight"], p["up_weight"], p["down_weight"])
 
 
-def _layer(x, p, attention, ffn, eps):
-    """One pre-norm layer, ``x + Attn(RMS(x))`` then ``x + FFN(RMS(x))``:
-    ``attention`` and ``ffn`` are forms, ``(h, p) -> (B, T, U)``."""
+def mamba_mixer(h, p, *, memory=False):
+    """A Mamba mixer (Gu & Dao, arXiv:2312.00752) over normed states ``h
+    (B, T, U)``: ``(B, T, U)``, the output projection included, all of it
+    under scope ``mx_ssm``.  ``p`` holds one layer's ``in_weight (2 E,
+    U)``, ``conv_weight (K, E)`` (tap ``j`` reads the row ``j`` back),
+    ``conv_bias (E,)``, ``x_weight (R + 2 N, E)``, ``dt_weight (E, R)``,
+    ``dt_bias (E,)``, ``a_log_weight (E, N)``, ``d_weight (E,)`` and
+    ``out_weight (U, E)``::
+
+        [u ; z] = W_in h                   no bias
+        u'      = silu(conv(u) + b_c)      causal, depthwise
+        [dl ; B ; C] = W_x u'              R + N + N
+        delta   = softplus(W_dt dl + b_dt) float32
+        y       = selective_scan(u', delta, -exp(A_log), B, C, D)
+        out     = W_out (y * silu(z))
+
+    (``F.contrib.selective_scan``: the Pallas kernel pair on the TPU).
+    With ``memory`` the form returns ``(out, {"memory": y})``: the scan's
+    output before its gate is the memory a decoder-hybrid-decoder's gated
+    memory units read (:func:`gated_memory`)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ...ops.contrib import _causal_conv, _selective_scan
+    from ...telemetry import phases
+
+    n, r = p["a_log_weight"].shape[1], p["dt_weight"].shape[1]
+    with jax.named_scope(phases.SSM_SCOPE):
+        u, z = jnp.split(jnp.einsum("btu,ou->bto", h, p["in_weight"]), 2, -1)
+        u = jax.nn.silu(_causal_conv(u, p["conv_weight"]) + p["conv_bias"])
+        dbc = jnp.einsum("bte,oe->bto", u, p["x_weight"])
+        delta = jax.nn.softplus(
+            jnp.einsum("btr,er->bte", dbc[..., :r], p["dt_weight"],
+                       preferred_element_type=jnp.float32)
+            + p["dt_bias"].astype(jnp.float32))
+        a = -jnp.exp(p["a_log_weight"].astype(jnp.float32))
+        y = _selective_scan(u, delta, a, dbc[..., r:r + n],
+                            dbc[..., r + n:], p["d_weight"])
+        out = jnp.einsum("bte,ue->btu", y * jax.nn.silu(z), p["out_weight"])
+    return (out, {"memory": y}) if memory else out
+
+
+def gated_memory(h, p):
+    """A gated memory unit (SambaY, Ren et al., arXiv:2507.06607) over
+    normed states ``h (B, T, U)``: ``W_o (silu(W_i h) * m)``, ``m`` the
+    memory an earlier Mamba mixer wrote (``p["memory"] (B, T, E)``,
+    :func:`mamba_mixer`), ``gmu_in_weight (E, U)`` and ``out_weight (U,
+    E)`` without biases; scope ``mx_gmu``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ...telemetry import phases
+    with jax.named_scope(phases.GMU_SCOPE):
+        gate = jax.nn.silu(jnp.einsum("btu,eu->bte", h, p["gmu_in_weight"]))
+        return jnp.einsum("bte,ue->btu", gate * p["memory"], p["out_weight"])
+
+
+def differential_attention(h, p, *, num_heads, num_kv_heads, lambda_init,
+                           window=None, cross=False, shares=False, eps=1e-5,
+                           kept=False):
+    """Differential attention (Ye et al., arXiv:2410.05258) over normed
+    states ``h (B, T, U)``, causal, with no positional encoding:
+    ``(B, T, U)``, the output projection included.  ``p`` holds
+    ``qkv_weight ((H + 2 Hkv) D, U)`` with ``qkv_bias`` — in a ``cross``
+    layer ``q_weight (H D, U)`` with ``q_bias``, its keys and values
+    ``p["shared_k"]``, ``p["shared_v"] (B, T, Hkv, D)`` written by the
+    layer that ``shares`` them — ``lambda_{q1,k1,q2,k2}_weight (D,)``,
+    ``subln_gamma (2 D,)``, ``out_weight (U, H D)`` and ``out_bias``.
+    Query heads pair up (``2 i``, ``2 i + 1``), key heads too, the values
+    are ``Hkv / 2`` heads of ``2 D``, and query pair ``i`` reads key/value
+    pair ``i // 2``::
+
+        O_i = softmax(Q1_i K1^T / sqrt(D)) V
+              - lam softmax(Q2_i K2^T / sqrt(D)) V
+        lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init
+        O_i = RMS(O_i; subln_gamma) (1 - lambda_init)
+
+    ONE flash call a layer: the query heads are ordered so that head ``n``
+    reads key head ``n // 2`` and each value head serves both key heads of
+    its pair; ``lam``, the difference and the norm are elementwise after
+    it.  Everything before the output projection is under scope
+    ``mx_attn_cross`` (``cross``), ``mx_attn_window`` (a ``window``) or
+    ``mx_attn_full``.  ``shares``: the form returns ``(out, {"shared_k":
+    k, "shared_v": v})``.  ``kept``: the caller is a layer under
+    :func:`_layer_keeps`."""
+    import jax
+    import jax.numpy as jnp
+
+    from ...ops.contrib import _flash_attention_op
     from ...ops.nn import _rms_norm
-    x = x + attention(_rms_norm(x, p["norm1_gamma"], eps=eps), p)
-    return x + ffn(_rms_norm(x, p["norm2_gamma"], eps=eps), p)
+    from ...telemetry import phases
+
+    b, t, _u = h.shape
+    pairs = num_kv_heads // 2
+    proj = lambda w: jnp.einsum("btu,ou->bto", h, p[w + "_weight"]) \
+        + p[w + "_bias"]
+    scope = phases.ATTN_CROSS_SCOPE if cross else \
+        phases.ATTN_FULL_SCOPE if window is None else phases.ATTN_WINDOW_SCOPE
+    with jax.named_scope(scope):
+        if cross:
+            q, k, v = proj("q"), p["shared_k"], p["shared_v"]
+        else:
+            q, k, v = jnp.split(proj("qkv"), (
+                num_heads * p["lambda_q1_weight"].shape[0],
+                (num_heads + num_kv_heads) * p["lambda_q1_weight"].shape[0]),
+                -1)
+            k, v = (a.reshape(b, t, num_kv_heads, -1) for a in (k, v))
+        d = k.shape[-1]
+        # query head 4 j + 2 pair + map  ->  2 (2 j + map) + pair
+        q = jnp.swapaxes(q.reshape(b, t, pairs, 2, 2, d), 3, 4).reshape(
+            b, t, num_heads, d)
+        o = _flash_attention_op(
+            q, k, jnp.repeat(v.reshape(b, t, pairs, 2 * d), 2, axis=2),
+            causal=True, window=window, kept=kept)
+        o = o.reshape(b, t, pairs, 2, 2, 2 * d).astype(jnp.float32)
+        f32 = lambda n: p["lambda_%s_weight" % n].astype(jnp.float32)
+        lam = jnp.exp(jnp.sum(f32("q1") * f32("k1"))) \
+            - jnp.exp(jnp.sum(f32("q2") * f32("k2"))) + lambda_init
+        o = _rms_norm(o[:, :, :, 0] - lam * o[:, :, :, 1], p["subln_gamma"],
+                      eps=eps) * (1.0 - lambda_init)
+    out = jnp.einsum("bto,uo->btu", o.astype(h.dtype).reshape(b, t, -1),
+                     p["out_weight"]) + p["out_bias"]
+    return (out, {"shared_k": k, "shared_v": v}) if shares else out
 
 
-def _trunk(params, tokens, layers, *, eps, block_diffusion=False):
+def _norm(x, p, which, eps, layer_norm):
+    """``x`` under the gain ``which + "_gamma"``: RMSNorm, or LayerNorm
+    with the bias ``which + "_beta"`` under ``layer_norm``."""
+    from ...ops.nn import _layer_norm, _rms_norm
+    if layer_norm:
+        return _layer_norm(x, p[which + "_gamma"], p[which + "_beta"],
+                           eps=eps)
+    return _rms_norm(x, p[which + "_gamma"], eps=eps)
+
+
+def _layer(x, p, attention, ffn, eps, layer_norm=False):
+    """One pre-norm layer, ``x + Attn(N(x))`` then ``x + FFN(N(x))``,
+    ``N`` RMSNorm (LayerNorm under ``layer_norm``): ``attention`` and
+    ``ffn`` are forms, ``(h, p) -> (B, T, U)``; an attention form may
+    return ``(out, wrote)`` instead, ``wrote`` a dict of tensors later
+    layers read (:func:`_trunk`).  Returns ``(x, wrote)``."""
+    out = attention(_norm(x, p, "norm1", eps, layer_norm), p)
+    out, wrote = out if isinstance(out, tuple) else (out, {})
+    x = x + out
+    return x + ffn(_norm(x, p, "norm2", eps, layer_norm), p), wrote
+
+
+def _trunk(params, tokens, layers, *, eps, block_diffusion=False,
+           layer_norm=False):
     """The expert LMs' one trunk: the embedding's gather of ``tokens (B,
     T)`` (under ``mx_noise`` in a ``block_diffusion`` forward), the
-    ``layers`` in turn, the final norm ``norm_gamma``.  Each layer is
-    ``(prefix, names, attention, ffn)``: its leaves are ``params[prefix +
-    name]`` in the order ``names`` gives, the order they are handed to
+    ``layers`` in turn, the final norm ``norm_gamma`` (with ``norm_beta``:
+    every norm a LayerNorm under ``layer_norm``, :func:`_norm`).  Each
+    layer is ``(prefix, names, attention, ffn)`` or ``(prefix, names,
+    attention, ffn, reads)``: its leaves are ``params[prefix + name]`` in
+    the order ``names`` gives, then the tensors an earlier layer WROTE
+    under the names ``reads`` gives (a layer's attention form returns
+    ``(out, {name: tensor})`` to write them: the state a layer hands to a
+    later one that is not its neighbour, whose gradient comes back
+    through both checkpoints), the order they are handed to
     its ``jax.checkpoint`` in (:func:`_layer_keeps`: the backward pass
     keeps the state that enters a layer and runs the layer again —
     except the flash call, whose output and row statistics are kept, 67
@@ -741,11 +922,13 @@ def _trunk(params, tokens, layers, *, eps, block_diffusion=False):
     A new architecture costs one form — a function ``(h, p, **static) ->
     (B, T, U)`` of normed states and the layer's leaves, like
     :func:`grouped_query_attention`, :func:`compressed_conv_attention`,
-    :func:`latent_attention`, :func:`routed_ffn` or :func:`dense_ffn` —
-    and its leaf entry (``_gqa_leaves``, ``_LATENT_LEAVES``,
-    ``_routed_leaves``, ``_DENSE_LEAVES``): the block that takes it
-    chooses the form from its arguments and names the sizes its shapes
-    read; no new forward or block."""
+    :func:`latent_attention`, :func:`mamba_mixer`, :func:`gated_memory`,
+    :func:`differential_attention`, :func:`routed_ffn` or
+    :func:`dense_ffn` — and its leaf entry (``_gqa_leaves``,
+    ``_LATENT_LEAVES``, ``_MAMBA_LEAVES``, ``_GMU_LEAVES``,
+    ``_differential_leaves``, ``_routed_leaves``, ``_DENSE_LEAVES``): the
+    block that takes it chooses the form from its arguments and names the
+    sizes its shapes read; no new forward."""
     import collections
     import contextlib
     import functools
@@ -753,20 +936,22 @@ def _trunk(params, tokens, layers, *, eps, block_diffusion=False):
     import jax
     import jax.numpy as jnp
 
-    from ...ops.nn import _rms_norm
     from ...telemetry import phases
 
     with jax.named_scope(phases.NOISE_SCOPE) if block_diffusion \
             else contextlib.nullcontext():
         x = params["embed_weight"][tokens.astype(jnp.int32)]
-    for prefix, names, attention, ffn in layers:
+    written = {}
+    for prefix, names, attention, ffn, *reads in layers:
         p = collections.OrderedDict((n, params[prefix + n]) for n in names)
-        x = jax.checkpoint(functools.partial(
-            _layer, attention=attention, ffn=ffn, eps=eps),
-            policy=_layer_keeps())(x, p)
+        p.update((n, written[n]) for n in (reads[0] if reads else ()))
+        x, wrote = jax.checkpoint(functools.partial(
+            _layer, attention=attention, ffn=ffn, eps=eps,
+            layer_norm=layer_norm), policy=_layer_keeps())(x, p)
+        written.update(wrote)
     if block_diffusion:
         x = x[:, :tokens.shape[1] // 2]
-    return _rms_norm(x, params["norm_gamma"], eps=eps)
+    return _norm(x, params, "norm", eps, layer_norm)
 
 
 def moe_lm_forward(params, tokens, *, layer_types, num_heads, num_kv_heads,
@@ -929,7 +1114,7 @@ def latent_moe_lm_forward(params, tokens, *, mlp_layer_types, num_heads,
         hn = _rms_norm(h, front["hidden_norm_gamma"], eps=eps)
         x = jnp.einsum("btc,uc->btu", jnp.concatenate([e, hn], -1),
                        front["proj_weight"])
-        return _layer(x, p, attention, ffn[SPARSE], eps)
+        return _layer(x, p, attention, ffn[SPARSE], eps)[0]
 
     tokens = tokens.astype(jnp.int32)
     states = _trunk(params, tokens,
@@ -977,26 +1162,15 @@ def _export_expert_rows(model, tokens, top_k, held, num_routed):
     return rows
 
 
-class _ExpertLM(HybridBlock):
-    """What the sparse-expert LMs share around the one trunk
-    (:func:`_trunk`): the held experts' check, the parameters made in
-    order, the expert layer's gauges, the forward through ``invoke_fn``
-    and the head.  A subclass turns its arguments into ``_config``, the
-    leaves and the sizes their shapes read, and calls its pure forward
-    in ``_forward`` (looked up when the block runs, not when it is
-    made)."""
+class _TrunkLM(HybridBlock):
+    """What the LMs of the one trunk (:func:`_trunk`) share around it: the
+    parameters made in order, the forward through ``invoke_fn`` and the
+    head.  A subclass turns its arguments into ``_config``, the leaves and
+    the sizes their shapes read, exports its gauges in
+    ``_export_gauges`` and calls its pure forward in ``_forward`` (looked
+    up when the block runs, not when it is made)."""
 
     _tied = False
-
-    @staticmethod
-    def _held_experts(num_routed, held, top_k):
-        held = (0, num_routed) if held is None else \
-            (int(held[0]), int(held[1]))
-        if held[0] < 0 or held[1] < 1 or sum(held) > num_routed \
-                or not 1 <= top_k <= num_routed:
-            raise ValueError("held experts %r and top_k %d do not fit %d "
-                             "routed experts" % (held, top_k, num_routed))
-        return held
 
     def _make_params(self, shapes, sizes):
         """Parameters in the order of ``shapes``, ``(name, shape)`` pairs
@@ -1013,32 +1187,16 @@ class _ExpertLM(HybridBlock):
                     **held_only))
         self._export_gauges()
 
-    def _export_gauges(self):
-        """The routed layer, in gauges: the router's width, the experts
-        held here, the experts a token takes."""
+    @staticmethod
+    def _export_window(window):
         from ... import telemetry
-        c = self._config
-        experts = telemetry.gauge(
-            "mxnet_moe_experts", "experts of a layer of the newest MoELM or "
-            "LatentMoELM: the router's width (published) and the ones this "
-            "block holds (held)")
-        experts.labels(which="published").set(self._num_routed)
-        experts.labels(which="held").set(c["held"][1])
-        telemetry.gauge("mxnet_moe_top_k", "experts a token is routed to in "
-                        "the newest MoELM or LatentMoELM").set(c["top_k"])
-
-    def expected_rows(self, tokens):
-        """Rows a step of ``tokens`` tokens sends to this block's held
-        experts, a layer, in expectation (:func:`_export_expert_rows`:
-        exported with the two static shapes those rows lie in)."""
-        c = self._config
-        return _export_expert_rows(type(self).__name__, tokens, c["top_k"],
-                                   c["held"], self._num_routed)
+        telemetry.gauge("mxnet_attn_window", "keys a query of a "
+                        "sliding_attention layer of the newest MoELM or "
+                        "SambaYLM sees").set(window)
 
     def hybrid_forward(self, F, tokens, **params):
         from ...imperative import invoke_fn
         names = [n for n in params if n != "head_weight"]
-        self.expected_rows(tokens.shape[0] * tokens.shape[1])
 
         def forward(tokens_, *leaves):
             return self._forward(dict(zip(names, leaves)), tokens_)
@@ -1063,6 +1221,48 @@ class _ExpertLM(HybridBlock):
         return LinearCELoss(params=self.params,
                             head="embed_weight" if self._tied
                             else "head_weight", **kwargs)
+
+
+class _ExpertLM(_TrunkLM):
+    """A trunk LM with a routed expert layer: the held experts' check,
+    the expert layer's gauges and the rows a step sends to the held
+    experts, exported each time the block is traced at a shape."""
+
+    @staticmethod
+    def _held_experts(num_routed, held, top_k):
+        held = (0, num_routed) if held is None else \
+            (int(held[0]), int(held[1]))
+        if held[0] < 0 or held[1] < 1 or sum(held) > num_routed \
+                or not 1 <= top_k <= num_routed:
+            raise ValueError("held experts %r and top_k %d do not fit %d "
+                             "routed experts" % (held, top_k, num_routed))
+        return held
+
+    def _export_gauges(self):
+        """The routed layer, in gauges: the router's width, the experts
+        held here, the experts a token takes."""
+        from ... import telemetry
+        c = self._config
+        experts = telemetry.gauge(
+            "mxnet_moe_experts", "experts of a layer of the newest MoELM or "
+            "LatentMoELM: the router's width (published) and the ones this "
+            "block holds (held)")
+        experts.labels(which="published").set(self._num_routed)
+        experts.labels(which="held").set(c["held"][1])
+        telemetry.gauge("mxnet_moe_top_k", "experts a token is routed to in "
+                        "the newest MoELM or LatentMoELM").set(c["top_k"])
+
+    def expected_rows(self, tokens):
+        """Rows a step of ``tokens`` tokens sends to this block's held
+        experts, a layer, in expectation (:func:`_export_expert_rows`:
+        exported with the two static shapes those rows lie in)."""
+        c = self._config
+        return _export_expert_rows(type(self).__name__, tokens, c["top_k"],
+                                   c["held"], self._num_routed)
+
+    def hybrid_forward(self, F, tokens, **params):
+        self.expected_rows(tokens.shape[0] * tokens.shape[1])
+        return super().hybrid_forward(F, tokens, **params)
 
 
 class MoELM(_ExpertLM):
@@ -1225,9 +1425,7 @@ class MoELM(_ExpertLM):
             "attention (sliding_attention / full_attention)")
         for kind in (SLIDING, FULL):
             layers.labels(kind=kind).set(c["layer_types"].count(kind))
-        telemetry.gauge("mxnet_attn_window", "keys a query of a "
-                        "sliding_attention layer of the newest MoELM "
-                        "sees").set(c["window"])
+        self._export_window(c["window"])
         telemetry.gauge(
             "mxnet_diffusion_block_length", "positions of a block of the "
             "newest MoELM trained by diffusion over blocks (0: a causal "
@@ -1392,3 +1590,125 @@ class LatentMoELM(_ExpertLM):
             return super().lm_loss(**kwargs)
         return MultiTokenCELoss(mtp_weight=mtp_weight, params=self.params,
                                 **kwargs)
+
+
+MAMBA, GMU, CROSS = "mamba", "gmu", "cross_attention"
+
+
+class SambaYLM(_TrunkLM):
+    """A decoder-hybrid-decoder LM (SambaY; Ren et al., arXiv:2507.06607,
+    as Phi-4-mini-flash runs it): every layer's mixer is, by
+    ``layer_types``, a Mamba mixer (``mamba``, :func:`mamba_mixer`: a
+    state of ``state_size`` a channel over ``expand x units`` channels, a
+    causal convolution of ``conv_kernel`` taps),
+    differential attention over a ``window`` of keys (``sliding_attention``)
+    or over every key before (``full_attention``,
+    :func:`differential_attention`), a gated memory unit (``gmu``,
+    :func:`gated_memory`) or cross-attention (``cross_attention``); every
+    feed-forward part one SwiGLU of ``hidden_size`` (:func:`dense_ffn`).
+    A ``gmu`` layer reads the scan output of the last ``mamba`` layer
+    before it, a ``cross_attention`` layer the keys and values of the last
+    ``full_attention`` layer before it: those layers write them
+    (:func:`_trunk`) and their gradients come back from every reader.
+    Pre-LayerNorm with biases (``epsilon``), a final LayerNorm, no
+    positional encoding, biased attention projections of ``units //
+    num_heads`` a head, steps of rank ``ceil(units / 16)``, no experts;
+    the head is the embedding table.  ``first_layer``: the published
+    index of the first layer (``lambda_init`` of a differential layer
+    reads its own).
+
+    Input ``(B, T)`` token ids; output the final-normed states ``(B, T,
+    U)``, whose head ``lm_loss()`` fuses with the cross-entropy.  Each
+    layer is rematerialised in the backward pass (the block's own
+    property, no option)."""
+
+    _tied = True
+
+    def __init__(self, vocab_size, units=128, hidden_size=512,
+                 layer_types=(MAMBA, SLIDING, MAMBA, FULL, GMU, CROSS),
+                 num_heads=4, num_kv_heads=2, window=32, state_size=16,
+                 conv_kernel=4, expand=2, first_layer=0, epsilon=1e-5,
+                 **kwargs):
+        super().__init__(**kwargs)
+        import functools
+        import math
+        kinds = (MAMBA, SLIDING, FULL, GMU, CROSS)
+        if any(k not in kinds for k in layer_types):
+            raise ValueError("layer_types are among %r, got %r"
+                             % (kinds, list(layer_types)))
+        head_dim, dt_rank = units // num_heads, -(-units // 16)
+        if num_kv_heads % 2 or num_heads != 2 * num_kv_heads:
+            raise ValueError("differential attention pairs its heads: it "
+                             "takes an even number of key/value heads and "
+                             "twice as many query heads, got %d and %d"
+                             % (num_heads, num_kv_heads))
+        inner = expand * units
+        writes, reads = set(), []
+        for i, kind in enumerate(layer_types):
+            source = {GMU: MAMBA, CROSS: FULL}.get(kind)
+            if source is None:
+                reads.append(())
+                continue
+            j = max((j for j in range(i) if layer_types[j] == source),
+                    default=None)
+            if j is None:
+                raise ValueError("a %s layer reads a %s layer before it; "
+                                 "layer %d has none" % (kind, source, i))
+            writes.add(j)
+            reads.append(("memory",) if kind == GMU
+                         else ("shared_k", "shared_v"))
+        self._kinds = tuple(layer_types)
+        self._window = int(window)
+        self._state = int(state_size)
+        lam = lambda i: 0.8 - 0.6 * math.exp(-0.3 * (first_layer + i))
+        heads = dict(num_heads=num_heads, num_kv_heads=num_kv_heads,
+                     eps=epsilon, kept=True)
+        mixers = {MAMBA: lambda i: functools.partial(
+                      mamba_mixer, memory=i in writes),
+                  SLIDING: lambda i: functools.partial(
+                      differential_attention, lambda_init=lam(i),
+                      window=self._window, **heads),
+                  FULL: lambda i: functools.partial(
+                      differential_attention, lambda_init=lam(i),
+                      shares=i in writes, **heads),
+                  GMU: lambda i: gated_memory,
+                  CROSS: lambda i: functools.partial(
+                      differential_attention, lambda_init=lam(i),
+                      cross=True, **heads)}
+        leaves = {MAMBA: _MAMBA_LEAVES, SLIDING: _differential_leaves(),
+                  FULL: _differential_leaves(), GMU: _GMU_LEAVES,
+                  CROSS: _differential_leaves(cross=True)}
+        sizes = dict(
+            units=units, inner=inner, inner2=2 * inner, conv=conv_kernel,
+            dbc=dt_rank + 2 * state_size, dt_rank=dt_rank,
+            state=state_size, head=head_dim, pair=2 * head_dim,
+            queries=num_heads * head_dim,
+            qkv=(num_heads + 2 * num_kv_heads) * head_dim,
+            dense=hidden_size)
+        shapes, self._layers = [("embed_weight", (vocab_size, units))], []
+        for i, kind in enumerate(layer_types):
+            layer = _layer_leaves(leaves[kind], _DENSE_LEAVES,
+                                  layer_norm=True)
+            shapes += [("l%d_%s" % (i, n), shape) for n, shape in layer]
+            self._layers.append(("l%d_" % i, [n for n, _ in layer],
+                                 mixers[kind](i), dense_ffn, reads[i]))
+        shapes += [("norm_gamma", ("units",)), ("norm_beta", ("units",))]
+        self._eps = epsilon
+        self._make_params(shapes, sizes)
+
+    def _forward(self, params, tokens):
+        return _trunk(params, tokens, self._layers, eps=self._eps,
+                      layer_norm=True)
+
+    def _export_gauges(self):
+        from ... import telemetry
+        layers = telemetry.gauge(
+            "mxnet_hybrid_layers", "layers of the newest SambaYLM by kind "
+            "of mixer (mamba / sliding_attention / full_attention / gmu / "
+            "cross_attention)")
+        for kind in (MAMBA, SLIDING, FULL, GMU, CROSS):
+            layers.labels(kind=kind).set(self._kinds.count(kind))
+        self._export_window(self._window)
+        telemetry.gauge("mxnet_ssm_state_size", "state values a channel "
+                        "of the newest SambaYLM's Mamba mixers carries").set(
+                            self._state)

@@ -1021,6 +1021,56 @@ def _causal_conv(data, weight, **attrs):
     return out.astype(data.dtype)
 
 
+def _ssm_eligible(channels):
+    """The selective scan's kernel path: a TPU, and channels the kernels
+    tile whole (``pallas_kernels.ssm_scan_ok``)."""
+    from . import pallas_kernels as pk
+    return pk._on_tpu() and pk.ssm_scan_ok(channels)
+
+
+def _selective_scan_plain(u, delta, a, b, c, d):
+    """The scan as a ``lax.scan`` over rows, float32: what runs off the
+    TPU (``pallas_kernels.selective_scan`` has the equations)."""
+    f32 = lambda x: x.astype(jnp.float32)
+    a = f32(a)
+
+    def row(s, xs):
+        dl, uu, bb, cc = xs
+        s = jnp.exp(dl[..., None] * a) * s + (dl * uu)[..., None] \
+            * bb[:, None, :]
+        return s, jnp.einsum("ben,bn->be", s, cc)
+
+    first = lambda x: jnp.swapaxes(f32(x), 0, 1)
+    _, y = lax.scan(row, jnp.zeros(u.shape[:1] + a.shape, jnp.float32),
+                    (first(delta), first(u), first(b), first(c)))
+    return (jnp.swapaxes(y, 0, 1) + f32(d) * f32(u)).astype(u.dtype)
+
+
+@register("_contrib_selective_scan")
+def _selective_scan(data, delta, a, b, c, d, **attrs):
+    """Mamba's selective scan over ``data``, ``delta (B, T, E)``, ``a (E,
+    N)`` (negative), ``b``, ``c (B, T, N)``, ``d (E,)``: ``s_t =
+    exp(delta_t a) * s_{t-1} + (delta_t data_t) b_t`` from ``s_0 = 0``, ``y_t
+    = s_t c_t + d data_t``, float32 inside, ``data``'s dtype out.  On the
+    TPU the Pallas kernel pair (``pallas_kernels.selective_scan``: the
+    state in VMEM, never in HBM), elsewhere a ``lax.scan`` over rows; the
+    path taken is counted in ``mxnet_ssm_scan_calls_total{path}`` (trace
+    time)."""
+    from .. import telemetry
+    kernel = _ssm_eligible(data.shape[-1])
+    if telemetry.enabled():
+        telemetry.counter(
+            "mxnet_ssm_scan_calls_total",
+            "selective-scan (F.contrib.selective_scan) instantiations by "
+            "path: kernel (the Pallas pair) or plain (a lax.scan over "
+            "rows); trace time").labels(
+                path="kernel" if kernel else "plain").inc()
+    if kernel:
+        from .pallas_kernels import selective_scan
+        return selective_scan(data, delta, a, b, c, d)
+    return _selective_scan_plain(data, delta, a, b, c, d)
+
+
 def _head_kernel_eligible(rows, units, dtype):
     """The fused head's kernel path: a TPU, and rows and units the
     kernels tile whole (``pallas_kernels.head_ce_ok``)."""

@@ -1173,6 +1173,294 @@ flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 # ---------------------------------------------------------------------------
+# Selective scan (Mamba's state-space recurrence)
+# ---------------------------------------------------------------------------
+# ``s_t = exp(delta_t A) * s_{t-1} + (delta_t u_t) B_t``, ``y_t = s_t C_t +
+# D u_t`` with a state of N values for each of E channels.  A grid step owns
+# (a sequence, a block of channels, a chunk of rows); the chunk axis is the
+# grid's sequential one, and the (N, channels) float32 state rides it in
+# VMEM scratch, N on the sublanes and the channels on the lanes, stepped once
+# a row.  B_t and C_t come in transposed, (N, rows): a row's column is
+# picked out of the chunk's lane-dense block by its lane index.  The forward
+# writes ``y`` and the state ENTERING every chunk; the backward visits the
+# chunks last first, runs a chunk's rows again from that state (holding the
+# state before every row in VMEM), then walks them back with the state's
+# adjoint carried in scratch across chunks.  The state is never written to
+# HBM a row: T x E x N float32 is 2.7 GB at 8192 x 5120 x 16.
+SSM_CHUNK_ROWS = 128      # rows a grid step carries the state through
+_SSM_SUB = 16             # rows a loop iteration loads (a bf16 tile)
+_SSM_CHANNELS = {"fwd": 512, "bwd": 256}   # channels a grid step holds
+_SSM_SEMANTICS = ("parallel", "parallel", "arbitrary")
+# the forward kernel's results by the names a checkpoint policy keeps them
+# under: the output and the states entering the chunks
+SSM_KEPT = ("ssm_out", "ssm_states")
+
+
+def ssm_blocks(t, e, kernel):
+    """``(rows a chunk, padded rows, channels a block)`` of a scan over
+    ``t`` rows and ``e`` channels: chunks of ``SSM_CHUNK_ROWS`` (the whole
+    sequence in whole sub-blocks where it is shorter), channel blocks of
+    whole lane tiles where ``e`` has them, else every channel."""
+    tc = SSM_CHUNK_ROWS if t >= SSM_CHUNK_ROWS \
+        else -(-t // _SSM_SUB) * _SSM_SUB
+    eb = next((c for c in (_SSM_CHANNELS[kernel], 256, LANES)
+               if c <= _SSM_CHANNELS[kernel] and e % c == 0), e)
+    return tc, -(-t // tc) * tc, eb
+
+
+def ssm_scan_ok(e):
+    """Whether the scan kernels tile ``e`` channels natively: whole lane
+    tiles (interpret mode takes any)."""
+    return e % LANES == 0
+
+
+def _ssm_column(block, lane, t):
+    """``block (N, rows)``'s column ``t`` as ``(N, 1)``."""
+    return jnp.sum(jnp.where(lane == t, block, 0.0), axis=1, keepdims=True)
+
+
+def _ssm_fwd_kernel(u_ref, d_ref, a_ref, b_ref, c_ref, dd_ref, y_ref, s0_ref,
+                    s_ref, *, tc):
+    @pl.when(pl.program_id(2) == 0)
+    def _first_chunk():
+        s_ref[:] = jnp.zeros_like(s_ref)
+
+    s0_ref[:] = s_ref[:]
+    a, dd = a_ref[:], dd_ref[:]
+    bt, ct = b_ref[:].astype(jnp.float32), c_ref[:].astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, bt.shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (_SSM_SUB, a.shape[1]), 0)
+
+    def rows(i, s):
+        r0 = pl.multiple_of(i * _SSM_SUB, _SSM_SUB)
+        dl = d_ref[pl.ds(r0, _SSM_SUB), :].astype(jnp.float32)
+        uu = u_ref[pl.ds(r0, _SSM_SUB), :].astype(jnp.float32)
+        du = dl * uu
+        ys = jnp.zeros(row.shape, jnp.float32)
+        for r in range(_SSM_SUB):
+            s = jnp.exp(dl[r:r + 1] * a) * s \
+                + du[r:r + 1] * _ssm_column(bt, lane, r0 + r)
+            ys = jnp.where(row == r, jnp.sum(
+                s * _ssm_column(ct, lane, r0 + r), axis=0, keepdims=True), ys)
+        y_ref[pl.ds(r0, _SSM_SUB), :] = (ys + dd * uu).astype(y_ref.dtype)
+        return s
+
+    s_ref[:] = jax.lax.fori_loop(0, tc // _SSM_SUB, rows, s_ref[:])
+
+
+def _ssm_bwd_kernel(u_ref, d_ref, a_ref, b_ref, c_ref, dd_ref, s0_ref, dy_ref,
+                    du_ref, ddl_ref, db_ref, dc_ref, da_ref, dD_ref, p_ref,
+                    g_ref, *, tc):
+    @pl.when(pl.program_id(2) == 0)
+    def _last_chunk():
+        g_ref[:] = jnp.zeros_like(g_ref)
+        da_ref[:] = jnp.zeros_like(da_ref)
+        dD_ref[:] = jnp.zeros_like(dD_ref)
+
+    a, dd = a_ref[:], dd_ref[:]
+    bt, ct = b_ref[:].astype(jnp.float32), c_ref[:].astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, bt.shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (_SSM_SUB, a.shape[1]), 0)
+    nsub = tc // _SSM_SUB
+
+    def load(r0):
+        return (d_ref[pl.ds(r0, _SSM_SUB), :].astype(jnp.float32),
+                u_ref[pl.ds(r0, _SSM_SUB), :].astype(jnp.float32))
+
+    def again(i, s):
+        # the chunk's rows run again: the state BEFORE each row is held
+        r0 = pl.multiple_of(i * _SSM_SUB, _SSM_SUB)
+        dl, uu = load(r0)
+        du = dl * uu
+        for r in range(_SSM_SUB):
+            p_ref[r0 + r] = s
+            s = jnp.exp(dl[r:r + 1] * a) * s \
+                + du[r:r + 1] * _ssm_column(bt, lane, r0 + r)
+        return s
+
+    jax.lax.fori_loop(0, nsub, again, s0_ref[:])
+
+    def back(j, carry):
+        g, da, dbt, dct = carry
+        r0 = pl.multiple_of((nsub - 1 - j) * _SSM_SUB, _SSM_SUB)
+        dl, uu = load(r0)
+        dy = dy_ref[pl.ds(r0, _SSM_SUB), :].astype(jnp.float32)
+        du = dl * uu
+        gu = jnp.zeros(row.shape, jnp.float32)
+        gd = jnp.zeros(row.shape, jnp.float32)
+        for r in reversed(range(_SSM_SUB)):
+            t = r0 + r
+            bcol = _ssm_column(bt, lane, t)
+            sp = p_ref[t]
+            decay = jnp.exp(dl[r:r + 1] * a)
+            s = decay * sp + du[r:r + 1] * bcol
+            g = g + _ssm_column(ct, lane, t) * dy[r:r + 1]
+            dct = jnp.where(lane == t, jnp.sum(s * dy[r:r + 1], axis=1,
+                                               keepdims=True), dct)
+            dbt = jnp.where(lane == t, jnp.sum(g * du[r:r + 1], axis=1,
+                                               keepdims=True), dbt)
+            gb = jnp.sum(g * bcol, axis=0, keepdims=True)
+            w = g * decay * sp
+            da = da + w * dl[r:r + 1]
+            gu = jnp.where(row == r, gb * dl[r:r + 1], gu)
+            gd = jnp.where(row == r, jnp.sum(w * a, axis=0, keepdims=True)
+                           + uu[r:r + 1] * gb, gd)
+            g = decay * g
+        du_ref[pl.ds(r0, _SSM_SUB), :] = (gu + dd * dy).astype(du_ref.dtype)
+        ddl_ref[pl.ds(r0, _SSM_SUB), :] = gd.astype(ddl_ref.dtype)
+        dD_ref[:] += dy * uu
+        return g, da, dbt, dct
+
+    g, da, dbt, dct = jax.lax.fori_loop(
+        0, nsub, back, (g_ref[:], jnp.zeros(a.shape, jnp.float32),
+                        jnp.zeros(bt.shape, jnp.float32),
+                        jnp.zeros(bt.shape, jnp.float32)))
+    g_ref[:] = g
+    da_ref[:] += da
+    db_ref[:] = dbt
+    dc_ref[:] = dct
+
+
+def ssm_scan_plan(bs, t, e, n, kernel, dtypes=(jnp.bfloat16, jnp.float32)):
+    """Plan of one scan kernel over ``bs`` sequences of ``t`` (padded)
+    rows, ``e`` channels and a state of ``n``: forward ``(u, delta, A (N,
+    E), B^T, C^T (bs, N, T), D (1, E)) -> (y, the states entering the
+    chunks (bs, chunks, N, E))``; backward the same operands, the states
+    and ``dy`` -> ``(du, ddelta, dB^T, dC^T (bs, channel blocks, N, T),
+    dA (bs, N, E), dD (bs, sub-block rows, E))``, the chunks visited last
+    first.  ``dtypes``: ``u``'s and ``delta``'s (``y`` and ``du`` are
+    ``u``'s, ``ddelta`` is ``delta``'s)."""
+    tc, tp, eb = ssm_blocks(t, e, kernel)
+    nc, ne = tp // tc, e // eb
+    rev = (lambda c: c) if kernel == "fwd" else (lambda c: nc - 1 - c)
+    rows = pl.BlockSpec((None, tc, eb), lambda b, j, c: (b, rev(c), j))
+    chan = pl.BlockSpec((n, eb), lambda b, j, c: (0, j))
+    cols = pl.BlockSpec((None, n, tc), lambda b, j, c: (b, 0, rev(c)))
+    state = pl.BlockSpec((None, None, n, eb),
+                         lambda b, j, c: (b, rev(c), 0, j))
+    ins = [rows, rows, chan, cols, cols,
+           pl.BlockSpec((1, eb), lambda b, j, c: (0, j))]
+    plan = {"grid": (bs, ne, nc), "chunk": tc, "channels": eb,
+            "in_specs": ins, "scratch": [(n, eb)]}
+    if kernel == "fwd":
+        plan.update(out_specs=[rows, state],
+                    out_shapes=[(bs, tp, e), (bs, nc, n, e)],
+                    dtypes=[dtypes[0], jnp.float32])
+        return plan
+    plan.update(
+        in_specs=ins + [state, rows],
+        out_specs=[rows, rows,
+                   pl.BlockSpec((None, None, n, tc),
+                                lambda b, j, c: (b, j, 0, rev(c))),
+                   pl.BlockSpec((None, None, n, tc),
+                                lambda b, j, c: (b, j, 0, rev(c))),
+                   pl.BlockSpec((None, n, eb), lambda b, j, c: (b, 0, j)),
+                   pl.BlockSpec((None, _SSM_SUB, eb),
+                                lambda b, j, c: (b, 0, j))],
+        out_shapes=[(bs, tp, e), (bs, tp, e), (bs, ne, n, tp),
+                    (bs, ne, n, tp), (bs, n, e), (bs, _SSM_SUB, e)],
+        dtypes=list(dtypes) + [jnp.float32] * 4,
+        scratch=[(tc, n, eb), (n, eb)])
+    return plan
+
+
+def _ssm_call(kernel, bs, t, e, n, *operands):
+    plan = ssm_scan_plan(bs, t, e, n, kernel,
+                         (operands[0].dtype, operands[1].dtype))
+    body = _ssm_fwd_kernel if kernel == "fwd" else _ssm_bwd_kernel
+    _export_ssm_gauges(plan)
+    return pl.pallas_call(
+        functools.partial(body, tc=plan["chunk"]),
+        grid=plan["grid"],
+        in_specs=plan["in_specs"],
+        out_specs=plan["out_specs"],
+        out_shape=[jax.ShapeDtypeStruct(s, d) for s, d in zip(
+            plan["out_shapes"], plan["dtypes"])],
+        scratch_shapes=[pltpu.VMEM(s, jnp.float32) for s in plan["scratch"]],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_SSM_SEMANTICS),
+        name=body.__name__,
+        interpret=_interpret(),
+    )(*operands)
+
+
+def _export_ssm_gauges(plan):
+    from .. import telemetry
+    if telemetry.enabled():
+        telemetry.gauge(
+            "mxnet_ssm_scan_chunk_rows",
+            "rows a grid step of the newest selective-scan kernel carries "
+            "the state through (the chunk)").set(plan["chunk"])
+
+
+def _ssm_operands(u, delta, a, b, c, d):
+    """The kernels' operands: rows padded to whole chunks (a padded row
+    has ``delta = 0``: it leaves the state as it is), ``A`` and the
+    columns transposed, ``D`` a row."""
+    t = u.shape[1]
+    tp = ssm_blocks(t, u.shape[2], "fwd")[1]
+    pad = lambda x: jnp.pad(x, ((0, 0), (0, tp - t), (0, 0)))
+    cols = lambda x: jnp.swapaxes(pad(x.astype(jnp.float32)), 1, 2)
+    return (pad(u), pad(delta),
+            jnp.transpose(a.astype(jnp.float32)), cols(b), cols(c),
+            d.astype(jnp.float32).reshape(1, -1))
+
+
+def _ssm_fwd(u, delta, a, b, c, d):
+    _count("ssm_scan_fwd")
+    ops = _ssm_operands(u, delta, a, b, c, d)
+    bs, tp, e = ops[0].shape
+    y, states = _ssm_call("fwd", bs, tp, e, a.shape[1], *ops)
+    return y[:, :u.shape[1]], states
+
+
+@jax.custom_vjp
+def selective_scan(u, delta, a, b, c, d):
+    """Mamba's selective scan (Gu & Dao, arXiv:2312.00752, Algorithm 2)
+    over ``u``, ``delta (Bs, T, E)`` with ``a (E, N)`` (``-exp(A_log)``),
+    ``b``, ``c (Bs, T, N)`` and ``d (E,)``::
+
+        s_t = exp(delta_t A) * s_{t-1} + (delta_t u_t) B_t     s_0 = 0
+        y_t = s_t C_t + D u_t
+
+    every step in float32 (``u`` and ``delta`` are read in their own
+    dtypes); ``y (Bs, T, E)`` in ``u``'s dtype.  The state is
+    carried in VMEM (the section's header); the backward returns every
+    operand's gradient, ``B``'s and ``C``'s summed over the channel blocks
+    and ``A``'s and ``D``'s over the sequences and chunks.  The forward's
+    two results carry the names ``SSM_KEPT``: a layer whose checkpoint
+    keeps them runs no forward kernel again in the backward pass."""
+    return _ssm_fwd(u, delta, a, b, c, d)[0]
+
+
+def _ssm_fwd_rule(u, delta, a, b, c, d):
+    y, states = _ssm_fwd(u, delta, a, b, c, d)
+    y = checkpoint_name(y, SSM_KEPT[0])
+    states = checkpoint_name(states, SSM_KEPT[1])
+    return y, (u, delta, a, b, c, d, states)
+
+
+def _ssm_bwd_rule(res, dy):
+    _count("ssm_scan_bwd")
+    u, delta, a, b, c, d, states = res
+    t = u.shape[1]
+    ops = _ssm_operands(u, delta, a, b, c, d)
+    bs, tp, e = ops[0].shape
+    dyp = jnp.pad(dy.astype(u.dtype), ((0, 0), (0, tp - t), (0, 0)))
+    du, ddl, dbt, dct, da, dD = _ssm_call(
+        "bwd", bs, tp, e, a.shape[1], *ops, states, dyp)
+    cols = lambda x, like: jnp.swapaxes(jnp.sum(x, axis=1), 1, 2)[
+        :, :t].astype(like.dtype)
+    return (du[:, :t], ddl[:, :t],
+            jnp.transpose(jnp.sum(da, axis=0)).astype(a.dtype),
+            cols(dbt, b), cols(dct, c),
+            jnp.sum(dD, axis=(0, 1)).astype(d.dtype))
+
+
+selective_scan.defvjp(_ssm_fwd_rule, _ssm_bwd_rule)
+
+
+# ---------------------------------------------------------------------------
 # Grouped matrix product (sparse experts)
 # ---------------------------------------------------------------------------
 # ``P`` rows sorted by group, every group's rows starting at a multiple of

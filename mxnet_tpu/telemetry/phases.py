@@ -25,16 +25,21 @@ __all__ = ["FWD_SCOPE", "LOSS_SCOPE", "UPDATE_SCOPE", "CODEC_SCOPE",
            "COLLECTIVE_PREFIX", "LOOP_SCOPE", "EXIT_SCOPE", "MOE_SCOPE",
            "MOE_EXPERTS_SCOPE", "ATTN_WINDOW_SCOPE", "ATTN_FULL_SCOPE",
            "ATTN_LATENT_SCOPE", "SHARED_EXPERT_SCOPE", "MTP_SCOPE",
-           "ATTN_BLOCKDIFF_SCOPE", "NOISE_SCOPE", "CCA_SCOPE", "FWD", "BWD",
+           "ATTN_BLOCKDIFF_SCOPE", "NOISE_SCOPE", "CCA_SCOPE", "SSM_SCOPE",
+           "GMU_SCOPE", "ATTN_CROSS_SCOPE", "FWD", "BWD",
            "UPDATE", "COLLECTIVE", "CONTROL", "OTHER", "LOOP",
            "EXIT", "ROUTE", "EXPERTS", "ATTN_WINDOW", "ATTN_FULL",
            "ATTN_LATENT", "SHARED_EXPERT", "ATTN_BLOCKDIFF", "NOISE", "CCA",
-           "phase_of", "instruction_phases", "loop_part_of",
+           "SSM", "GMU", "ATTN_CROSS",
+           "LOOP_PARTS", "BLOCK_PARTS", "LATENT_PARTS", "DIFFUSION_PARTS",
+           "CCA_PARTS", "HYBRID_PARTS", "phase_of", "instruction_phases",
+           "scope_part_of", "instruction_scope_parts", "loop_part_of",
            "instruction_loop_parts", "block_part_of",
            "instruction_block_parts", "latent_part_of",
            "instruction_latent_parts", "diffusion_part_of",
            "instruction_diffusion_parts", "cca_part_of",
-           "instruction_cca_parts", "register_program", "program_hlo",
+           "instruction_cca_parts", "hybrid_part_of",
+           "instruction_hybrid_parts", "register_program", "program_hlo",
            "program_names"]
 
 FWD_SCOPE, LOSS_SCOPE = "mx_fwd", "mx_loss"
@@ -84,6 +89,15 @@ ATTN_BLOCKDIFF_SCOPE, NOISE_SCOPE = "mx_attn_blockdiff", "mx_noise"
 # the QK norm with its temperature and the partial rotary.  The
 # projections are the layer's, the flash call is mx_attn_full
 CCA_SCOPE = "mx_cca"
+# inside mx_fwd, in a decoder-hybrid-decoder block
+# (gluon.contrib.transformer.SambaYLM): a whole Mamba mixer, from its
+# input projection to its output projection (transformer.mamba_mixer:
+# the causal convolution, the selective scan and the gate among them); a
+# gated memory unit (transformer.gated_memory); a cross-attention layer's
+# query projection, flash call, difference of its two maps and their
+# norm (transformer.differential_attention over the shared keys and
+# values).  Its window and full layers keep mx_attn_window / mx_attn_full
+SSM_SCOPE, GMU_SCOPE, ATTN_CROSS_SCOPE = "mx_ssm", "mx_gmu", "mx_attn_cross"
 # what jax writes into the name stack of a forward that is run again in
 # the backward pass (jax.checkpoint)
 REMAT_MARK = "rematted_computation"
@@ -95,6 +109,7 @@ ATTN_WINDOW, ATTN_FULL = "attn_window", "attn_full"
 ATTN_LATENT, SHARED_EXPERT = "attn_latent", "shared_expert"
 ATTN_BLOCKDIFF, NOISE = "attn_blockdiff", "noise"
 CCA = "cca"
+SSM, GMU, ATTN_CROSS = "ssm", "gmu", "attn_cross"
 
 
 def phase_of(op_name):
@@ -111,65 +126,68 @@ def phase_of(op_name):
     return OTHER
 
 
-def loop_part_of(op_name):
-    """``(part, recomputed)`` of an HLO ``op_name``: ``part`` is
-    :data:`EXIT`, :data:`LOOP` or None (the inner scope wins: an exit's
-    norm and gate sit inside the loop's body), ``recomputed`` whether the
-    instruction is a forward run again for the backward pass."""
+# ``(scope, part)`` tables of the per-family maps, inner scope first: the
+# first scope an op_name holds names its part (an exit's norm and gate sit
+# inside the loop's body; ``mx_moe_experts`` inside ``mx_moe``)
+LOOP_PARTS = ((EXIT_SCOPE, EXIT), (LOOP_SCOPE, LOOP))
+BLOCK_PARTS = ((MOE_EXPERTS_SCOPE, EXPERTS), (MOE_SCOPE, ROUTE),
+               (ATTN_WINDOW_SCOPE, ATTN_WINDOW), (ATTN_FULL_SCOPE, ATTN_FULL))
+LATENT_PARTS = ((ATTN_LATENT_SCOPE, ATTN_LATENT),
+                (SHARED_EXPERT_SCOPE, SHARED_EXPERT))
+DIFFUSION_PARTS = ((ATTN_BLOCKDIFF_SCOPE, ATTN_BLOCKDIFF),
+                   (NOISE_SCOPE, NOISE))
+CCA_PARTS = ((CCA_SCOPE, CCA),)
+HYBRID_PARTS = ((SSM_SCOPE, SSM), (GMU_SCOPE, GMU),
+                (ATTN_CROSS_SCOPE, ATTN_CROSS))
+
+
+def scope_part_of(op_name, scopes, flag=REMAT_MARK):
+    """``(part, flagged)`` of an HLO ``op_name``: ``part`` that of the
+    first ``(scope, part)`` of ``scopes`` whose scope the name holds, else
+    None; ``flagged`` whether it holds ``flag`` — by default whether the
+    instruction is a forward run again for the backward pass
+    (``jax.checkpoint``)."""
     if not op_name:
         return None, False
-    part = EXIT if EXIT_SCOPE in op_name else \
-        LOOP if LOOP_SCOPE in op_name else None
-    return part, REMAT_MARK in op_name
+    part = next((p for scope, p in scopes if scope in op_name), None)
+    return part, flag in op_name
+
+
+def loop_part_of(op_name):
+    """:func:`scope_part_of` under :data:`LOOP_PARTS`: a looped stack's
+    ``exit`` or ``loop``."""
+    return scope_part_of(op_name, LOOP_PARTS)
 
 
 def block_part_of(op_name):
-    """``(part, recomputed)`` of an HLO ``op_name`` in a block of expert
-    layers under two kinds of attention: ``part`` is :data:`EXPERTS`
-    (scope ``mx_moe_experts``: the inner scope wins), :data:`ROUTE`
-    (``mx_moe`` outside it), :data:`ATTN_WINDOW`, :data:`ATTN_FULL` or
-    None; ``recomputed`` as in :func:`loop_part_of`."""
-    if not op_name:
-        return None, False
-    part = EXPERTS if MOE_EXPERTS_SCOPE in op_name else \
-        ROUTE if MOE_SCOPE in op_name else \
-        ATTN_WINDOW if ATTN_WINDOW_SCOPE in op_name else \
-        ATTN_FULL if ATTN_FULL_SCOPE in op_name else None
-    return part, REMAT_MARK in op_name
+    """:func:`scope_part_of` under :data:`BLOCK_PARTS`: an expert layer's
+    ``experts`` or ``route``, or ``attn_window`` / ``attn_full``."""
+    return scope_part_of(op_name, BLOCK_PARTS)
 
 
 def latent_part_of(op_name):
-    """``(part, in_mtp)`` of an HLO ``op_name`` in a block of latent-
-    attention layers: ``part`` is :data:`ATTN_LATENT` (scope
-    ``mx_attn_latent``), :data:`SHARED_EXPERT` (``mx_shared_expert``) or
-    None; ``in_mtp`` whether the instruction belongs to a multi-token-
-    prediction module (``mx_mtp``), whatever its part."""
-    if not op_name:
-        return None, False
-    part = ATTN_LATENT if ATTN_LATENT_SCOPE in op_name else \
-        SHARED_EXPERT if SHARED_EXPERT_SCOPE in op_name else None
-    return part, MTP_SCOPE in op_name
+    """``(part, in_mtp)``: :func:`scope_part_of` under
+    :data:`LATENT_PARTS` (``attn_latent``, ``shared_expert``), flagged
+    where a multi-token-prediction module (``mx_mtp``) runs the
+    instruction, whatever its part."""
+    return scope_part_of(op_name, LATENT_PARTS, MTP_SCOPE)
 
 
 def diffusion_part_of(op_name):
-    """``(part, recomputed)`` of an HLO ``op_name`` in a block trained by
-    diffusion over blocks: ``part`` is :data:`ATTN_BLOCKDIFF` (scope
-    ``mx_attn_blockdiff``), :data:`NOISE` (``mx_noise``) or None;
-    ``recomputed`` as in :func:`loop_part_of`."""
-    if not op_name:
-        return None, False
-    part = ATTN_BLOCKDIFF if ATTN_BLOCKDIFF_SCOPE in op_name else \
-        NOISE if NOISE_SCOPE in op_name else None
-    return part, REMAT_MARK in op_name
+    """:func:`scope_part_of` under :data:`DIFFUSION_PARTS`:
+    ``attn_blockdiff`` or ``noise``."""
+    return scope_part_of(op_name, DIFFUSION_PARTS)
 
 
 def cca_part_of(op_name):
-    """``(part, recomputed)`` of an HLO ``op_name`` in a block of
-    compressed convolutional attention: ``part`` is :data:`CCA` (scope
-    ``mx_cca``) or None; ``recomputed`` as in :func:`loop_part_of`."""
-    if not op_name:
-        return None, False
-    return (CCA if CCA_SCOPE in op_name else None), REMAT_MARK in op_name
+    """:func:`scope_part_of` under :data:`CCA_PARTS`: ``cca``."""
+    return scope_part_of(op_name, CCA_PARTS)
+
+
+def hybrid_part_of(op_name):
+    """:func:`scope_part_of` under :data:`HYBRID_PARTS`: a Mamba mixer's
+    ``ssm``, a gated memory unit's ``gmu`` or ``attn_cross``."""
+    return scope_part_of(op_name, HYBRID_PARTS)
 
 
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%([\w.\-]+) = (.*)$")
@@ -275,49 +293,52 @@ def instruction_phases(hlo_text):
     return _classify(hlo_text, phase_of, OTHER, CONTROL)
 
 
+def instruction_scope_parts(hlo_text, scopes, flag=REMAT_MARK):
+    """``{instruction name: (part, flagged)}`` beside
+    :func:`instruction_phases`, each named instruction classed by
+    :func:`scope_part_of` under ``scopes`` and ``flag``; the same
+    inheritance."""
+    return _classify(hlo_text,
+                     lambda op_name: scope_part_of(op_name, scopes, flag),
+                     (None, False), (None, False))
+
+
 def instruction_loop_parts(hlo_text):
-    """``{instruction name: (part, recomputed)}`` beside
-    :func:`instruction_phases`, for a program that applies one stack of
-    layers several times: ``part`` is ``loop``, ``exit`` or None,
-    ``recomputed`` whether the instruction is a forward run again in the
-    backward pass (:func:`loop_part_of`); the same inheritance."""
-    return _classify(hlo_text, loop_part_of, (None, False), (None, False))
+    """:func:`instruction_scope_parts` under :data:`LOOP_PARTS`, for a
+    program that applies one stack of layers several times."""
+    return instruction_scope_parts(hlo_text, LOOP_PARTS)
 
 
 def instruction_block_parts(hlo_text):
-    """``{instruction name: (part, recomputed)}`` beside
-    :func:`instruction_loop_parts`, for a program whose layers route
-    tokens to experts under window and full attention: ``part`` is
-    ``route``, ``experts``, ``attn_window``, ``attn_full`` or None
-    (:func:`block_part_of`); the same inheritance."""
-    return _classify(hlo_text, block_part_of, (None, False), (None, False))
+    """:func:`instruction_scope_parts` under :data:`BLOCK_PARTS`, for a
+    program whose layers route tokens to experts under window and full
+    attention."""
+    return instruction_scope_parts(hlo_text, BLOCK_PARTS)
 
 
 def instruction_latent_parts(hlo_text):
-    """``{instruction name: (part, in_mtp)}`` beside
-    :func:`instruction_block_parts`, for a program of latent-attention
-    layers with a shared expert and prediction modules: ``part`` is
-    ``attn_latent``, ``shared_expert`` or None, ``in_mtp`` whether a
-    prediction module runs it (:func:`latent_part_of`); the same
-    inheritance."""
-    return _classify(hlo_text, latent_part_of, (None, False), (None, False))
+    """:func:`instruction_scope_parts` under :data:`LATENT_PARTS`, flagged
+    by ``mx_mtp``, for a program of latent-attention layers with a shared
+    expert and prediction modules."""
+    return instruction_scope_parts(hlo_text, LATENT_PARTS, MTP_SCOPE)
 
 
 def instruction_diffusion_parts(hlo_text):
-    """``{instruction name: (part, recomputed)}`` beside
-    :func:`instruction_block_parts`, for a program trained by diffusion
-    over blocks: ``part`` is ``attn_blockdiff``, ``noise`` or None
-    (:func:`diffusion_part_of`); the same inheritance."""
-    return _classify(hlo_text, diffusion_part_of, (None, False),
-                     (None, False))
+    """:func:`instruction_scope_parts` under :data:`DIFFUSION_PARTS`, for
+    a program trained by diffusion over blocks."""
+    return instruction_scope_parts(hlo_text, DIFFUSION_PARTS)
 
 
 def instruction_cca_parts(hlo_text):
-    """``{instruction name: (part, recomputed)}`` beside
-    :func:`instruction_block_parts`, for a program of compressed
-    convolutional attention: ``part`` is ``cca`` or None
-    (:func:`cca_part_of`); the same inheritance."""
-    return _classify(hlo_text, cca_part_of, (None, False), (None, False))
+    """:func:`instruction_scope_parts` under :data:`CCA_PARTS`, for a
+    program of compressed convolutional attention."""
+    return instruction_scope_parts(hlo_text, CCA_PARTS)
+
+
+def instruction_hybrid_parts(hlo_text):
+    """:func:`instruction_scope_parts` under :data:`HYBRID_PARTS`, for a
+    program of Mamba mixers, gated memory units and cross-attention."""
+    return instruction_scope_parts(hlo_text, HYBRID_PARTS)
 
 
 # name -> [jitted fn, abstract args, context factory or None, HLO text]

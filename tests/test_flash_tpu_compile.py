@@ -396,3 +396,58 @@ def test_the_head_kernels_compile_for_the_chip(one_chip, monkeypatch, n, v,
     else:
         assert len(kernels) == 2
         assert logits == [], logits[:3]
+
+
+# the selective scan's pair at the Phi-4-mini-flash cell's width (8192
+# rows, 5120 channels, a state of 16; u in bfloat16, delta in float32)
+# and at a ragged length of bfloat16 steps
+@pytest.mark.parametrize("bs,t,e,delta_dtype", [
+    (1, 8192, 5120, "float32"), (2, 1000, 1024, "bfloat16")])
+def test_the_scan_kernels_compile_for_the_chip(one_chip, monkeypatch, bs, t,
+                                               e, delta_dtype):
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    n = 16
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+
+    def grads(u, delta, a, b, c, d):
+        y, back = jax.vjp(pk.selective_scan, u, delta, a, b, c, d)
+        return back(y)
+
+    compiled = jax.jit(grads).lower(
+        sds((bs, t, e), jnp.bfloat16), sds((bs, t, e), delta_dtype),
+        sds((e, n), jnp.float32), sds((bs, t, n), jnp.bfloat16),
+        sds((bs, t, n), jnp.bfloat16), sds((e,), jnp.float32)).compile()
+    text = compiled.as_text()
+    for kernel in ("_ssm_fwd_kernel", "_ssm_bwd_kernel"):
+        assert kernel in text
+    # the state over every row is never written: what the pair keeps in
+    # HBM beside its operands is the states entering the chunks
+    chunks = -(-t // pk.SSM_CHUNK_ROWS)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 8 * bs * t * e * 4 + 2 * 4 * bs * chunks * n * e
+
+
+# differential attention's one flash call: 40 query heads of 64 over 20
+# key heads, values of 128 (each serving both key heads of its pair), a
+# 512-key window and the full causal mask, at 8192 rows
+@pytest.mark.parametrize("window", [512, None])
+def test_the_differential_flash_call_compiles_for_the_chip(
+        one_chip, monkeypatch, window):
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                              sharding=one_chip)
+
+    def loss(q, k, v):
+        o = pk.flash_attention(q, k, v, True, None, None, None, window)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    # the chip runs with the default precision (the suite asks for float32
+    # products, which Mosaic takes from no bf16 operand)
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            sds(40, 8192, 64), sds(20, 8192, 64),
+            sds(20, 8192, 128)).compile().as_text()
+    for kernel in ("_flash_fwd_kernel", "_flash_bwd_dq_kernel",
+                   "_flash_bwd_dkv_kernel"):
+        assert kernel in text
